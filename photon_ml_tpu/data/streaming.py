@@ -51,6 +51,7 @@ Design constraints that shape this module:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Iterable
@@ -80,14 +81,6 @@ def _cpu_device():
         return jax.local_devices(backend="cpu")[0]
     except RuntimeError:
         return None
-
-
-class _nullctx:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *a):
-        return False
 
 
 @dataclasses.dataclass
@@ -366,13 +359,10 @@ def streaming_from_blocks(
             shard_mats = []
             for s in range(max(n_shards, 1)):
                 coo = X[s * per_shard:(s + 1) * per_shard].tocoo()
-                # A fresh context per entry: jax.default_device returns a
-                # single-use context manager on older jax releases.
-                ctx = (
+                with (
                     jax.default_device(cpu) if cpu is not None
-                    else _nullctx()
-                )
-                with ctx:
+                    else contextlib.nullcontext()
+                ):
                     P = build_pallas_matrix(
                         coo.row.astype(np.int64), coo.col.astype(np.int64),
                         coo.data.astype(np.float32), per_shard, d,

@@ -35,6 +35,11 @@ from photon_ml_tpu.utils.compile_cache import (
     add_compile_cache_arg,
     enable_from_args,
 )
+from photon_ml_tpu.utils.device_report import (
+    CompileClock,
+    describe_devices,
+    runtime_block,
+)
 from photon_ml_tpu.utils.logging import PhotonLogger
 from photon_ml_tpu.utils.timer import Timer
 
@@ -93,16 +98,19 @@ def run(argv: Optional[Sequence[str]] = None) -> dict:
             logger=logger,
             enabled=args.telemetry != "off",
         )
-        with tel, tel.span("run", driver="game_scoring_driver"):
-            return _run_impl(args, logger, tel)
+        with tel, tel.span(
+            "run", driver="game_scoring_driver"
+        ), CompileClock() as clock:
+            return _run_impl(args, logger, tel, clock)
 
 
-def _run_impl(args, logger, tel) -> dict:
+def _run_impl(args, logger, tel, clock) -> dict:
     timer = Timer().start()
-    enable_from_args(args, logger)
+    cache_dir = enable_from_args(args, logger)
     from photon_ml_tpu.parallel.multihost import initialize_logged
 
     initialize_logged(logger)
+    logger.info("device: %s", describe_devices())
 
     model, index_maps = load_game_model(os.path.join(args.model_dir, "models"))
     transformer = GameTransformer(model, logger=logger)
@@ -215,7 +223,13 @@ def _run_impl(args, logger, tel) -> dict:
         )
         n_rows = len(scores)
 
-    result = {"n_rows": int(n_rows), "wall_seconds": timer.stop()}
+    result = {
+        "n_rows": int(n_rows),
+        "wall_seconds": timer.stop(),
+        # GameTransformer scores on the HOST (scipy matvec + packed-table
+        # gathers); only --mean / --device-metrics touch the device.
+        "runtime": runtime_block(clock, cache_dir, "host scipy CSR"),
+    }
     tel.gauge("scored_rows").set(int(n_rows))
     tel.gauge("run_wall_seconds").set(result["wall_seconds"])
     if args.evaluator:

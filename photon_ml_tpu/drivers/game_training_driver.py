@@ -58,6 +58,12 @@ from photon_ml_tpu.utils.compile_cache import (
     enable_from_args,
     publish_cache_metrics,
 )
+from photon_ml_tpu.utils.device_report import (
+    CompileClock,
+    bytes_in_use,
+    describe_devices,
+    runtime_block,
+)
 from photon_ml_tpu.utils.logging import PhotonLogger
 from photon_ml_tpu.utils.timer import Timer
 
@@ -396,16 +402,17 @@ def run(argv: Optional[Sequence[str]] = None) -> dict:
         ), telemetry_mod.mount_ops_plane(
             tel, port=args.metrics_port,
             interval_s=args.metrics_interval_s, logger=logger,
-        ):
-            return _run_impl(args, logger, tel)
+        ), CompileClock() as clock:
+            return _run_impl(args, logger, tel, clock)
 
 
-def _run_impl(args, logger, tel) -> dict:
+def _run_impl(args, logger, tel, clock) -> dict:
     timer = Timer().start()
     cache_dir = enable_from_args(args, logger)
     from photon_ml_tpu.parallel.multihost import initialize_logged
 
     initialize_logged(logger)
+    logger.info("device: %s", describe_devices())
 
     with open(args.config) as f:
         config = json.load(f)
@@ -735,6 +742,11 @@ def _run_impl(args, logger, tel) -> dict:
         )
     if retry_stats.retries or retry_stats.failures:
         result["retry"] = retry_stats.snapshot()
+    # The training data is still placed here (the estimator holds its
+    # coordinates), so this is the per-device footprint of the fit.
+    result["runtime"] = runtime_block(
+        clock, cache_dir, estimator.feature_layouts, bytes_in_use()
+    )
     result["wall_seconds"] = timer.stop()
     with open(os.path.join(args.output_dir, "training_result.json"), "w") as f:
         json.dump(result, f, indent=2)

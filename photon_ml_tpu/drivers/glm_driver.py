@@ -59,6 +59,13 @@ from photon_ml_tpu.utils.compile_cache import (
     enable_from_args,
     publish_cache_metrics,
 )
+from photon_ml_tpu.utils.device_report import (
+    CompileClock,
+    bytes_in_use,
+    describe_devices,
+    describe_layout,
+    runtime_block,
+)
 from photon_ml_tpu.utils.logging import PhotonLogger
 from photon_ml_tpu.utils.timer import Timer
 from photon_ml_tpu.utils.tracker import OptimizationStatesTracker
@@ -445,16 +452,26 @@ def _run(args) -> dict:
         ), telemetry_mod.mount_ops_plane(
             tel, port=args.metrics_port,
             interval_s=args.metrics_interval_s, logger=logger,
-        ):
-            return _run_impl(args, logger, tel)
+        ), CompileClock() as clock:
+            return _run_impl(args, logger, tel, clock)
 
 
-def _run_impl(args, logger, tel) -> dict:
+def _run_impl(args, logger, tel, clock) -> dict:
     timer = Timer().start()
     cache_dir = enable_from_args(args, logger)
     from photon_ml_tpu.parallel.multihost import initialize_logged
 
     initialize_logged(logger)
+    logger.info("device: %s", describe_devices())
+    # What the training matrix became and what placing it left on each
+    # device — reported in the result so a run shows which kernels it
+    # exercised (the layout follows the backend and the data size).
+    placed = {"layout": None, "bytes": None}
+
+    def note_placement(features, shards: int = 1) -> None:
+        layout = describe_layout(features, shards)
+        placed.update(layout=layout, bytes=bytes_in_use())
+        logger.info("feature layout: %s", layout)
 
     # Stage 1: read ---------------------------------------------------------
     with tel.span("read", path=args.train_data):
@@ -506,6 +523,7 @@ def _run_impl(args, logger, tel) -> dict:
             summary = summarize_host(X_train)
         else:
             train_data = make_glm_data(X_train, y_train)
+            note_placement(train_data.features)
             summary = summarize(train_data)
     norm_type = NormalizationType(args.normalization)
     normalization = (
@@ -717,6 +735,9 @@ def _run_impl(args, logger, tel) -> dict:
             stream.n_chunks, stream.chunk_rows,
             stream.nbytes() / 1e6, n_shards,
         )
+        placed["layout"] = (
+            f"streamed {describe_layout(stream.chunks[0].features)}"
+        )
     elif data_parallel:
         from photon_ml_tpu.parallel.distributed import data_mesh
 
@@ -763,6 +784,7 @@ def _run_impl(args, logger, tel) -> dict:
             )
 
             dist = shard_glm_data(X_train, y_train, mesh)
+            note_placement(dist.data.features, dist.n_shards)
             return run_grid_distributed(
                 problem, dist, mesh, reg_weights, w0=w0, l1_mask=l1_mask,
                 solved=solved_now, on_solved=on_solved,
@@ -788,6 +810,7 @@ def _run_impl(args, logger, tel) -> dict:
                     "access", X_sh.shape[0], X_sh.shape[1],
                 )
             dist = shard_glm_data(X_sh, y_train, None, n_shards=n_shards)
+            note_placement(dist.data.features, n_shards)
             logger.info(
                 "solver %s: %d logical shard(s)", args.solver, n_shards
             )
@@ -941,6 +964,16 @@ def _run_impl(args, logger, tel) -> dict:
         "solver_wall_seconds": {
             str(lam): w for lam, w in sorted(grid_walls.items())
         },
+        # Final objective per solved λ (absent for checkpoint-restored
+        # points): a non-finite value here is a failed run however the
+        # validation metric looks.
+        "objective_values": {
+            str(lam): float(res.value)
+            for lam, _, res in grid if res is not None
+        },
+        "runtime": runtime_block(
+            clock, cache_dir, placed["layout"], placed["bytes"]
+        ),
     }
     if retry_stats.retries or retry_stats.failures:
         result["retry"] = retry_stats.snapshot()

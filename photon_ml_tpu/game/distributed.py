@@ -28,7 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from photon_ml_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from photon_ml_tpu.game.coordinates import (
@@ -144,6 +144,12 @@ class DistributedFixedEffectCoordinate(FixedEffectCoordinate):
                 check_vma=False,
             )
         )
+
+    @property
+    def feature_layout(self) -> str:
+        from photon_ml_tpu.utils.device_report import describe_layout
+
+        return describe_layout(self.dist.data.features, self._n_shards)
 
     def _block_offsets(self, offsets: Array) -> Array:
         total = self._n_shards * self._rows_per_shard

@@ -207,6 +207,9 @@ class GameEstimator:
         self.logger = logger
         self.mesh = mesh
         self.pipeline = bool(pipeline)
+        #: coordinate name → feature layout of the most recent fit (see
+        #: ``Coordinate.feature_layout``); the drivers report it.
+        self.feature_layouts: dict[str, str] = {}
 
     def build_coordinates(self, shards, ids, response, weight=None, offset=None):
         """Build per-coordinate datasets + coordinate objects once.  Tuning
@@ -707,6 +710,9 @@ class GameEstimator:
         the returned GameModel unchanged."""
         from photon_ml_tpu.evaluation.suite import EvaluationSuite
 
+        self.feature_layouts = {c.name: c.feature_layout for c in coordinates}
+        if self.logger is not None:
+            self.logger.info("feature layouts: %s", self.feature_layouts)
         n = len(response)
         response = np.asarray(response, np.float32)
         base_offsets = (

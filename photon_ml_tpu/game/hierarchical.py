@@ -4,7 +4,7 @@ across mesh devices.
 The bucket ladder (game/data.py) turns one random effect into a list of
 independent dense blocks; ``RandomEffectCoordinate`` runs them all on one
 device, so per-coordinate seconds stay flat no matter how many devices
-the mesh has (BENCH_r05: per_user 0.173 s vs fixed 0.119 s).  Per-entity
+the mesh has.  Per-entity
 solves are embarrassingly parallel — Snap ML's nested node/accelerator
 hierarchy (PAPERS.md) — so this module distributes the ladder itself:
 

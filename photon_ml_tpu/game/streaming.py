@@ -148,6 +148,12 @@ class StreamingFixedEffectCoordinate(Coordinate):
     def _l2(self) -> float:
         return self.config.regularization.l2_weight(1.0) * self.reg_weight
 
+    @property
+    def feature_layout(self) -> str:
+        from photon_ml_tpu.utils.device_report import describe_layout
+
+        return f"streamed {describe_layout(self.stream.chunks[0].features)}"
+
     def train(self, offsets: Array, warm_state: Optional[Array] = None):
         w0 = (
             jnp.zeros((self.stream.n_features,), jnp.float32)

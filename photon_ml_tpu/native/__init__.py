@@ -6,7 +6,10 @@ environment; the C ABI + ctypes needs no Python headers).  The build is
 cached next to the source and invalidated on source change.  Everything
 here degrades gracefully: ``load_game_decoder()`` returns None when a
 compiler is unavailable or the build fails, and callers fall back to the
-pure-Python decoders.
+pure-Python decoders.  The fallback is many times slower, so it is never
+silent: :func:`status` says, per library, whether this process built it,
+loaded a build found on disk, or fell back, and the entry points put that
+in their result JSON.
 
 Set ``PHOTON_NO_NATIVE=1`` to force the Python paths (used by parity
 tests).
@@ -26,6 +29,8 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "game_decoder.cpp")
 _LOCK = threading.Lock()
 _CACHE: dict = {}
+#: library prefix -> "built" | "loaded" | "fallback" (absent: never asked)
+_STATUS: dict = {}
 
 logger = logging.getLogger(__name__)
 
@@ -35,6 +40,7 @@ def _compile_cached(src: str, prefix: str, what: str) -> Optional[str]:
     atomic install (concurrent builders race safely), None + a warning on
     ANY failure (missing source/toolchain, compile error) — callers fall
     back to their pure-Python paths."""
+    _STATUS[prefix] = "fallback"
     try:
         with open(src, "rb") as f:
             tag = hashlib.sha256(f.read()).hexdigest()[:16]
@@ -43,6 +49,7 @@ def _compile_cached(src: str, prefix: str, what: str) -> Optional[str]:
         return None
     so_path = os.path.join(_DIR, f"{prefix}_{tag}.so")
     if os.path.exists(so_path):
+        _STATUS[prefix] = "loaded"
         return so_path
     tmp = f"{so_path}.build.{os.getpid()}"  # unique per builder: no
     # interleaved writes; the os.replace below is the atomic install
@@ -60,6 +67,7 @@ def _compile_cached(src: str, prefix: str, what: str) -> Optional[str]:
                 cmd, check=True, capture_output=True, timeout=240
             )
             os.replace(tmp, so_path)
+            _STATUS[prefix] = "built"
             return so_path
         except (OSError, subprocess.SubprocessError) as e:
             last_err = e
@@ -71,8 +79,35 @@ def _compile_cached(src: str, prefix: str, what: str) -> Optional[str]:
     return None
 
 
-def _build() -> Optional[str]:
-    return _compile_cached(_SRC, "_game_decoder", "game decoder")
+def status() -> dict:
+    """Per native library this process asked for: ``"built"`` (compiled in
+    this process), ``"loaded"`` (a build for this exact source was on
+    disk), ``"fallback"`` (the Python path is running instead — build,
+    load or ``PHOTON_NO_NATIVE``).  Libraries never asked for are absent."""
+    with _LOCK:
+        return {k.lstrip("_"): v for k, v in sorted(_STATUS.items())}
+
+
+def _load(key: str, src: str, prefix: str, what: str, bind):
+    """Build-or-find, load and bind one library (memoized under ``key``);
+    None — and a ``"fallback"`` status — on any failure or when
+    ``PHOTON_NO_NATIVE=1``."""
+    with _LOCK:
+        if os.environ.get("PHOTON_NO_NATIVE") == "1":
+            _STATUS[prefix] = "fallback"
+            return None
+        if key in _CACHE:
+            return _CACHE[key]
+        so_path = _compile_cached(src, prefix, what)
+        lib = None
+        if so_path is not None:
+            try:
+                lib = bind(ctypes.CDLL(so_path))
+            except OSError as e:
+                logger.warning("native %s load failed: %s", what, e)
+                _STATUS[prefix] = "fallback"
+        _CACHE[key] = lib
+        return lib
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -125,20 +160,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 def load_game_decoder() -> Optional[ctypes.CDLL]:
     """The bound shared library, building it if needed; None on failure or
     when ``PHOTON_NO_NATIVE=1``."""
-    if os.environ.get("PHOTON_NO_NATIVE") == "1":
-        return None
-    with _LOCK:
-        if "lib" in _CACHE:
-            return _CACHE["lib"]
-        so_path = _build()
-        lib = None
-        if so_path is not None:
-            try:
-                lib = _bind(ctypes.CDLL(so_path))
-            except OSError as e:
-                logger.warning("native game decoder load failed: %s", e)
-        _CACHE["lib"] = lib
-        return lib
+    return _load("lib", _SRC, "_game_decoder", "game decoder", _bind)
 
 
 # ---------------------------------------------------------------------------
@@ -146,10 +168,6 @@ def load_game_decoder() -> Optional[ctypes.CDLL]:
 # ---------------------------------------------------------------------------
 
 _SORT_SRC = os.path.join(_DIR, "layout_sort.cpp")
-
-
-def _build_sorter() -> Optional[str]:
-    return _compile_cached(_SORT_SRC, "_layout_sort", "layout sorter")
 
 
 def _bind_sorter(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -176,20 +194,9 @@ def load_layout_sorter() -> Optional[ctypes.CDLL]:
     """The layout-sorter library, building it if needed; None on failure
     or when ``PHOTON_NO_NATIVE=1`` (numpy fallback — bit-identical
     output, parity-tested)."""
-    if os.environ.get("PHOTON_NO_NATIVE") == "1":
-        return None
-    with _LOCK:
-        if "sorter" in _CACHE:
-            return _CACHE["sorter"]
-        so_path = _build_sorter()
-        lib = None
-        if so_path is not None:
-            try:
-                lib = _bind_sorter(ctypes.CDLL(so_path))
-            except OSError as e:
-                logger.warning("native layout sorter load failed: %s", e)
-        _CACHE["sorter"] = lib
-        return lib
+    return _load(
+        "sorter", _SORT_SRC, "_layout_sort", "layout sorter", _bind_sorter
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -197,10 +204,6 @@ def load_layout_sorter() -> Optional[ctypes.CDLL]:
 # ---------------------------------------------------------------------------
 
 _ENC_SRC = os.path.join(_DIR, "score_encoder.cpp")
-
-
-def _build_encoder() -> Optional[str]:
-    return _compile_cached(_ENC_SRC, "_score_encoder", "score encoder")
 
 
 def _bind_encoder(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -226,17 +229,7 @@ def load_score_encoder() -> Optional[ctypes.CDLL]:
     """The scoring-result encoder library, building it if needed; None on
     failure or when ``PHOTON_NO_NATIVE=1`` (pure-Python fallback —
     bit-identical output, parity-tested)."""
-    if os.environ.get("PHOTON_NO_NATIVE") == "1":
-        return None
-    with _LOCK:
-        if "encoder" in _CACHE:
-            return _CACHE["encoder"]
-        so_path = _build_encoder()
-        lib = None
-        if so_path is not None:
-            try:
-                lib = _bind_encoder(ctypes.CDLL(so_path))
-            except OSError as e:
-                logger.warning("native score encoder load failed: %s", e)
-        _CACHE["encoder"] = lib
-        return lib
+    return _load(
+        "encoder", _ENC_SRC, "_score_encoder", "score encoder",
+        _bind_encoder,
+    )

@@ -45,8 +45,11 @@ The kernel design exploits exactly that:
   sweep over column-his).  Both directions therefore run at the same rate —
   the property Spark's treeAggregate had for free and TPUs do not.
 
-Measured on one v5e chip (1M rows x 8192 features, 32 nnz/row): ~40x the
-pure-XLA COO path for the fused objective; see bench.py / ops/README.md.
+Measured on one TPU v5e chip (131,072 rows x 8192 features, 32 nnz/row,
+PR 21's probe run — PERF.md): the fused logistic value+gradient takes
+1.25 ms through these kernels and 124 ms through the XLA COO path.  The
+other timings quoted in this module's comments predate that run and are
+not measured on the current hardware.
 
 Precision: everything is f32 — bit-comparable to the COO path (only
 summation ORDER differs).  Table construction is pure selection (no
@@ -146,8 +149,18 @@ def _extract_fields(r32: np.ndarray, c32: np.ndarray, nbc: int):
 
 
 def _interpret() -> bool:
-    """Run kernels in interpreter mode (CPU tests set this env var)."""
-    return os.environ.get("PHOTON_PALLAS_INTERPRET", "") == "1"
+    """Run kernels in interpreter mode (CPU tests set this env var).
+
+    On a TPU the variable is an error, not a mode: an interpreted kernel
+    there returns right answers while Mosaic compiles nothing, which is
+    exactly what a run on the chip exists to rule out."""
+    on = os.environ.get("PHOTON_PALLAS_INTERPRET", "") == "1"
+    if on and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "PHOTON_PALLAS_INTERPRET=1 with a TPU backend: interpret mode "
+            "is for CPU tests; unset it to run the Mosaic-compiled kernels"
+        )
+    return on
 
 
 # Gather-side table build: one-hot matmul on the MXU (all-finite fast
@@ -567,13 +580,6 @@ def _tiled_apply(code, val, vec_padded, *, nbo, nbg, square, unit=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    # jax renamed pltpu.TPUCompilerParams → pltpu.CompilerParams; accept
-    # both so the kernels (and interpret-mode CPU tests) run on either
-    # side of the rename.
-    compiler_params_cls = getattr(
-        pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-    )
-
     a = code.shape[2]
     batch, chunk = _pick_rect(nbo, nbg, a, unit=unit)
     tab = vec_padded.reshape(nbg, WINS, WIN)
@@ -600,7 +606,7 @@ def _tiled_apply(code, val, vec_padded, *, nbo, nbg, square, unit=False):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((batch, WINS, WIN), lambda i, j: (i, 0, 0),
                                memory_space=pltpu.VMEM),
-        compiler_params=compiler_params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
     )(*operands)
@@ -616,9 +622,8 @@ def _tiled_apply(code, val, vec_padded, *, nbo, nbg, square, unit=False):
 class HostCoo:
     """Host-side canonical COO triples for COLD paths (stats, min/max,
     densify) — one-shot per job, so they run in numpy on the host instead of
-    keeping a full device COO copy alive (at 33M nnz that copy cost ~670 MB
-    of HBM and ~14 s of transfer over this transport for ops the hot loop
-    never touches).
+    keeping a full device COO copy alive (at 33M nnz that copy is ~400 MB
+    of HBM — 12 bytes per entry — for ops the hot loop never touches).
 
     Lives in a pytree META field, never traced, never transferred.
     Equality/hash use the (n_rows, n_cols, nnz) shape class — NOT content —

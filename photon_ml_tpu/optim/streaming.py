@@ -65,7 +65,7 @@ from photon_ml_tpu.chaos import core as chaos_mod
 from photon_ml_tpu.data.prefetch import TransferStats, run_prefetched
 from photon_ml_tpu.data.staging import COMPRESSION_MODES, plan_compression
 from photon_ml_tpu.data.streaming import StreamingGlmData
-from photon_ml_tpu.parallel.compat import shard_map
+from jax import shard_map
 from photon_ml_tpu.optim.lbfgs import (
     LBFGSConfig,
     SolveResult,

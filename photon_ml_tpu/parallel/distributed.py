@@ -32,7 +32,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from photon_ml_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from photon_ml_tpu.data.dataset import GlmData
@@ -97,8 +97,7 @@ def shard_glm_data(
     """
     import scipy.sparse as sp
 
-    from photon_ml_tpu.data.dataset import make_glm_data
-    from photon_ml_tpu.ops.sparse import from_coo
+    from photon_ml_tpu.ops.sparse import canonicalize_coo
 
     if mesh is not None:
         n_shards = mesh.devices.size
@@ -117,6 +116,18 @@ def shard_glm_data(
     weights = np.concatenate([weights, np.zeros(pad, np.float32)])
     offsets = np.concatenate([offsets, np.zeros(pad, np.float32)])
 
+    # Every stacked array is built on the HOST and placed once: with a mesh
+    # each device receives only its own row block (a jnp.stack here would
+    # materialize the whole dataset on the default device first — on a
+    # multi-chip host that is the full matrix on chip 0).
+    if mesh is not None:
+        sharding = NamedSharding(mesh, P(DATA_AXIS))
+
+        def place(x: np.ndarray) -> Array:
+            return jax.device_put(x, sharding)
+    else:
+        place = jnp.asarray
+
     if sp.issparse(data_host):
         csr = data_host.tocsr()
         csr.sum_duplicates()
@@ -129,32 +140,31 @@ def shard_glm_data(
         shards = []
         for i in range(n_shards):
             lo, hi = min(i * rows_per, n), min((i + 1) * rows_per, n)
-            block = csr[lo:hi]
-            coo = block.tocoo()
+            coo = csr[lo:hi].tocoo()
             shards.append(
-                from_coo(coo.row, coo.col, coo.data, rows_per, d, budget, dtype)
+                canonicalize_coo(coo.row, coo.col, coo.data, rows_per, d, budget)
             )
+        row_ids, col_ids, values = (np.stack(x) for x in zip(*shards))
         features = SparseMatrix(
-            row_ids=jnp.stack([s.row_ids for s in shards]),
-            col_ids=jnp.stack([s.col_ids for s in shards]),
-            values=jnp.stack([s.values for s in shards]),
+            row_ids=place(row_ids),
+            col_ids=place(col_ids),
+            values=place(values.astype(dtype)),
             n_rows=rows_per,
             n_cols=d,
         )
     else:
         dense = np.asarray(data_host, np.float32)
         dense = np.concatenate([dense, np.zeros((pad, d), np.float32)])
-        features = DenseMatrix(jnp.asarray(dense.reshape(n_shards, rows_per, d), dtype))
+        features = DenseMatrix(
+            place(dense.reshape(n_shards, rows_per, d).astype(dtype))
+        )
 
     stacked = GlmData(
         features=features,
-        labels=jnp.asarray(labels.reshape(n_shards, rows_per)),
-        weights=jnp.asarray(weights.reshape(n_shards, rows_per)),
-        offsets=jnp.asarray(offsets.reshape(n_shards, rows_per)),
+        labels=place(labels.reshape(n_shards, rows_per)),
+        weights=place(weights.reshape(n_shards, rows_per)),
+        offsets=place(offsets.reshape(n_shards, rows_per)),
     )
-    if mesh is not None:
-        sharding = NamedSharding(mesh, P(DATA_AXIS))
-        stacked = jax.tree.map(lambda x: jax.device_put(x, sharding), stacked)
     return DistributedGlmData(data=stacked, n_shards=n_shards)
 
 

@@ -35,7 +35,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from photon_ml_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

@@ -183,6 +183,9 @@ def build_arg_parser() -> argparse.ArgumentParser:
         "--membership registry and exit; the watcher drains it from "
         "the router once converged",
     )
+    from photon_ml_tpu.utils.compile_cache import add_compile_cache_arg
+
+    add_compile_cache_arg(p)
     return p
 
 
@@ -220,9 +223,16 @@ def _make_service(args):
         adaptive_wait=args.adaptive_wait,
     )
     if args.workers:
-        from photon_ml_tpu.serving.procpool import WorkerPool
+        from photon_ml_tpu.serving.procpool import (
+            WorkerPool,
+            check_device_workers,
+        )
         from photon_ml_tpu.serving.supervisor import ReplicaSupervisor
 
+        try:
+            check_device_workers(args.workers)
+        except ValueError as exc:
+            raise SystemExit(f"--workers: {exc}")
         if workload is not None:
             model, index_maps, path = (
                 workload.model, workload.index_maps, None
@@ -1426,7 +1436,25 @@ def main(argv=None) -> int:
         run_name="serving",
         sinks=None if args.output_dir else [],
     )
-    with tel:
+    from photon_ml_tpu.utils import compile_cache, device_report
+
+    with tel, device_report.CompileClock() as clock:
+        # The bucket ladder compiles at start-up; a restarted server
+        # warms it from the persistent cache.  Every program is kept
+        # (threshold 0): on a TPU v5e each bucket compiles in under the
+        # drivers' 0.5 s threshold, and time to ready is what a restart
+        # costs.
+        cache_dir = compile_cache.enable_from_args(
+            args, min_compile_secs=0.0
+        )
+        if not args.workers:
+            # Process mode leaves the chip to the worker: the parent must
+            # not initialise a JAX backend, so only in-process serving
+            # reports (and thereby claims) the device here.
+            print(
+                f"device: {json.dumps(device_report.describe_devices())}",
+                flush=True,
+            )
         service, workload = _make_service(args)
         plane_ctx = telemetry_mod.mount_ops_plane(
             tel, port=args.metrics_port,
@@ -1440,7 +1468,28 @@ def main(argv=None) -> int:
                     "(/metrics /snapshot /healthz /livez /readyz)",
                     flush=True,
                 )
-            return _run_service(args, service, workload)
+            try:
+                return _run_service(args, service, workload)
+            finally:
+                if args.output_dir and not args.workers:
+                    _write_result(args.output_dir, service, clock, cache_dir)
+
+
+def _write_result(output_dir, service, clock, cache_dir) -> None:
+    """``serving_result.json``: the final /stats plus the same runtime
+    block the drivers write — what the server ran on, what start-up
+    compiled, and whether any batch left the device path."""
+    from photon_ml_tpu.utils import device_report
+
+    result = {
+        "stats": service.stats(),
+        "runtime": device_report.runtime_block(
+            clock, cache_dir, "dense request batches",
+            device_report.bytes_in_use(),
+        ),
+    }
+    with open(os.path.join(output_dir, "serving_result.json"), "w") as f:
+        json.dump(result, f, indent=2, default=str)
 
 
 def _run_service(args, service, workload) -> int:

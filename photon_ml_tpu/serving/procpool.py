@@ -29,12 +29,19 @@ the in-process ``serving.replica`` seam — actually SIGKILLs the routed
 worker before raising, so a scripted fault exercises the real
 death-mid-batch path: EOF on the pipe, transient failure of in-flight
 rows, supervisor mark-down, decorrelated-jitter respawn.
+
+One chip belongs to one process: a worker on an accelerator platform
+opens every chip visible to it, and workers are not pinned to distinct
+chips, so a second device worker fails or hangs at start-up.
+:func:`check_device_workers` refuses that configuration before anything
+is spawned; CPU workers (``JAX_PLATFORMS=cpu``) are unlimited.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import multiprocessing
+import os
 import queue
 import socket
 import threading
@@ -55,7 +62,26 @@ from photon_ml_tpu.serving.batcher import (
 from photon_ml_tpu.serving.protocol import FrameConn
 from photon_ml_tpu.serving.runtime import RequestParser, RuntimeConfig
 
-__all__ = ["WorkerPool", "ProcessReplica"]
+__all__ = ["WorkerPool", "ProcessReplica", "check_device_workers"]
+
+
+def check_device_workers(n_workers: int) -> None:
+    """Refuse more device workers than can hold a chip.
+
+    The workers' platform is whatever ``JAX_PLATFORMS`` names first (the
+    parent must not initialise JAX to find out — it would take the chip
+    from its own worker); unset means JAX picks an accelerator when it
+    finds one, so only an explicit ``cpu`` counts as CPU workers."""
+    platform = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
+    if n_workers > 1 and platform.lower() != "cpu":
+        raise ValueError(
+            f"{n_workers} worker processes on platform "
+            f"{platform or 'unset (JAX picks an accelerator)'!r}: one chip "
+            "belongs to one process and workers are not pinned to "
+            "distinct chips, so only one device worker can start. Use "
+            "--workers 1, in-process --replicas N, or JAX_PLATFORMS=cpu "
+            "for CPU workers"
+        )
 
 
 @dataclasses.dataclass

@@ -394,6 +394,13 @@ class ScoringService:
         else:
             out["runtime"] = self.current_runtime.stats()
             out["batcher"] = self.batcher.stats()
+        if self.supervisor is None or self.supervisor.pool is None:
+            # In-process scoring: this process holds the device.  (In
+            # process mode the workers do, and the parent must not
+            # initialise a backend to look.)
+            from photon_ml_tpu.utils.device_report import describe_devices
+
+            out["device"] = describe_devices()
         return out
 
 

@@ -133,6 +133,10 @@ class ReplicaSupervisor:
                 "pass exactly one of runtime_factory (in-process "
                 "replicas) or pool (process workers)"
             )
+        if pool is not None:
+            from photon_ml_tpu.serving.procpool import check_device_workers
+
+            check_device_workers(n_replicas)
         self.runtime_factory = runtime_factory
         self.pool = pool
         self.n_replicas = n_replicas

@@ -65,21 +65,6 @@ from photon_ml_tpu.serving.runtime import RuntimeConfig, ScoringRuntime
 __all__ = ["worker_main"]
 
 
-def _pin_platform() -> None:
-    """Honor JAX_PLATFORMS before any kernel work: spawned children
-    re-import jax, and an installed accelerator plugin would otherwise
-    win platform selection even with the env var set."""
-    platform = os.environ.get("JAX_PLATFORMS")
-    if not platform:
-        return
-    try:
-        import jax
-
-        jax.config.update("jax_platforms", platform)
-    except Exception:  # noqa: BLE001 — env pinning is best-effort
-        pass
-
-
 def _error_kind(exc: BaseException) -> str:
     """Collapse a scoring failure to the protocol's error taxonomy so
     the parent can reconstruct the SAME exception type — the supervisor
@@ -469,7 +454,6 @@ def worker_main(
     failures are reported as a ``fatal`` frame so the parent's spawn
     raises a pointed error instead of timing out.
     """
-    _pin_platform()
     conn = FrameConn(sock)
     sinks: list = []
     trace_dir = os.environ.get("PHOTON_TRACE_DIR")

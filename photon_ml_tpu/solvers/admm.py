@@ -125,7 +125,7 @@ def make_sharded_solver(problem, dist, mesh, l1_mask=None):
     the default device.  ``dist_override`` lets callers swap the dataset
     (same shapes) per solve without recompiling — the GAME fixed-effect
     coordinate re-slots its per-iteration offsets this way."""
-    from photon_ml_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from photon_ml_tpu.parallel.distributed import DATA_AXIS
     from photon_ml_tpu.solvers import registry as registry_mod
 

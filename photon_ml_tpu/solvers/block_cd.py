@@ -91,7 +91,7 @@ def make_sharded_solver(problem, dist, mesh, l1_mask=None):
     """Registry ``sharded`` factory (same contract as
     solvers.admm.make_sharded_solver)."""
     from photon_ml_tpu.ops.sparse import DenseMatrix
-    from photon_ml_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from photon_ml_tpu.parallel.distributed import DATA_AXIS
     from photon_ml_tpu.solvers import registry as registry_mod
 

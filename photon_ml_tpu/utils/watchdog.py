@@ -10,11 +10,11 @@ AUTOMATIC piece: classify an exception as transient, back off, and re-run
 the training closure — which reloads the checkpoint and continues where
 the crashed attempt stopped, so a retry never repeats finished work.
 
-Classification is by exception type name + message patterns rather than
-imports: the concrete error type for a lost device is
-``jaxlib.xla_extension.XlaRuntimeError`` with a gRPC-style status prefix
-("UNAVAILABLE: Socket closed", "DEADLINE_EXCEEDED", ...), and importing
-jaxlib internals just to isinstance them is brittle across versions.
+Classification is by message patterns: the concrete error type for a lost
+device is ``XlaRuntimeError`` with a gRPC-style status prefix
+("UNAVAILABLE: Socket closed", "DEADLINE_EXCEEDED", ...), and the same type
+carries compile failures and out-of-memory, so the type alone decides
+nothing.
 """
 
 from __future__ import annotations
@@ -47,8 +47,13 @@ _TRANSIENT_PATTERNS = (
     "preempted",
 )
 
-# Status markers that mean a retry will deterministically fail again —
-# they VETO the XlaRuntimeError type-name fallback below.
+# Markers that mean a retry will deterministically fail again — they VETO
+# every transient pattern above.  The last group is compile-shaped: a
+# Mosaic or XLA compile failure arrives as ``XlaRuntimeError: INTERNAL:
+# Mosaic failed to compile TPU kernel ...``, and recompiling the same
+# program fails the same way.  Retrying it as a lost device (training) or
+# answering from the host instead (serving) would let a run whose kernels
+# do not build look healthy.
 _NON_TRANSIENT_PATTERNS = (
     "RESOURCE_EXHAUSTED",
     "out of memory",
@@ -56,9 +61,11 @@ _NON_TRANSIENT_PATTERNS = (
     "FAILED_PRECONDITION",
     "NOT_FOUND",
     "UNIMPLEMENTED",
+    "mosaic",
+    "compil",  # compile / compiler / compilation
+    "lowering",
+    "vmem",
 )
-
-_TRANSIENT_TYPE_NAMES = ("XlaRuntimeError",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,9 +75,9 @@ class Classification:
     emits as a telemetry event per attempt."""
 
     transient: bool
-    #: the matched message pattern or type name, None when nothing matched
+    #: the matched message pattern, None when nothing matched
     matched: Optional[str] = None
-    #: "non_transient_pattern" | "transient_pattern" | "type_name" | "none"
+    #: "interrupt" | "non_transient_pattern" | "transient_pattern" | "none"
     source: str = "none"
 
 
@@ -132,9 +139,9 @@ class RetryPolicy:
             # from a CLI guard must not put the process back to work).
             return Classification(False, type(exc).__name__, "interrupt")
         msg = str(exc).lower()
-        # Deterministic-failure markers veto everything, including the
-        # type-name fallback: an XlaRuntimeError carrying
-        # RESOURCE_EXHAUSTED re-runs the same allocation and dies again.
+        # Deterministic-failure markers veto everything: an
+        # XlaRuntimeError carrying RESOURCE_EXHAUSTED re-runs the same
+        # allocation and dies again.
         for p in _NON_TRANSIENT_PATTERNS:
             if p.lower() in msg:
                 return Classification(False, p, "non_transient_pattern")
@@ -142,9 +149,8 @@ class RetryPolicy:
         for p in patterns:
             if p.lower() in msg:
                 return Classification(True, p, "transient_pattern")
-        name = type(exc).__name__
-        if name in _TRANSIENT_TYPE_NAMES:
-            return Classification(True, name, "type_name")
+        # No marker, no retry: an XlaRuntimeError is transient only when
+        # its status says so, never by its type alone.
         return Classification(False)
 
     def is_transient(self, exc: BaseException) -> bool:

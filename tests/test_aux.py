@@ -111,14 +111,16 @@ class TestHyperparameterSearch:
 
 
 class TestCompileCache:
-    """Persistent XLA compilation cache plumbing (utils/compile_cache.py)."""
+    """The one compile-cache resolver (utils/compile_cache.py): the
+    directory is $JAX_COMPILATION_CACHE_DIR when set — and then nothing
+    is set in code — else the fixed <checkout>/.jax_cache."""
 
     @pytest.fixture(autouse=True)
     def _restore_jax_cache_config(self):
         """These tests mutate process-global JAX config; restore it so
-        later tests don't persist every trivial compile (min secs 0.0) or
-        write into this class's tmp dirs."""
+        later tests keep the suite's cache settings (conftest.py)."""
         import jax
+        from jax._src import compilation_cache as _cc
 
         prev_dir = jax.config.jax_compilation_cache_dir
         prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
@@ -127,57 +129,80 @@ class TestCompileCache:
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs", prev_min
         )
-        try:
-            from jax._src import compilation_cache as _cc
+        _cc.reset_cache()
 
-            _cc.reset_cache()
-        except Exception:
-            pass
-
-    def test_enable_returns_and_creates_dir(self, tmp_path):
+    def test_env_set_uses_that_dir_and_sets_no_other(
+        self, monkeypatch, tmp_path
+    ):
         import jax
+        from jax._src import compilation_cache as _cc
 
-        from photon_ml_tpu.utils.compile_cache import enable_compile_cache
+        from photon_ml_tpu.utils import compile_cache
 
-        target = str(tmp_path / "cache")
-        got = enable_compile_cache(target, min_compile_secs=0.0)
-        assert got == target
-        assert os.path.isdir(target)
+        target = str(tmp_path / "envcache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", target)
+        # The state a process STARTED with the variable is in: JAX reads
+        # it into its config and opens the cache there at first compile.
+        jax.config.update("jax_compilation_cache_dir", target)
+        _cc.reset_cache()
+        dir_updates = []
+        real_update = jax.config.update
+
+        def spy(name, value):
+            if name == "jax_compilation_cache_dir":
+                dir_updates.append(value)
+            return real_update(name, value)
+
+        monkeypatch.setattr(jax.config, "update", spy)
+        assert compile_cache.cache_dir() == target
+        got = compile_cache.enable_compile_cache("auto", min_compile_secs=0.0)
+        assert got == target and os.path.isdir(target)
+        assert dir_updates == []  # the directory was never set in code
         assert jax.config.jax_compilation_cache_dir == target
-        # A jitted computation should land an entry in the cache dir.  The
-        # baked-in constant makes the HLO unique so an in-memory executable
-        # from an earlier test can't satisfy it without a fresh compile.
+        # A jitted computation lands an entry there.  The baked-in
+        # constant makes the HLO unique so an in-memory executable from an
+        # earlier test can't satisfy it without a fresh compile.
         const = float(np.random.default_rng().uniform(1.0, 2.0))
         jax.jit(lambda x: x * 2.0 + const)(
             jax.numpy.ones((8, 8))
         ).block_until_ready()
         assert len(os.listdir(target)) >= 1
 
-    def test_off_and_failure_are_non_fatal(self, tmp_path):
+    def test_env_unset_uses_fixed_path_in_checkout(self, monkeypatch):
         import jax
 
         from photon_ml_tpu.utils import compile_cache
 
-        # 'off' must actively disable a previously enabled cache (bench
-        # relies on this for honest cold-run driver timing).
-        compile_cache.enable_compile_cache(str(tmp_path / "on"))
-        assert compile_cache.enable_compile_cache("off") is None
-        assert jax.config.jax_compilation_cache_dir is None
-        # unwritable parent: degrade to None, never raise
-        blocked = tmp_path / "ro"
-        blocked.mkdir()
-        blocked.chmod(0o500)
-        try:
-            got = compile_cache.enable_compile_cache(str(blocked / "sub"))
-            assert got is None or os.path.isdir(got)  # root can still write
-        finally:
-            blocked.chmod(0o700)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        assert compile_cache.cache_dir() == want
+        first = compile_cache.enable_compile_cache("auto")
+        second = compile_cache.enable_compile_cache()
+        assert first == second == want  # never a temp name, pid or clock
+        assert jax.config.jax_compilation_cache_dir == want
 
-    def test_default_dir_env_override(self, monkeypatch, tmp_path):
+    def test_off_disables_and_paths_are_refused(self, tmp_path):
+        import jax
+
         from photon_ml_tpu.utils import compile_cache
 
-        monkeypatch.setenv("PHOTON_COMPILE_CACHE", str(tmp_path / "envcache"))
-        assert compile_cache.default_cache_dir() == str(tmp_path / "envcache")
+        assert compile_cache.enable_compile_cache("auto") is not None
+        assert compile_cache.enable_compile_cache("off") is None
+        assert jax.config.jax_compilation_cache_dir is None
+        # The directory is not a per-call argument any more.
+        with pytest.raises(ValueError, match="'auto' or 'off'"):
+            compile_cache.enable_compile_cache(str(tmp_path / "elsewhere"))
+
+    def test_uncreatable_dir_degrades_to_off(self, monkeypatch, tmp_path):
+        from photon_ml_tpu.utils import compile_cache
+
+        blocker = tmp_path / "file"
+        blocker.write_text("not a directory")
+        monkeypatch.setenv(
+            "JAX_COMPILATION_CACHE_DIR", str(blocker / "sub")
+        )
+        assert compile_cache.enable_compile_cache("auto") is None
 
 
 class TestMarginalLikelihoodFit:

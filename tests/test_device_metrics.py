@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from photon_ml_tpu.parallel.compat import shard_map
+from jax import shard_map
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 

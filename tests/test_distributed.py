@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from photon_ml_tpu.parallel.compat import shard_map
+from jax import shard_map
 import pytest
 import scipy.sparse as sp
 

@@ -5,7 +5,7 @@ tested here (the compute paths themselves are host-count-agnostic SPMD)."""
 
 import numpy as np
 
-from photon_ml_tpu.parallel.compat import shard_map
+from jax import shard_map
 import pytest
 
 import jax

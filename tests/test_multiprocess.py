@@ -17,7 +17,7 @@ import sys
 
 import numpy as np
 
-from photon_ml_tpu.parallel.compat import shard_map
+from jax import shard_map
 import pytest
 
 _WORKER = r"""
@@ -74,7 +74,7 @@ def spmd(Xl, yl):
     )
 
 
-res = jax.jit(shard_map(
+res = jax.jit(jax.shard_map(
     spmd, mesh=mesh,
     in_specs=(P(DATA_AXIS), P(DATA_AXIS)), out_specs=P(),
     check_vma=False,

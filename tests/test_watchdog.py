@@ -42,9 +42,9 @@ class TestRetryPolicy:
             RuntimeError("RESOURCE_EXHAUSTED: out of memory")
         )
 
-    def test_type_name_classification(self):
+    def test_type_name_alone_is_not_transient(self):
         XlaRuntimeError = type("XlaRuntimeError", (RuntimeError,), {})
-        assert RetryPolicy().is_transient(XlaRuntimeError("whatever"))
+        assert not RetryPolicy().is_transient(XlaRuntimeError("whatever"))
 
     def test_extra_patterns(self):
         p = RetryPolicy(extra_patterns=("my-cluster-oops",))
@@ -196,8 +196,12 @@ class TestClassification:
         assert c.source == "non_transient_pattern"
         XlaRuntimeError = type("XlaRuntimeError", (RuntimeError,), {})
         c = p.classify(XlaRuntimeError("mystery"))
-        assert c.transient and c.matched == "XlaRuntimeError"
-        assert c.source == "type_name"
+        assert not c.transient and c.matched is None and c.source == "none"
+        c = p.classify(XlaRuntimeError(
+            "INTERNAL: Mosaic failed to compile TPU kernel: scoped vmem"
+        ))
+        assert not c.transient and c.matched == "mosaic"
+        assert c.source == "non_transient_pattern"
         c = p.classify(ValueError("bad shape"))
         assert not c.transient and c.matched is None and c.source == "none"
 
@@ -482,10 +486,10 @@ class TestGameDriverRecovery:
         assert result["history"]
 
 
-class TestTypeNameVeto:
+class TestDeterministicStatusVeto:
     def test_xla_error_with_oom_status_not_retried(self):
-        """RESOURCE_EXHAUSTED inside an XlaRuntimeError must veto the
-        type-name fallback — a retry re-runs the same allocation."""
+        """RESOURCE_EXHAUSTED inside an XlaRuntimeError is never
+        transient — a retry re-runs the same allocation."""
         XlaRuntimeError = type("XlaRuntimeError", (RuntimeError,), {})
         p = RetryPolicy(max_retries=3)
         assert not p.is_transient(
@@ -496,7 +500,14 @@ class TestTypeNameVeto:
         )
         # ...but a genuinely transient status still retries.
         assert p.is_transient(XlaRuntimeError("UNAVAILABLE: Socket closed"))
-        assert p.is_transient(XlaRuntimeError("unrecognized plugin error"))
+        assert p.is_transient(XlaRuntimeError("INTERNAL: device halted"))
+        # A compile or lowering failure repeats on every retry.
+        for msg in (
+            "INTERNAL: Mosaic failed to compile TPU kernel",
+            "INTERNAL: during XLA compilation: ...",
+            "INTERNAL: scoped vmem limit exceeded",
+        ):
+            assert not p.is_transient(XlaRuntimeError(msg)), msg
 
 
 class TestGameGridRecovery:
