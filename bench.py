@@ -28,11 +28,9 @@ Prints ONE JSON line: {"metric", "value", "unit", "device", "extra"} — the
 primary metric in the required fields, the other metrics under "extra".
 
 Env knobs: BENCH_SMALL=1 shrinks every workload (CI/smoke); BENCH_ONLY=
-glm|game|driver|stream|serving|freshness|tuning|solvers|chaos|telemetry|
-tracing|analysis|cluster runs a single section (tracing: trace-
-propagation overhead A/B, gated <= 1% of the closed-loop serving
-baseline; cluster: the 3-host control-plane drill as a gate plus the
-checksum-verified snapshot-fetch MB/s).
+glm|game|driver|stream|serving|freshness|tuning|solvers|chaos|analysis|
+cluster runs a single section (cluster: the 3-host control-plane drill as
+a gate plus the checksum-verified snapshot-fetch MB/s).
 """
 
 import json
@@ -1119,137 +1117,6 @@ def bench_chaos() -> dict:
     }
 
 
-def bench_telemetry() -> dict:
-    """Live ops-plane cost gate (ISSUE 7 acceptance): the ENABLED plane
-    — time-series sampler + /metrics exporter + per-chunk HBM gauges —
-    must add ≤ 1% to a streamed GLM pass.
-
-    Gate methodology mirrors ``bench_chaos``: each component's unit cost
-    is measured directly (tight loop), multiplied by its per-pass call
-    count, and compared against the streamed pass wall — noise-free
-    where a wall-clock A/B on a ~100 ms pass is not.  The measured A/B
-    delta is reported alongside for the record.  Components:
-
-    - sampler: one ``sample()`` per ``interval_s`` (1 s default) —
-      cost/sample ÷ interval is the steady-state fraction;
-    - HBM gauges: 2 locked ``gauge.set`` calls per chunk bump (2 bumps/
-      chunk) + 2 per-pass gauges — counted exactly;
-    - exporter: zero unless scraped; one /metrics render is timed and
-      amortized over a 5 s scrape interval.
-    """
-    import tempfile
-
-    import jax.numpy as jnp
-    import scipy.sparse as sp
-
-    from photon_ml_tpu import telemetry as telemetry_mod
-    from photon_ml_tpu.data.streaming import make_streaming_glm_data
-    from photon_ml_tpu.optim.streaming import StreamingObjective
-    from photon_ml_tpu.telemetry.exporter import prometheus_text
-    from photon_ml_tpu.telemetry.timeseries import TimeSeriesSampler
-
-    # -- workload: the bench_chaos streamed shape --------------------------
-    rng = np.random.default_rng(23)
-    n, d = (1 << 13), 256
-    nnz = n * 16
-    rows = np.repeat(np.arange(n, dtype=np.int64), 16)
-    cols = rng.integers(0, d, size=nnz).astype(np.int64)
-    X = sp.coo_matrix(
-        (rng.normal(size=nnz).astype(np.float32), (rows, cols)),
-        shape=(n, d),
-    ).tocsr()
-    y = (rng.uniform(size=n) < 0.5).astype(np.float32)
-    stream = make_streaming_glm_data(
-        X, y, chunk_rows=-(-n // STREAM_CHUNKS), use_pallas=False
-    )
-    sobj = StreamingObjective("logistic", stream)
-    w = jnp.zeros(d, jnp.float32)
-
-    def one_pass():
-        _v, g = sobj.value_and_grad(w, 1.0)
-        _read_sync(g)
-
-    prev = telemetry_mod.set_current(telemetry_mod.NULL)
-    try:
-        one_pass()  # warm (compile)
-        wall_off = np.inf
-        for _ in range(N_REPS):
-            t0 = time.perf_counter()
-            one_pass()
-            wall_off = min(wall_off, time.perf_counter() - t0)
-
-        with tempfile.TemporaryDirectory(prefix="bench_tel_") as td:
-            with telemetry_mod.Telemetry(
-                output_dir=td, run_name="bench-telemetry"
-            ) as tel:
-                plane = telemetry_mod.mount_ops_plane(
-                    tel, port=0, interval_s=1.0
-                )
-                try:
-                    one_pass()  # re-warm under the enabled hub
-                    wall_on = np.inf
-                    for _ in range(N_REPS):
-                        t0 = time.perf_counter()
-                        one_pass()
-                        wall_on = min(
-                            wall_on, time.perf_counter() - t0
-                        )
-
-                    # -- unit costs --------------------------------------
-                    sampler: TimeSeriesSampler = plane.sampler
-                    reps = 200
-                    t0 = time.perf_counter()
-                    for _ in range(reps):
-                        sampler.sample()
-                    sample_s = (time.perf_counter() - t0) / reps
-
-                    g = tel.gauge("hbm_live_bytes")
-                    reps = 100_000
-                    t0 = time.perf_counter()
-                    for i in range(reps):
-                        g.set(i)
-                    gauge_s = (time.perf_counter() - t0) / reps
-
-                    snap = tel.snapshot()
-                    reps = 50
-                    t0 = time.perf_counter()
-                    for _ in range(reps):
-                        prometheus_text(snap)
-                    render_s = (time.perf_counter() - t0) / reps
-                finally:
-                    plane.close()
-    finally:
-        telemetry_mod.set_current(prev)
-
-    # -- per-pass accounting ----------------------------------------------
-    chunks = stream.n_chunks
-    # 2 gauge sets per _bump x 2 bumps per chunk, + 2 window gauges/pass.
-    gauge_calls = 4 * chunks + 2
-    frac_gauges = gauge_calls * gauge_s / wall_off
-    frac_sampler = sample_s / 1.0  # one sample per interval_s=1.0
-    frac_exporter = render_s / 5.0  # one scrape per 5 s, rendered live
-    overhead_frac = frac_gauges + frac_sampler + frac_exporter
-    gate_ok = overhead_frac <= 0.01
-    measured_delta = (wall_on - wall_off) / wall_off
-    _log(
-        f"telemetry: ops plane — gauges {gauge_s * 1e9:.0f} ns/set x "
-        f"{gauge_calls}/pass, sampler {sample_s * 1e3:.2f} ms/sample, "
-        f"/metrics render {render_s * 1e3:.2f} ms -> "
-        f"{overhead_frac * 100:.4f}% of a {wall_off * 1e3:.1f} ms "
-        f"streamed pass ({'PASS' if gate_ok else 'FAIL'} @ <=1%); "
-        f"measured A/B delta {measured_delta * 100:+.2f}%"
-    )
-    return {
-        "telemetry_gauge_set_ns": round(gauge_s * 1e9, 1),
-        "telemetry_sample_ms": round(sample_s * 1e3, 3),
-        "telemetry_prom_render_ms": round(render_s * 1e3, 3),
-        "telemetry_streamed_pass_wall_s": round(wall_off, 4),
-        "telemetry_ops_plane_overhead_frac": round(overhead_frac, 6),
-        "telemetry_overhead_gate_ok": gate_ok,
-        "telemetry_measured_delta_frac": round(measured_delta, 4),
-    }
-
-
 def bench_analysis() -> dict:
     """Lock-order sanitizer cost gate (ISSUE 10 acceptance): the ENABLED
     sanitizer — every tracked-lock acquire/release feeding the witness
@@ -1257,7 +1124,7 @@ def bench_analysis() -> dict:
     free by construction (``sanitizers.tracked`` returns the raw lock
     when nothing is installed), asserted here rather than timed.
 
-    Gate methodology mirrors ``bench_chaos``/``bench_telemetry``: the
+    Gate methodology mirrors ``bench_chaos``: the
     tracked acquire+release pair cost is measured in a tight loop and
     multiplied by the exact per-pass acquisition count (prefetch's
     ``_bump`` takes ``prefetch.live`` twice per chunk), then compared
@@ -1277,7 +1144,7 @@ def bench_analysis() -> dict:
     from photon_ml_tpu.data.streaming import make_streaming_glm_data
     from photon_ml_tpu.optim.streaming import StreamingObjective
 
-    # -- workload: the bench_chaos/bench_telemetry streamed shape ----------
+    # -- workload: the bench_chaos streamed shape -------------------------
     rng = np.random.default_rng(29)
     n, d = (1 << 13), 256
     nnz = n * 16
@@ -2088,127 +1955,6 @@ def _bench_serving_fleet(workload) -> dict:
     return out
 
 
-def bench_tracing() -> dict:
-    """Distributed-tracing propagation overhead (PR 17): the same
-    closed-loop in-process serving workload as bench_serving, A/B'd with
-    trace-context propagation OFF (sink-less hub — every adopt/span is
-    the one-branch no-op) vs ON at the DEFAULT 1/256 head sampling
-    against an active hub.  The ON leg pays, per request, exactly what
-    the transport edges pay: mint the context, render the header string,
-    re-parse it, adopt it, and open the hop span (emitted for the ~0.4%
-    sampled traces, elided otherwise).  Gate: overhead <= 1% of
-    baseline throughput.
-
-    The GATED number is deterministic: per-request propagation cost
-    (tight-loop median over the exact wrapper, sans the submit) divided
-    by the baseline per-request service time (clients / closed-loop
-    rps) — the throughput delta the A/B converges to in expectation.
-    The raw alternating off/on closed-loop pairs are still run and
-    reported, but this box's throughput drifts 10-30% between
-    back-to-back IDENTICAL legs, so the raw delta measures machine
-    weather, not the ~0.3% tracing cost."""
-    from photon_ml_tpu import telemetry as telemetry_mod
-    from photon_ml_tpu.serving import loadgen
-    from photon_ml_tpu.serving.batcher import BatcherConfig
-    from photon_ml_tpu.serving.runtime import RuntimeConfig, ScoringRuntime
-    from photon_ml_tpu.serving.service import ScoringService
-    from photon_ml_tpu.serving.synthetic import SyntheticWorkload
-    from photon_ml_tpu.telemetry.recorder import FlightRecorder
-
-    n_entities = 10_000
-    duration = 1.5 if SMALL else 4.0
-    clients = 16
-    _log(f"tracing: building synthetic GAME model ({n_entities} "
-         "entities)...")
-    workload = SyntheticWorkload(
-        n_entities=n_entities, fixed_dim=64, re_dim=8, seed=11
-    )
-    runtime = ScoringRuntime(
-        workload.model, workload.index_maps,
-        RuntimeConfig(max_batch_size=64, hot_entities=4096),
-    )
-    service = ScoringService(runtime, BatcherConfig(
-        max_batch_size=64, max_wait_us=1000, max_queue=1024,
-    ))
-
-    # ON leg: an ACTIVE hub (in-memory ring sink — no disk I/O in the
-    # timed window) at the default head-sampling rate, driven the way
-    # the HTTP edge drives it.
-    traced_hub = telemetry_mod.Telemetry(sinks=[FlightRecorder()])
-    TraceContext = telemetry_mod.TraceContext
-
-    def submit_traced(request):
-        ctx = traced_hub.new_trace()
-        wire = ctx.header_value()          # what the transport renders
-        parsed = TraceContext.parse(wire)  # ...and the far edge parses
-        with traced_hub.adopt(parsed), \
-                traced_hub.span("serving.http_score"):
-            return service.submit(request)
-
-    pairs = 3
-    off_rps: list = []
-    on_rps: list = []
-    with service:
-        loadgen.closed_loop(
-            service.submit, workload.request, clients=4, duration_s=0.5
-        )
-        for k in range(pairs):
-            for leg, submit, sink in (
-                ("off", service.submit, off_rps),
-                ("on", submit_traced, on_rps),
-            ):
-                report = loadgen.closed_loop(
-                    submit, workload.request,
-                    clients=clients, duration_s=duration,
-                )
-                sink.append(report.snapshot()["throughput_rps"])
-                _log(f"tracing: pair {k} leg {leg}: {sink[-1]} rps")
-    # Deterministic per-request propagation cost: the SAME wrapper with
-    # the submit replaced by a no-op, tight loop, median of 5 runs.
-    def noop_submit(request):
-        return request
-
-    n_iter = 50_000
-    costs = []
-    for _ in range(5):
-        t0 = time.perf_counter()
-        for i in range(n_iter):
-            ctx = traced_hub.new_trace()
-            wire = ctx.header_value()
-            parsed = TraceContext.parse(wire)
-            with traced_hub.adopt(parsed), \
-                    traced_hub.span("serving.http_score"):
-                noop_submit(None)
-        costs.append((time.perf_counter() - t0) / n_iter)
-    cost_s = float(np.median(costs))
-    traced_hub.close()
-
-    base = float(np.median(off_rps))
-    # Closed loop: rps = clients / t_req, so adding cost_s per request
-    # costs cost_s / t_req = cost_s * rps / clients of throughput.
-    t_req = clients / base if base > 0 else float("inf")
-    overhead = cost_s / t_req
-    raw_deltas = [
-        round(1.0 - on / off, 4) if off > 0 else None
-        for off, on in zip(off_rps, on_rps)
-    ]
-    _log(f"tracing: {cost_s * 1e6:.2f} us/request propagation cost over "
-         f"{t_req * 1e3:.2f} ms/request baseline -> {overhead * 100:.3f}% "
-         f"throughput overhead (gate: <= 1%); raw A/B deltas "
-         f"{raw_deltas} (machine noise)")
-    return {
-        "tracing_baseline_rps": round(base, 1),
-        "tracing_on_rps": round(float(np.median(on_rps)), 1),
-        "tracing_off_rps": off_rps,
-        "tracing_on_rps_legs": on_rps,
-        "tracing_raw_ab_deltas": raw_deltas,
-        "tracing_cost_us_per_request": round(cost_s * 1e6, 3),
-        "tracing_sample_every": traced_hub.trace_sample_every,
-        "tracing_overhead_frac": round(overhead, 5),
-        "tracing_overhead_pass": overhead <= 0.01,
-    }
-
-
 def bench_freshness() -> dict:
     """Continuous train→serve loop (PR 12): the wall cost of staying
     fresh.  Two measurements:
@@ -2737,8 +2483,6 @@ def main() -> int:
         ("tuning", bench_tuning),
         ("solvers", bench_solvers),
         ("chaos", bench_chaos),
-        ("telemetry", bench_telemetry),
-        ("tracing", bench_tracing),
         ("analysis", bench_analysis),
         ("cluster", bench_cluster),
     ):

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_ml_tpu.ops.sparse import DenseMatrix, FeatureMatrix, from_scipy_csr
+from photon_ml_tpu.telemetry import layer_span
 
 Array = jax.Array
 
@@ -75,57 +76,85 @@ def make_glm_data(
     large enough for the kernels to win (the tiled layout costs host build
     time and ~3x slot memory, and pays off via ~70x faster value+grad);
     ``True``/``False`` force it.
+
+    The result is resident when this returns (every leaf is ready): the
+    call is one ``data.make_glm_data`` layer span, the host build of the
+    tiled layout its child ``layout.build``, and everything from the first
+    host-to-device copy to the last leaf being ready its child
+    ``layout.place`` (docs/telemetry.md "Layer spans"; the COO layout has
+    no build of its own, so its host canonicalisation counts as placing).
     """
     import scipy.sparse as sp
 
-    n = features.shape[0]
-    labels = np.asarray(labels, dtype=np.float32)
-    weights = (
-        np.ones(n, np.float32) if weights is None else np.asarray(weights, np.float32)
-    )
-    offsets = (
-        np.zeros(n, np.float32) if offsets is None else np.asarray(offsets, np.float32)
-    )
-    target_rows = pad_rows if pad_rows is not None else n
-    if target_rows < n:
-        raise ValueError(f"pad_rows={target_rows} < n_rows={n}")
-    pad = target_rows - n
-    if pad:
-        labels = np.concatenate([labels, np.zeros(pad, np.float32)])
-        weights = np.concatenate([weights, np.zeros(pad, np.float32)])
-        offsets = np.concatenate([offsets, np.zeros(pad, np.float32)])
-
-    if sp.issparse(features):
+    with layer_span("data.make_glm_data") as span:
+        n = features.shape[0]
+        labels = np.asarray(labels, dtype=np.float32)
+        weights = (
+            np.ones(n, np.float32) if weights is None
+            else np.asarray(weights, np.float32)
+        )
+        offsets = (
+            np.zeros(n, np.float32) if offsets is None
+            else np.asarray(offsets, np.float32)
+        )
+        target_rows = pad_rows if pad_rows is not None else n
+        if target_rows < n:
+            raise ValueError(f"pad_rows={target_rows} < n_rows={n}")
+        pad = target_rows - n
         if pad:
-            features = sp.vstack(
-                [features.tocsr(), sp.csr_matrix((pad, features.shape[1]))]
-            )
-        if use_pallas == "auto":
-            from photon_ml_tpu.ops.sparse_pallas import pallas_available
+            labels = np.concatenate([labels, np.zeros(pad, np.float32)])
+            weights = np.concatenate([weights, np.zeros(pad, np.float32)])
+            offsets = np.concatenate([offsets, np.zeros(pad, np.float32)])
 
-            use_pallas = (
-                pallas_available()
-                and features.shape[0] >= 65536
-                and features.nnz >= 1 << 20
-            )
-        if use_pallas:
-            from photon_ml_tpu.ops.sparse_pallas import from_scipy_csr_pallas
+        if sp.issparse(features):
+            if pad:
+                features = sp.vstack(
+                    [features.tocsr(), sp.csr_matrix((pad, features.shape[1]))]
+                )
+            if use_pallas == "auto":
+                from photon_ml_tpu.ops.sparse_pallas import pallas_available
 
-            fm: FeatureMatrix = from_scipy_csr_pallas(
-                features, pad_nnz=pad_nnz, dtype=dtype)
+                use_pallas = (
+                    pallas_available()
+                    and features.shape[0] >= 65536
+                    and features.nnz >= 1 << 20
+                )
+            nnz = int(features.nnz)
+            if use_pallas:
+                from photon_ml_tpu.ops.sparse_pallas import (
+                    host_layout_from_scipy_csr,
+                    place_pallas_matrix,
+                )
+
+                features = host_layout_from_scipy_csr(
+                    features, pad_nnz=pad_nnz, dtype=dtype)
+                to_device = place_pallas_matrix
+            else:
+                to_device = partial(
+                    from_scipy_csr, pad_nnz=pad_nnz, dtype=dtype)
         else:
-            fm = from_scipy_csr(features, pad_nnz=pad_nnz, dtype=dtype)
-    else:
-        dense = np.asarray(features)
-        if pad:
-            dense = np.concatenate(
-                [dense, np.zeros((pad, dense.shape[1]), dense.dtype)]
-            )
-        fm = DenseMatrix(jnp.asarray(dense, dtype=dtype))
+            features = np.asarray(features)
+            if pad:
+                features = np.concatenate([
+                    features,
+                    np.zeros((pad, features.shape[1]), features.dtype),
+                ])
+            nnz = int(features.size)
 
-    return GlmData(
-        features=fm,
-        labels=jnp.asarray(labels),
-        weights=jnp.asarray(weights),
-        offsets=jnp.asarray(offsets),
-    )
+            def to_device(dense) -> FeatureMatrix:
+                return DenseMatrix(jnp.asarray(dense, dtype=dtype))
+
+        with layer_span("layout.place") as place:
+            data = GlmData(
+                features=to_device(features),
+                labels=jnp.asarray(labels),
+                weights=jnp.asarray(weights),
+                offsets=jnp.asarray(offsets),
+            )
+            # Every caller solves on these next; waiting here is what lets
+            # the span say when the data is resident.
+            jax.block_until_ready(data)
+            place.set(bytes=sum(x.nbytes for x in jax.tree.leaves(data)))
+        span.set(rows=int(target_rows), nnz=nnz,
+                 layout=type(data.features).__name__)
+    return data

@@ -51,7 +51,6 @@ Design constraints that shape this module:
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 from typing import Iterable
@@ -72,15 +71,6 @@ from photon_ml_tpu.ops.sparse import (
     canonicalize_coo,
     pad_coo_triples,
 )
-
-
-def _cpu_device():
-    """The host CPU device, when a CPU backend exists next to the TPU —
-    layout builds placed there never round-trip chunk data through HBM."""
-    try:
-        return jax.local_devices(backend="cpu")[0]
-    except RuntimeError:
-        return None
 
 
 @dataclasses.dataclass
@@ -316,7 +306,6 @@ def streaming_from_blocks(
     per_shard = chunk_rows // max(n_shards, 1)
 
     d = int(n_features)
-    cpu = _cpu_device()
 
     # Raw row buffer (≤ one chunk + one incoming block) and finished
     # chunks.  For the tiled-Pallas path the finished entry is a host
@@ -346,29 +335,22 @@ def streaming_from_blocks(
         final partial chunk; their weights are 0)."""
         vectors.append((y, w, o))
         if mode == "pallas":
-            from photon_ml_tpu.ops.sparse_pallas import (
-                build_pallas_matrix,
-                layout_to_host,
-            )
+            from photon_ml_tpu.ops.sparse_pallas import build_pallas_host
 
             # One tiled layout per shard's row block, over (per_shard, d);
             # with n_shards == 1 that is the whole chunk.  All chunk×shard
             # layouts are uniformized together at the end, so one
             # shard_map program serves every chunk (streamed DP at the
-            # kernel rate, not the COO rate).
+            # kernel rate, not the COO rate).  Built and kept on the host:
+            # chunk data never passes through a device here.
             shard_mats = []
             for s in range(max(n_shards, 1)):
                 coo = X[s * per_shard:(s + 1) * per_shard].tocoo()
-                with (
-                    jax.default_device(cpu) if cpu is not None
-                    else contextlib.nullcontext()
-                ):
-                    P = build_pallas_matrix(
-                        coo.row.astype(np.int64), coo.col.astype(np.int64),
-                        coo.data.astype(np.float32), per_shard, d,
-                        depth_cap=depth_cap, col_permutation=False,
-                    )
-                shard_mats.append(layout_to_host(P))
+                shard_mats.append(build_pallas_host(
+                    coo.row.astype(np.int64), coo.col.astype(np.int64),
+                    coo.data.astype(np.float32), per_shard, d,
+                    depth_cap=depth_cap, col_permutation=False,
+                ))
             if raw_dir is not None:
                 shard_mats = [
                     spill_tree(m, raw_dir, f"c{len(finished)}_s{s}")

@@ -71,8 +71,8 @@ from photon_ml_tpu.ops.sparse import (
     DenseMatrix,
     SparseMatrix,
     canonicalize_coo,
-    from_coo,
 )
+from photon_ml_tpu.telemetry import layer_span
 
 Array = jax.Array
 
@@ -567,15 +567,20 @@ def _pick_rect(nbo: int, nbg: int, a: int,
     return batch, chunk
 
 
-@functools.partial(jax.jit, static_argnames=("nbo", "nbg", "square", "unit"))
-def _tiled_apply(code, val, vec_padded, *, nbo, nbg, square, unit=False):
+@functools.partial(
+    jax.jit, static_argnames=("nbo", "nbg", "square", "side", "unit"))
+def _tiled_apply(code, val, vec_padded, *, nbo, nbg, square, side,
+                 unit=False):
     """out[i] = sum over entries (i, j, v) of v * vec[j] (+ optional v²).
 
     ``code``/``val``: (nbo, nbg, A, 128); ``vec_padded``: (nbg * TILE_C,).
     Returns (nbo * TILE_R,) output.  The packed sublane count A comes from
     the array shape (jit already specializes on it).  ``unit``: the
     binary-matrix layout — ``val`` is ignored (pass the placeholder) and
-    only codes stream through the kernel.
+    only codes stream through the kernel.  ``side`` (``"fwd"``: a product
+    with X, ``"bwd"``: with its transpose) only names the kernel: the HLO
+    instruction, and so its event in a device trace, is
+    ``_tiled_apply_fwd.N`` or ``_tiled_apply_bwd.N``.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -609,6 +614,7 @@ def _tiled_apply(code, val, vec_padded, *, nbo, nbg, square, unit=False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
+        name=f"_tiled_apply_{side}",
     )(*operands)
     # out[i, h, l] = output element i*TILE_R + h*128 + l
     return out.reshape(nbo * TILE_R)
@@ -814,7 +820,8 @@ class PallasSparseMatrix:
     def matvec(self, w: Array) -> Array:
         out = _tiled_apply(
             self.f_code, self.f_val, self._pad_cols(w),
-            nbo=self.nbr, nbg=self.nbc, square=False, unit=self.unit_vals,
+            nbo=self.nbr, nbg=self.nbc, square=False, side="fwd",
+            unit=self.unit_vals,
         )[: self.n_rows]
         out = out + self.spill.matvec(w)
         if self.has_dense_cols:
@@ -827,7 +834,8 @@ class PallasSparseMatrix:
     def rmatvec(self, u: Array) -> Array:
         out = self._uncols(_tiled_apply(
             self.b_code, self.b_val, self._pad_rows(u),
-            nbo=self.nbc, nbg=self.nbr, square=False, unit=self.unit_vals,
+            nbo=self.nbc, nbg=self.nbr, square=False, side="bwd",
+            unit=self.unit_vals,
         ))
         out = out + self.spill.rmatvec(u)
         if self.has_dense_cols:
@@ -840,7 +848,8 @@ class PallasSparseMatrix:
     def row_sq_matvec(self, v: Array) -> Array:
         out = _tiled_apply(
             self.f_code, self.f_val, self._pad_cols(v),
-            nbo=self.nbr, nbg=self.nbc, square=True, unit=self.unit_vals,
+            nbo=self.nbr, nbg=self.nbc, square=True, side="fwd",
+            unit=self.unit_vals,
         )[: self.n_rows]
         out = out + self.spill.row_sq_matvec(v)
         if self.has_dense_cols:
@@ -855,7 +864,8 @@ class PallasSparseMatrix:
     def sq_rmatvec(self, u: Array) -> Array:
         out = self._uncols(_tiled_apply(
             self.b_code, self.b_val, self._pad_rows(u),
-            nbo=self.nbc, nbg=self.nbr, square=True, unit=self.unit_vals,
+            nbo=self.nbc, nbg=self.nbr, square=True, side="bwd",
+            unit=self.unit_vals,
         ))
         out = out + self.spill.sq_rmatvec(u)
         if self.has_dense_cols:
@@ -1010,7 +1020,7 @@ def _extract_dense(counts, threshold, max_stripes, long_axis,
     return cand.astype(np.int64)
 
 
-def build_pallas_matrix(
+def build_pallas_host(
     rows: np.ndarray,
     cols: np.ndarray,
     vals: np.ndarray,
@@ -1025,7 +1035,10 @@ def build_pallas_matrix(
     col_permutation: bool = True,
     unit_values: bool | str = "auto",
 ) -> PallasSparseMatrix:
-    """Build the tiled layout from host COO triples.
+    """Build the tiled layout from host COO triples, on the host: every
+    leaf of the result is a numpy array (:func:`place_pallas_matrix` puts
+    them on the device).  One ``layout.build`` layer span, with a child
+    per phase (docs/telemetry.md "Layer spans").
 
     Storage-class split (see :class:`PallasSparseMatrix`):
 
@@ -1041,167 +1054,202 @@ def build_pallas_matrix(
        (see ``_build_orientation``; ≤ ``depth_cap``);
     3. the residual overflow becomes a COMPACT spill COO (cost ∝ spill).
     """
-    # Canonicalize ON HOST (dedup + sort + nnz-budget pad/validation) —
-    # the old path built a full device COO first and read it straight
-    # back, paying two transfers of the entire entry set for nothing.
-    # Padding entries carry value 0, so the tiled build excludes them via
-    # the live filter below; P.nnz still reports the padded budget.
-    r_all, c_all, v_all = canonicalize_coo(
-        rows, cols, vals, n_rows, n_cols, pad_nnz
-    )
-    host_coo = HostCoo(r_all, c_all, v_all, int(n_rows), int(n_cols))
-    # Zero-valued entries contribute nothing; excluding them keeps explicit
-    # zeros from faking a dense cell.
-    live = np.flatnonzero(v_all != 0)
-    r, c, v = r_all[live], c_all[live], v_all[live]
+    with layer_span("layout.build") as build:
+        # Canonicalize ON HOST (dedup + sort + nnz-budget pad/validation) —
+        # the old path built a full device COO first and read it straight
+        # back, paying two transfers of the entire entry set for nothing.
+        # Padding entries carry value 0, so the tiled build excludes them via
+        # the live filter below; P.nnz still reports the padded budget.
+        with layer_span("layout.canonicalize"):
+            r_all, c_all, v_all = canonicalize_coo(
+                rows, cols, vals, n_rows, n_cols, pad_nnz
+            )
+            host_coo = HostCoo(r_all, c_all, v_all, int(n_rows), int(n_cols))
+            # Zero-valued entries contribute nothing; excluding them keeps
+            # explicit zeros from faking a dense cell.
+            live = np.flatnonzero(v_all != 0)
+            r, c, v = r_all[live], c_all[live], v_all[live]
 
-    # --- dense stripe extraction (columns first, rows from the rest) ------
-    dense_col_ids = _extract_dense(
-        np.bincount(c, minlength=n_cols),
-        max(256, int(n_rows * dense_frac)), max_dense,
-        n_rows, dense_budget_bytes,
-    )
-    in_dc = (
-        np.isin(c, dense_col_ids) if dense_col_ids.size else
-        np.zeros(len(c), bool)
-    )
-    # Zero-SIZE placeholder when absent (never read; has_dense_cols gates).
-    dense_cols = np.zeros((len(dense_col_ids), n_rows), np.float32)
-    if dense_col_ids.size:
-        pos = np.searchsorted(dense_col_ids, c[in_dc])
-        dense_cols[pos, r[in_dc]] = v[in_dc]
-        r, c, v = r[~in_dc], c[~in_dc], v[~in_dc]
+        # --- dense stripe extraction (columns first, rows from the rest) --
+        with layer_span("layout.dense_split"):
+            dense_col_ids = _extract_dense(
+                np.bincount(c, minlength=n_cols),
+                max(256, int(n_rows * dense_frac)), max_dense,
+                n_rows, dense_budget_bytes,
+            )
+            in_dc = (
+                np.isin(c, dense_col_ids) if dense_col_ids.size else
+                np.zeros(len(c), bool)
+            )
+            # Zero-SIZE placeholder when absent (never read; has_dense_cols
+            # gates).
+            dense_cols = np.zeros((len(dense_col_ids), n_rows), np.float32)
+            if dense_col_ids.size:
+                pos = np.searchsorted(dense_col_ids, c[in_dc])
+                dense_cols[pos, r[in_dc]] = v[in_dc]
+                r, c, v = r[~in_dc], c[~in_dc], v[~in_dc]
 
-    dense_row_ids = _extract_dense(
-        np.bincount(r, minlength=n_rows),
-        max(256, int(n_cols * dense_frac)), max_dense,
-        n_cols, dense_budget_bytes,
-    )
-    in_dr = (
-        np.isin(r, dense_row_ids) if dense_row_ids.size else
-        np.zeros(len(r), bool)
-    )
-    dense_rows = np.zeros((len(dense_row_ids), n_cols), np.float32)
-    if dense_row_ids.size:
-        pos = np.searchsorted(dense_row_ids, r[in_dr])
-        dense_rows[pos, c[in_dr]] = v[in_dr]
-        r, c, v = r[~in_dr], c[~in_dr], v[~in_dr]
+            dense_row_ids = _extract_dense(
+                np.bincount(r, minlength=n_rows),
+                max(256, int(n_cols * dense_frac)), max_dense,
+                n_cols, dense_budget_bytes,
+            )
+            in_dr = (
+                np.isin(r, dense_row_ids) if dense_row_ids.size else
+                np.zeros(len(r), bool)
+            )
+            dense_rows = np.zeros((len(dense_row_ids), n_cols), np.float32)
+            if dense_row_ids.size:
+                pos = np.searchsorted(dense_row_ids, r[in_dr])
+                dense_rows[pos, c[in_dr]] = v[in_dr]
+                r, c, v = r[~in_dr], c[~in_dr], v[~in_dr]
 
-    nbr = max(1, -(-n_rows // TILE_R))
-    nbc = max(1, -(-n_cols // TILE_C))
+        nbr = max(1, -(-n_rows // TILE_R))
+        nbc = max(1, -(-n_cols // TILE_C))
 
-    # --- optional column permutation (clustered-data balance) -------------
-    # Relabel columns frequency-round-robin across windows when that
-    # predicts fewer packed sublanes (summed over both orientations).
-    # Spill/dense/cold paths keep ORIGINAL column ids; only the tiled
-    # layouts see permuted ones, at the cost of one d-sized gather of the
-    # input vector (matvec side) / output vector (rmatvec side).
-    col_perm = None
-    c_tiled = c
-    if col_permutation and r.size and n_cols > WIN:
-        m = _balance_col_perm(c, n_cols, nbc)
-        c_perm = m[c]
-        a_id = (_predict_a(r, c, nbr, nbc)
-                + _predict_a(c, r, nbc, nbr))
-        a_pm = (_predict_a(r, c_perm, nbr, nbc)
-                + _predict_a(c_perm, r, nbc, nbr))
-        # Engage only when the predicted slot-BYTE saving clearly exceeds
-        # the gather traffic the permutation adds (a d-sized take of w per
-        # matvec + an unpermute take per rmatvec).  The 8x margin covers
-        # jnp.take's per-byte inefficiency vs pure streaming for
-        # moderate-sized gathers; marginal predicted wins stay identity.
-        saving_bytes = (a_id - a_pm) * (nbr * nbc) * WIN * (CODE_BYTES + 4)
-        gather_bytes = 2 * (nbc * TILE_C) * 4
-        if a_pm < a_id and saving_bytes >= 8 * gather_bytes:
-            col_perm = m
-            c_tiled = c_perm
+        # --- optional column permutation (clustered-data balance) ---------
+        # Relabel columns frequency-round-robin across windows when that
+        # predicts fewer packed sublanes (summed over both orientations).
+        # Spill/dense/cold paths keep ORIGINAL column ids; only the tiled
+        # layouts see permuted ones, at the cost of one d-sized gather of the
+        # input vector (matvec side) / output vector (rmatvec side).
+        col_perm = None
+        c_tiled = c
+        if col_permutation and r.size and n_cols > WIN:
+            with layer_span("layout.col_perm"):
+                m = _balance_col_perm(c, n_cols, nbc)
+                c_perm = m[c]
+                a_id = (_predict_a(r, c, nbr, nbc)
+                        + _predict_a(c, r, nbc, nbr))
+                a_pm = (_predict_a(r, c_perm, nbr, nbc)
+                        + _predict_a(c_perm, r, nbc, nbr))
+            # Engage only when the predicted slot-BYTE saving clearly exceeds
+            # the gather traffic the permutation adds (a d-sized take of w per
+            # matvec + an unpermute take per rmatvec).  The 8x margin covers
+            # jnp.take's per-byte inefficiency vs pure streaming for
+            # moderate-sized gathers; marginal predicted wins stay identity.
+            saving_bytes = (a_id - a_pm) * (nbr * nbc) * WIN * (CODE_BYTES + 4)
+            gather_bytes = 2 * (nbc * TILE_C) * 4
+            if a_pm < a_id and saving_bytes >= 8 * gather_bytes:
+                col_perm = m
+                c_tiled = c_perm
 
-    f_code, f_val, f_spill, a_f, depth_f = _build_orientation(
-        r, c_tiled, v, nbr, nbc, depth_cap)
-    b_code, b_val, b_spill, a_b, depth_b = _build_orientation(
-        c_tiled, r, v, nbc, nbr, depth_cap)
+        def orient(side, rows_, cols_, vals_, **kw):
+            with layer_span("layout.orient", side=side):
+                if side == "f":
+                    return _build_orientation(
+                        rows_, cols_, vals_, nbr, nbc, depth_cap, **kw)
+                return _build_orientation(
+                    cols_, rows_, vals_, nbc, nbr, depth_cap, **kw)
 
-    # Entries spilled from EITHER orientation go through the COO path for
-    # BOTH directions (keeps matvec and rmatvec consistent with one X).
-    spilled = np.union1d(f_spill, b_spill)
-    if spilled.size:
-        spill_coo = from_coo(
-            r[spilled], c[spilled], v[spilled], n_rows, n_cols, dtype=dtype,
+        f_code, f_val, f_spill, a_f, depth_f = orient("f", r, c_tiled, v)
+        b_code, b_val, b_spill, a_b, depth_b = orient("b", r, c_tiled, v)
+
+        # Entries spilled from EITHER orientation go through the COO path for
+        # BOTH directions (keeps matvec and rmatvec consistent with one X).
+        spilled = np.union1d(f_spill, b_spill)
+        if spilled.size:
+            spill_triples = (r[spilled], c[spilled], v[spilled])
+            # Rebuild both orientations without the spilled entries so neither
+            # tiled layout double-counts them (host-side, one extra pass).
+            keep = np.ones(r.shape[0], bool)
+            keep[spilled] = False
+            f_code, f_val, fs2, a_f, depth_f = orient(
+                "f", r[keep], c_tiled[keep], v[keep], spill_cost_ratio=np.inf)
+            b_code, b_val, bs2, a_b, depth_b = orient(
+                "b", r[keep], c_tiled[keep], v[keep], spill_cost_ratio=np.inf)
+            assert fs2.size == 0 and bs2.size == 0, "re-spill after rebuild"
+        else:
+            spill_triples = (np.zeros(1, np.int64), np.zeros(1, np.int64),
+                             np.zeros(1, np.float32))
+        s_rows, s_cols, s_vals = canonicalize_coo(
+            *spill_triples, n_rows, n_cols)
+        spill_coo = SparseMatrix(
+            row_ids=s_rows, col_ids=s_cols, values=np.asarray(s_vals, dtype),
+            n_rows=int(n_rows), n_cols=int(n_cols),
         )
-        # Rebuild both orientations without the spilled entries so neither
-        # tiled layout double-counts them (host-side, one extra pass).
-        keep = np.ones(r.shape[0], bool)
-        keep[spilled] = False
-        f_code, f_val, fs2, a_f, depth_f = _build_orientation(
-            r[keep], c_tiled[keep], v[keep], nbr, nbc, depth_cap,
-            spill_cost_ratio=np.inf)
-        b_code, b_val, bs2, a_b, depth_b = _build_orientation(
-            c_tiled[keep], r[keep], v[keep], nbc, nbr, depth_cap,
-            spill_cost_ratio=np.inf)
-        assert fs2.size == 0 and bs2.size == 0, "re-spill after rebuild"
-    else:
-        spill_coo = from_coo(
-            np.zeros(1, np.int64), np.zeros(1, np.int64),
-            np.zeros(1, np.float32), n_rows, n_cols, dtype=dtype,
+
+        if col_perm is not None:
+            inv = np.full(nbc * TILE_C, n_cols, np.int64)  # default: zero slot
+            inv[col_perm] = np.arange(n_cols)
+            perm_fwd = col_perm.astype(np.int32)
+            perm_inv = inv.astype(np.int32)
+        else:
+            perm_fwd = np.zeros((1,), np.int32)
+            perm_inv = np.zeros((1,), np.int32)
+
+        # Binary-matrix fast path: when every TILED value is 1.0 (dense
+        # stripes and spill keep their true values), drop the f32 val
+        # stream — the kernels then move 2 bytes/slot instead of 6 ("auto";
+        # False forces the valued layout, e.g. for A/B measurement).
+        tiled_vals = v[keep] if spilled.size else v
+        unit = (
+            unit_values == "auto"
+            and (tiled_vals.size == 0 or bool(np.all(tiled_vals == 1.0)))
+        ) or unit_values is True
+        if unit_values is True and tiled_vals.size and not np.all(
+            tiled_vals == 1.0
+        ):
+            raise ValueError(
+                "unit_values=True but tiled values are not all 1.0")
+        if unit:
+            f_val = np.zeros((1,), np.float32)
+            b_val = np.zeros((1,), np.float32)
+
+        P = PallasSparseMatrix(
+            f_code=f_code, f_val=f_val, b_code=b_code, b_val=b_val,
+            spill=SpillData(
+                spill_coo=spill_coo, has_spill=bool(spilled.size),
+            ),
+            dense_cols=dense_cols,
+            dense_col_ids=dense_col_ids.astype(np.int32),
+            dense_rows=dense_rows,
+            dense_row_ids=dense_row_ids.astype(np.int32),
+            col_perm_fwd=perm_fwd, col_perm_inv=perm_inv,
+            host_coo=host_coo,
+            n_rows=int(n_rows), n_cols=int(n_cols),
+            nbr=nbr, nbc=nbc, a_f=a_f, a_b=a_b,
+            depth_f=depth_f, depth_b=depth_b,
+            has_dense_cols=bool(dense_col_ids.size),
+            has_dense_rows=bool(dense_row_ids.size),
+            has_col_perm=col_perm is not None,
+            unit_vals=unit,
         )
+        build.set(
+            nnz=P.nnz, a_f=a_f, a_b=a_b,
+            stripes=len(dense_col_ids) + len(dense_row_ids),
+            has_col_perm=P.has_col_perm, spilled=int(spilled.size),
+        )
+    return P
 
-    if col_perm is not None:
-        inv = np.full(nbc * TILE_C, n_cols, np.int64)  # default: zero slot
-        inv[col_perm] = np.arange(n_cols)
-        perm_fwd = jnp.asarray(col_perm, jnp.int32)
-        perm_inv = jnp.asarray(inv, jnp.int32)
-    else:
-        perm_fwd = jnp.zeros((1,), jnp.int32)
-        perm_inv = jnp.zeros((1,), jnp.int32)
 
-    # Binary-matrix fast path: when every TILED value is 1.0 (dense
-    # stripes and spill keep their true values), drop the f32 val stream —
-    # the kernels then move 2 bytes/slot instead of 6 ("auto"; False
-    # forces the valued layout, e.g. for A/B measurement).
-    tiled_vals = v[keep] if spilled.size else v
-    unit = (
-        unit_values == "auto"
-        and (tiled_vals.size == 0 or bool(np.all(tiled_vals == 1.0)))
-    ) or unit_values is True
-    if unit_values is True and tiled_vals.size and not np.all(
-        tiled_vals == 1.0
-    ):
-        raise ValueError("unit_values=True but tiled values are not all 1.0")
-    if unit:
-        f_val = np.zeros((1,), np.float32)
-        b_val = np.zeros((1,), np.float32)
+def place_pallas_matrix(P: PallasSparseMatrix) -> PallasSparseMatrix:
+    """Put every leaf of a host-built layout on the default device."""
+    return jax.tree.map(jnp.asarray, P)
 
-    return PallasSparseMatrix(
-        f_code=jnp.asarray(f_code), f_val=jnp.asarray(f_val),
-        b_code=jnp.asarray(b_code), b_val=jnp.asarray(b_val),
-        spill=SpillData(
-            spill_coo=spill_coo, has_spill=bool(spilled.size),
-        ),
-        dense_cols=jnp.asarray(dense_cols),
-        dense_col_ids=jnp.asarray(dense_col_ids, jnp.int32),
-        dense_rows=jnp.asarray(dense_rows),
-        dense_row_ids=jnp.asarray(dense_row_ids, jnp.int32),
-        col_perm_fwd=perm_fwd, col_perm_inv=perm_inv,
-        host_coo=host_coo,
-        n_rows=int(n_rows), n_cols=int(n_cols),
-        nbr=nbr, nbc=nbc, a_f=a_f, a_b=a_b,
-        depth_f=depth_f, depth_b=depth_b,
-        has_dense_cols=bool(dense_col_ids.size),
-        has_dense_rows=bool(dense_row_ids.size),
-        has_col_perm=col_perm is not None,
-        unit_vals=unit,
-    )
+
+def build_pallas_matrix(*args, **kwargs) -> PallasSparseMatrix:
+    """:func:`build_pallas_host`, placed (same arguments)."""
+    return place_pallas_matrix(build_pallas_host(*args, **kwargs))
+
+
+def host_layout_from_scipy_csr(csr, depth_cap: int = 128,
+                               pad_nnz: Optional[int] = None,
+                               dtype=jnp.float32) -> PallasSparseMatrix:
+    """:func:`build_pallas_host` of a scipy CSR matrix."""
+    csr = csr.tocsr()
+    csr.sum_duplicates()
+    coo = csr.tocoo()
+    return build_pallas_host(
+        coo.row.astype(np.int64), coo.col.astype(np.int64), coo.data,
+        csr.shape[0], csr.shape[1], depth_cap=depth_cap, pad_nnz=pad_nnz,
+        dtype=dtype)
 
 
 def from_scipy_csr_pallas(csr, depth_cap: int = 128, pad_nnz: Optional[int] = None,
                           dtype=jnp.float32) -> PallasSparseMatrix:
-    csr = csr.tocsr()
-    csr.sum_duplicates()
-    coo = csr.tocoo()
-    return build_pallas_matrix(
-        coo.row.astype(np.int64), coo.col.astype(np.int64), coo.data,
-        csr.shape[0], csr.shape[1], depth_cap=depth_cap, pad_nnz=pad_nnz,
-        dtype=dtype)
+    return place_pallas_matrix(
+        host_layout_from_scipy_csr(csr, depth_cap, pad_nnz, dtype))
 
 
 # ---------------------------------------------------------------------------

@@ -68,6 +68,10 @@ class SolveResult(NamedTuple):
     # plateau exit into ``converged`` (the historical contract); SPG
     # reports it.
     stalled: Array | None = None
+    # Objective (value+gradient) evaluations the solve made, the starting
+    # one included, counted inside the solve; None for solvers that do not
+    # count them.  ``lbfgs_solve`` reports it.
+    fn_evals: Array | None = None
 
 
 class _LBFGSState(NamedTuple):
@@ -79,6 +83,7 @@ class _LBFGSState(NamedTuple):
     rho: Array  # (m,) 1 / <s, y>;  0 marks an empty/skipped slot
     gamma: Array  # initial-Hessian scale <s,y>/<y,y>
     k: Array  # iteration counter
+    fn_evals: Array  # objective evaluations so far
     n_pairs: Array  # total pairs ever stored (for masking)
     done: Array
     converged: Array
@@ -180,6 +185,7 @@ def lbfgs_solve(
         rho=jnp.zeros((m,), dtype),
         gamma=jnp.asarray(1.0, dtype),
         k=jnp.asarray(0, jnp.int32),
+        fn_evals=jnp.asarray(1, jnp.int32),
         n_pairs=jnp.asarray(0, jnp.int32),
         done=g0_norm <= config.tolerance * tol_scale,
         converged=g0_norm <= config.tolerance * tol_scale,
@@ -191,14 +197,15 @@ def lbfgs_solve(
         return jnp.logical_and(~s.done, s.k < config.max_iters)
 
     def body(s: _LBFGSState):
-        direction = -_two_loop(
-            s.grad, s.S, s.Y, s.rho, s.gamma, s.n_pairs, w_axis
-        )
-        dg = pvdot(direction, s.grad, w_axis)
-        # Fall back to steepest descent if the history produced a
-        # non-descent direction (can happen after skipped updates).
-        bad = dg >= 0.0
-        direction = jnp.where(bad, -s.grad, direction)
+        with jax.named_scope("lbfgs.two_loop"):
+            direction = -_two_loop(
+                s.grad, s.S, s.Y, s.rho, s.gamma, s.n_pairs, w_axis
+            )
+            dg = pvdot(direction, s.grad, w_axis)
+            # Fall back to steepest descent if the history produced a
+            # non-descent direction (can happen after skipped updates).
+            bad = dg >= 0.0
+            direction = jnp.where(bad, -s.grad, direction)
 
         # First iteration: scale the initial step like Breeze
         # (1 / ||g||, capped at 1) so the unit quasi-Newton step is sane later.
@@ -207,15 +214,18 @@ def lbfgs_solve(
             first, jnp.minimum(1.0, 1.0 / pnorm(s.grad, w_axis)), 1.0
         )
 
-        ls = wolfe_line_search(
-            value_and_grad, s.w, s.value, s.grad, direction,
-            initial_step=init_step, config=config.line_search, w_axis=w_axis,
-        )
+        with jax.named_scope("lbfgs.linesearch"):
+            ls = wolfe_line_search(
+                value_and_grad, s.w, s.value, s.grad, direction,
+                initial_step=init_step, config=config.line_search,
+                w_axis=w_axis,
+            )
 
-        S, Y, rho, gamma, n_pairs = update_history(
-            s.S, s.Y, s.rho, s.gamma, s.n_pairs, ls.w - s.w, ls.grad - s.grad,
-            w_axis,
-        )
+        with jax.named_scope("lbfgs.update"):
+            S, Y, rho, gamma, n_pairs = update_history(
+                s.S, s.Y, s.rho, s.gamma, s.n_pairs, ls.w - s.w,
+                ls.grad - s.grad, w_axis,
+            )
 
         k = s.k + 1
         g_norm = pnorm(ls.grad, w_axis)
@@ -248,6 +258,7 @@ def lbfgs_solve(
             grad=grad_next,
             S=S, Y=Y, rho=rho, gamma=gamma,
             k=k,
+            fn_evals=s.fn_evals + ls.n_evals,
             n_pairs=n_pairs,
             done=jnp.logical_or(converged, stalled),
             converged=converged,
@@ -266,4 +277,5 @@ def lbfgs_solve(
         converged=final.converged,
         values=final.values,
         grad_norms=final.grad_norms,
+        fn_evals=final.fn_evals,
     )

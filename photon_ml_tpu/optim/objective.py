@@ -136,11 +136,13 @@ class GlmObjective:
     def value_and_grad(
         self, w: Array, data: GlmData, l2_weight=0.0, axis_name: str | None = None
     ) -> tuple[Array, Array]:
-        val, grad = self.raw_value_and_grad(w, data)
-        if axis_name is not None:
-            # The treeAggregate analogue: one fused all-reduce over ICI.
-            val, grad = lax.psum((val, grad), axis_name)
-        return val + 0.5 * l2_weight * jnp.dot(w, w), grad + l2_weight * w
+        with jax.named_scope("objective.value_and_grad"):
+            val, grad = self.raw_value_and_grad(w, data)
+            if axis_name is not None:
+                # The treeAggregate analogue: one fused all-reduce over ICI.
+                val, grad = lax.psum((val, grad), axis_name)
+            return (val + 0.5 * l2_weight * jnp.dot(w, w),
+                    grad + l2_weight * w)
 
     def hvp(
         self,

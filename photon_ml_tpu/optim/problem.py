@@ -256,57 +256,66 @@ class GlmOptimizationProblem:
         ``variance_fn(w, lam)`` runs for EVERY grid point (including
         restored ones) when coefficient variances are requested.
 
-        Each fresh solve runs under a ``solver`` telemetry span and is
-        wall-clocked to COMPLETION (``Timer.stop_blocking`` on the
-        solution vector — the grid is a warm-start chain, so solves were
-        already serialized; the block only moves the sync to where it can
-        be attributed).  Per-λ walls land in ``self.grid_wall_seconds``
-        so drivers can put real wall-clock on their convergence
-        trackers."""
-        from photon_ml_tpu.utils.timer import Timer
-
+        The call is one ``grid`` layer span; each fresh solve runs under
+        a ``solver`` layer span (telemetry.layer_span: recorded with or
+        without a hub) that ends at the blocking read of the solution
+        vector -- the grid is a warm-start chain, so solves were already
+        serialized; the block only moves the sync to where it can be
+        attributed.  Per-λ walls, the span's own duration, land in
+        ``self.grid_wall_seconds`` so drivers can put real wall-clock on
+        their convergence trackers."""
         tel = telemetry_mod.current()
         self.grid_wall_seconds: dict[float, float] = {}
         results = []
         w_prev = w0
         solved = solved or {}
-        for lam in sorted(reg_weights, reverse=True):
-            if lam in solved:
-                w = jnp.asarray(solved[lam])
-                res = None
-                tel.event("grid.restored", reg_weight=float(lam))
-            else:
-                with tel.span(
-                    "solver",
-                    reg_weight=float(lam),
-                    optimizer=self.config.optimizer.optimizer.value,
-                ) as sp:
-                    timer = Timer().start()
-                    res = solve_fn(lam, w_prev)
-                    wall = timer.stop_blocking(res.w)
-                    if tel.enabled:
-                        # res.w is ready (blocked above), so these scalar
-                        # readbacks cost a copy, not a device sync.
-                        iters = int(res.iterations)
+        with telemetry_mod.layer_span("grid"):
+            for lam in sorted(reg_weights, reverse=True):
+                if lam in solved:
+                    w = jnp.asarray(solved[lam])
+                    res = None
+                    tel.event("grid.restored", reg_weight=float(lam))
+                else:
+                    with telemetry_mod.layer_span(
+                        "solver",
+                        reg_weight=float(lam),
+                        optimizer=self.config.optimizer.optimizer.value,
+                    ) as sp:
+                        res = solve_fn(lam, w_prev)
+                        # Queue the scalars' copies behind the solve: they
+                        # are on the host when the blocking read returns,
+                        # and the read-back below waits for nothing (a
+                        # copy asked for only then idles the device ~1 ms
+                        # a solve on a TPU v5e).
+                        counts = jax.copy_to_host_async(
+                            (res.iterations, res.fn_evals, res.converged))
+                        jax.block_until_ready(res.w)
+                        wall = sp.stop()
+                        iters, fn_evals, converged = jax.device_get(counts)
+                        iters = int(iters)
                         sp.set(
                             iterations=iters,
-                            converged=bool(res.converged),
+                            converged=bool(converged),
                             wall_seconds=wall,
                         )
                         tel.counter("solver_iterations").inc(iters)
                         tel.histogram("solver_wall_seconds").observe(wall)
-                self.grid_wall_seconds[lam] = wall
-                w = res.w
-                if on_solved is not None:
-                    on_solved(lam, w)
-                # The natural crash/resume boundary of the warm-start
-                # chain: the point is solved AND persisted, nothing of
-                # the next λ has started (docs/robustness.md).
-                chaos_mod.maybe_fail("grid.point", reg_weight=float(lam))
-            variances = variance_fn(w, lam) if variance_fn is not None else None
-            results.append((lam, self.make_model(w, variances), res))
-            if warm_start:
-                w_prev = w
+                        if fn_evals is not None:
+                            sp.set(fn_evals=int(fn_evals))
+                            tel.counter("solver_fn_evals").inc(int(fn_evals))
+                    self.grid_wall_seconds[lam] = wall
+                    w = res.w
+                    if on_solved is not None:
+                        on_solved(lam, w)
+                    # The natural crash/resume boundary of the warm-start
+                    # chain: the point is solved AND persisted, nothing of
+                    # the next λ has started (docs/robustness.md).
+                    chaos_mod.maybe_fail("grid.point", reg_weight=float(lam))
+                variances = (variance_fn(w, lam) if variance_fn is not None
+                             else None)
+                results.append((lam, self.make_model(w, variances), res))
+                if warm_start:
+                    w_prev = w
         return results
 
     def run_grid(

@@ -214,7 +214,8 @@ def _make_tp_solver(task: str, mesh: Mesh, config: LBFGSConfig):
             spmd,
             mesh=mesh,
             in_specs=_TP_IN_SPECS,
-            out_specs=_TP_OUT_SPECS,
+            # lbfgs_solve alone counts its objective evaluations
+            out_specs=_TP_OUT_SPECS._replace(fn_evals=P()),
             check_vma=False,
         )
     )

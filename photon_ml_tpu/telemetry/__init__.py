@@ -29,6 +29,7 @@ from photon_ml_tpu.telemetry.core import (  # noqa: F401
     Counter,
     Gauge,
     Histogram,
+    LayerSpan,
     MetricsRegistry,
     Span,
     Telemetry,
@@ -36,6 +37,8 @@ from photon_ml_tpu.telemetry.core import (  # noqa: F401
     current,
     dump_flight_recorder,
     json_safe,
+    layer_span,
+    layer_spans,
     set_current,
 )
 from photon_ml_tpu.telemetry.exporter import (  # noqa: F401

@@ -36,6 +36,8 @@ import time
 import uuid
 from typing import Optional
 
+from photon_ml_tpu.telemetry.recorder import FlightRecorder
+
 
 # ---------------------------------------------------------------------------
 # JSON sanitization (device-sync-safe)
@@ -530,7 +532,12 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        t1 = time.perf_counter()
+        return self.close(time.perf_counter(), exc_type, exc)
+
+    def close(self, t1: float, exc_type=None, exc=None) -> bool:
+        """End the span at ``t1`` (a ``perf_counter`` reading).  What
+        ``__exit__`` does; a :class:`LayerSpan` calls it with its own
+        clock read, so one interval is measured once."""
         hub = self._hub
         stack = hub._span_stack()
         # Defensive pop: a mismatched exit (caller error) must not corrupt
@@ -978,3 +985,108 @@ def dump_flight_recorder(reason: str, path=None) -> Optional[str]:
     watchdog's fatal path call this so every deliberate or fatal failure
     leaves its trailing event window on disk."""
     return current().dump_flight_recorder(reason, path)
+
+
+# ---------------------------------------------------------------------------
+# Layer spans: always recorded, hub or no hub
+# ---------------------------------------------------------------------------
+
+#: Process-wide ring of the layer spans (docs/telemetry.md "Layer spans").
+#: Bounded, appended to without a lock, never written anywhere by itself.
+_LAYER_RING = FlightRecorder(capacity=4096)
+_layer_ids = itertools.count(1)
+_layer_local = threading.local()
+
+
+class LayerSpan:
+    """A span at a LAYER boundary of the training path, as
+    ``layer_span(name, **attrs)``: work of a millisecond or more (a layout
+    build, a placement, a grid, a solve), never per request, row or
+    iteration -- those stay :meth:`Telemetry.span`, one branch under the
+    ``NULL`` hub.
+
+    Unlike :meth:`Telemetry.span` it records with no hub installed: on
+    exit one record of :class:`Span`'s schema (``type, name, ts, dur, id,
+    parent, tid, attrs``) goes into a process-wide bounded ring, with
+    ``ts`` in absolute ``time.perf_counter()`` seconds and ``parent`` the
+    enclosing layer span of this thread.  When the process-current hub is
+    active the same interval is also that hub's :class:`Span` (same two
+    clock reads, same attributes).  A ``jax.profiler.TraceAnnotation`` of
+    the same name is entered, so under a profiler session the span lies on
+    the host's ``python`` line, on the device plane's clock.
+    """
+
+    __slots__ = ("name", "attrs", "span_id", "parent_id", "t0", "t1",
+                 "_hub_span", "_annotation")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.t1 = None
+
+    def set(self, **attrs) -> "LayerSpan":
+        """Attach attributes mid-span (counts made at this boundary)."""
+        self.attrs.update(attrs)
+        return self
+
+    def __enter__(self) -> "LayerSpan":
+        from jax.profiler import TraceAnnotation
+
+        stack = getattr(_layer_local, "stack", None)
+        if stack is None:
+            stack = _layer_local.stack = []
+        self.parent_id = stack[-1].span_id if stack else None
+        self.span_id = next(_layer_ids)
+        stack.append(self)
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        # Where the hub's span is a real Span it shares this attrs dict
+        # and gives the start reading: one interval, two clock reads.
+        self._hub_span = current().span(self.name)
+        if isinstance(self._hub_span, Span):
+            self._hub_span.attrs = self.attrs
+            self.t0 = self._hub_span.__enter__().t0
+        else:
+            self.t0 = time.perf_counter()
+        return self
+
+    def stop(self) -> float:
+        """End the span now and return its duration in seconds; the
+        ``with`` block's end then only files the record.  For a caller
+        that reports the span's own duration among the attributes it
+        sets (``solver``'s ``wall_seconds``)."""
+        if self.t1 is None:
+            self.t1 = time.perf_counter()
+            self._annotation.__exit__(None, None, None)
+            stack = _layer_local.stack
+            while stack and stack.pop() is not self:
+                pass
+        return self.t1 - self.t0
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        record = {
+            "type": "span",
+            "name": self.name,
+            "ts": self.t0,
+            "dur": self.stop(),
+            "id": self.span_id,
+            "parent": self.parent_id,
+            "tid": threading.get_ident(),
+        }
+        if exc_type is not None:
+            record["error"] = f"{exc_type.__name__}: {exc}"
+        if self.attrs:
+            record["attrs"] = {k: json_safe(v)
+                               for k, v in self.attrs.items()}
+        _LAYER_RING.emit(record)
+        if isinstance(self._hub_span, Span):
+            self._hub_span.close(self.t1, exc_type, exc)
+        return False
+
+
+layer_span = LayerSpan
+
+
+def layer_spans() -> list[dict]:
+    """A copy of the ring of layer-span records, oldest first."""
+    return _LAYER_RING.snapshot()

@@ -1,0 +1,75 @@
+"""The tile kernel's names in a program compiled for the chip: a described
+``v5e:2x2`` (nothing attached, nothing runs).  ``pl.pallas_call(name=...)``
+renames the HLO instruction, and ``benchmarks/trace.py`` finds the kernel's
+device events by that name, so the name is part of the yardstick.
+
+The topology is described in a module-scoped fixture and nowhere at import:
+only one process may load the TPU's library, and every xdist worker imports
+every test file.  Keep such tests in this one file."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from benchmarks import trace as trace_mod
+from photon_ml_tpu.ops.sparse_pallas import build_pallas_host
+
+N_ROWS, N_COLS, NNZ = 4096, 3000, 40000
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def layout_shapes(one_chip):
+    rng = np.random.default_rng(0)
+    P = build_pallas_host(
+        rng.integers(0, N_ROWS, NNZ), rng.integers(0, N_COLS, NNZ),
+        rng.normal(size=NNZ).astype(np.float32), N_ROWS, N_COLS)
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        P)
+
+
+def _kernel_calls(compiled_text):
+    """Names of the custom calls that ``trace.is_kernel`` accepts."""
+    lines = [ln.strip() for ln in compiled_text.splitlines()
+             if trace_mod.KERNEL_OPCODE in ln]
+    return [trace_mod.short_name(ln) for ln in lines
+            if trace_mod.is_kernel(ln)]
+
+
+@pytest.mark.parametrize("product, length, name", [
+    ("matvec", N_COLS, "_tiled_apply_fwd"),
+    ("rmatvec", N_ROWS, "_tiled_apply_bwd"),
+    ("row_sq_matvec", N_COLS, "_tiled_apply_fwd"),
+    ("sq_rmatvec", N_ROWS, "_tiled_apply_bwd"),
+])
+def test_kernel_instruction_names(monkeypatch, one_chip, layout_shapes,
+                                  product, length, name):
+    # Mosaic, not the interpreter: other test files set this for the process
+    monkeypatch.delenv("PHOTON_PALLAS_INTERPRET", raising=False)
+    vec = jax.ShapeDtypeStruct((length,), jnp.float32, sharding=one_chip)
+    # the chip runs without x64 (tests/conftest.py turns it on), and Mosaic
+    # takes no 64-bit scalar
+    with jax.enable_x64(False):
+        text = jax.jit(lambda P, v: getattr(P, product)(v)).lower(
+            layout_shapes, vec).compile().as_text()
+    calls = _kernel_calls(text)
+    assert len(calls) == 1, calls
+    stem, _, suffix = calls[0].rpartition(".")
+    assert stem == name and suffix.isdigit()

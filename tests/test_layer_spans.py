@@ -1,0 +1,361 @@
+"""Layer spans of the training path (telemetry.layer_span): recorded with no
+hub installed, the same interval as the hub's span under a driver's hub,
+mirrored into the JAX profiler; and the solver's own count of objective
+evaluations (``SolveResult.fn_evals``).  CPU, Pallas in interpret mode,
+small sizes."""
+
+import glob
+import json
+import os
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+import scipy.sparse as sp
+
+from photon_ml_tpu import telemetry
+from photon_ml_tpu.data.dataset import make_glm_data
+from photon_ml_tpu.optim.lbfgs import LBFGSConfig, lbfgs_solve
+from photon_ml_tpu.optim.problem import (
+    GlmOptimizationConfig,
+    GlmOptimizationProblem,
+    OptimizerConfig,
+)
+from photon_ml_tpu.optim.regularization import RegularizationContext
+from photon_ml_tpu.telemetry import core as telemetry_core
+
+GRID = [1.0, 0.1]
+LAYOUT_CHILDREN = ["layout.canonicalize", "layout.dense_split",
+                   "layout.col_perm", "layout.orient", "layout.orient"]
+
+
+@pytest.fixture
+def interpret(monkeypatch):
+    monkeypatch.setenv("PHOTON_PALLAS_INTERPRET", "1")
+
+
+def _corpus(n=2048, d=1000):
+    rng = np.random.default_rng(0)
+    X = sp.random(n, d, density=0.01, random_state=1, format="csr",
+                  dtype=np.float32)
+    return X, (rng.uniform(size=n) < 0.5).astype(np.float32)
+
+
+def _problem(max_iters=4):
+    return GlmOptimizationProblem(
+        "logistic",
+        GlmOptimizationConfig(
+            optimizer=OptimizerConfig(max_iters=max_iters),
+            regularization=RegularizationContext.l2(),
+        ),
+    )
+
+
+def _mark():
+    """The newest layer span so far (the ring may be full, and a parent is
+    filed after its children: ids, not positions, say what is new)."""
+    return max((r["id"] for r in telemetry.layer_spans()), default=0)
+
+
+def _since(mark):
+    return [r for r in telemetry.layer_spans() if r["id"] > mark]
+
+
+def _fit():
+    """A tiny make_glm_data + run_grid; returns the layer spans it left."""
+    mark = _mark()
+    X, y = _corpus()
+    data = make_glm_data(X, y, use_pallas=True)
+    results = _problem().run_grid(data, GRID)
+    return _since(mark), data, results
+
+
+@pytest.fixture
+def fit(interpret):
+    assert telemetry.current() is telemetry.NULL
+    return _fit()
+
+
+def _named(spans, name):
+    return [r for r in spans if r["name"] == name]
+
+
+def _inside(child, parent):
+    return (parent["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+
+
+class TestNoHub:
+    def test_layout_spans_and_parents(self, fit):
+        spans, _data, _results = fit
+        (made,) = _named(spans, "data.make_glm_data")
+        (build,) = _named(spans, "layout.build")
+        (place,) = _named(spans, "layout.place")
+        assert made["parent"] is None
+        assert build["parent"] == made["id"] == place["parent"]
+        kids = sorted((r for r in spans if r["parent"] == build["id"]),
+                      key=lambda r: r["ts"])
+        assert [k["name"] for k in kids] == LAYOUT_CHILDREN
+        assert [k["attrs"]["side"] for k in kids[-2:]] == ["f", "b"]
+        for child, parent in [(build, made), (place, made),
+                              *((k, build) for k in kids)]:
+            assert _inside(child, parent), (child["name"], parent["name"])
+        # the build ends before the first copy to the device starts
+        assert build["ts"] + build["dur"] <= place["ts"]
+
+    def test_layout_attributes(self, fit):
+        spans, data, _results = fit
+        (made,) = _named(spans, "data.make_glm_data")
+        (build,) = _named(spans, "layout.build")
+        (place,) = _named(spans, "layout.place")
+        P = data.features
+        assert made["attrs"] == {"rows": 2048, "nnz": P.nnz,
+                                 "layout": "PallasSparseMatrix"}
+        assert build["attrs"] == {
+            "nnz": P.nnz, "a_f": P.a_f, "a_b": P.a_b,
+            "stripes": len(P.dense_col_ids) + len(P.dense_row_ids),
+            "has_col_perm": P.has_col_perm, "spilled": 0,
+        }
+        assert place["attrs"]["bytes"] == sum(
+            x.nbytes for x in jax.tree.leaves(data))
+
+    def test_make_glm_data_returns_resident_data(self, fit):
+        _spans, data, _results = fit
+        assert all(x.is_fully_addressable and x.is_ready()
+                   for x in jax.tree.leaves(data))
+
+    def test_grid_and_solver_spans(self, fit):
+        spans, _data, results = fit
+        (grid,) = _named(spans, "grid")
+        solvers = _named(spans, "solver")
+        assert grid["parent"] is None and len(solvers) == len(GRID)
+        for s, (lam, _model, res) in zip(solvers, results):
+            assert s["parent"] == grid["id"] and _inside(s, grid)
+            assert s["attrs"] == {
+                "reg_weight": lam, "optimizer": "lbfgs",
+                "iterations": int(res.iterations),
+                "fn_evals": int(res.fn_evals),
+                "converged": bool(res.converged),
+                "wall_seconds": s["dur"],
+            }
+            assert s["attrs"]["fn_evals"] > s["attrs"]["iterations"] > 0
+
+    def test_a_solve_is_measured_once(self, interpret):
+        problem = _problem()
+        X, y = _corpus()
+        mark = _mark()
+        problem.run_grid(make_glm_data(X, y, use_pallas=True), GRID)
+        solvers = _named(_since(mark), "solver")
+        assert problem.grid_wall_seconds == {
+            s["attrs"]["reg_weight"]: s["dur"] for s in solvers}
+
+    def test_a_spill_rebuilds_both_orientations(self, interpret):
+        from photon_ml_tpu.ops.sparse_pallas import build_pallas_host
+
+        rng = np.random.default_rng(3)
+        # one cell of the slot grid far over the depth cap
+        rows = np.concatenate([np.full(40, 5), rng.integers(0, 512, 300)])
+        cols = np.concatenate([np.arange(40) * 128,
+                               rng.integers(0, 6000, 300)])
+        mark = _mark()
+        P = build_pallas_host(rows, cols, np.ones(340, np.float32), 512,
+                              6000, depth_cap=2, col_permutation=False)
+        spans = _since(mark)
+        (build,) = _named(spans, "layout.build")
+        assert P.spill.has_spill and build["attrs"]["spilled"] > 0
+        assert [r["attrs"]["side"] for r in _named(spans, "layout.orient")
+                ] == ["f", "b", "f", "b"]
+        assert all(isinstance(x, np.ndarray) for x in jax.tree.leaves(P))
+
+
+class TestCostWhenOff:
+    def test_no_hub_no_sink_no_io(self, monkeypatch):
+        """Under the NULL hub a layer span touches no sink and no hub lock:
+        either would raise here."""
+        def refuse(*a, **k):
+            raise AssertionError("a layer span reached a hub's sinks")
+
+        monkeypatch.setattr(telemetry.Telemetry, "_emit", refuse)
+        monkeypatch.setattr("builtins.open", refuse)
+        assert telemetry.current() is telemetry.NULL
+        with telemetry.layer_span("layout.build", nnz=3) as sp_:
+            sp_.set(a_f=8)
+        last = telemetry.layer_spans()[-1]
+        assert last["name"] == "layout.build"
+        assert last["attrs"] == {"nnz": 3, "a_f": 8}
+
+    def test_ring_is_bounded(self):
+        capacity = telemetry_core._LAYER_RING.capacity
+        for _ in range(capacity + 10):
+            with telemetry.layer_span("grid"):
+                pass
+        assert len(telemetry.layer_spans()) == capacity
+
+    def test_other_call_sites_stay_one_branch(self):
+        """Every ``tel.span`` call site under the NULL hub is still the
+        shared no-op: nothing allocated, nothing recorded."""
+        mark = _mark()
+        tel = telemetry.current()
+        assert tel is telemetry.NULL
+        assert tel.span("solver", reg_weight=1.0) is telemetry_core._NULL_SPAN
+        with tel.span("chunk") as sp_:
+            sp_.set(rows=1)
+        assert _since(mark) == []
+
+    def test_spans_on_threads_are_roots_of_their_own(self):
+        seen = []
+
+        def work():
+            with telemetry.layer_span("grid") as sp_:
+                seen.append(sp_.parent_id)
+
+        with telemetry.layer_span("data.make_glm_data"):
+            t = threading.Thread(target=work)
+            t.start()
+            t.join(timeout=30)
+        assert not t.is_alive() and seen == [None]
+
+    def test_an_error_is_recorded_and_raised(self):
+        with pytest.raises(ValueError):
+            with telemetry.layer_span("layout.place"):
+                raise ValueError("boom")
+        last = telemetry.layer_spans()[-1]
+        assert last["name"] == "layout.place"
+        assert last["error"] == "ValueError: boom"
+        with telemetry.layer_span("grid") as sp_:
+            assert sp_.parent_id is None  # the failed span left the stack
+
+
+class TestUnderADriverHub:
+    def test_solver_record_in_events_jsonl(self, interpret, tmp_path):
+        with telemetry.Telemetry(output_dir=str(tmp_path)) as tel:
+            with tel.span("train"):
+                spans, _data, results = _fit()
+        with open(os.path.join(tmp_path, "events.jsonl")) as f:
+            records = [json.loads(line) for line in f]
+        by_id = {r["id"]: r for r in records if r.get("type") == "span"}
+        solvers = [r for r in by_id.values() if r["name"] == "solver"]
+        assert len(solvers) == len(GRID)
+        ring = _named(spans, "solver")
+        for rec, mine, (lam, _m, res) in zip(solvers, ring, results):
+            assert set(rec) == {"type", "name", "ts", "dur", "id", "parent",
+                                "tid", "attrs"}
+            assert rec["attrs"] == mine["attrs"]
+            assert rec["attrs"]["wall_seconds"] == rec["dur"] == mine["dur"]
+            assert rec["ts"] == pytest.approx(
+                mine["ts"] - tel._epoch_perf, abs=1e-9)
+            assert by_id[rec["parent"]]["name"] == "grid"
+            assert by_id[by_id[rec["parent"]]["parent"]]["name"] == "train"
+        names = {r["name"] for r in by_id.values()}
+        assert {"data.make_glm_data", "layout.build", "layout.place",
+                "layout.orient", "grid"} <= names
+        snap = records[-1]["snapshot"]
+        assert snap["counters"]["solver_iterations"] == sum(
+            int(res.iterations) for _l, _m, res in results)
+        assert snap["counters"]["solver_fn_evals"] == sum(
+            int(res.fn_evals) for _l, _m, res in results)
+        hist = snap["histograms"]["solver_wall_seconds"]
+        assert hist["count"] == len(GRID)
+        assert hist["sum"] == pytest.approx(sum(s["dur"] for s in ring))
+
+
+class TestFnEvals:
+    @staticmethod
+    def _counted(value_and_grad):
+        calls = []
+
+        def counted(w):
+            jax.debug.callback(lambda: calls.append(1))
+            return value_and_grad(w)
+
+        return counted, calls
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_equals_an_independent_count(self, seed):
+        rng = np.random.default_rng(seed)
+        A = jnp.asarray(rng.normal(size=(40, 12)), jnp.float32)
+        y = jnp.asarray(rng.uniform(size=40) < 0.5, jnp.float32)
+
+        def f(w):  # logistic loss: the line search has to work for its step
+            m = A @ w
+            return jnp.sum(jnp.logaddexp(0.0, m) - y * m) + 0.05 * w @ w
+
+        counted, calls = self._counted(jax.value_and_grad(f))
+        res = jax.jit(lambda w0: lbfgs_solve(
+            counted, w0, LBFGSConfig(max_iters=25)))(
+                jnp.asarray(rng.normal(size=12) * 3, jnp.float32))
+        jax.block_until_ready(res)
+        jax.effects_barrier()
+        assert int(res.fn_evals) == len(calls)
+        assert int(res.fn_evals) > int(res.iterations) + 1  # extra trials
+
+    def test_quadratic_that_accepts_every_first_step(self):
+        rng = np.random.default_rng(5)
+        # float64: in float32 the last iterations' Armijo test is lost in
+        # rounding and the search makes trials that no algebra asks for
+        diag = jnp.asarray(rng.uniform(1.0, 1.5, size=16), jnp.float64)
+        b = jnp.asarray(rng.normal(size=16), jnp.float64)
+        b = 0.5 * b / jnp.linalg.norm(b)  # ||g0|| < 1: the first step is 1
+
+        def vg(w):
+            return 0.5 * w @ (diag * w) - b @ w, diag * w - b
+
+        counted, calls = self._counted(vg)
+        res = jax.jit(lambda w0: lbfgs_solve(
+            counted, w0, LBFGSConfig(max_iters=30)))(jnp.zeros(16, jnp.float64))
+        jax.block_until_ready(res)
+        jax.effects_barrier()
+        assert int(res.iterations) > 2
+        assert int(res.fn_evals) == int(res.iterations) + 1 == len(calls)
+
+    def test_solvers_that_do_not_count_say_none(self, interpret):
+        from photon_ml_tpu.optim.problem import OptimizerType
+
+        X, y = _corpus(512, 40)
+        problem = GlmOptimizationProblem(
+            "logistic",
+            GlmOptimizationConfig(
+                optimizer=OptimizerConfig(optimizer=OptimizerType.TRON,
+                                          max_iters=3),
+                regularization=RegularizationContext.l2(),
+            ),
+        )
+        mark = _mark()
+        ((_lam, _model, res),) = problem.run_grid(
+            make_glm_data(X, y, use_pallas=False), [1.0])
+        assert res.fn_evals is None
+        (solver,) = _named(_since(mark), "solver")
+        assert "fn_evals" not in solver["attrs"]
+        assert solver["attrs"]["iterations"] == int(res.iterations)
+
+
+class TestProfiler:
+    def test_annotations_on_the_host_line(self, interpret, tmp_path):
+        """Under a profiler session the layer spans lie on the host's
+        ``python`` line (where the device plane's clock is the trace's)."""
+        from jax.profiler import ProfileData
+
+        _fit()  # compile first: the trace then holds the spans, not XLA
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            spans, _data, _results = _fit()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+        counts = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:CPU"):
+                for line in plane.lines:
+                    if line.name == "python":
+                        for ev in line.events:
+                            counts[ev.name] = counts.get(ev.name, 0) + 1
+        for name in {r["name"] for r in spans}:
+            assert counts.get(name) == len(_named(spans, name)), name
+
+    def test_nothing_fails_without_a_session(self, fit):
+        spans, _data, _results = fit
+        assert {"data.make_glm_data", "grid", "solver"} <= {
+            r["name"] for r in spans}

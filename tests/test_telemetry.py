@@ -363,7 +363,7 @@ class TestDriverTelemetry:
         names = {r["name"] for r in span_records(records)}
         assert {"run", "read", "summarize", "train", "solver",
                 "validate", "write"} <= names
-        # solver spans nest under train under run
+        # solver spans nest under the grid's span, under train under run
         spans = {r["id"]: r for r in span_records(records)}
         solver = [r for r in span_records(records) if r["name"] == "solver"]
         assert solver
@@ -373,7 +373,7 @@ class TestDriverTelemetry:
             while cur["parent"] is not None:
                 cur = spans[cur["parent"]]
                 chain.append(cur["name"])
-            assert chain == ["train", "run"]
+            assert chain == ["grid", "train", "run"]
             assert s["attrs"]["iterations"] > 0
         trace = json.load(open(os.path.join(out, "trace.json")))
         assert isinstance(trace, list) and any(
