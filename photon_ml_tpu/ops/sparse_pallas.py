@@ -737,10 +737,11 @@ class PallasSparseMatrix:
     storage classes, split at build time:
 
     - **tiled slot grids** — the bulk of the entries, Pallas-kernel fast;
-    - **dense stripes** — ultra-dense columns/rows (an explicit bias column,
-      a few very popular features) extracted into small dense blocks that
-      ride plain MXU matmuls: they would otherwise overload their slot
-      cells and drag the whole layout's depth up;
+    - **dense stripes** — the popular columns/rows (an explicit bias column,
+      the hot tail of a power-law vocabulary) extracted into dense blocks
+      that are multiplied in f32 at the HBM rate: they would otherwise
+      overload their slot cells and drag the whole layout's depth up
+      (how many: :func:`_choose_stripes`);
     - **compact spill** — the residual overflow past the cost-model depth,
       a COO matrix holding ONLY the spilled entries (cost scales with
       spill size, not total nnz).
@@ -817,65 +818,69 @@ class PallasSparseMatrix:
         return jnp.pad(u, (0, target - self.n_rows))
 
     # -- hot paths ---------------------------------------------------------
-    def matvec(self, w: Array) -> Array:
+    # The dense stripes' share of each product: f32 products and f32 sums,
+    # said to the compiler (HIGHEST), so that a stripe block of any height
+    # is multiplied as exactly as the slots are.  On the TPU each is one
+    # bandwidth-bound multiply-reduce fusion over the block; the squared
+    # forms square inside that fusion (no (stripes, long axis) temporary).
+    @staticmethod
+    def _stripes_t_dot(coef: Array, stripes: Array) -> Array:
+        """Σ_k coef[k] · stripes[k, :]."""
+        return jnp.einsum(
+            "k,kn->n", coef, stripes, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    @staticmethod
+    def _stripes_dot(stripes: Array, vec: Array) -> Array:
+        """Σ_n stripes[:, n] · vec[n]."""
+        return jnp.einsum(
+            "kn,n->k", stripes, vec, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    def _apply(self, vec: Array, *, transpose: bool, square: bool) -> Array:
+        """X·vec, or Xᵀ·vec; with ``square``, of the element-wise X²."""
+        sq = (lambda x: x * x) if square else (lambda x: x)
+        if transpose:
+            out = self._uncols(_tiled_apply(
+                self.b_code, self.b_val, self._pad_rows(vec),
+                nbo=self.nbc, nbg=self.nbr, square=square, side="bwd",
+                unit=self.unit_vals,
+            ))
+            spill = self.spill.sq_rmatvec if square else self.spill.rmatvec
+            out = out + spill(vec)
+            if self.has_dense_cols:
+                out = out.at[self.dense_col_ids].add(
+                    self._stripes_dot(sq(self.dense_cols), vec))
+            if self.has_dense_rows:
+                out = out + self._stripes_t_dot(
+                    vec[self.dense_row_ids], sq(self.dense_rows))
+            return out
         out = _tiled_apply(
-            self.f_code, self.f_val, self._pad_cols(w),
-            nbo=self.nbr, nbg=self.nbc, square=False, side="fwd",
+            self.f_code, self.f_val, self._pad_cols(vec),
+            nbo=self.nbr, nbg=self.nbc, square=square, side="fwd",
             unit=self.unit_vals,
         )[: self.n_rows]
-        out = out + self.spill.matvec(w)
+        spill = self.spill.row_sq_matvec if square else self.spill.matvec
+        out = out + spill(vec)
         if self.has_dense_cols:
-            out = out + jnp.einsum(
-                "k,kn->n", w[self.dense_col_ids], self.dense_cols)
-        if self.has_dense_rows:
-            out = out.at[self.dense_row_ids].add(self.dense_rows @ w)
-        return out
-
-    def rmatvec(self, u: Array) -> Array:
-        out = self._uncols(_tiled_apply(
-            self.b_code, self.b_val, self._pad_rows(u),
-            nbo=self.nbc, nbg=self.nbr, square=False, side="bwd",
-            unit=self.unit_vals,
-        ))
-        out = out + self.spill.rmatvec(u)
-        if self.has_dense_cols:
-            out = out.at[self.dense_col_ids].add(self.dense_cols @ u)
-        if self.has_dense_rows:
-            out = out + jnp.einsum(
-                "k,kn->n", u[self.dense_row_ids], self.dense_rows)
-        return out
-
-    def row_sq_matvec(self, v: Array) -> Array:
-        out = _tiled_apply(
-            self.f_code, self.f_val, self._pad_cols(v),
-            nbo=self.nbr, nbg=self.nbc, square=True, side="fwd",
-            unit=self.unit_vals,
-        )[: self.n_rows]
-        out = out + self.spill.row_sq_matvec(v)
-        if self.has_dense_cols:
-            out = out + jnp.einsum(
-                "k,kn->n", v[self.dense_col_ids],
-                self.dense_cols * self.dense_cols)
+            out = out + self._stripes_t_dot(
+                vec[self.dense_col_ids], sq(self.dense_cols))
         if self.has_dense_rows:
             out = out.at[self.dense_row_ids].add(
-                (self.dense_rows * self.dense_rows) @ v)
+                self._stripes_dot(sq(self.dense_rows), vec))
         return out
 
+    def matvec(self, w: Array) -> Array:
+        return self._apply(w, transpose=False, square=False)
+
+    def rmatvec(self, u: Array) -> Array:
+        return self._apply(u, transpose=True, square=False)
+
+    def row_sq_matvec(self, v: Array) -> Array:
+        return self._apply(v, transpose=False, square=True)
+
     def sq_rmatvec(self, u: Array) -> Array:
-        out = self._uncols(_tiled_apply(
-            self.b_code, self.b_val, self._pad_rows(u),
-            nbo=self.nbc, nbg=self.nbr, square=True, side="bwd",
-            unit=self.unit_vals,
-        ))
-        out = out + self.spill.sq_rmatvec(u)
-        if self.has_dense_cols:
-            out = out.at[self.dense_col_ids].add(
-                (self.dense_cols * self.dense_cols) @ u)
-        if self.has_dense_rows:
-            out = out + jnp.einsum(
-                "k,kn->n", u[self.dense_row_ids],
-                self.dense_rows * self.dense_rows)
-        return out
+        return self._apply(u, transpose=True, square=True)
 
     # -- cold paths: host-side over the canonical triples ------------------
     def col_nnz(self, row_mask=None) -> Array:
@@ -959,21 +964,11 @@ def _predict_a(rows, cols, nbr, nbc):
     return int(a_t.max())
 
 
-def _balance_col_perm(cols, n_cols, nbc):
-    """Frequency round-robin column relabeling: rank columns by entry count
-    (descending) and stripe them across ALL column windows of all tiles,
-    rotating the within-window offset so orientation B's lanes (col % 128)
-    spread too.  Returns ``m`` (old col → new col, len n_cols), a bijection
-    into [0, nbc*TILE_C).
-
-    Clustered real-world data (ids sorted by popularity, feature shards
-    grouped by type) concentrates hot columns in a few windows; each
-    window pays its own worst lane in the packed layout, so spreading the
-    mass is a direct A reduction.  Uniform data is unaffected — the
-    builder compares predicted A and keeps the identity when it wins.
-    """
-    counts = np.bincount(cols, minlength=n_cols)
-    ranks = np.argsort(-counts, kind="stable")
+def _round_robin_positions(n_cols, nbc):
+    """Tiled position of the column of popularity rank r (r = 0 the most
+    entries): ranks are striped across ALL column windows of all tiles, and
+    the within-window offset rotates so orientation B's lanes (col % 128)
+    spread too.  A bijection of [0, n_cols) into [0, nbc*TILE_C)."""
     n_win_total = nbc * WINS
     r = np.arange(n_cols, dtype=np.int64)
     w = r % n_win_total            # window round-robin (F-side balance)
@@ -996,28 +991,200 @@ def _balance_col_perm(cols, n_cols, nbc):
         # round order (weaker B-lane spreading, never wrong).
         lane = k
     new = w * WIN + lane
-    m = np.empty(n_cols, np.int64)
-    m[ranks] = new
     assert len(np.unique(new)) == n_cols, "column relabeling not bijective"
+    return new
+
+
+def _balance_col_perm(cols, n_cols, nbc):
+    """Frequency round-robin column relabeling: rank columns by entry count
+    (descending) and place rank r at :func:`_round_robin_positions`.
+    Returns ``m`` (old col → new col, len n_cols), a bijection into
+    [0, nbc*TILE_C).
+
+    Clustered real-world data (ids sorted by popularity, feature shards
+    grouped by type) concentrates hot columns in a few windows; each
+    window pays its own worst lane in the packed layout, so spreading the
+    mass is a direct A reduction.  Uniform data is unaffected — the
+    builder compares predicted A and keeps the identity when it wins.
+    """
+    counts = np.bincount(cols, minlength=n_cols)
+    ranks = np.argsort(-counts, kind="stable")
+    m = np.empty(n_cols, np.int64)
+    m[ranks] = _round_robin_positions(n_cols, nbc)
     return m
 
 
-def _extract_dense(counts, threshold, max_stripes, long_axis,
-                   budget_bytes):
-    """Pick up to ``max_stripes`` indices whose entry count ≥ threshold,
-    densest first, additionally capped so the stripes' dense storage
-    (``long_axis × 4`` bytes each) stays within ``budget_bytes`` — at
-    10⁸-row matrices each column stripe costs ~400 MB, so the count cap
-    alone would blow HBM."""
-    mem_cap = int(budget_bytes // max(long_axis * 4, 1))
-    max_stripes = min(max_stripes, mem_cap)
-    if max_stripes <= 0:
-        return np.empty(0, np.int64)
-    cand = np.flatnonzero(counts >= threshold)
-    if cand.size > max_stripes:
-        cand = cand[np.argsort(-counts[cand], kind="stable")[:max_stripes]]
-        cand = np.sort(cand)
-    return cand.astype(np.int64)
+# What one product pays on a TPU v5e for the two storage classes the stripe
+# chooser trades against each other.  From one sweep on the chip (my chip
+# run, PR 26; PERF.md §6): 804,414 x 47,237, 61.9 M entries, the top K
+# columns forced into stripes, K = 64 ... 1024.  Kernel time is affine in the
+# depth: a forward product 5.35 ms + 0.0411 ms a sublane of a_f, a backward
+# 4.1 ms + 0.0436 ms a sublane of a_b, at 9,432 tiles x 128 slots a sublane;
+# the stripes' fusions run at 748 GB/s.  Only the ratio moves the choice.
+#: device seconds one more slot costs a product (mean of the two slopes)
+SLOT_SECONDS = 35e-12
+#: device seconds an f32 stripe element costs a product
+STRIPE_ELEMENT_SECONDS = 5.4e-12
+# Tiles of the striped axis the chooser evaluates (the hottest by worst
+# lane and by mass); the others are no worse.
+_EVAL_TILES = 32
+
+
+def _threshold_stripes(sorted_counts, long_axis):
+    """How many stripes the rule before PR 26 took: every index in at least
+    1/32 of the long axis (and 256 entries), at most 64, within 512 MiB of
+    dense storage.  The chooser's candidate of last resort and the
+    yardstick of its memory guard (both sides scale with the long axis, so
+    a 10⁸-row input stays as safe as it was)."""
+    above = int(np.searchsorted(
+        -sorted_counts, -max(256, long_axis // 32), side="right"))
+    return min(above, 64, (512 << 20) // max(long_axis * 4, 1))
+
+
+def _capped_max_pmf(mu, copies, cap):
+    """pmf over 0..cap of ``min(cap, max)`` of independent Poisson loads:
+    ``mu`` (G, L) the means of L lanes, each standing for ``copies``
+    lanes alike.  Returns (G, cap + 1)."""
+    m = np.arange(cap)
+    log_fact = np.concatenate([[0.0], np.cumsum(np.log(np.arange(1, cap)))])
+    mu = np.maximum(mu, 1e-300)[..., None]
+    cdf = np.cumsum(np.exp(m * np.log(mu) - mu - log_fact), axis=-1)
+    log_f = copies * np.log(np.clip(cdf, 1e-300, 1.0)).sum(axis=1)
+    below = np.concatenate(
+        [np.zeros((len(log_f), 1)), np.exp(log_f),
+         np.ones((len(log_f), 1))], axis=1)
+    return np.diff(below, axis=1)
+
+
+def _expected_depth(pmfs, copies, replicas):
+    """Expected packed sublane count: each of G tiles sums its W window
+    loads (``pmfs`` (G, W, cap + 1), independent), ``copies`` times over;
+    each tile stands for ``replicas`` tiles alike; the grid's depth is the
+    largest sum."""
+    # support that carries any mass (a load is far under the cap as a rule)
+    c = int(np.flatnonzero(pmfs.max(axis=(0, 1)) > 1e-15)[-1]) + 1
+    n = pmfs.shape[1] * copies * (c - 1) + 1
+    nfft = 1 << (n - 1).bit_length()
+    total = np.fft.irfft(
+        np.fft.rfft(pmfs[:, :, :c], n=nfft, axis=-1).prod(axis=1) ** copies,
+        n=nfft)[:, :n]
+    cdf = np.clip(np.cumsum(np.maximum(total, 0.0), axis=1), 1e-300, 1.0)
+    return float(np.sum(1.0 - np.exp(replicas * np.log(cdf).sum(axis=0))))
+
+
+def _hottest_tiles(mu):
+    """Indices of the tiles of ``mu`` (tiles, ...) worth evaluating."""
+    if len(mu) <= _EVAL_TILES:
+        return np.arange(len(mu))
+    flat = mu.reshape(len(mu), -1)
+    half = _EVAL_TILES // 2
+    return np.union1d(np.argsort(-flat.max(axis=1))[:half],
+                      np.argsort(-flat.sum(axis=1))[:half])
+
+
+def _predict_depths(density, other_len, cap):
+    """(window side, lane side) packed sublane counts predicted from the
+    histogram alone, for entries whose index along the striped axis has
+    ``density`` (tiled position space, a multiple of the tile edge: the
+    share of the other axis's ``other_len`` indices each position meets)
+    and whose other index is uniform.  A cell's load is then Poisson.
+
+    Window side (columns: orientation F): a cell is one lane of the other
+    axis (``per_lane`` indices) under one window of this one.  Lane side
+    (columns: orientation B): a cell is ``win_len`` indices of the other
+    axis under the ≤ WINS positions of this axis that share a lane."""
+    per_tile = min(other_len, TILE_R)
+    replicas = -(-other_len // TILE_R)
+    lanes = min(WIN, other_len)
+    grid = density.reshape(-1, WINS, WIN)
+
+    mu_w = grid.sum(axis=2) * -(-per_tile // lanes)          # (tiles, WINS)
+    mu_w = mu_w[_hottest_tiles(mu_w)]
+    a_window = _expected_depth(
+        _capped_max_pmf(mu_w.reshape(-1, 1), lanes, cap).reshape(
+            len(mu_w), WINS, cap + 1),
+        1, replicas)
+
+    mu_l = grid.sum(axis=1) * lanes                          # (tiles, WIN)
+    mu_l = mu_l[_hottest_tiles(mu_l)]
+    a_lane = _expected_depth(
+        _capped_max_pmf(mu_l, 1, cap)[:, None, :], -(-per_tile // WIN),
+        replicas)
+    return a_window, a_lane
+
+
+def _pad_depth(a):
+    return max(SUBPAD, int(-(-round(a) // SUBPAD) * SUBPAD))
+
+
+def _choose_stripes(counts, long_axis, n_tiles, slot_bytes, cap,
+                    max_stripes, permute):
+    """Which indices of one axis become dense stripes: the prefix of the
+    indices by descending entry count that minimises the predicted device
+    time of one forward plus one backward product,
+
+        tiles · 128 · (a_f + a_b) · SLOT_SECONDS
+            + 2 · K · long_axis · STRIPE_ELEMENT_SECONDS,
+
+    among prefixes whose layout (slots at ``slot_bytes`` + stripes) is no
+    larger than the one :func:`_threshold_stripes` would have given.  The
+    depths come from the histogram (:func:`_predict_depths`), under the
+    identity placement and, with ``permute``, under the round-robin one
+    the column permutation would give: no sort of the entry set.  Ties go
+    to the threshold rule's count, then to the fewest stripes: an axis
+    without a hot tail keeps the stripes it had.
+
+    Returns (sorted stripe ids, (window-side, lane-side) predicted padded
+    depths at the choice)."""
+    n = len(counts)
+    order = np.argsort(-counts, kind="stable")
+    sorted_counts = counts[order]
+    dens = sorted_counts / float(max(long_axis, 1))
+    n_pos = -(-n // TILE_R) * TILE_R
+    k_top = int(np.count_nonzero(sorted_counts))
+    if max_stripes is not None:
+        k_top = min(k_top, int(max_stripes))
+    k_old = min(_threshold_stripes(sorted_counts, long_axis), k_top)
+    # prefix sizes: every count up to 8, then a half-octave ladder in
+    # multiples of 8 (the stripes' sublane tiling); the cost is flat around
+    # its minimum (PERF.md §6, PR 26: within 2% from 290 to 512 stripes)
+    ladder = 8 * np.unique(np.round(2.0 ** np.arange(0, 20, 0.5)))
+    cands = np.unique(np.concatenate(
+        [np.arange(9), ladder, [k_old]]).astype(np.int64))
+    cands = cands[cands <= k_top].tolist()
+    positions = _round_robin_positions(n, n_pos // TILE_R) if permute else None
+
+    def depths(k):
+        """Padded (window-side, lane-side) depths without the top k, under
+        the placement that needs the fewer sublanes."""
+        pairs = []
+        for place in ([order[k:]] if positions is None
+                      else [order[k:], positions[:n - k]]):
+            density = np.zeros(n_pos)
+            density[place] = dens[k:]
+            a_w, a_l = _predict_depths(density, long_axis, cap)
+            pairs.append((_pad_depth(a_w), _pad_depth(a_l)))
+        return min(pairs, key=sum)
+
+    def seconds_bytes(k, pair):
+        slots = n_tiles * WIN * sum(pair)
+        return (slots * SLOT_SECONDS
+                + 2 * k * long_axis * STRIPE_ELEMENT_SECONDS,
+                slots * slot_bytes + 4 * k * long_axis)
+
+    chosen = k_old
+    chosen_pair = depths(k_old)
+    best_s, limit_bytes = seconds_bytes(k_old, chosen_pair)
+    for k in cands:
+        if 2 * k * long_axis * STRIPE_ELEMENT_SECONDS >= best_s:
+            break               # the stripes alone cost more than the best
+        if k == k_old:
+            continue
+        pair = depths(k)
+        s, nbytes = seconds_bytes(k, pair)
+        if nbytes <= limit_bytes and s < best_s:
+            chosen, chosen_pair, best_s = k, pair, s
+    return np.sort(order[:chosen]).astype(np.int64), chosen_pair
 
 
 def build_pallas_host(
@@ -1029,9 +1196,7 @@ def build_pallas_host(
     depth_cap: int = 128,
     pad_nnz: Optional[int] = None,
     dtype=jnp.float32,
-    dense_frac: float = 1.0 / 32.0,
-    max_dense: int = 64,
-    dense_budget_bytes: int = 512 << 20,
+    max_dense: Optional[int] = None,
     col_permutation: bool = True,
     unit_values: bool | str = "auto",
 ) -> PallasSparseMatrix:
@@ -1042,14 +1207,16 @@ def build_pallas_host(
 
     Storage-class split (see :class:`PallasSparseMatrix`):
 
-    1. columns with ≥ ``max(256, n_rows·dense_frac)`` entries (then rows
-       with ≥ ``max(256, n_cols·dense_frac)``, from what remains) become
-       dense MXU stripes, at most ``max_dense`` each and within
-       ``dense_budget_bytes`` of dense storage per side — a bias column
-       or popularity-head feature would otherwise drive its tiles' slot
-       packing toward the cap (measured on zipf data: stripes 8 → 64 cut
-       rmatvec 1.73×, the B orientation pays ~16× a hot column's max
-       lane load otherwise);
+    1. the most popular columns (then rows, from what remains) become
+       dense stripes, f32 products at the HBM rate: a hot column would
+       otherwise put its 128·density entries into one lane of every
+       row-window of its tiles, and orientation B pays ~16× that in
+       depth, in every tile.  How many is :func:`_choose_stripes`'s
+       business: the prefix by popularity that minimises the predicted
+       time of a forward plus a backward product, within the bytes the
+       threshold rule it replaced would have used (``glm_lbfgs_fit``:
+       PERF.md §6, PR 26).  ``max_dense`` caps the count per side; tests
+       pass 0 to build without stripes;
     2. the rest lands in the tiled slot grids, at the cost-model depth
        (see ``_build_orientation``; ≤ ``depth_cap``);
     3. the residual overflow becomes a COMPACT spill COO (cost ∝ spill).
@@ -1070,42 +1237,43 @@ def build_pallas_host(
             live = np.flatnonzero(v_all != 0)
             r, c, v = r_all[live], c_all[live], v_all[live]
 
-        # --- dense stripe extraction (columns first, rows from the rest) --
-        with layer_span("layout.dense_split"):
-            dense_col_ids = _extract_dense(
-                np.bincount(c, minlength=n_cols),
-                max(256, int(n_rows * dense_frac)), max_dense,
-                n_rows, dense_budget_bytes,
-            )
-            in_dc = (
-                np.isin(c, dense_col_ids) if dense_col_ids.size else
-                np.zeros(len(c), bool)
-            )
-            # Zero-SIZE placeholder when absent (never read; has_dense_cols
-            # gates).
-            dense_cols = np.zeros((len(dense_col_ids), n_rows), np.float32)
-            if dense_col_ids.size:
-                pos = np.searchsorted(dense_col_ids, c[in_dc])
-                dense_cols[pos, r[in_dc]] = v[in_dc]
-                r, c, v = r[~in_dc], c[~in_dc], v[~in_dc]
-
-            dense_row_ids = _extract_dense(
-                np.bincount(r, minlength=n_rows),
-                max(256, int(n_cols * dense_frac)), max_dense,
-                n_cols, dense_budget_bytes,
-            )
-            in_dr = (
-                np.isin(r, dense_row_ids) if dense_row_ids.size else
-                np.zeros(len(r), bool)
-            )
-            dense_rows = np.zeros((len(dense_row_ids), n_cols), np.float32)
-            if dense_row_ids.size:
-                pos = np.searchsorted(dense_row_ids, r[in_dr])
-                dense_rows[pos, c[in_dr]] = v[in_dr]
-                r, c, v = r[~in_dr], c[~in_dr], v[~in_dr]
-
         nbr = max(1, -(-n_rows // TILE_R))
         nbc = max(1, -(-n_cols // TILE_C))
+
+        # --- dense stripe extraction (columns first, rows from the rest) --
+        with layer_span("layout.dense_split"):
+            n_valued = len(v)
+            # the slot bytes the memory guard counts: the unit-value layout
+            # streams codes alone
+            slot_bytes = CODE_BYTES + (
+                0 if unit_values is True or (
+                    unit_values == "auto" and bool(np.all(v == 1.0)))
+                else 4)
+
+            def split(idx, other, vals_, n_idx, long_axis, permute):
+                """Stripe ids of one axis, their dense block (stripe,
+                long axis), which entries stay tiled, predicted depths."""
+                ids, predicted = _choose_stripes(
+                    np.bincount(idx, minlength=n_idx), long_axis, nbr * nbc,
+                    slot_bytes, depth_cap, max_dense, permute)
+                # Zero-SIZE block when absent (never read; has_dense_*
+                # gates).
+                block = np.zeros((len(ids), long_axis), np.float32)
+                inside = (np.isin(idx, ids) if ids.size
+                          else np.zeros(len(idx), bool))
+                block[np.searchsorted(ids, idx[inside]), other[inside]] = (
+                    vals_[inside])
+                return ids, block, ~inside, predicted
+
+            dense_col_ids, dense_cols, stay, (a_f_pred, a_b_pred) = split(
+                c, r, v, n_cols, n_rows, col_permutation and n_cols > WIN)
+            if dense_col_ids.size:
+                r, c, v = r[stay], c[stay], v[stay]
+            dense_row_ids, dense_rows, stay, _ = split(
+                r, c, v, n_rows, n_cols, False)
+            if dense_row_ids.size:
+                r, c, v = r[stay], c[stay], v[stay]
+            stripe_nnz = n_valued - len(v)
 
         # --- optional column permutation (clustered-data balance) ---------
         # Relabel columns frequency-round-robin across windows when that
@@ -1217,7 +1385,10 @@ def build_pallas_host(
         )
         build.set(
             nnz=P.nnz, a_f=a_f, a_b=a_b,
+            a_f_predicted=a_f_pred, a_b_predicted=a_b_pred,
             stripes=len(dense_col_ids) + len(dense_row_ids),
+            stripe_nnz_share=stripe_nnz / max(n_valued, 1),
+            stripe_bytes=int(dense_cols.nbytes + dense_rows.nbytes),
             has_col_perm=P.has_col_perm, spilled=int(spilled.size),
         )
     return P

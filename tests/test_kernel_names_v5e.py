@@ -16,7 +16,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from benchmarks import trace as trace_mod
-from photon_ml_tpu.ops.sparse_pallas import build_pallas_host
+from photon_ml_tpu.ops.sparse_pallas import (
+    PallasSparseMatrix,
+    build_pallas_host,
+)
 
 N_ROWS, N_COLS, NNZ = 4096, 3000, 40000
 
@@ -73,3 +76,35 @@ def test_kernel_instruction_names(monkeypatch, one_chip, layout_shapes,
     assert len(calls) == 1, calls
     stem, _, suffix = calls[0].rpartition(".")
     assert stem == name and suffix.isdigit()
+
+
+# The dense stripes' share of a product at the benchmark cell's width: 300
+# stripes over 804,414 rows (0.97 GB).  Each form has to stay one
+# bandwidth-bound fusion over the block: no MXU convolution (whose default
+# precision would round the operands to bfloat16) and no temporary of the
+# block's size (the squared forms square inside the fusion).
+STRIPES, LONG_AXIS = 300, 804_414
+
+
+@pytest.mark.parametrize("form, squared", [
+    ("_stripes_t_dot", False), ("_stripes_dot", False),
+    ("_stripes_t_dot", True), ("_stripes_dot", True),
+])
+def test_stripe_products_are_fusions(one_chip, form, squared):
+    def struct(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    block = struct(STRIPES, LONG_AXIS)
+    dot = getattr(PallasSparseMatrix, form)
+    sq = (lambda x: x * x) if squared else (lambda x: x)
+    if form == "_stripes_t_dot":
+        fn, vec = (lambda d, v: dot(v, sq(d))), struct(STRIPES)
+    else:
+        fn, vec = (lambda d, v: dot(sq(d), v)), struct(LONG_AXIS)
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn).lower(block, vec).compile()
+    text = compiled.as_text()
+    assert " fusion(" in text
+    assert " convolution(" not in text and "bf16[" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        STRIPES * LONG_AXIS * 4 // 8)

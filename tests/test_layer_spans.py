@@ -113,11 +113,17 @@ class TestNoHub:
         P = data.features
         assert made["attrs"] == {"rows": 2048, "nnz": P.nnz,
                                  "layout": "PallasSparseMatrix"}
+        predicted = {k: build["attrs"].pop(k)
+                     for k in ("a_f_predicted", "a_b_predicted")}
         assert build["attrs"] == {
             "nnz": P.nnz, "a_f": P.a_f, "a_b": P.a_b,
             "stripes": len(P.dense_col_ids) + len(P.dense_row_ids),
+            "stripe_nnz_share": 0.0, "stripe_bytes": 0,
             "has_col_perm": P.has_col_perm, "spilled": 0,
         }
+        # the stripe chooser's forecast of the depths, from the histogram
+        assert abs(predicted["a_f_predicted"] - P.a_f) <= 16
+        assert abs(predicted["a_b_predicted"] - P.a_b) <= 16
         assert place["attrs"]["bytes"] == sum(
             x.nbytes for x in jax.tree.leaves(data))
 

@@ -314,26 +314,213 @@ class TestStorageClasses:
                     C.sq_rmatvec(jnp.asarray(u))) < 1e-5
 
 
-class TestDenseStripeBudget:
-    def test_memory_budget_caps_stripe_count(self, rng):
-        """The per-side dense budget must bound stripes regardless of how
-        many columns clear the count threshold (at 10^8 rows each stripe
-        is ~400 MB — the count cap alone would blow HBM)."""
-        n, d = 4000, 600
-        # 40 columns all above threshold (max(256, n/32) = 256)
-        hot = np.repeat(np.arange(40, dtype=np.int64), 300)
-        rows = rng.integers(0, n, size=len(hot)).astype(np.int64)
-        vals = rng.normal(size=len(hot)).astype(np.float32)
-        budget = 10 * n * 4  # room for exactly 10 column stripes
-        P = build_pallas_matrix(rows, hot, vals, n, d,
-                                dense_budget_bytes=budget)
-        assert P.has_dense_cols
-        assert P.dense_col_ids.shape[0] <= 10
-        C = from_coo(rows, hot, vals, n, d)
+def _zipf_dry():
+    """The benchmark cell's own law (Zipf-Mandelbrot, exponent 1, shift 16,
+    76 distinct terms a row, an intercept) at its dry shape."""
+    import json
+
+    from benchmarks.datagen import glm_sparse
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmarks/configs/glm_logistic_l2_lbfgs_rcv1.json")) as f:
+        cfg = json.load(f)
+    data = glm_sparse.generate({**cfg, **cfg["dry"]}, 7)
+    n, k1 = data["cols"].shape
+    return (np.repeat(np.arange(n, dtype=np.int64), k1),
+            data["cols"].reshape(-1).astype(np.int64),
+            data["vals"].reshape(-1), n, data["n_features"] + 1)
+
+
+def _uniform():
+    rng = np.random.default_rng(11)
+    n, d, nnz = 12000, 5000, 240000
+    return (rng.integers(0, n, nnz), rng.integers(0, d, nnz),
+            rng.normal(size=nnz).astype(np.float32), n, d)
+
+
+def _steep_sorted_ids():
+    """A steeper law (exponent 1.3, shift 2) whose ids are sorted by
+    popularity, with a bias column: the hot ids share the first windows,
+    so the column permutation has to engage."""
+    rng = np.random.default_rng(12)
+    n, d, k = 10000, 6000, 24
+    p = (np.arange(1, d) + 2.0) ** -1.3
+    cols = 1 + np.searchsorted(np.cumsum(p / p.sum()), rng.random(n * k))
+    rows = np.repeat(np.arange(n), k)
+    return (np.concatenate([rows, np.arange(n)]),
+            np.concatenate([np.minimum(cols, d - 1), np.zeros(n, np.int64)]),
+            np.concatenate([rng.normal(size=n * k),
+                            np.ones(n)]).astype(np.float32), n, d)
+
+
+def _hot_block():
+    """40 columns in 7.5% of the rows each and nothing else (the old
+    byte-budget test's input)."""
+    rng = np.random.default_rng(13)
+    n, d = 4000, 600
+    cols = np.repeat(np.arange(40, dtype=np.int64), 300)
+    return (rng.integers(0, n, len(cols)), cols,
+            rng.normal(size=len(cols)).astype(np.float32), n, d)
+
+
+LAWS = {"zipf_dry": _zipf_dry, "uniform": _uniform,
+        "steep_sorted_ids": _steep_sorted_ids, "hot_block": _hot_block}
+
+
+def _layout_bytes(P):
+    import jax
+
+    return sum(x.nbytes for x in jax.tree.leaves(P))
+
+
+@pytest.fixture(scope="module")
+def built():
+    """Per law, built once: the problem, the layout under the cost rule
+    with its ``layout.build`` span, and the layout under the threshold
+    rule the cost rule replaced (its memory guard's yardstick)."""
+    from photon_ml_tpu import telemetry
+    from photon_ml_tpu.ops import sparse_pallas as sp
+
+    def threshold_rule(counts, long_axis, *args):
+        order = np.argsort(-counts, kind="stable")
+        k = sp._threshold_stripes(counts[order], long_axis)
+        return np.sort(order[:k]).astype(np.int64), (0, 0)
+
+    cache = {}
+
+    def get(law):
+        if law not in cache:
+            problem = LAWS[law]()
+            P = sp.build_pallas_host(*problem)
+            span = [r for r in telemetry.layer_spans()
+                    if r["name"] == "layout.build"][-1]["attrs"]
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(sp, "_choose_stripes", threshold_rule)
+                P_old = sp.build_pallas_host(*problem)
+            cache[law] = problem, P, span, P_old
+        return cache[law]
+
+    return get
+
+
+class TestStripesChosenByCost:
+    """Which columns become dense stripes is chosen by the predicted time
+    of a forward plus a backward product, from the count histogram."""
+
+    def test_hot_tail_leaves_the_grids(self, built):
+        """More stripes than the old cap of 64 on the cell's law, shallower
+        grids for them, and all four products still the COO path's."""
+        from photon_ml_tpu.ops.sparse_pallas import place_pallas_matrix
+
+        (rows, cols, vals, n, d), P, span, P_old = built("zipf_dry")
+        assert len(P_old.dense_col_ids) == 64
+        assert len(P.dense_col_ids) > 64 and not P.has_dense_rows
+        assert P.a_f + P.a_b < P_old.a_f + P_old.a_b
+        assert span["stripes"] == len(P.dense_col_ids)
+        assert span["stripe_bytes"] == P.dense_cols.nbytes
+        in_stripes = np.isin(cols, P.dense_col_ids).sum() / len(cols)
+        assert span["stripe_nnz_share"] == pytest.approx(in_stripes)
+        P = place_pallas_matrix(P)
+        C = from_coo(rows, cols, vals, n, d)
+        rng = np.random.default_rng(0)
         w = jnp.asarray(rng.normal(size=d).astype(np.float32))
-        assert _rel(P.matvec(w), C.matvec(w)) < 1e-5
         u = jnp.asarray(rng.normal(size=n).astype(np.float32))
+        assert _rel(P.matvec(w), C.matvec(w)) < 1e-5
         assert _rel(P.rmatvec(u), C.rmatvec(u)) < 1e-5
+        assert _rel(P.row_sq_matvec(w), C.row_sq_matvec(w)) < 1e-5
+        assert _rel(P.sq_rmatvec(u), C.sq_rmatvec(u)) < 1e-5
+
+    def test_uniform_columns_bypass(self, built):
+        """No hot tail: the stripes and every leaf of the layout are the
+        threshold rule's, bit for bit."""
+        import jax
+
+        _, P, _, P_old = built("uniform")
+        assert not P.has_dense_cols and not P.has_dense_rows
+        new, treedef = jax.tree.flatten(P)
+        old, treedef_old = jax.tree.flatten(P_old)
+        assert treedef == treedef_old
+        for x, y in zip(new, old):
+            assert x.dtype == y.dtype
+            np.testing.assert_array_equal(x, y)
+
+    @pytest.mark.parametrize(
+        "law", ["zipf_dry", "uniform", "steep_sorted_ids"])
+    def test_histogram_predicts_built_depths(self, built, law):
+        from photon_ml_tpu.ops.sparse_pallas import SUBPAD
+
+        _, P, span, _ = built(law)
+        assert (span["a_f"], span["a_b"]) == (P.a_f, P.a_b)
+        assert abs(span["a_f_predicted"] - P.a_f) <= SUBPAD
+        assert abs(span["a_b_predicted"] - P.a_b) <= SUBPAD
+
+    @pytest.mark.parametrize("law", sorted(LAWS))
+    def test_layout_no_larger_than_threshold_rule(self, built, law):
+        """The memory guard: slots + stripes never exceed what the old
+        rule (1/32 of the rows, 64 stripes, 512 MiB) would have held."""
+        _, P, _, P_old = built(law)
+        assert _layout_bytes(P) <= _layout_bytes(P_old)
+
+    def test_max_dense_caps_the_count(self, built):
+        from photon_ml_tpu.ops.sparse_pallas import build_pallas_host
+
+        problem, P, _, _ = built("hot_block")
+        assert P.has_dense_cols
+        assert not build_pallas_host(*problem, max_dense=0).has_dense_cols
+        assert len(build_pallas_host(
+            *problem, max_dense=3).dense_col_ids) <= 3
+
+
+def _stripe_eqns(jaxpr, shape):
+    """(multiplications of a stripe block by itself, dot_generals that
+    take a stripe-block-shaped operand), through nested jaxprs."""
+    squares, dots = [], []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            for sub in eqn.params.values():
+                for j in (sub if isinstance(sub, (list, tuple)) else [sub]):
+                    inner = getattr(j, "jaxpr", j)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+            shapes = [getattr(v.aval, "shape", None) for v in eqn.invars]
+            if eqn.primitive.name == "mul" and shapes == [shape, shape]:
+                squares.append((jp, eqn))
+            if eqn.primitive.name == "dot_general" and shape in shapes:
+                dots.append(eqn)
+
+    walk(jaxpr)
+    return squares, dots
+
+
+class TestStripeProducts:
+    """What the stripes' share of each product asks the compiler for."""
+
+    @pytest.mark.parametrize(
+        "product,squared",
+        [("matvec", False), ("rmatvec", False),
+         ("row_sq_matvec", True), ("sq_rmatvec", True)])
+    def test_f32_stated_and_no_squared_copy(self, built, product, squared):
+        import jax
+
+        (_, _, _, n, d), P, _, _ = built("zipf_dry")
+        block = P.dense_cols.shape
+        vec = jnp.zeros(d if "rmatvec" not in product else n, jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda P, v: getattr(P, product)(v))(P, vec)
+        squares, dots = _stripe_eqns(jaxpr.jaxpr, block)
+        assert len(dots) == 1
+        (dot,) = dots
+        assert dot.params["precision"] == (
+            jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+        assert dot.params["preferred_element_type"] == jnp.float32
+        assert len(squares) == (1 if squared else 0)
+        for jp, eqn in squares:
+            # the square exists only as the dot's operand, where the
+            # compiler fuses it: nothing else reads it, nothing returns it
+            (sq,) = eqn.outvars
+            readers = [e for e in jp.eqns if sq in e.invars]
+            assert readers == [dot] and sq not in jp.outvars
 
 
 class TestNonPowerOfTwoTile:
