@@ -41,6 +41,7 @@ from photon_ml_tpu.ops import losses as losses_lib
 from photon_ml_tpu.optim.lbfgs import LBFGSConfig, lbfgs_solve
 from photon_ml_tpu.optim.owlqn import OWLQNConfig, owlqn_solve
 from photon_ml_tpu.optim.problem import GlmOptimizationConfig, OptimizerType
+from photon_ml_tpu import telemetry as telemetry_mod
 
 Array = jax.Array
 
@@ -49,6 +50,17 @@ class Coordinate:
     """Protocol: train against offsets, score into the global row space."""
 
     name: str
+    #: "fixed" or "random": what the descent's ``coordinate.train`` layer
+    #: span says this coordinate is.
+    kind: str = "coordinate"
+
+    def train_counts(self) -> dict:
+        """What the last ``train`` counted, as attributes for the descent's
+        ``coordinate.train`` layer span: a tree of host numbers and 0-d
+        DEVICE arrays not yet read.  The descent reads every update's in
+        one batched read, with its history flush, and amends the span.
+        Nothing by default."""
+        return {}
 
     @property
     def feature_layout(self) -> str:
@@ -125,20 +137,31 @@ def _fixed_effect_jits(
     # closures bake them into the HLO, forcing recompiles per dataset /
     # per tuning point and oversized programs.  Hyperparameter tuning
     # mutates reg_weight between runs at zero recompile cost.
-    def _train(data: GlmData, offsets: Array, w0: Array, reg_weight: Array):
+    # The functions' names are the programs' names in a device trace
+    # (``jit_fixed_effect_train``, ``jit_fixed_effect_score``).
+    def fixed_effect_train(
+        data: GlmData, offsets: Array, w0: Array, reg_weight: Array
+    ):
         data = dataclasses.replace(data, offsets=offsets)
-        return problem.solve(data, reg_weight, w0, axis_name=axis_name).w
+        return problem.solve(data, reg_weight, w0, axis_name=axis_name)
 
-    def _score(data: GlmData, w: Array) -> Array:
+    def fixed_effect_score(data: GlmData, w: Array) -> Array:
         # Margin WITHOUT offsets: coordinate scores are additive pieces.
         return data.features.matvec(w)
 
-    return jax.jit(_train), jax.jit(_score)
+    return jax.jit(fixed_effect_train), jax.jit(fixed_effect_score)
 
 
 class FixedEffectCoordinate(Coordinate):
     """Reference: ``FixedEffectCoordinate`` — DistributedOptimizationProblem
     over the full dataset (SURVEY.md §3.2)."""
+
+    kind = "fixed"
+    #: The last ``train``'s whole ``SolveResult``, on the device and not
+    #: yet read: the value and gradient the solver reported at the
+    #: coefficients it returned, and what it counted.  ``None`` before the
+    #: first ``train`` and for a trainer that reports none.
+    last_solve = None
 
     def __init__(
         self,
@@ -187,6 +210,15 @@ class FixedEffectCoordinate(Coordinate):
             self.task, config, axis_name, _layout_sig(dataset.data)
         )
 
+    def train_counts(self) -> dict:
+        res = self.last_solve
+        if res is None:
+            return {}
+        counts = {"iterations": res.iterations, "value": res.value}
+        if res.fn_evals is not None:
+            counts["fn_evals"] = res.fn_evals
+        return counts
+
     @property
     def feature_layout(self) -> str:
         from photon_ml_tpu.utils.device_report import describe_layout
@@ -200,11 +232,13 @@ class FixedEffectCoordinate(Coordinate):
             else warm_state
         )
         if self._sharded_trainer is not None:
+            self.last_solve = None
             return self._sharded_trainer(offsets, w0, self.reg_weight)
-        return self._train_jit(
+        self.last_solve = self._train_jit(
             self.dataset.data, offsets, w0,
             jnp.asarray(self.reg_weight, jnp.float32),
         )
+        return self.last_solve.w
 
     def score(self, state: Array) -> Array:
         return self._score_jit(self.dataset.data, state)
@@ -290,7 +324,7 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
         device ops instead of hundreds (the while_loop step count, not
         FLOPs, dominates these buckets).  Smooth objectives only (L1 breaks
         the proportionality)."""
-        X = block.X[:, 0, :]                       # (E, D)
+        X = block.x_erd[:, 0, :]                   # (E, D)
         y = block.labels[:, 0]
         wt = block.weights[:, 0]
         off = offsets_block[:, 0].astype(X.dtype)  # robust under x64 callers
@@ -312,18 +346,18 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
         done0 = (jnp.abs(g0) <= gtol) | (s <= 0)
 
         def cond(carry):
-            i, _alpha, done = carry
+            i, _alpha, done, _n = carry
             return (i < 30) & ~jnp.all(done)
 
         def body(carry):
-            i, alpha, done = carry
+            i, alpha, done, n = carry
             m, g1 = grad_at(alpha)
             done = done | (jnp.abs(g1) <= gtol)
             g2 = wt * loss.d2(m, y) * s * s + l2 * s
             step = g1 / jnp.maximum(g2, 1e-12)
             step = jnp.clip(step, -clip, clip)
             alpha = alpha - jnp.where(done, 0.0, step)
-            return i + 1, alpha, done
+            return i + 1, alpha, done, n + ~done
 
         # Up to 30 damped steps with a per-lane relative-gradient exit
         # (newton_block's test, seeded so lanes converged at entry run
@@ -332,10 +366,11 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
         # Poisson count), so the cap must stay high — but warm-started CD
         # iterations converge every lane in 1-3 steps, and sequential
         # step count is what these buckets are bound by.
-        _, alpha, _ = jax.lax.while_loop(
-            cond, body, (jnp.zeros((), jnp.int32), alpha, done0)
+        _, alpha, _, n = jax.lax.while_loop(
+            cond, body,
+            (jnp.zeros((), jnp.int32), alpha, done0, _no_steps(done0)),
         )
-        return alpha[:, None] * X
+        return alpha[:, None] * X, n
 
     def dim1_newton(block, offsets_block, w0, l2):
         """Single-FEATURE entities (D == 1 — the reference's flagship
@@ -344,7 +379,7 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
         count: damped scalar Newton replaces the vmapped L-BFGS
         machinery, as rank1_newton does for R == 1.  Smooth objectives
         only."""
-        X = block.X[:, :, 0]                       # (E, R)
+        X = block.x_erd[:, :, 0]                   # (E, R)
         y = block.labels
         wt = block.weights
         off = offsets_block.astype(X.dtype)
@@ -362,11 +397,11 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
         gtol = opt.tolerance * jnp.maximum(1.0, jnp.abs(g0))
 
         def cond(carry):
-            i, _w, done = carry
+            i, _w, done, _n = carry
             return (i < 30) & ~jnp.all(done)
 
         def body(carry):
-            i, w, done = carry
+            i, w, done, n = carry
             m, g = grad_at(w)
             done = done | (jnp.abs(g) <= gtol)
             h = jnp.sum(wt * loss.d2(m, y) * X * X, axis=1) + l2
@@ -377,15 +412,16 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
             # generic solver reports).
             step = jnp.clip(g / jnp.maximum(h, 1e-12), -clip, clip)
             w = w - jnp.where(done, 0.0, step)
-            return i + 1, w, done
+            return i + 1, w, done, n + ~done
 
         # Same per-lane relative-gradient exit + 30-step cap as
         # rank1_newton, seeded from the entry gradient.
-        _, w, _ = jax.lax.while_loop(
+        done0 = jnp.abs(g0) <= gtol
+        _, w, _, n = jax.lax.while_loop(
             cond, body,
-            (jnp.zeros((), jnp.int32), w, jnp.abs(g0) <= gtol),
+            (jnp.zeros((), jnp.int32), w, done0, _no_steps(done0)),
         )
-        return w[:, None]
+        return w[:, None], n
 
     _HI = jax.lax.Precision.HIGHEST
 
@@ -430,7 +466,7 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
         L-BFGS convergence semantics.  Small einsums run at HIGHEST
         precision: default MXU bf16 puts a noise floor above the 1e-6
         gradient tolerance, which silently disables the early exit."""
-        X, yb, wt = block.X, block.labels, block.weights
+        X, yb, wt = block.x_erd, block.labels, block.weights
         off = offsets_block.astype(X.dtype)
         d = block.block_dim
         eye = jnp.eye(d, dtype=X.dtype)
@@ -446,11 +482,11 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
         gtol = tol * jnp.maximum(1.0, jnp.linalg.norm(g0, axis=1))
 
         def cond(carry):
-            i, _w, done = carry
+            i, _w, done, _n = carry
             return (i < max_iters) & ~jnp.all(done)
 
         def body(carry):
-            i, w, done = carry
+            i, w, done, n = carry
             m, g = grad_at(w)
             newly = jnp.linalg.norm(g, axis=1) <= gtol
             d2 = wt * loss.d2(m, yb)
@@ -467,13 +503,14 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
             )
             keep = done | newly
             w = jnp.where(keep[:, None], w, w - scale[:, None] * step)
-            return i + 1, w, keep
+            return i + 1, w, keep, n + ~keep
 
-        _, w, _ = jax.lax.while_loop(
-            cond, body, (jnp.zeros((), jnp.int32), w0,
-                         jnp.zeros((X.shape[0],), bool))
+        done0 = jnp.zeros((X.shape[0],), bool)
+        _, w, _, n = jax.lax.while_loop(
+            cond, body,
+            (jnp.zeros((), jnp.int32), w0, done0, _no_steps(done0)),
         )
-        return w
+        return w, n
 
     def make_solve_one(history: int):
         def solve_one(X, y, wts, off, w0, l1, l2):
@@ -493,7 +530,7 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
                         tolerance=opt.tolerance,
                         history=history,
                     ),
-                ).w
+                )
             if use_tron:
                 def hvp(w, v, aux):
                     return X.T @ (aux * (X @ v)) + l2 * v
@@ -507,7 +544,7 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
                         max_iters=opt.max_iters, tolerance=opt.tolerance
                     ),
                     d2_fn=d2f,
-                ).w
+                )
             return lbfgs_solve(
                 vg,
                 w0,
@@ -516,14 +553,14 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
                     tolerance=opt.tolerance,
                     history=history,
                 ),
-            ).w
+            )
 
         return solve_one
 
-    @jax.jit
     def solve_block(
         block: EntityBlock, offsets_block: Array, w0: Array, l1: Array, l2: Array
-    ) -> Array:
+    ) -> tuple[Array, Array]:
+        """``(E, D)`` coefficients and each lane's iteration count."""
         # Static shape dispatch (trace-time): single-row buckets take the
         # rank-1 Newton path for smooth objectives.  (A gram-space dual
         # Newton for 2 <= R <= 16 was tried and measured 4.5x SLOWER than
@@ -546,11 +583,31 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
     # adds two scan steps per iteration — sequential step count is what
         # dominates these small batched solves.
         solve_one = make_solve_one(min(opt.history, block.block_dim))
-        return jax.vmap(
+        res = jax.vmap(
             solve_one, in_axes=(0, 0, 0, 0, 0, None, None)
-        )(block.X, block.labels, block.weights, offsets_block, w0, l1, l2)
+        )(block.x_erd, block.labels, block.weights, offsets_block, w0, l1, l2)
+        return res.w, res.iterations
 
-    return solve_block
+    return _BlockSolver(solve_block)
+
+
+def _no_steps(done: Array) -> Array:
+    """A per-lane step counter at zero, for a solver loop's carry."""
+    return jnp.zeros(done.shape, jnp.int32)
+
+
+class _BlockSolver:
+    """``solver(block, offsets_block, w0, l1, l2)`` gives the ``(E, D)``
+    coefficients; ``solver.counted(...)`` also each lane's iteration count
+    (a lane that froze early stopped counting).  Both jitted; under an
+    outer jit they inline."""
+
+    def __init__(self, solve):
+        self.counted = jax.jit(solve)
+        self._coefficients = jax.jit(lambda *args: solve(*args)[0])
+
+    def __call__(self, *args) -> Array:
+        return self._coefficients(*args)
 
 
 def pack_entity_tables(cmap: np.ndarray, w: np.ndarray, var=None):
@@ -589,16 +646,33 @@ def _re_train_all_jit(
     per-instance jits meant every new coordinate object (a second fit, a
     grid point, a fresh estimator) re-traced and re-compiled identical
     programs.  ``layout_sig`` is unused inside: it is the eviction
-    granule (see ``_layout_sig``)."""
+    granule (see ``_layout_sig``).
+
+    Returns ``(states, counts)``: per bucket the ``(E, D)`` coefficients
+    and what its solve counted -- the block's iterations (its slowest
+    lane's), the sum over its real lanes, and how many real lanes froze
+    before the block's loop ended.  A real lane is one with a weighted
+    row.  The function's name is the program's in a device trace
+    (``jit_random_effect_train``)."""
     solver = _make_block_solver(task, config)
 
-    def _train_all(blocks, offsets, w0s, l1, l2):
-        return [
-            solver(b, _gather_block_offsets(offsets, b), w0, l1, l2)
-            for b, w0 in zip(blocks, w0s)
-        ]
+    def random_effect_train(blocks, offsets, w0s, l1, l2):
+        states, counts = [], []
+        for b, w0 in zip(blocks, w0s):
+            with jax.named_scope("re.block_solve"):
+                w, n = solver.counted(
+                    b, _gather_block_offsets(offsets, b), w0, l1, l2)
+            real = jnp.any(b.weights > 0, axis=1)
+            n = jnp.where(real, n, 0)
+            states.append(w)
+            counts.append({
+                "iterations_max": jnp.max(n),
+                "iterations_sum": jnp.sum(n),
+                "frozen_early": jnp.sum(real & (n < jnp.max(n))),
+            })
+        return states, counts
 
-    return jax.jit(_train_all)
+    return jax.jit(random_effect_train)
 
 
 @functools.lru_cache(maxsize=64)
@@ -608,24 +682,26 @@ def _re_score_all_jit(n_rows: int, layout_sig: tuple):
     vary per dataset/fold, and an unbounded cache would pin one compiled
     program per distinct layout for process lifetime."""
 
-    def _score_all(blocks, passive_blocks, coefs_list):
+    # The function's name is the program's in a device trace
+    # (``jit_random_effect_score``).
+    def random_effect_score(blocks, passive_blocks, coefs_list):
         total = jnp.zeros((n_rows + 1,), jnp.float32)
         passive = passive_blocks or [None] * len(blocks)
         for block, passive_block, coefs in zip(blocks, passive, coefs_list):
-            s = jnp.einsum("erd,ed->er", block.X, coefs)
+            s = jnp.einsum("erd,ed->er", block.x_erd, coefs)
             # Padding rows (sentinel index) scatter into the trailing slot.
             total = total.at[block.row_index.ravel()].add(s.ravel())
             if passive_block is not None:
                 # Active/passive split: capped-out rows are never trained
                 # on but MUST be scored, or other coordinates would see
                 # offsets missing this coordinate's contribution there.
-                sp_ = jnp.einsum("erd,ed->er", passive_block.X, coefs)
+                sp_ = jnp.einsum("erd,ed->er", passive_block.x_erd, coefs)
                 total = total.at[passive_block.row_index.ravel()].add(
                     sp_.ravel()
                 )
         return total[:n_rows]
 
-    return jax.jit(_score_all)
+    return jax.jit(random_effect_score)
 
 
 class RandomEffectCoordinate(Coordinate):
@@ -634,6 +710,11 @@ class RandomEffectCoordinate(Coordinate):
     State is a list of per-bucket coefficient arrays ``(E, D)`` in each
     block's LOCAL (projected) column space.
     """
+
+    kind = "random"
+    #: What the last ``train`` counted (rebound by ``train``; a subclass
+    #: with a ``train`` of its own counts nothing).
+    _counts: dict = {}
 
     def __init__(
         self,
@@ -656,6 +737,11 @@ class RandomEffectCoordinate(Coordinate):
         sig = _layout_sig((dataset.blocks, dataset.passive_blocks))
         self._train_all_jit = _re_train_all_jit(self.task, config, sig)
         self._score_all_jit = _re_score_all_jit(dataset.n_global_rows, sig)
+        telemetry_mod.current().gauge("game_re_bucket_count").set(
+            len(dataset.blocks))
+
+    def train_counts(self) -> dict:
+        return self._counts
 
     def train(self, offsets: Array, warm_state=None) -> list[Array]:
         l1 = jnp.asarray(
@@ -676,10 +762,22 @@ class RandomEffectCoordinate(Coordinate):
             )
             for bi, block in enumerate(self.dataset.blocks)
         ]
-        return self._train_all_jit(
+        state, counts = self._train_all_jit(
             self.dataset.blocks, jnp.asarray(offsets, jnp.float32), w0s,
             l1, l2,
         )
+        # All buckets solve inside ONE program, so a bucket has no host
+        # interval of its own: its shape and what its solve counted on the
+        # device are one entry of the update's ``buckets`` attribute.
+        rows_real = self.dataset.block_rows_real or [None] * len(w0s)
+        self._counts = {"buckets": [
+            {"lanes": block.n_entities,
+             "rows_padded": block.n_entities * block.rows_per_entity,
+             "rows_real": real, "dim": block.block_dim, **counted}
+            for block, real, counted in zip(
+                self.dataset.blocks, rows_real, counts)
+        ]}
+        return state
 
     def score(self, state: list[Array]) -> Array:
         return self._score_all_jit(
@@ -697,9 +795,10 @@ class RandomEffectCoordinate(Coordinate):
             jnp.float32,
         )
         off_b = _gather_block_offsets(jnp.asarray(offsets, jnp.float32), block)
-        m = jnp.einsum("erd,ed->er", block.X, coefs) + off_b
+        X = block.x_erd
+        m = jnp.einsum("erd,ed->er", X, coefs) + off_b
         d2w = block.weights * loss.d2(m, block.labels)
-        diag = jnp.einsum("er,erd->ed", d2w, block.X * block.X) + l2
+        diag = jnp.einsum("er,erd->ed", d2w, X * X) + l2
         return np.asarray(1.0 / jnp.maximum(diag, 1e-12))
 
     def finalize(self, state: list[Array], offsets=None) -> RandomEffectModel:

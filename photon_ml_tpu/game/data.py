@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_ml_tpu.data.dataset import GlmData
+from photon_ml_tpu.telemetry import layer_span
 
 Array = jax.Array
 
@@ -46,20 +47,27 @@ Array = jax.Array
 @partial(
     jax.tree_util.register_dataclass,
     data_fields=["X", "labels", "weights", "col_map", "row_index"],
-    meta_fields=["n_entities", "rows_per_entity", "block_dim"],
+    meta_fields=["n_entities", "rows_per_entity", "block_dim", "x_minor"],
 )
 @dataclasses.dataclass
 class EntityBlock:
     """One size-bucket of entities as a dense padded batch.
 
-    ``X[e, r, k]`` is the value of local feature k in row r of entity e;
-    ``col_map[e, k]`` maps local feature k to its global column (or -1).
+    ``x_erd[e, r, k]`` is the value of local feature k in row r of entity
+    e; ``col_map[e, k]`` maps local feature k to its global column (or -1).
     ``row_index[e, r]`` is the row's index in the global dataset (or the
     sentinel ``n_global_rows`` for padding — callers gather from arrays
     padded with one trailing zero slot).
+
+    ``X`` is the STORED array, ``(E, R, D)`` (``x_minor == "d"``) or
+    ``(E, D, R)`` (``"r"``): a TPU pads an array's minor axis to 128 lanes,
+    so a narrow block (a per-user effect of 21 columns) stored features-
+    minor would take six times its bytes in device memory and in every
+    pass over it (:func:`_x_minor`).  Arithmetic reads ``x_erd``; code that
+    only cuts or pads the entity axis may touch ``X``.
     """
 
-    X: Array  # (E, R, D) float
+    X: Array  # (E, R, D) float, or (E, D, R) when x_minor == "r"
     labels: Array  # (E, R)
     weights: Array  # (E, R) — 0 for padding rows / entities
     col_map: Array  # (E, D) int32 — global column ids, -1 pad
@@ -67,6 +75,38 @@ class EntityBlock:
     n_entities: int
     rows_per_entity: int
     block_dim: int
+    x_minor: str = "d"
+
+    @property
+    def x_erd(self):
+        """The features as ``(E, R, D)`` whatever the storage order (under
+        ``jit`` the compiler folds the transpose into the products)."""
+        if self.x_minor == "d":
+            return self.X
+        xp = jnp if isinstance(self.X, jax.Array) else np
+        return xp.swapaxes(self.X, 1, 2)
+
+
+def _device_tile():
+    """``(sublanes, lanes)`` to which the default backend pads the two
+    minor axes of a 32-bit array in device memory; ``None`` where arrays
+    are stored dense (CPU, GPU)."""
+    return (8, 128) if jax.default_backend() == "tpu" else None
+
+
+def _x_minor(rows: int, dim: int, tile) -> str:
+    """Which axis of a block's features to store minor: the one that pads
+    to fewer bytes under the device's tiling; features (``"d"``) on a tie
+    and where nothing is padded."""
+    if tile is None:
+        return "d"
+    sub, lanes = tile
+
+    def pad(n, m):
+        return -(-n // m) * m
+
+    rows_minor = pad(dim, sub) * pad(rows, lanes)
+    return "r" if rows_minor < pad(rows, sub) * pad(dim, lanes) else "d"
 
 
 @dataclasses.dataclass
@@ -103,6 +143,9 @@ class RandomEffectDataset:
     # (host-rebuilt scoring paths).
     padded_flops: int = 0
     exact_flops: int = 0
+    #: Per block, the rows that are some entity's (trained on: weighted or
+    #: not, but not padding); empty on datasets built before the count.
+    block_rows_real: list = dataclasses.field(default_factory=list)
 
     @property
     def n_entities(self) -> int:
@@ -174,6 +217,10 @@ class RepackPlan:
     padded_flops: int
     exact_flops: int
 
+
+#: (entity, column) cells up to which the grouping finds each entity's
+#: active columns with a presence table (one byte a cell) and not a sort.
+_PAIR_TABLE_CELLS = 1 << 28
 
 #: Distinct (rows, dims) shapes above which the repacker pre-quantizes
 #: on a fine geometric grid before the O(K²)-per-merge greedy runs.
@@ -409,10 +456,107 @@ def build_random_effect_dataset(
                 ),
                 n_global_rows=n_rows,
             )
-    entity_keys = entity_keys.astype(str)
-    _asarray = (lambda x, dt=None: jnp.asarray(x, dt)) if device else (
-        lambda x, dt=None: np.asarray(x, dt) if dt else np.asarray(x)
+    with layer_span("game.group", rows=int(n_rows)) as group_span:
+        host = _group_entities(
+            entity_keys, rows_csr, labels, weights, max_rows_per_entity,
+            bucket_growth, repack, program_budget, repack_seed,
+            _device_tile() if device else None,
+        )
+        if host is not None:
+            group_span.set(
+                entities=len(host["entity_to_slot"]),
+                buckets=len(host["blocks"]),
+            )
+    if host is None:
+        return RandomEffectDataset(
+            blocks=[], entity_ids=[], entity_to_slot={},
+            n_global_rows=n_rows, n_features=d, passive_blocks=[],
+        )
+
+    def place(fields, **shared):
+        if not device:
+            return EntityBlock(
+                **dict(fields, X=np.asarray(fields["X"], dtype)), **shared)
+        arrays = {k: jnp.asarray(fields[k]) for k in (
+            "labels", "weights", "col_map", "row_index") if k in fields}
+        return EntityBlock(
+            **dict(fields, X=jnp.asarray(fields["X"], dtype), **arrays),
+            **shared)
+
+    with layer_span("game.place") as place_span:
+        blocks = [place(f) for f in host["blocks"]]
+        # The passive companion shares its active block's col_map.
+        passive_blocks = [
+            None if f is None else place(f, col_map=b.col_map)
+            for f, b in zip(host["passive_blocks"], blocks)
+        ]
+        if device:
+            jax.block_until_ready((blocks, passive_blocks))
+        place_span.set(bytes=sum(
+            x.nbytes for x in jax.tree.leaves((blocks, passive_blocks))))
+
+    padded_flops = int(
+        sum(b.n_entities * b.rows_per_entity * b.block_dim for b in blocks)
     )
+    ds = RandomEffectDataset(
+        blocks=blocks,
+        entity_ids=host["entity_ids"],
+        entity_to_slot=host["entity_to_slot"],
+        n_global_rows=n_rows,
+        n_features=d,
+        passive_blocks=passive_blocks,
+        padded_flops=padded_flops,
+        exact_flops=host["exact_flops"],
+        block_rows_real=host["block_rows_real"],
+    )
+    from photon_ml_tpu import telemetry as telemetry_mod
+
+    telemetry_mod.current().gauge("game_bucket_padding_ratio").set(
+        ds.padding_ratio
+    )
+    return ds
+
+
+def _sort_by_entity(entity_keys: np.ndarray):
+    """``(order, starts, ent_keys)``: the stable order of the rows by their
+    entity's STRING key, where each entity starts in it, and the entities'
+    string keys, ascending.
+
+    Keys of an integer or string dtype are ranked through their distinct
+    values, so the string conversion and the string sort touch one value
+    per entity and the rows sort as integers: at 20 M rows the row-wise
+    ``astype(str)`` + string argsort this replaces took most of a minute.
+    The order is the same either way (a stable sort by the key's rank is a
+    stable sort by the key)."""
+    if entity_keys.dtype.kind not in "iubSU":
+        keys = entity_keys.astype(str)
+        order = np.argsort(keys, kind="stable")
+        sorted_keys = keys[order]
+        starts = np.flatnonzero(
+            np.concatenate([[True], sorted_keys[1:] != sorted_keys[:-1]])
+        )
+        return order, starts, sorted_keys[starts]
+    uniq, inverse = np.unique(entity_keys, return_inverse=True)
+    ukeys = uniq.astype(str)
+    by_string = np.argsort(ukeys, kind="stable")
+    rank = np.empty(len(uniq), np.int64)
+    rank[by_string] = np.arange(len(uniq))
+    row_rank = rank[inverse.reshape(-1)]
+    order = np.argsort(row_rank, kind="stable")
+    counts = np.bincount(row_rank, minlength=len(uniq))
+    starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
+    return order, starts, ukeys[by_string]
+
+
+def _group_entities(
+    entity_keys, rows_csr, labels, weights, max_rows_per_entity,
+    bucket_growth, repack, program_budget, repack_seed, tile,
+):
+    """The host half of :func:`build_random_effect_dataset`: numpy fields
+    of every block, or ``None`` without rows.  ``tile`` is the device's
+    tiling (:func:`_device_tile`), or ``None`` for blocks that stay on the
+    host."""
+    n_rows, d = rows_csr.shape
 
     # Group rows by entity — FLAT-ARRAY pipeline throughout.  A previous
     # version sliced scipy CSR per entity (rows_csr[ridx] then
@@ -420,21 +564,13 @@ def build_random_effect_dataset(
     # spent ~26 s in scipy index validation for ~2 s of real work.
     # Everything below runs on the raw indptr/indices/data arrays of ONE
     # bulk row gather, with per-bucket flat scatters filling the blocks.
-    order = np.argsort(entity_keys, kind="stable")
+    if len(entity_keys) == 0:
+        return None
+    order, starts, ent_keys = _sort_by_entity(entity_keys)
     n_sorted = len(order)
-    if n_sorted == 0:
-        return RandomEffectDataset(
-            blocks=[], entity_ids=[], entity_to_slot={},
-            n_global_rows=n_rows, n_features=d, passive_blocks=[],
-        )
-    sorted_keys = entity_keys[order]
-    starts = np.flatnonzero(
-        np.concatenate([[True], sorted_keys[1:] != sorted_keys[:-1]])
-    )
     ends = np.append(starts[1:], n_sorted)
     span_sizes = ends - starts
     n_ent = len(starts)
-    ent_keys = sorted_keys[starts]
 
     # Active-set cap (the reference's split): capped entities keep a
     # uniformly-spaced row subset, the rest become score-only passive
@@ -471,7 +607,16 @@ def build_random_effect_dataset(
     # upair is sorted entity-major, so each entity's active columns come
     # out ascending — the same order np.unique(sub.indices) produced.
     pair = ent_of_nnz.astype(np.int64) * d + sorted_csr.indices
-    upair, inv_kept = np.unique(pair[nnz_keep], return_inverse=True)
+    if n_ent * d <= _PAIR_TABLE_CELLS:
+        # Few enough (entity, column) cells for a presence table: one
+        # pass in place of the sort inside np.unique (8 s at 62 M pairs).
+        present = np.zeros(n_ent * d, bool)
+        present[pair[nnz_keep]] = True
+        upair = np.flatnonzero(present)
+        inv_kept = (np.cumsum(present) - 1)[pair[nnz_keep]]
+        del present
+    else:
+        upair, inv_kept = np.unique(pair[nnz_keep], return_inverse=True)
     act_ent = (upair // d).astype(np.int64)
     act_col = (upair % d).astype(np.int32)
     act_counts = np.bincount(act_ent, minlength=n_ent).astype(np.int64)
@@ -538,22 +683,53 @@ def build_random_effect_dataset(
         lane_of_ent[m] = np.arange(len(m))
         block_of_ent[m] = bi
 
+    # Each bucket's sorted positions, stored entries and active pairs as
+    # index lists, ascending, from ONE stable sort by bucket each: a
+    # boolean mask over all rows and all entries per bucket cost a pass
+    # over the whole data for every bucket.
+    def by_block(block_ids):
+        small = block_ids.astype(
+            np.int16 if len(ordered_buckets) < (1 << 15) else np.int64)
+        idx = np.argsort(small, kind="stable")
+        bounds = np.concatenate([[0], np.cumsum(np.bincount(
+            block_ids, minlength=len(ordered_buckets)))])
+        return idx, bounds
+
+    block_of_pos = block_of_ent[ent_of_pos]
+    pos_idx, pos_bounds = by_block(block_of_pos)
+    nnz_idx, nnz_bounds = by_block(block_of_pos[pos_of_nnz])
+    act_idx, act_bounds = by_block(block_of_ent[act_ent])
+    del block_of_pos
+    # An entry's index among the KEPT entries (inv_kept's space).
+    kept_rank = np.cumsum(nnz_keep) - 1
+
     labels = np.asarray(labels)
     weights = np.asarray(weights)
     row_of_pos = order  # global row id of each sorted position
-    blocks: list[EntityBlock] = []
-    passive_blocks: list[Optional[EntityBlock]] = []
+    blocks: list[dict] = []
+    passive_blocks: list[Optional[dict]] = []
     ids_per_block: list[list] = []
     entity_to_slot: dict = {}
+    block_rows_real: list[int] = []
     for bi, m in enumerate(ordered_buckets):
         E = len(m)
         R = int(kept_counts[m].max())
         D = max(1, int(act_counts[m].max()))
-        in_b = np.zeros(n_ent, bool)
-        in_b[m] = True
+        minor = _x_minor(R, D, tile)
+
+        def features(rows_, lane, row, col, values, minor=minor, E=E, D=D):
+            """The block's features, scattered in its storage order."""
+            if minor == "r":
+                X = np.zeros((E, D, rows_), np.float32)
+                X[lane, col, row] = values
+            else:
+                X = np.zeros((E, rows_, D), np.float32)
+                X[lane, row, col] = values
+            return X
 
         # Row-level fills: labels/weights/row_index at (lane, local_row).
-        sel = in_b[ent_of_pos] & keep
+        in_bucket = pos_idx[pos_bounds[bi]:pos_bounds[bi + 1]]
+        sel = in_bucket[keep[in_bucket]]
         lane_r = lane_of_ent[ent_of_pos[sel]]
         lrow = local_kept[sel]
         lab = np.zeros((E, R), np.float32)
@@ -563,41 +739,33 @@ def build_random_effect_dataset(
         lab[lane_r, lrow] = labels[rows_sel]
         wts[lane_r, lrow] = weights[rows_sel]
         rindex[lane_r, lrow] = rows_sel
+        block_rows_real.append(int(len(sel)))
 
         # col_map: each unique active (entity, col) lands at its rank
         # within the entity's active list.
         cmap = np.full((E, D), -1, np.int32)
-        a_sel = in_b[act_ent]
-        local_c = (np.arange(len(upair)) - act_before[act_ent])[a_sel]
+        a_sel = act_idx[act_bounds[bi]:act_bounds[bi + 1]]
+        local_c = a_sel - act_before[act_ent[a_sel]]
         cmap[lane_of_ent[act_ent[a_sel]], local_c] = act_col[a_sel]
 
         # X: every kept nnz of the bucket scatters to
         # (lane, local_row, local_col); duplicates were pre-summed.
-        n_sel = in_b[ent_of_nnz] & nnz_keep
-        n_sel_k = n_sel[nnz_keep]  # same nnz, indexed in kept-nnz space
+        entries = nnz_idx[nnz_bounds[bi]:nnz_bounds[bi + 1]]
+        n_sel = entries[nnz_keep[entries]]
         e_n = ent_of_nnz[n_sel]
-        X = np.zeros((E, R, D), np.float32)
-        X[
-            lane_of_ent[e_n],
-            local_kept[pos_of_nnz[n_sel]],
-            inv_kept[n_sel_k] - act_before[e_n],
-        ] = sorted_csr.data[n_sel]
+        X = features(
+            R, lane_of_ent[e_n], local_kept[pos_of_nnz[n_sel]],
+            inv_kept[kept_rank[n_sel]] - act_before[e_n],
+            sorted_csr.data[n_sel],
+        )
 
         ids = list(ent_keys[m])
         for lane, key in enumerate(ids):
             entity_to_slot[key] = (bi, lane)
-        blocks.append(
-            EntityBlock(
-                X=_asarray(X, dtype),
-                labels=_asarray(lab),
-                weights=_asarray(wts),
-                col_map=_asarray(cmap),
-                row_index=_asarray(rindex),
-                n_entities=E,
-                rows_per_entity=R,
-                block_dim=D,
-            )
-        )
+        blocks.append(dict(
+            X=X, labels=lab, weights=wts, col_map=cmap, row_index=rindex,
+            n_entities=E, rows_per_entity=R, block_dim=D, x_minor=minor,
+        ))
         ids_per_block.append(ids)
 
         # Score-only passive companion block, lane-aligned with the
@@ -606,7 +774,7 @@ def build_random_effect_dataset(
         if Rp == 0:
             passive_blocks.append(None)
             continue
-        selp = in_b[ent_of_pos] & psv
+        selp = in_bucket[~keep[in_bucket]]
         lane_p = lane_of_ent[ent_of_pos[selp]]
         lrow_p = local_psv[selp]
         rows_p = row_of_pos[selp]
@@ -621,50 +789,33 @@ def build_random_effect_dataset(
         # entity never trained on drop, as in the reference's projected
         # scoring): locate each passive nnz's (entity, col) in the sorted
         # unique-pair table; misses drop.
-        Xp = np.zeros((E, Rp, D), np.float32)
-        np_sel = in_b[ent_of_nnz] & ~nnz_keep
+        np_sel = entries[~nnz_keep[entries]]
+        hit = np.zeros(len(np_sel), bool)
+        ss = np.zeros(len(np_sel), np.int64)
         if len(upair):  # no active pairs at all → every passive nnz drops
             p_pair = pair[np_sel]
             ss = np.searchsorted(upair, p_pair)
             hit = (ss < len(upair)) & (
                 upair[np.minimum(ss, len(upair) - 1)] == p_pair
             )
-            e_p = ent_of_nnz[np_sel][hit]
-            Xp[
-                lane_of_ent[e_p],
-                local_psv[pos_of_nnz[np_sel][hit]],
-                ss[hit] - act_before[e_p],
-            ] = sorted_csr.data[np_sel][hit]
-        passive_blocks.append(
-            EntityBlock(
-                X=_asarray(Xp, dtype),
-                labels=_asarray(labp),
-                weights=_asarray(wtsp),
-                col_map=blocks[-1].col_map,
-                row_index=_asarray(rindexp),
-                n_entities=E,
-                rows_per_entity=Rp,
-                block_dim=D,
-            )
+        e_p = ent_of_nnz[np_sel][hit]
+        passive_minor = _x_minor(Rp, D, tile)
+        Xp = features(
+            Rp, lane_of_ent[e_p], local_psv[pos_of_nnz[np_sel][hit]],
+            ss[hit] - act_before[e_p], sorted_csr.data[np_sel][hit],
+            minor=passive_minor,
         )
+        passive_blocks.append(dict(
+            X=Xp, labels=labp, weights=wtsp, row_index=rindexp,
+            n_entities=E, rows_per_entity=Rp, block_dim=D,
+            x_minor=passive_minor,
+        ))
 
-    padded_flops = int(
-        sum(b.n_entities * b.rows_per_entity * b.block_dim for b in blocks)
-    )
-    exact_flops = int(np.sum(kept_counts * np.maximum(act_counts, 1)))
-    ds = RandomEffectDataset(
-        blocks=blocks,
-        entity_ids=ids_per_block,
-        entity_to_slot=entity_to_slot,
-        n_global_rows=n_rows,
-        n_features=d,
-        passive_blocks=passive_blocks,
-        padded_flops=padded_flops,
-        exact_flops=exact_flops,
-    )
-    from photon_ml_tpu import telemetry as telemetry_mod
-
-    telemetry_mod.current().gauge("game_bucket_padding_ratio").set(
-        ds.padding_ratio
-    )
-    return ds
+    return {
+        "blocks": blocks,
+        "passive_blocks": passive_blocks,
+        "entity_ids": ids_per_block,
+        "entity_to_slot": entity_to_slot,
+        "exact_flops": int(np.sum(kept_counts * np.maximum(act_counts, 1))),
+        "block_rows_real": block_rows_real,
+    }

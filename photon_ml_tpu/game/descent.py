@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_ml_tpu import telemetry as telemetry_mod
+from photon_ml_tpu.telemetry import layer_span
 from photon_ml_tpu.chaos import core as chaos_mod
 from photon_ml_tpu.game.coordinates import Coordinate
 
@@ -205,8 +206,36 @@ class CoordinateDescent:
         # at the END of the run, so the whole multi-iteration loop
         # pipelines on the device with a single host sync.
         pending: list[dict] = []
+        # (its ``coordinate.train`` layer span, ``train_counts()``) of every
+        # update since the last flush.
+        counted: list[tuple] = []
+
+        def read_counts():
+            """One read of every waiting count; each joins its span."""
+            if not counted:
+                return
+            leaves, tree = jax.tree.flatten([c for _span, c in counted])
+            waiting = [i for i, v in enumerate(leaves)
+                       if isinstance(v, jax.Array)]
+            if waiting:
+                values = np.asarray(jnp.stack([
+                    jnp.asarray(leaves[i], jnp.float32) for i in waiting
+                ])).tolist()
+                for i, v in zip(waiting, values):
+                    whole = jnp.issubdtype(leaves[i].dtype, jnp.integer)
+                    leaves[i] = int(v) if whole else v
+            for (span, _c), counts in zip(
+                counted, jax.tree.unflatten(tree, leaves)
+            ):
+                span.amend(**counts)
+                frozen = sum(
+                    b["frozen_early"] for b in counts.get("buckets", ()))
+                if frozen:
+                    tel.counter("game_re_frozen_early_total").inc(frozen)
+            counted.clear()
 
         def flush():
+            read_counts()
             if not pending:
                 return
             # Floating scalars stack at f64 under x64 so fp64 device
@@ -284,70 +313,84 @@ class CoordinateDescent:
         trainable = [
             c for c in self.coordinates if c.name not in locked
         ]
-        for it in range(start_it, n_iterations):
-            it_t0 = time.perf_counter()
-            with tel.span("cd_iteration", iteration=it):
-                for ci, coord in enumerate(trainable):
-                    offsets = total - scores[coord.name]
-                    if self.pipeline and ci + 1 < len(trainable):
-                        # Overlap hint: the next coordinate's
-                        # offset-independent host packing runs while
-                        # this one's solve owns the device/foreground.
-                        # Its warm state is untouched by this update
-                        # (only states[coord.name] changes below), so
-                        # the staged payloads stay valid.
-                        nxt = trainable[ci + 1]
-                        nxt.prestage(states[nxt.name])
-                    upd_t0 = time.perf_counter()
-                    # Coordinate/solver spans cover the HOST wall of the
-                    # update: real wall for streamed/out-of-core
-                    # coordinates (their train blocks per pass), dispatch
-                    # wall for resident ones — the batched-flush design
-                    # forbids a per-update device sync, so the true
-                    # per-iteration wall rides the cd_iteration span /
-                    # histogram measured across the flush below.
-                    with tel.span(
-                        "coordinate", coordinate=coord.name, iteration=it
-                    ):
+        # Layer spans (docs/telemetry.md): cd.fit > cd.iteration >
+        # coordinate.train / coordinate.score; with a hub they are its
+        # spans too, around its own ``coordinate`` span (train + score).
+        # They time the HOST: real wall for streamed/out-of-core
+        # coordinates (their train blocks per pass), dispatch wall for
+        # resident ones — the batched-flush design forbids a per-update
+        # device sync, so the true per-iteration wall rides the
+        # cd_iteration_seconds histogram measured across the flush below,
+        # and what an update counted on the device joins its
+        # ``coordinate.train`` span when the flush reads it.
+        with layer_span(
+            "cd.fit", iterations=n_iterations,
+            coordinates=[c.name for c in trainable],
+        ):
+            for it in range(start_it, n_iterations):
+                it_t0 = time.perf_counter()
+                with layer_span("cd.iteration", iteration=it):
+                    for ci, coord in enumerate(trainable):
+                        offsets = total - scores[coord.name]
+                        if self.pipeline and ci + 1 < len(trainable):
+                            # Overlap hint: the next coordinate's
+                            # offset-independent host packing runs while
+                            # this one's solve owns the device/foreground.
+                            # Its warm state is untouched by this update
+                            # (only states[coord.name] changes below), so
+                            # the staged payloads stay valid.
+                            nxt = trainable[ci + 1]
+                            nxt.prestage(states[nxt.name])
+                        upd_t0 = time.perf_counter()
                         with tel.span(
-                            "solver",
-                            coordinate=coord.name,
-                            optimizer=_optimizer_name(coord),
+                            "coordinate", coordinate=coord.name, iteration=it
                         ):
-                            state = coord.train(
-                                offsets, warm_state=states[coord.name]
-                            )
-                        new_score = coord.score(state)
-                    states[coord.name] = state
-                    total = offsets + new_score
-                    scores[coord.name] = new_score
+                            with layer_span(
+                                "coordinate.train", coordinate=coord.name,
+                                kind=coord.kind, iteration=it,
+                                optimizer=_optimizer_name(coord),
+                            ) as train_span:
+                                state = coord.train(
+                                    offsets, warm_state=states[coord.name]
+                                )
+                            counts = coord.train_counts()
+                            if counts:
+                                counted.append((train_span, counts))
+                            with layer_span(
+                                "coordinate.score", coordinate=coord.name,
+                                iteration=it,
+                            ):
+                                new_score = coord.score(state)
+                        states[coord.name] = state
+                        total = offsets + new_score
+                        scores[coord.name] = new_score
 
-                    entry = {"iteration": it, "coordinate": coord.name}
-                    if eval_fn is not None:
-                        entry.update(eval_fn(it, coord.name, scores, states))
-                    # The norm is just another deferred floating scalar —
-                    # the flush walk materializes it with the metrics.
-                    entry["score_norm"] = jnp.linalg.norm(new_score)
-                    entry["wall_seconds"] = time.perf_counter() - upd_t0
-                    pending.append(entry)
-                if flush_per_iteration:
-                    flush()
-                if checkpointer is not None:
-                    checkpointer.save(
-                        it, total, scores, states, history,
-                        locked=sorted(locked),
+                        entry = {"iteration": it, "coordinate": coord.name}
+                        if eval_fn is not None:
+                            entry.update(eval_fn(it, coord.name, scores, states))
+                        # The norm is just another deferred floating scalar —
+                        # the flush walk materializes it with the metrics.
+                        entry["score_norm"] = jnp.linalg.norm(new_score)
+                        entry["wall_seconds"] = time.perf_counter() - upd_t0
+                        pending.append(entry)
+                    if flush_per_iteration:
+                        flush()
+                    if checkpointer is not None:
+                        checkpointer.save(
+                            it, total, scores, states, history,
+                            locked=sorted(locked),
+                        )
+                    # The CD outer-iteration boundary (the distributed-CD
+                    # resume point): iteration ``it`` is complete AND
+                    # checkpointed; a kill here must resume at it+1
+                    # bit-identically (docs/robustness.md).
+                    chaos_mod.maybe_fail("cd.iteration", iteration=it)
+                if flush_per_iteration and tel.enabled:
+                    # The flush materialized device scalars (a real sync), so
+                    # this iteration wall is achieved wall-clock, not
+                    # dispatch rate.
+                    tel.histogram("cd_iteration_seconds").observe(
+                        time.perf_counter() - it_t0
                     )
-                # The CD outer-iteration boundary (the distributed-CD
-                # resume point): iteration ``it`` is complete AND
-                # checkpointed; a kill here must resume at it+1
-                # bit-identically (docs/robustness.md).
-                chaos_mod.maybe_fail("cd.iteration", iteration=it)
-            if flush_per_iteration and tel.enabled:
-                # The flush materialized device scalars (a real sync), so
-                # this iteration wall is achieved wall-clock, not
-                # dispatch rate.
-                tel.histogram("cd_iteration_seconds").observe(
-                    time.perf_counter() - it_t0
-                )
-        flush()
+            flush()
         return CoordinateDescentResult(states=states, scores=scores, history=history)

@@ -225,6 +225,7 @@ def _pad_block_entities(block: EntityBlock, multiple: int, sentinel: int):
         n_entities=target,
         rows_per_entity=block.rows_per_entity,
         block_dim=block.block_dim,
+        x_minor=block.x_minor,
     )
 
 

@@ -35,6 +35,7 @@ from photon_ml_tpu.game.descent import CoordinateDescent
 from photon_ml_tpu.game.model import FixedEffectModel, GameModel, RandomEffectModel
 from photon_ml_tpu.ops import losses as losses_lib
 from photon_ml_tpu.optim.problem import GlmOptimizationConfig
+from photon_ml_tpu.telemetry import layer_span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -219,6 +220,16 @@ class GameEstimator:
             self.coordinate_configs, shards, ids, response, weight, offset
         )
 
+    def _build_coordinates(self, coordinate_configs, shards, *args, **kw):
+        """One ``game.build`` layer span around the datasets' builds: its
+        children are each fixed effect's ``data.make_glm_data`` and each
+        random effect's ``game.group`` and ``game.place``."""
+        with layer_span(
+            "game.build", coordinates=list(coordinate_configs)
+        ):
+            return self._build_datasets(
+                coordinate_configs, shards, *args, **kw)
+
     @staticmethod
     def dataset_key(cfg: "CoordinateConfig") -> tuple:
         """Cache key identifying the DATASET a config needs — grid points
@@ -245,7 +256,7 @@ class GameEstimator:
             cfg.repack_seed,
         )
 
-    def _build_coordinates(
+    def _build_datasets(
         self,
         coordinate_configs,
         shards,

@@ -67,15 +67,18 @@ def _gather_v(V: Array, cmap: Array) -> Array:
 
 
 def _project_block(block: EntityBlock, V: Array, rank: int) -> EntityBlock:
-    """The block with features projected through V: X (E,R,D) → Z (E,R,k)."""
+    """The block with features projected through V: X (E,R,D) → Z (E,R,k),
+    stored as computed (``x_minor == "d"``) whatever order the block keeps
+    its own X in."""
     vsub = _gather_v(V, block.col_map)
-    z = jnp.einsum("erd,edk->erk", block.X, vsub)
+    z = jnp.einsum("erd,edk->erk", block.x_erd, vsub)
     # col_map is meaningless in latent space; the solver never reads it.
     return dataclasses.replace(
         block,
         X=z,
         col_map=jnp.zeros((block.n_entities, rank), jnp.int32),
         block_dim=rank,
+        x_minor="d",
     )
 
 
@@ -144,7 +147,7 @@ class FactoredRandomEffectCoordinate(Coordinate):
                 vsub = _gather_v(V, block.col_map)
                 off = _gather_block_offsets(offsets, block)
                 m = (
-                    jnp.einsum("erd,edk,ek->er", block.X, vsub, u)
+                    jnp.einsum("erd,edk,ek->er", block.x_erd, vsub, u)
                     + off.astype(jnp.float32)
                 )
                 val = val + jnp.sum(
@@ -152,7 +155,7 @@ class FactoredRandomEffectCoordinate(Coordinate):
                 )
                 dm = block.weights * loss.d1(m, block.labels)  # (E, R)
                 g_local = jnp.einsum(
-                    "er,erd,ek->edk", dm, block.X, u
+                    "er,erd,ek->edk", dm, block.x_erd, u
                 )  # (E, D, rank)
                 idx = jnp.where(
                     block.col_map >= 0, block.col_map, n_features
@@ -198,13 +201,13 @@ class FactoredRandomEffectCoordinate(Coordinate):
             for block, pblock, u in zip(blocks, passive, u_list):
                 s = jnp.einsum(
                     "erd,edk,ek->er",
-                    block.X, _gather_v(V, block.col_map), u,
+                    block.x_erd, _gather_v(V, block.col_map), u,
                 )
                 total = total.at[block.row_index.ravel()].add(s.ravel())
                 if pblock is not None:
                     sp_ = jnp.einsum(
                         "erd,edk,ek->er",
-                        pblock.X, _gather_v(V, pblock.col_map), u,
+                        pblock.x_erd, _gather_v(V, pblock.col_map), u,
                     )
                     total = total.at[pblock.row_index.ravel()].add(
                         sp_.ravel()

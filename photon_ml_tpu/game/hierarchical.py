@@ -142,7 +142,7 @@ def _re_block_scores_jit(layout_sig: tuple):
 
     def _scores(blocks, coefs_list):
         return [
-            jnp.einsum("erd,ed->er", b.X, c)
+            jnp.einsum("erd,ed->er", b.x_erd, c)
             for b, c in zip(blocks, coefs_list)
         ]
 
@@ -311,7 +311,7 @@ class ShardedBucketRandomEffectCoordinate(RandomEffectCoordinate):
                 )
                 for i, b in zip(idxs, blocks)
             ]
-            outs = self._group_train_jits[key](
+            outs, _counts = self._group_train_jits[key](
                 blocks, off_for[key], w0s, l1, l2
             )
             for i, out in zip(idxs, outs):

@@ -131,14 +131,14 @@ class OutOfCoreFactoredRandomEffectCoordinate(OutOfCoreRandomEffectCoordinate):
             vsub = _gather_v(V, block.col_map)
             off = _gather_block_offsets(offsets, block)
             m = (
-                jnp.einsum("erd,edk,ek->er", block.X, vsub, u)
+                jnp.einsum("erd,edk,ek->er", block.x_erd, vsub, u)
                 + off.astype(jnp.float32)
             )
             acc_val = acc_val + jnp.sum(
                 block.weights * loss.value(m, block.labels)
             )
             dm = block.weights * loss.d1(m, block.labels)
-            g_local = jnp.einsum("er,erd,ek->edk", dm, block.X, u)
+            g_local = jnp.einsum("er,erd,ek->edk", dm, block.x_erd, u)
             idx = jnp.where(block.col_map >= 0, block.col_map, n_features)
             acc_g = acc_g.at[idx.reshape(-1)].add(g_local.reshape(-1, rank))
             return acc_val, acc_g

@@ -110,9 +110,9 @@ def _ooc_slice_jits(
 
     def _var_slice(block, coefs, offsets, l2):
         off_b = _gather_block_offsets(offsets, block)
-        m = jnp.einsum("erd,ed->er", block.X, coefs) + off_b
+        m = jnp.einsum("erd,ed->er", block.x_erd, coefs) + off_b
         d2w = block.weights * loss.d2(m, block.labels)
-        diag = jnp.einsum("er,erd->ed", d2w, block.X * block.X) + l2
+        diag = jnp.einsum("er,erd->ed", d2w, block.x_erd * block.x_erd) + l2
         return 1.0 / jnp.maximum(diag, 1e-12)
 
     return jax.jit(_solve_slice), jax.jit(_var_slice)
@@ -170,6 +170,7 @@ def _slice_block(
         n_entities=padded_e,
         rows_per_entity=block.rows_per_entity,
         block_dim=block.block_dim,
+        x_minor=block.x_minor,
     )
 
 

@@ -61,7 +61,7 @@ def _re_val_score_jit(n_val: int, layout_sig: tuple):
         total_scores = jnp.zeros((n_val + 1,), jnp.float32)
         for vb, gidx in zip(blocks, gidxs):
             coefs = jnp.take(flat, gidx, axis=0)  # (E_v, D_v)
-            s = jnp.einsum("erd,ed->er", vb.X, coefs)
+            s = jnp.einsum("erd,ed->er", vb.x_erd, coefs)
             total_scores = total_scores.at[vb.row_index.ravel()].add(
                 s.ravel()
             )

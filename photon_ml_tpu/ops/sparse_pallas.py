@@ -122,6 +122,22 @@ if DMA_BUDGET <= 0:
     )
 
 
+#: Entries the stripe split hands one thread at a time.
+_SPLIT_CHUNK = 1 << 24
+
+
+def _in_threads(fn, items) -> list:
+    """``[fn(x) for x in items]`` on a few threads: for numpy passes over
+    large arrays, which release the interpreter lock."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    items = list(items)
+    if len(items) <= 1:
+        return [fn(x) for x in items]
+    with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as pool:
+        return list(pool.map(fn, items))
+
+
 def _cptr(arr: np.ndarray, ct):
     """ctypes pointer to a contiguous numpy array (native build glue)."""
     import ctypes
@@ -1234,8 +1250,11 @@ def build_pallas_host(
             host_coo = HostCoo(r_all, c_all, v_all, int(n_rows), int(n_cols))
             # Zero-valued entries contribute nothing; excluding them keeps
             # explicit zeros from faking a dense cell.
-            live = np.flatnonzero(v_all != 0)
-            r, c, v = r_all[live], c_all[live], v_all[live]
+            if np.all(v_all != 0):
+                r, c, v = r_all, c_all, v_all
+            else:
+                live = np.flatnonzero(v_all != 0)
+                r, c, v = r_all[live], c_all[live], v_all[live]
 
         nbr = max(1, -(-n_rows // TILE_R))
         nbc = max(1, -(-n_cols // TILE_C))
@@ -1259,20 +1278,39 @@ def build_pallas_host(
                 # Zero-SIZE block when absent (never read; has_dense_*
                 # gates).
                 block = np.zeros((len(ids), long_axis), np.float32)
-                inside = (np.isin(idx, ids) if ids.size
-                          else np.zeros(len(idx), bool))
-                block[np.searchsorted(ids, idx[inside]), other[inside]] = (
-                    vals_[inside])
-                return ids, block, ~inside, predicted
+                if not ids.size:
+                    return ids, block, np.ones(len(idx), bool), predicted
+                # stripe of each index, -1 for the tiled ones: one table
+                # lookup per entry (np.isin + np.searchsorted were a sort
+                # and a search over every entry)
+                stripe_of = np.full(n_idx, -1, np.int32)
+                stripe_of[ids] = np.arange(len(ids), dtype=np.int32)
+                stay = np.empty(len(idx), bool)
+
+                def fill(lo):
+                    # Each entry has a (stripe, position) of its own, so
+                    # chunks of the entry list write disjoint cells.
+                    hi = min(len(idx), lo + _SPLIT_CHUNK)
+                    stripe = stripe_of[idx[lo:hi]]
+                    inside = stripe >= 0
+                    block[stripe[inside], other[lo:hi][inside]] = (
+                        vals_[lo:hi][inside])
+                    np.logical_not(inside, out=stay[lo:hi])
+
+                _in_threads(fill, range(0, len(idx), _SPLIT_CHUNK))
+                return ids, block, stay, predicted
+
+            def tiled(stay):
+                return _in_threads(lambda a: a[stay], (r, c, v))
 
             dense_col_ids, dense_cols, stay, (a_f_pred, a_b_pred) = split(
                 c, r, v, n_cols, n_rows, col_permutation and n_cols > WIN)
             if dense_col_ids.size:
-                r, c, v = r[stay], c[stay], v[stay]
+                r, c, v = tiled(stay)
             dense_row_ids, dense_rows, stay, _ = split(
                 r, c, v, n_rows, n_cols, False)
             if dense_row_ids.size:
-                r, c, v = r[stay], c[stay], v[stay]
+                r, c, v = tiled(stay)
             stripe_nnz = n_valued - len(v)
 
         # --- optional column permutation (clustered-data balance) ---------
@@ -1412,7 +1450,7 @@ def host_layout_from_scipy_csr(csr, depth_cap: int = 128,
     csr.sum_duplicates()
     coo = csr.tocoo()
     return build_pallas_host(
-        coo.row.astype(np.int64), coo.col.astype(np.int64), coo.data,
+        coo.row, coo.col, coo.data,
         csr.shape[0], csr.shape[1], depth_cap=depth_cap, pad_nnz=pad_nnz,
         dtype=dtype)
 

@@ -52,7 +52,7 @@ def random_effect_block_scores(
     out = np.zeros(n + 1, np.float32)
     for block, block_ids in zip(dataset.blocks, dataset.entity_ids):
         coefs = model.coefficient_matrix_for(block.col_map, block_ids)
-        scores = np.einsum("erd,ed->er", block.X, coefs)
+        scores = np.einsum("erd,ed->er", block.x_erd, coefs)
         np.add.at(out, block.row_index.ravel(), scores.ravel())
     return out[:n]
 

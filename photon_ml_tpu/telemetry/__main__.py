@@ -57,13 +57,13 @@ def _build_synthetic_run(out_dir: str) -> dict:
         plane = mount_ops_plane(tel, port=0, interval_s=0.02)
         with tel.span("run", driver="selfcheck"):
             for it in range(2):
-                with tel.span("cd_iteration", iteration=it):
+                with tel.span("cd.iteration", iteration=it):
                     for coord in ("fixed", "per_user"):
                         with tel.span(
                             "coordinate", coordinate=coord, iteration=it
                         ):
                             with tel.span(
-                                "solver", coordinate=coord,
+                                "coordinate.train", coordinate=coord,
                                 optimizer="lbfgs",
                             ) as sp:
                                 time.sleep(0.001)

@@ -1017,16 +1017,29 @@ class LayerSpan:
     """
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "t0", "t1",
-                 "_hub_span", "_annotation")
+                 "_hub_span", "_annotation", "_record")
 
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs = attrs
         self.t1 = None
+        self._record = None
 
     def set(self, **attrs) -> "LayerSpan":
         """Attach attributes mid-span (counts made at this boundary)."""
         self.attrs.update(attrs)
+        return self
+
+    def amend(self, **attrs) -> "LayerSpan":
+        """Attach attributes to a span that has ENDED: counts the device
+        made under it, read back later in one batched read (coordinate
+        descent reads every update's counters in its history flush).  They
+        join the filed record in the ring; a hub's sinks have already
+        written theirs."""
+        self.attrs.update(attrs)
+        if self._record is not None:
+            self._record.setdefault("attrs", {}).update(
+                {k: json_safe(v) for k, v in attrs.items()})
         return self
 
     def __enter__(self) -> "LayerSpan":
@@ -1078,6 +1091,7 @@ class LayerSpan:
         if self.attrs:
             record["attrs"] = {k: json_safe(v)
                                for k, v in self.attrs.items()}
+        self._record = record
         _LAYER_RING.emit(record)
         if isinstance(self._hub_span, Span):
             self._hub_span.close(self.t1, exc_type, exc)

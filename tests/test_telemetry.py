@@ -463,13 +463,14 @@ class TestDriverTelemetry:
             return chain
 
         solver = [
-            r for r in span_records(records_) if r["name"] == "solver"
+            r for r in span_records(records_)
+            if r["name"] == "coordinate.train"
         ]
         # 2 CD iterations x 2 coordinates
         assert len(solver) == 4
         for s in solver:
             assert ancestry(s) == [
-                "coordinate", "cd_iteration", "train", "run"
+                "coordinate", "cd.iteration", "cd.fit", "train", "run"
             ]
         coords = {
             r["attrs"]["coordinate"]
