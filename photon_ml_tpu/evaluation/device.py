@@ -9,10 +9,11 @@ round 1, weak #8).  These are the on-device counterparts:
   weighted reduction, ``psum``-able over a mesh axis — usable INSIDE
   ``shard_map`` on row-sharded scores, so distributed validation costs one
   scalar all-reduce, exactly like a training objective evaluation;
-- weighted AUC with tie handling: device ``argsort``-based, bit-matching
-  the host evaluator (single-device; a distributed AUC needs a global sort,
-  which the reference also does not attempt — its sharded AUC averages
-  per-partition AUCs instead, our grouped-AUC analogue).
+- weighted AUC with tie handling: one device sort that carries the class
+  weights, then prefix scans; no gather or scatter.  Matches the host
+  evaluator to float tolerance (single-device; a distributed AUC needs a
+  global sort, which the reference also does not attempt — its sharded AUC
+  averages per-partition AUCs instead, our grouped-AUC analogue).
 
 Parity with the host evaluators is tested to float tolerance in
 tests/test_device_metrics.py.
@@ -149,6 +150,13 @@ def device_auc(
     Same math as the host evaluator: for each tie group, pairs against
     strictly-lower negatives count 1, within-group pairs count ½.
     Zero-weight rows are excluded.  Returns NaN when a class is missing.
+
+    One sort carries the class weights with the scores, and every
+    per-tie-group quantity is a running extreme of the negatives' prefix
+    sum: tie groups are contiguous in sorted order and that prefix sum
+    never falls (labels in [0, 1], as a class weight cannot be negative).
+    No gather and no scatter: XLA's run at ~0.1 G elem/s on a TPU
+    (ops/sparse_pallas.py), so one of either costs more than the sort.
     """
     scores = scores.astype(jnp.float64 if jax.config.jax_enable_x64
                            else jnp.float32)
@@ -158,33 +166,29 @@ def device_auc(
     )
     w = jnp.where(w > 0, w, 0.0)
 
-    order = jnp.argsort(scores, stable=True)
-    s = scores[order]
-    y = labels[order]
-    ws = w[order]
-    wp = ws * y
-    wn = ws * (1.0 - y)
-
+    # Ties need no order among themselves: their sums do not depend on it.
+    s, wp, wn = lax.sort(
+        (scores, w * labels, w * (1.0 - labels)), num_keys=1,
+        is_stable=False,
+    )
     pos_w = jnp.sum(wp)
     neg_w = jnp.sum(wn)
 
-    cum_neg = jnp.concatenate([jnp.zeros((1,), wn.dtype), jnp.cumsum(wn)])
-    boundaries = jnp.concatenate(
-        [jnp.ones((1,), bool), s[1:] != s[:-1]]
+    cum_neg = jnp.cumsum(wn)
+    cum_neg_before = jnp.concatenate(
+        [jnp.zeros((1,), wn.dtype), cum_neg[:-1]]
     )
-    group_id = jnp.cumsum(boundaries) - 1  # (n,) tie-group index
-
-    # Per-group sums via segment_sum over tie groups (n groups <= n).
-    n = s.shape[0]
-    group_neg = jax.ops.segment_sum(wn, group_id, num_segments=n)
-    # Index of each group's first element → neg weight strictly below it.
-    first_idx = jax.ops.segment_min(
-        jnp.arange(n), group_id, num_segments=n
-    )
-    neg_below_group = cum_neg[jnp.where(first_idx > n, 0, first_idx)]
-    contrib = wp * (
-        neg_below_group[group_id] + 0.5 * group_neg[group_id]
-    )
+    differs = s[1:] != s[:-1]  # -0.0 ties with +0.0, as in the sort
+    edge = jnp.ones((1,), bool)
+    first = jnp.concatenate([edge, differs])  # opens a tie group
+    last = jnp.concatenate([differs, edge])   # closes one
+    # Neg weight strictly below a row's tie group: the prefix sum before
+    # the group's first row, carried forward over the group.
+    neg_below = lax.cummax(jnp.where(first, cum_neg_before, 0.0))
+    # Neg weight up to the group's end: the prefix sum at its last row,
+    # carried backward.
+    neg_upto = lax.cummin(jnp.where(last, cum_neg, jnp.inf), reverse=True)
+    contrib = wp * (neg_below + 0.5 * (neg_upto - neg_below))
     auc = jnp.sum(contrib) / (pos_w * neg_w)
     return jnp.where(
         jnp.logical_or(pos_w == 0, neg_w == 0), jnp.nan, auc

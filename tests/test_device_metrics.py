@@ -1,5 +1,7 @@
 """Device-side metrics match the host evaluators (VERDICT weak #8)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,6 +83,46 @@ class TestPointwiseParity:
         assert float(sharded) == pytest.approx(float(whole), rel=1e-5)
 
 
+def _auc_case(name):
+    """``(scores, labels, weights)`` of one tie structure the sort-and-scan
+    AUC has to get right."""
+    rng = np.random.default_rng(28)
+
+    def rounded(n, digits=1):
+        return np.round(rng.normal(size=n), digits).astype(np.float32)
+
+    def labels_and_weights(n):
+        return ((rng.uniform(size=n) < 0.4).astype(np.float32),
+                rng.uniform(0.0, 2.0, size=n).astype(np.float32))
+
+    if name == "few_distinct_scores":
+        s = rng.choice([-1.5, -0.25, 0.0, 0.75, 3.0], size=50_000)
+    elif name == "all_scores_equal":
+        s = np.full(4096, 0.37)
+    elif name == "negative_zero_ties_positive_zero":
+        s = rng.choice([-0.0, 0.0, -1.0, 1.0], size=8192)
+        assert len(set(np.signbit(s[s == 0]))) == 2
+    elif name == "tie_group_at_each_end":
+        s = rng.normal(size=10_000).clip(-1.0, 1.0)
+    elif name == "2^20_rows_float32":
+        s = rounded(1 << 20, 3)
+    else:
+        s = rounded(20_000)
+    s = np.asarray(s, np.float32)
+    y, w = labels_and_weights(s.size)
+    if name in ("zero_weight_rows_and_group", "2^20_rows_float32"):
+        w[rng.uniform(size=w.size) < 0.3] = 0.0  # inside tie groups
+    if name == "zero_weight_rows_and_group":
+        w[s == np.float32(0.2)] = 0.0  # a whole tie group
+    elif name == "no_weights":
+        w = None
+    elif name == "integer_weights":
+        w = rng.integers(0, 4, size=s.size).astype(np.int32)
+    elif name == "soft_labels":
+        y = rng.uniform(size=s.size).astype(np.float32)
+    return s, y, w
+
+
 class TestAucParity:
     def test_matches_host_with_ties_and_weights(self, arrays):
         scores, labels, weights = arrays
@@ -89,6 +131,35 @@ class TestAucParity:
         ))
         want = AreaUnderROCCurveEvaluator().evaluate(scores, labels, weights)
         assert got == pytest.approx(want, abs=1e-6)
+
+    @pytest.mark.parametrize("case", [
+        "few_distinct_scores", "all_scores_equal",
+        "zero_weight_rows_and_group", "no_weights", "integer_weights",
+        "negative_zero_ties_positive_zero", "tie_group_at_each_end",
+        "soft_labels", "2^20_rows_float32",
+    ])
+    def test_tie_structures_match_host(self, case):
+        scores, labels, weights = _auc_case(case)
+        with jax.enable_x64(not case.endswith("float32")):
+            got = float(device_auc(
+                jnp.asarray(scores), jnp.asarray(labels),
+                None if weights is None else jnp.asarray(weights)))
+        want = AreaUnderROCCurveEvaluator().evaluate(scores, labels, weights)
+        assert got == pytest.approx(want, abs=1e-6)
+        if case == "all_scores_equal":
+            assert got == 0.5
+
+    def test_lowers_to_one_sort_and_no_gather_or_scatter(self):
+        """The mechanism, as a count a later edit cannot quietly undo: XLA's
+        gather and scatter run at ~0.1 G elem/s on the TPU, and eight of
+        them over 20 M rows were 41% of a GAME fit's device time."""
+        x = jax.ShapeDtypeStruct((4096,), jnp.float32)
+        with jax.enable_x64(False):  # as the chip runs it
+            text = device_auc.lower(x, x, x).compile().as_text()
+        opcodes = re.findall(r" = .*? ([a-z\-]+)\(", text)
+        assert "reduce-window" in opcodes  # the scans are read as opcodes
+        assert opcodes.count("sort") == 1
+        assert "gather" not in opcodes and "scatter" not in opcodes
 
     def test_single_class_nan(self):
         scores = jnp.asarray(np.random.default_rng(0).normal(size=10))
