@@ -1,6 +1,10 @@
 #!/usr/bin/env python3
 """The quickest proof that the train -> serve path still starts on the chip.
 
+A bring-up check, not a benchmark: it times nothing that a claim may rest
+on (that is ``python3 benchmarks/run.py``, ``BENCHMARK.json`` and the
+ledger: docs/performance.md "Measurement").
+
     python chip_smoke.py                  # one TPU v5e host, full width
     python chip_smoke.py --cpu-dry-run    # tiny sizes, CPU, interpret mode
 
@@ -103,7 +107,7 @@ def child_datagen(work: str, mode: str) -> None:
     sz = SIZES[mode]
     rng = np.random.default_rng(20260926)
 
-    # -- GLM: valued entries, planted sparse model (bench.py's config) -----
+    # -- GLM: valued entries, planted sparse model -----------------------
     d, k = sz["glm_features"], sz["glm_nnz"]
     w_true = (
         rng.normal(size=d) * (rng.uniform(size=d) < 0.2)
@@ -210,7 +214,7 @@ def child_datagen(work: str, mode: str) -> None:
                 {"name": "fixed", "type": "fixed",
                  "feature_shard": "global", **opt},
                 # bucket_growth 4: the zipf tail consolidates into a
-                # handful of compiled bucket shapes (bench.py's setting).
+                # handful of compiled bucket shapes.
                 {"name": "per_user", "type": "random",
                  "feature_shard": "userFeatures", "entity_key": "userId",
                  "bucket_growth": 4.0, **opt},

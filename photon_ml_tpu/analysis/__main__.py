@@ -106,7 +106,7 @@ def main(argv=None) -> int:
     mode.add_argument("--explain", metavar="RULE",
                       help="print the full rationale for one rule")
     p.add_argument("--root", action="append", default=None,
-                   help="scan root (repeatable; default: package + bench.py)")
+                   help="scan root (repeatable; default: the package)")
     p.add_argument("--baseline", default=None,
                    help="baseline path (default: analysis/baseline.json)")
     args = p.parse_args(argv)

@@ -199,16 +199,12 @@ def default_repo_root() -> str:
 
 
 def default_roots(repo_root: Optional[str] = None) -> list[str]:
-    """What ``--check`` scans by default: the package + bench.py (the
-    same surface the metric-name lint always covered).  Tests are NOT
+    """What ``--check`` scans by default: the package.  Tests are NOT
     scanned — they exist to poke invariants, including violating them
     on purpose in fixtures."""
     if repo_root is None:
         repo_root = default_repo_root()
-    return [
-        os.path.join(repo_root, "photon_ml_tpu"),
-        os.path.join(repo_root, "bench.py"),
-    ]
+    return [os.path.join(repo_root, "photon_ml_tpu")]
 
 
 # ---------------------------------------------------------------------------

@@ -346,7 +346,7 @@ RULES = [
             "off-convention names break dashboards' subsystem grouping "
             "and unit inference.  The rule scans string-literal "
             "registrations (.counter(\"x\")/.gauge/.histogram) across "
-            "the package + bench.py, enforcing lowercase snake_case, a "
+            "the package, enforcing lowercase snake_case, a "
             "known subsystem prefix, a known unit suffix, and cross-"
             "file kind consistency.  Pre-PR-7 names are grandfathered "
             "in LEGACY_NAMES (burn the list down, never grow it).  "

@@ -32,9 +32,8 @@ Cost contract (mirrors chaos/core.py): with no sanitizer installed,
 ``tracked()`` returns the RAW lock — zero added cost on the hot path,
 cheaper than chaos's one-branch contract.  Locks created WHILE a
 sanitizer is installed pay one module-global read + branch per
-acquire/release plus the witness bookkeeping; ``bench.py``'s
-``BENCH_ONLY=analysis`` section gates the enabled cost at ≤ 1% of a
-streamed pass.  Consequence of the construction-time choice: install
+acquire/release plus the witness bookkeeping (not measured on the
+chip).  Consequence of the construction-time choice: install
 the sanitizer BEFORE building the objects under test (the tests and
 selfcheck do).
 """

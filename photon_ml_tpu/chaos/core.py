@@ -22,9 +22,9 @@ every time.
 
 Cost contract (mirrors the telemetry hub): with no plan installed,
 every instrumented seam pays ONE module-global read + one branch
-(:func:`maybe_fail`).  ``bench.py``'s ``BENCH_ONLY=chaos`` section
-measures that disabled path against the streamed pass wall and gates it
-at ≤ 1%.
+(:func:`maybe_fail`).  What that costs against a streamed pass has not
+been measured on the chip; no benchmark cell reaches a seam that fires
+per chunk (PERF.md §7).
 
 Usage::
 

@@ -25,7 +25,7 @@ gates:
 - the aggregator's host set follows membership: the drained host's
   series are marked departed once it leaves, never summed forever.
 
-Registry utilities (the ops surface the runbooks in ops/README.md
+Registry utilities (the ops surface the runbooks in docs/serving.md
 drive)::
 
     python -m photon_ml_tpu.cluster --serve-registry --port 7000

@@ -33,17 +33,17 @@ optim/streaming.py).
 
 Every transfer is timed to completion on the transfer thread, so
 :class:`TransferStats` reports ACHIEVED bytes/second, not dispatch rate
-— the distinction that made round 1's throughput numbers wrong (see
-ops/README.md "Measurement discipline").  The stats attribute wall time
+— a timing that ends before the transfer does measures the enqueue
+(docs/performance.md "Measurement").  The stats attribute wall time
 to STAGES so a regression names the guilty one: ``pack_seconds`` (host
 materialization), ``dispatch_seconds`` (the ``put`` call itself, i.e.
 Python/runtime dispatch — a subset of ``h2d_seconds``), ``h2d_seconds``
 (dispatch through transfer completion) and ``consume_seconds`` (the
 caller's per-item compute dispatch + syncs).  When the pipeline
-overlaps, the summed stage seconds EXCEED the pass's wall time — the
-signature bench_streaming checks for.  Stall counters tell the two
+overlaps, the summed stage seconds EXCEED the pass's wall time.  Stall
+counters tell the two
 failure stories apart: ``consumer_stalls`` (compute waited on the
-queue: the stream is ingest-bound — the 150× gap's signature) vs
+queue: the stream is ingest-bound) vs
 ``producer_stalls`` (transfers waited on compute: the link is keeping
 up and further h2d work is pointless).
 """
@@ -116,8 +116,7 @@ class TransferStats:
     def stage_seconds(self) -> float:
         """Summed wall across the three pipeline stages (pack + transfer
         + compute).  When this exceeds a pass's wall-clock time, the
-        stages overlapped — the structural witness bench_streaming
-        reports.  ``dispatch_seconds`` is a subset of ``h2d_seconds``
+        stages overlapped.  ``dispatch_seconds`` is a subset of ``h2d_seconds``
         and is NOT double-counted here."""
         return self.pack_seconds + self.h2d_seconds + self.consume_seconds
 

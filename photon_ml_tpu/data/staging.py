@@ -542,7 +542,7 @@ def plan_compression(
     its values rule the candidate encodings out (e.g. an int64 block
     whose values genuinely need 64 bits, or a float segment exceeding
     fp16 range in fp16 mode) — callers that REQUIRE a win should check
-    :attr:`ChunkCodec.ratio` and fail loudly (bench_streaming does).
+    :attr:`ChunkCodec.ratio` and fail loudly.
     """
     if mode == "off":
         return None

@@ -423,7 +423,7 @@ def run(argv: Optional[Sequence[str]] = None) -> dict:
     args = build_arg_parser().parse_args(argv)
     # x64 is process-global jax state; restore it afterwards so one
     # --precise-accumulation run can't leak f64 defaults into later
-    # in-process runs (bench, tests, library users).
+    # in-process runs (tests, library users).
     prev_x64 = None
     if args.precise_accumulation:
         prev_x64 = bool(jax.config.jax_enable_x64)
@@ -439,7 +439,7 @@ def _run(args) -> dict:
     os.makedirs(args.output_dir, exist_ok=True)
     # The logger and telemetry hub own process-level resources (file
     # handles, the process-current hub slot); context managers release
-    # them on ANY exit — repeated in-process driver runs (tests, bench,
+    # them on ANY exit — repeated in-process driver runs (tests,
     # hyperparameter search) must not leak either.
     with PhotonLogger(args.output_dir) as logger:
         tel = telemetry_mod.Telemetry(

@@ -18,13 +18,18 @@ hierarchy (PAPERS.md) — so this module distributes the ladder itself:
   nothing — it runs where it lands, concurrently with its neighbours
   (per-device program dispatch is async, so devices overlap).
 
-Bitwise contract: the plan only changes WHERE each block's program runs,
+Parity contract: the plan only changes WHERE each block's program runs,
 never the block shapes or the per-bucket math, and the score scatter
 re-runs on one device in exactly ``_re_score_all_jit``'s block order —
-so sharded results are bit-for-bit the single-device coordinate's (the
-parity matrix in tests/test_game_hierarchical.py).  Contrast the
-repacker (game/data.py), which changes realized shapes and is therefore
-numerically-equivalent-not-bitwise vs the geometric ladder.
+so a PACKED block's results are bit-for-bit the single-device
+coordinate's.  A SPLIT block runs the same vmapped solve at another
+batch width (its lanes divided over the mesh), which is another compiled
+program: a lane's f32 sums may be ordered by that width, and results
+agree to rounding, not to the bit (tests/test_game_hierarchical.py holds
+packed blocks to the bit and split blocks to 4 ulp of the block's
+largest entry; XLA:CPU shows up to 1).  Contrast the repacker
+(game/data.py), which changes realized shapes and moves results within
+float tolerance vs the geometric ladder.
 """
 
 from __future__ import annotations

@@ -5,10 +5,12 @@ performance-critical kernels to write are Pallas/XLA kernels (sparse matvec,
 segment reductions)") — the analogue of the reference's netlib/Breeze BLAS
 under its ``ValueAndGradientAggregator`` hot loop.
 
-Why not XLA gather/scatter: on TPU, ``jnp.take`` on a 33M-element index set
-runs at ~0.1 G elem/s (measured on v5e — effectively a scalar loop), and
-``segment_sum`` lowers to scatter, which is as bad.  The whole 1B-row epoch
-metric dies there.  Mosaic's only fast data-movement primitive is
+Why not XLA gather/scatter: on TPU a large-index ``jnp.take`` is
+effectively a scalar loop, and ``segment_sum`` lowers to scatter, which is
+as bad (the one place the ledger shows it: six gathers and two scatters
+over 20 M rows were 7.33 s of a GAME fit's 17.94 device seconds before
+PR 28 removed them; ledger, PRs 27 and 28).  Mosaic's only fast
+data-movement primitive is
 ``tpu.dynamic_gather`` on a single 128-lane vreg: each sublane of an
 ``(A, 128)`` operand is an independent 128-wide lookup table.
 
@@ -26,16 +28,15 @@ The kernel design exploits exactly that:
   * extra sublanes per window absorb (window, lane) collisions; overflow
     past the cost-model depth spills to a tiny COO tail.
 
-  Packing beats the older uniform ``depth × WINS`` grid ~1.4x on slot
-  padding: A = Σ over windows of that window's own worst lane, instead of
+  Packing beats a uniform ``depth × WINS`` grid on slot padding:
+  A = Σ over windows of that window's own worst lane, instead of
   ``WINS ×`` the worst cell anywhere in the matrix.
 
 - matvec per tile: per-sublane gather tables are built from each sublane's
-  packed window id — by default ONE one-hot matmul on the MXU
+  packed window id — ONE one-hot matmul on the MXU
   (f32-HIGHEST, guarded per chunk tile: any non-finite vector window
   falls back to the exact 16-step masked-SELECT sweep so inf/nan stay
-  localized; measured 1.41x the select sweep on v5e — the table sweep
-  was the round-3 compute floor) — then ONE ``dynamic_gather`` of the
+  localized) — then ONE ``dynamic_gather`` of the
   whole ``(A, 128)`` block, then a 16-step masked sweep accumulates rows
   into the ``(16, 128)`` margin block (``ohi = (row % 2048) // 128``,
   packed per slot, selects the output sublane).  No scatter anywhere.
@@ -45,11 +46,11 @@ The kernel design exploits exactly that:
   sweep over column-his).  Both directions therefore run at the same rate —
   the property Spark's treeAggregate had for free and TPUs do not.
 
-Measured on one TPU v5e chip (131,072 rows x 8192 features, 32 nnz/row,
-PR 21's probe run — PERF.md): the fused logistic value+gradient takes
-1.25 ms through these kernels and 124 ms through the XLA COO path.  The
-other timings quoted in this module's comments predate that run and are
-not measured on the current hardware.
+Measured on one TPU v5e chip (ledger, PR 28): in ``glm_lbfgs_fit``
+(804,414 x 47,237, 76 nnz/row) the kernel is 84.378% of the device's busy
+time at 4.2956% of its bytes-bound roofline, a 10-iteration solve
+0.36542 s; in ``game_cd_fit``'s fixed effect 45.833% at 0.16136%.  About
+half of a product is a fixed cost per tile (PERF.md §5).
 
 Precision: everything is f32 — bit-comparable to the COO path (only
 summation ORDER differs).  Table construction is pure selection (no
@@ -76,10 +77,13 @@ from photon_ml_tpu.telemetry import layer_span
 
 Array = jax.Array
 
-# Tile edge: experimentally tunable (PHOTON_PALLAS_TILE); the per-tile
-# output sweep costs WINS = TILE/128 masked passes over the slot grid, so
-# smaller tiles trade DMA granularity for sweep work.  2048 measured best
-# on v5e for the bench workload; see ops/README.md.
+# Tile edge (PHOTON_PALLAS_TILE; read once at import, the kernels bake
+# it at trace time).  The per-tile output sweep costs WINS = TILE/128
+# masked passes over the slot grid, so smaller tiles trade DMA granularity
+# for sweep work; 2048 is the largest edge whose packed slot code still
+# fits int16 (CODE_DTYPE below: past it the index bytes double).  Both
+# benchmark cells run at 2048; no other edge has been timed on the chip
+# (ROADMAP S2 sweeps it, then takes the variable out).
 TILE_R = int(os.environ.get("PHOTON_PALLAS_TILE", "2048"))
 if TILE_R < 128 or TILE_R % 128 or TILE_R > 32768:
     # The packed slot code (win | ohi | lo) switches to int32 automatically
@@ -112,14 +116,13 @@ EMPTY_MARK = np.iinfo(CODE_DTYPE).min
 # Sublane-count granularity: the int16 slot arrays tile as (16, 128) on TPU,
 # so A is padded to a multiple of 16 (8 would re-pad internally).
 SUBPAD = 16
-# Per-grid-step DMA budget for the tile kernel (bytes); 4 MiB measured best
-# on v5e (2/8/16 MiB all slower — see ops/README.md).
-DMA_BUDGET = int(os.environ.get("PHOTON_PALLAS_BUDGET", 4 << 20))
-if DMA_BUDGET <= 0:
-    raise ValueError(
-        f"PHOTON_PALLAS_BUDGET must be a positive byte count, got "
-        f"{DMA_BUDGET}"
-    )
+# Per-grid-step DMA budget for the tile kernel (bytes).  A grid step's
+# input blocks are double-buffered in VMEM beside the tables and the
+# output block, so the budget stays well under the compiler's scoped-VMEM
+# limit (16 MiB on a v5e), and it is in MBs so that the stream is not
+# bound by per-step overhead.  Both benchmark cells and
+# tests/test_kernel_names_v5e.py compile with this one value.
+DMA_BUDGET = 4 << 20
 
 
 #: Entries the stripe split hands one thread at a time.
@@ -179,15 +182,6 @@ def _interpret() -> bool:
     return on
 
 
-# Gather-side table build: one-hot matmul on the MXU (all-finite fast
-# path, guarded per chunk tile) vs the 16-pass masked-select sweep.
-# Opt-out knob: the select sweep was the round-3 compute floor; set to
-# "0" if a TPU generation regresses on the tiny matmul.  Read ONCE at
-# import (the kernel bakes the choice at trace time) — A/B in separate
-# processes, exactly like PHOTON_PALLAS_TILE.
-_MXU_GATHER = os.environ.get("PHOTON_PALLAS_MXU_GATHER", "1") == "1"
-
-
 def pallas_available() -> bool:
     """True when the Pallas sparse path can run here (TPU, or interpret)."""
     return jax.default_backend() == "tpu" or _interpret()
@@ -223,14 +217,14 @@ def _build_orientation(
     ``need = min(max-lane-load, depth)`` sublanes, bin-packed per tile, so
     A = max over tiles of Σ_w need — instead of the old uniform
     ``WINS × global-max-depth`` grid.  On Poisson-spread data this cuts slot
-    padding ~1.5×: the old grid paid ``WINS ×`` the WORST cell anywhere,
+    padding: the old grid paid ``WINS ×`` the WORST cell anywhere,
     the packed layout pays each window's own worst lane, summed.
 
     Depth (the per-cell slot cap) is still COST-based: covering one more
     collision level costs real slots only where windows actually need it
     (Σ over windows of the increment to ``min(M, d)``, maxed over tiles),
     while each spilled entry costs ~``spill_cost_ratio`` slot-equivalents
-    on the XLA gather/segment_sum path (measured ~1000x per entry on v5e),
+    on the XLA gather/segment_sum path (a scalar loop per entry),
     plus a FIXED penalty for any nonzero spill (the XLA scatter's latency
     floor, worth ~16 uniform depth levels).  ``spill_cost_ratio=inf``
     forces full coverage (used for the post-spill rebuild).
@@ -430,8 +424,8 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
     """A (batch x chunk) rectangle of tiles per grid step.
 
     Batching many tiles per step keeps DMAs large (MBs, not hundreds of KB)
-    so the stream stays bandwidth-bound instead of per-step-overhead-bound
-    (measured: 2048 one-tile steps cost ~5 us each — more than the data).
+    so the stream is not bound by per-step overhead (a one-tile step's
+    fixed cost exceeds the time its data takes).
 
     code: (batch, chunk, A, 128) packed (win<<WIN_SHIFT | ohi<<7 | lo);
           empty slots carry EMPTY_MARK's sign bit (win bits preserved)
@@ -442,7 +436,7 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
     out:  (batch, WINS, 128), accumulated across the chunked grid dim
 
     Gather tables are built per tile from each sublane's packed window
-    id — by default a one-hot f32 matmul on the MXU, guarded per chunk
+    id — a one-hot f32 matmul on the MXU, guarded per chunk
     tile: a bare matmul would leak a non-finite vector entry into every
     sublane's table via 0·inf = NaN, so tiles whose table windows carry
     inf/nan take the exact masked-SELECT sweep instead (see the in-body
@@ -465,17 +459,12 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
         # predicate are invariant across the batch dimension — slicing and
         # reducing them once per (j) instead of per (b, j) saves
         # batch-1 redundant (WINS, 128) passes.
-        if _MXU_GATHER:
-            tab_j = tab_ref[pl.ds(j, 1), :, :][0]             # (WINS, 128)
-            tab_finite = jnp.all(jnp.isfinite(tab_j))
-        _batch_tiles(j, tab_j if _MXU_GATHER else None,
-                     tab_finite if _MXU_GATHER else None)
-        return 0
-
-    def _batch_tiles(j, tab_j, tab_finite):
+        tab_j = tab_ref[pl.ds(j, 1), :, :][0]                 # (WINS, 128)
+        tab_finite = jnp.all(jnp.isfinite(tab_j))
         jax.lax.fori_loop(
             0, batch, lambda b, _: tile_body(b, j, tab_j, tab_finite), 0
         )
+        return 0
 
     def tile_body(b, j, tab_j, tab_finite):
         code = code_ref[b, j].astype(jnp.int32)
@@ -488,14 +477,13 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
         win = fields[:, 0:1] >> WIN_SHIFT                     # (A, 1)
         a = code.shape[0]
 
-        # Per-sublane tables: WINS masked selects (exact; a non-finite
-        # vector entry stays localized to sublanes whose window actually
-        # holds it — a bare one-hot matmul would leak it everywhere via
-        # 0*inf=NaN).  With PHOTON_PALLAS_MXU_GATHER the common all-
-        # finite case rides ONE (A,WINS)x(WINS,128) one-hot matmul on
-        # the MXU instead of the 16-pass select sweep; a per-chunk-tile
-        # finiteness reduce guards the exact select path for vectors
-        # carrying inf/nan, so the localization contract is unchanged.
+        # Per-sublane tables.  The common all-finite case rides ONE
+        # (A,WINS)x(WINS,128) one-hot matmul on the MXU; a vector
+        # carrying inf/nan takes WINS masked selects instead (exact: a
+        # non-finite entry stays localized to sublanes whose window
+        # actually holds it, where a bare one-hot matmul would leak it
+        # everywhere via 0*inf=NaN).  A per-chunk-tile finiteness reduce
+        # chooses between the two.
         def select_tables(_):
             def w_body(wi, acc):
                 row = tab_ref[j, pl.ds(wi, 1), :]             # (1, 128)
@@ -507,30 +495,23 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
                 0, WINS, w_body, jnp.zeros((a, WIN), jnp.float32)
             )                                                 # (A, 128)
 
-        if _MXU_GATHER:
-            def mxu_tables(_):
-                onehot = (
-                    win == jax.lax.broadcasted_iota(
-                        jnp.int32, (a, WINS), 1
-                    )
-                ).astype(jnp.float32)
-                # HIGHEST: default matmul precision feeds the MXU bf16
-                # inputs, and bf16(table) != f32 table — the one-hot
-                # product must return window entries exactly (the value
-                # path is f32 end-to-end; sole exception: -0.0 gathers
-                # as +0.0, numerically inert in the product-sum).
-                return jax.lax.dot_general(
-                    onehot, tab_j,
-                    (((1,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32,
-                )
-
-            tables = jax.lax.cond(
-                tab_finite, mxu_tables, select_tables, 0
+        def mxu_tables(_):
+            onehot = (
+                win == jax.lax.broadcasted_iota(jnp.int32, (a, WINS), 1)
+            ).astype(jnp.float32)
+            # HIGHEST: default matmul precision feeds the MXU bf16
+            # inputs, and bf16(table) != f32 table — the one-hot
+            # product must return window entries exactly (the value
+            # path is f32 end-to-end; sole exception: -0.0 gathers
+            # as +0.0, numerically inert in the product-sum).
+            return jax.lax.dot_general(
+                onehot, tab_j,
+                (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
             )
-        else:
-            tables = select_tables(0)
+
+        tables = jax.lax.cond(tab_finite, mxu_tables, select_tables, 0)
         g = jnp.take_along_axis(tables, lo, axis=1)           # (A, 128)
         if unit:
             # Unit values: v = v² = 1 for every real slot; empty slots
@@ -564,13 +545,11 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
 
 
 def _pick_rect(nbo: int, nbg: int, a: int,
-               budget: int = None, unit: bool = False) -> tuple[int, int]:
-    """(batch, chunk) tiles per grid step fitting ~``budget`` input bytes."""
-    if budget is None:
-        budget = DMA_BUDGET
+               unit: bool = False) -> tuple[int, int]:
+    """(batch, chunk) tiles per grid step fitting ~DMA_BUDGET input bytes."""
     # packed code (+ f32 val unless the unit-value layout dropped it)
     per_tile = a * WIN * (CODE_BYTES + (0 if unit else 4))
-    cap = max(1, budget // per_tile)
+    cap = max(1, DMA_BUDGET // per_tile)
 
     def largest_divisor_leq(n, m):
         d = min(n, m)

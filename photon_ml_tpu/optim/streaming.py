@@ -259,7 +259,7 @@ class StreamingObjective:
     ``transfer_stats`` accumulates per-chunk h2d timing, achieved GB/s,
     per-stage wall attribution (pack/dispatch/h2d/consume) and
     queue-stall counters across passes — reset it around a measurement
-    window (bench_streaming does).
+    window.
 
     ``compress`` (off|lossless|fp16|int8) turns on the compressed chunk
     wire formats (data/staging.py): chunks cross the link as encoded
@@ -536,8 +536,8 @@ class StreamingObjective:
             return obj.margins(w, chunk)
 
         def acc_update(carry, v, g):
-            # Shared f32/kahan accumulator fold; elementwise, so the SAME
-            # formulas serve the plain and the batched ((K,)/(K,d)) carry.
+            # The f32/kahan accumulator fold of ONE candidate: the batched
+            # step calls it once per row of its ((K,)/(K,d)) carry.
             if accumulate == "f32":
                 vacc, gacc = carry
                 return (vacc + v, gacc + g)
@@ -590,20 +590,27 @@ class StreamingObjective:
                 if kind == "acc":
                     w = fl[nc]
                     if batch is None:
-                        v, g = chunk_vg(w, off, chunk)
-                    else:
-                        # UNROLLED over the K candidates, not vmapped:
-                        # each candidate's arithmetic is the exact graph
-                        # the single-w program runs, so a batched trial
-                        # matches a sequential trial bitwise (vmap would
-                        # re-block the matvecs by batch shape — the same
-                        # parity hazard serving/kernels.py documents).
-                        outs = [
-                            chunk_vg(w[i], off, chunk) for i in range(batch)
-                        ]
-                        v = jnp.stack([o[0] for o in outs])
-                        g = jnp.stack([o[1] for o in outs])
-                    return acc_update(carry, v, g)
+                        return acc_update(
+                            carry, *chunk_vg(w, off, chunk)
+                        )
+                    # UNROLLED over the K candidates, not vmapped, and
+                    # each candidate folded into ITS OWN accumulator row
+                    # before the rows are stacked: every candidate then
+                    # runs the exact graph of the single-w program, fold
+                    # included, so a batched trial matches a sequential
+                    # trial bitwise.  (vmap would re-block the matvecs by
+                    # batch shape; stacking first and folding the (K, d)
+                    # block once lets the compiler sum the chunk's
+                    # gradient straight into the single-w accumulator
+                    # but not into the stacked one — another order.)
+                    outs = [
+                        acc_update(
+                            tuple(c[i] for c in carry),
+                            *chunk_vg(w[i], off, chunk),
+                        )
+                        for i in range(batch)
+                    ]
+                    return tuple(jnp.stack(c) for c in zip(*outs))
                 if kind == "hvp":
                     w, vec = fl[nc], fl[nc + 1]
                     return hvp_update(carry, chunk_hvp(w, vec, off, chunk))
@@ -1059,7 +1066,7 @@ class StreamingObjective:
             # exactly like a psum across shards.  Publishing it here puts
             # the jit-kind solvers on the same instrument the distributed
             # solvers (solvers/admm.py, solvers/block_cd.py) report on, so
-            # BENCH_ONLY=solvers A/Bs reduces-per-solve directly.
+            # reduces per solve compare across solver kinds.
             tel.counter("solver_allreduce_count").inc(1)
             tel.counter("solver_allreduce_bytes_total").inc(
                 (batch or 1) * (self.stream.n_features + 1) * 4

@@ -27,8 +27,7 @@ TPU-native analogue of that request path over the batch stack:
   model hot-swap with verified one-step rollback.
 - :mod:`~photon_ml_tpu.serving.loadgen` — closed/open-loop load
   generators plus scripted scenarios (diurnal ramp, skew shift,
-  swap-under-load, replica-kill, worker-kill, noisy-neighbor;
-  ``bench.py bench_serving``).
+  swap-under-load, replica-kill, worker-kill, noisy-neighbor).
 - :mod:`~photon_ml_tpu.serving.tenancy` — multi-tenant isolation:
   ``TenantSpec`` / ``TenancyConfig`` (per-tenant bulkhead partitions,
   token-bucket quotas, tiered-admission watermarks, p99 SLOs, circuit

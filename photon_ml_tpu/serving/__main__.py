@@ -175,7 +175,7 @@ def build_arg_parser() -> argparse.ArgumentParser:
         "--fleet-join", metavar="SERVING_URL", default=None,
         help="one-shot admin verb: register SERVING_URL with the "
         "--membership registry and exit; the MembershipWatcher joins "
-        "it into the live rotation (ops/README.md runbook)",
+        "it into the live rotation (docs/serving.md runbook)",
     )
     p.add_argument(
         "--fleet-drain", metavar="HOST_ID", default=None,

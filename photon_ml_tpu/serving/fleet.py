@@ -43,15 +43,15 @@ door, built failure-first.
   ``kill()`` (listener torn down abruptly — new connections refuse,
   exactly what a crashed host looks like from the router) and
   ``restart()`` (rebind the same port).  The substrate for the
-  ``host_kill`` / ``quota_partition`` scenarios, the fleet selfcheck,
-  and bench gates; a production host runs the same service standalone.
+  ``host_kill`` / ``quota_partition`` scenarios and the fleet
+  selfcheck; a production host runs the same service standalone.
 
 Chaos seams: ``serving.host`` fires at routing time (a fault is a host
 dying as it picks up the request — mark down + resubmit, zero failed
 requests); ``quota.lease`` fires in the lease renewal (a fault is the
 coordinator partition — degrade to the last lease).  Metric family:
 ``serving_fleet_*`` (docs/telemetry.md).  See docs/serving.md "Fleet"
-and ops/README.md for the host-down / coordinator-unreachable runbooks.
+and its "Runbook" for the host-down / coordinator-unreachable entries.
 """
 
 from __future__ import annotations

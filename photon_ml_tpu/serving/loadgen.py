@@ -15,8 +15,8 @@ Two disciplines, both driving ``ScoringService.submit``:
 On top of the two disciplines, **scripted scenarios** (:func:`run_scenario`
 over the :data:`SCENARIOS` catalog) chain open-loop phases with varying
 rate, entity skew, and mid-phase ACTIONS (hot-swap, replica kill) — the
-repeatable "a bad day in serving" scripts that ``bench_serving`` and the
-HA selfcheck replay:
+repeatable "a bad day in serving" scripts that the HA selfcheck
+replays:
 
 - ``diurnal``      — rate ramps up 4x and back down (the daily curve);
   admission tiers should engage at the peak and release after.
@@ -41,8 +41,8 @@ HA selfcheck replay:
 Per-phase and whole-run p50/p99 come from the same shared
 ``telemetry.Histogram.quantile`` the live exposition uses.
 
-Used by ``python -m photon_ml_tpu.serving --loadgen ...`` and by
-``bench.py``'s ``bench_serving`` section.
+Used by ``python -m photon_ml_tpu.serving --loadgen ...`` and the
+selfchecks.
 """
 
 from __future__ import annotations
@@ -322,7 +322,7 @@ class ScenarioReport:
         }
 
 
-#: The scenario catalog ``bench_serving`` iterates.  Durations are short
+#: The scenario catalog.  Durations are short
 #: (seconds) — these are repeatable scripts, not endurance runs; scale
 #: offered load through ``base_rate_rps``.
 SCENARIOS = {
@@ -883,8 +883,7 @@ class HttpSubmitter:
 
     ``wire_format="json"`` sends the JSON compatibility body;
     ``"binary"`` sends a serving/wire.py request frame and decodes the
-    frame response — the A/B lever ``bench.py``'s
-    ``_bench_serving_wire`` pulls.  Per-row errors come back as the
+    frame response.  Per-row errors come back as the
     same exceptions the in-process ``ScoringService.submit`` path
     raises (RejectedError / DeadlineExceededError), so the load
     generators count rejections identically either way.

@@ -451,7 +451,7 @@ class ReplicaSupervisor:
     def kill_replica(
         self, rid: int, reason: str = "scripted kill"
     ) -> _Replica:
-        """Scripted crash of replica ``rid`` (bench scenarios, the
+        """Scripted crash of replica ``rid`` (loadgen scenarios, the
         selfcheck, tests): queued and in-flight requests on it fail
         transiently — and therefore resubmit to peers — and the replica
         takes the normal drain → backoff → restart path."""
@@ -529,7 +529,7 @@ class ReplicaSupervisor:
         rep.probe_failures = 0
         # Sustained health resets the backoff walk (a replica that
         # answers probes again is trusted again; see the flapping
-        # runbook in ops/README.md for threshold tuning).
+        # runbook in docs/serving.md for threshold tuning).
         rep.restart_attempt = 0
         rep.last_delay = None
 

@@ -1,7 +1,7 @@
-"""Synthetic GAME serving workload (selfcheck, tests, bench_serving).
+"""Synthetic GAME serving workload (selfcheck, tests).
 
 Builds an in-memory GAME model with one fixed effect and one per-entity
-random effect — the MovieLens shape the training benches use — plus a
+random effect — the MovieLens shape — plus a
 request generator with a zipf-tailed entity stream, so the LRU hot set
 sees realistic skew: a few heavy entities dominate (hot hits) over a long
 cold tail (fallback gathers + promotions).

@@ -31,8 +31,8 @@ Two sharding modes, same math: a real mesh (``shard_map`` + ``lax.psum``
 over ``parallel.distributed.DATA_AXIS`` — multihost-ready, nothing here is
 host-count-aware) when ≥2 devices participate, or LOGICAL shards (leading
 shard axis + ``vmap`` x-updates + an axis-0 sum standing in for the psum)
-on one device, so communication-per-iteration is measurable anywhere
-(bench.py ``BENCH_ONLY=solvers``).
+on one device, so reduces per solve (``solver_allreduce_count``) can be
+counted anywhere.
 
 Chaos sites: ``distributed.allreduce`` fires before each step dispatch (the
 reduce seam), ``admm.consensus`` after the consensus z-update commits (the

@@ -15,8 +15,8 @@ Sharding comes in two flavors, chosen by the caller:
   outer iteration (multihost-ready);
 - LOGICAL shards on one device (``mesh=None``) — the same leading-shard-axis
   layout (``shard_glm_data(..., mesh=None, n_shards=k)``), with ``vmap``'d
-  per-shard subproblems and an axis-0 sum standing in for the psum, so the
-  communication-per-iteration A/B (bench.py ``BENCH_ONLY=solvers``) runs
+  per-shard subproblems and an axis-0 sum standing in for the psum, so
+  reduces per solve (``solver_allreduce_count``) can be counted
   anywhere, and single-device callers (tuning ``fit_once``, the GAME
   fixed-effect coordinate) still get ≥2 shards.
 """

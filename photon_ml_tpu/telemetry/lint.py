@@ -22,7 +22,7 @@ from photon_ml_tpu.analysis.rules_registry import (  # noqa: F401
 
 def scan_source(roots=None) -> list[tuple[str, str, str, int]]:
     """Old entry point: ``(name, kind, relpath, lineno)`` hits over the
-    default roots (package + bench.py) or explicit ``roots``."""
+    default root (the package) or explicit ``roots``."""
     return scan_tree(SourceTree(roots=roots))
 
 

@@ -217,7 +217,7 @@ class RandomProposer(Proposer):
 
 
 class GridProposer(Proposer):
-    """A fixed, ordered list of points (λ-path sweeps, bench parity runs).
+    """A fixed, ordered list of points (λ-path sweeps, parity runs).
     RNG-free: sequential and parallel orchestration propose the identical
     trial set."""
 

@@ -110,8 +110,9 @@ if [[ "${1:-}" == "--fast" ]]; then
   # coordinator leader failover + journal replay, and checksum-verified
   # publication fetch (all three cluster.* chaos seams).  The
   # hierarchical-GAME smoke runs one sharded-vs-single parity leg on
-  # the forced multi-device mesh (resident + out-of-core, BITWISE) —
-  # the invariant the mesh bucket-shard plan rests on.
+  # the forced multi-device mesh (resident + out-of-core: packed blocks
+  # BITWISE, split blocks within 4 ulp) — the invariant the mesh
+  # bucket-shard plan rests on.
   exec env JAX_PLATFORMS=cpu python -m pytest \
     tests/test_telemetry.py tests/test_ops_plane.py \
     tests/test_watchdog.py \

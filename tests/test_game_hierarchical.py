@@ -2,12 +2,19 @@
 
 Three claims, each pinned here:
 
-- **Sharded is bitwise single-device.**  The bucket-shard plan
+- **Sharded is single-device: bit for bit where the program is the
+  same, within 4 ulp where it is not.**  The bucket-shard plan
   (game/hierarchical.py) moves WHERE each block's program runs, never
   the shapes or the math, and the score scatter re-runs on one device
-  in global block order — so the mesh-sharded coordinate (resident AND
-  out-of-core) must reproduce the single-device coordinate bit for bit
-  across per_user / per_item / per_context shapes.
+  in global block order — so every PACKED block (one whole block on one
+  device: the same program) must reproduce the single-device
+  coordinate bit for bit, resident AND out-of-core, across per_user /
+  per_item / per_context shapes.  A SPLIT block is another program: the
+  mesh runs its vmapped solve 2 lanes a device where one device runs
+  all 13, and XLA:CPU sums a lane's rows in another order at another
+  batch width.  ``PARITY_ULPS`` states, case by case, where that shows
+  (at most 1 ulp of the block's largest coefficient when the bound was
+  set) and holds those blocks, and the scores built from them, to 4 ulp.
 - **Pipelined is bitwise serial.**  The overlap schedule
   (game/descent.py ``pipeline=True``) prestages only offset-independent
   host work; the Gauss-Seidel trajectory is untouched.
@@ -99,14 +106,46 @@ COORD_GRID = [
 ]
 
 
-def _assert_states_match(st_ref, st_sharded, ref_blocks):
-    """Sharded split blocks carry entity-padding lanes (appended); the
-    real lanes must be bitwise the single-device state."""
-    assert len(st_ref) == len(st_sharded)
-    for a, b, blk in zip(st_ref, st_sharded, ref_blocks):
-        a, b = np.asarray(a), np.asarray(b)
-        assert b.shape[0] >= blk.n_entities
-        assert _bitwise(a, b[: blk.n_entities])
+#: Per case, the ulps allowed on (a SPLIT block's state, the resident
+#: scores, the out-of-core scores); 0 is bit for bit, which is what
+#: every packed block is held to in every case.  A split block's solve
+#: is the same vmapped program at another batch width (2 lanes a device
+#: against the whole block on one), and XLA:CPU orders a lane's f32
+#: sums by that width: measured, the states of one split block differ
+#: by at most 1.2e-7 absolute on coefficients up to 1.3 (0.8 ulp of the
+#: largest) and the scores by at most 4.8e-7 on scores up to 6.1 (0.7
+#: ulp).  A wrong block, a lost lane or stale offsets move these by
+#: 1e-2 and more.  per_item's split blocks happen to agree to the bit
+#: and stay pinned there.
+PARITY_ULPS = {
+    "per_user": (4, 4, 4),
+    "per_item": (0, 0, 0),
+    "per_context": (4, 0, 4),
+}
+
+
+def _assert_parity(a, b, ulps):
+    """``ulps`` units in the last place of ``a``'s largest entry, f32
+    (iterates of a solve are accurate to the block's scale, not to each
+    entry's own); 0 asks for the same bits."""
+    a, b = np.asarray(a), np.asarray(b)
+    if ulps == 0:
+        assert _bitwise(a, b)
+        return
+    assert a.dtype == b.dtype == np.float32
+    bound = ulps * np.finfo(np.float32).eps * np.abs(a).max()
+    np.testing.assert_allclose(b, a, rtol=0, atol=bound)
+
+
+def _assert_states_match(st_ref, st_sharded, n_entities, placements, ulps):
+    """Packed blocks must be bitwise the single-device state; a split
+    block (entity-padding lanes appended by the resident coordinate:
+    only the real lanes compare) within ``ulps``."""
+    assert len(st_ref) == len(st_sharded) == len(placements)
+    for a, b, n, p in zip(st_ref, st_sharded, n_entities, placements):
+        b = np.asarray(b)
+        assert b.shape[0] >= n
+        _assert_parity(a, b[:n], ulps if p[0] == "split" else 0)
 
 
 # ---------------------------------------------------------------------------
@@ -149,7 +188,8 @@ class TestBucketShardPlan:
 
 
 # ---------------------------------------------------------------------------
-# Sharded-vs-single bitwise parity: resident and out-of-core
+# Sharded-vs-single parity (packed blocks bitwise, split blocks 4 ulp):
+# resident and out-of-core
 # ---------------------------------------------------------------------------
 
 class TestShardedParity:
@@ -169,15 +209,24 @@ class TestShardedParity:
         offsets = jnp.asarray(
             np.random.default_rng(0).normal(size=len(y)).astype(np.float32)
         )
+        state_ulps, score_ulps, _ = PARITY_ULPS[name]
+        n_entities = [b.n_entities for b in ref.dataset.blocks]
+        placements = sharded.plan.placements
         st_ref = ref.train(offsets)
         st_sh = sharded.train(offsets)
-        _assert_states_match(st_ref, st_sh, ref.dataset.blocks)
-        assert _bitwise(ref.score(st_ref), sharded.score(st_sh))
+        _assert_states_match(
+            st_ref, st_sh, n_entities, placements, state_ulps
+        )
+        _assert_parity(ref.score(st_ref), sharded.score(st_sh), score_ulps)
         # warm-started second round: same contract
         st_ref2 = ref.train(offsets, warm_state=st_ref)
         st_sh2 = sharded.train(offsets, warm_state=st_sh)
-        _assert_states_match(st_ref2, st_sh2, ref.dataset.blocks)
-        assert _bitwise(ref.score(st_ref2), sharded.score(st_sh2))
+        _assert_states_match(
+            st_ref2, st_sh2, n_entities, placements, state_ulps
+        )
+        _assert_parity(
+            ref.score(st_ref2), sharded.score(st_sh2), score_ulps
+        )
 
     @pytest.mark.parametrize("name,shape", COORD_GRID)
     def test_out_of_core_bitwise(self, name, shape, eight_devices):
@@ -197,17 +246,19 @@ class TestShardedParity:
         offsets = jnp.asarray(
             np.random.default_rng(1).normal(size=len(y)).astype(np.float32)
         )
+        state_ulps, _, score_ulps = PARITY_ULPS[name]
+        n_entities = [b.n_entities for b in ds.blocks]
+        placements = sharded.bucket_plan.placements
         st_s = single.train(offsets)
         st_m = sharded.train(offsets)
-        assert len(st_s) == len(st_m)
-        for a, b in zip(st_s, st_m):
-            assert _bitwise(a, b)
-        assert _bitwise(single.score(st_s), sharded.score(st_m))
+        _assert_states_match(st_s, st_m, n_entities, placements, state_ulps)
+        _assert_parity(single.score(st_s), sharded.score(st_m), score_ulps)
         # warm round
         st_s2 = single.train(offsets, warm_state=st_s)
         st_m2 = sharded.train(offsets, warm_state=st_m)
-        for a, b in zip(st_s2, st_m2):
-            assert _bitwise(a, b)
+        _assert_states_match(
+            st_s2, st_m2, n_entities, placements, state_ulps
+        )
 
     def test_sharded_coordinate_finalize_exact_entities(self, eight_devices):
         keys, X, y, w = _zipf_data(seed=3, n_entities=120)

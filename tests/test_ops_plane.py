@@ -206,7 +206,7 @@ def _get_404(port):
 
 
 # ---------------------------------------------------------------------------
-# Histogram quantiles (satellite: the one estimator behind loadgen/bench)
+# Histogram quantiles (satellite: the one estimator behind loadgen)
 # ---------------------------------------------------------------------------
 
 class TestHistogramQuantile:

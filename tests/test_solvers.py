@@ -605,7 +605,7 @@ class TestStreamedReduceCounter:
     def test_streamed_passes_counted(self, rng):
         """Every streamed objective pass is one logical all-reduce; the
         counter puts OWL-QN/L-BFGS on the same instrument as the
-        distributed solvers (bench.py BENCH_ONLY=solvers)."""
+        distributed solvers."""
         from photon_ml_tpu.data.streaming import make_streaming_glm_data
         from photon_ml_tpu.optim.streaming import streaming_run_grid
 

@@ -11,7 +11,7 @@ Three contracts pinned here (ISSUE 1 acceptance):
    chunks cross as staging buffers with an in-program unpack.
 3. **Pipeline bounds & observability** — prefetch-depth edge cases
    (1, > n_chunks), the ≤depth liveness bound, error propagation, and
-   the transfer-stat counters bench_streaming reports.
+   the transfer-stat counters.
 """
 
 import os

@@ -1286,9 +1286,12 @@ class TestPipelineParity:
     on f32 to the ``prefetch_depth=1`` serial baseline for value/grad,
     HVP and scores (float-close on kahan — same order, but donation-free
     vs donated buffers may round identically anyway); chunk fusion must
-    preserve the accumulation order including the ragged tail; batched
-    line-search trials must evaluate the exact single-trial graph; a
-    failed pass must leave the objective reusable (no use-after-donate);
+    reproduce the unfused pass to 1e-6, the ragged tail included
+    (asserted close, not bitwise: the scan body is another program);
+    batched line-search trials must match the one-trial pass BITWISE in
+    value and gradient (each candidate folds into its own accumulator
+    row: the single-w program per candidate), hence the same iteration
+    count and solution as the unbatched solver; a failed pass must leave the objective reusable (no use-after-donate);
     and the stall counters must stay monotone across passes."""
 
     @staticmethod
@@ -1487,7 +1490,7 @@ class TestPipelineParity:
         np.testing.assert_array_equal(np.asarray(g2), np.asarray(ref_g))
 
     def test_stall_counters_monotone(self, rng):
-        """Counters only ever accumulate across passes (bench resets
+        """Counters only ever accumulate across passes (callers reset
         around measurement windows; a decrement would corrupt deltas)."""
         _, _, stream = self._stream4(rng)
         sobj = StreamingObjective("logistic", stream)

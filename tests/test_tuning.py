@@ -415,7 +415,7 @@ class TestExecutor:
 
 
 class TestGlmSweepParity:
-    """The bench acceptance bar: parallel-4 vs sequential best-metric
+    """The acceptance bar: parallel-4 vs sequential best-metric
     parity (±1e-6) on a real GLM λ sweep with warm starts ON."""
 
     def test_parity(self, tmp_path, rng):
