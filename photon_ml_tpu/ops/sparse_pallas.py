@@ -34,23 +34,33 @@ The kernel design exploits exactly that:
 
 - matvec per tile: per-sublane gather tables are built from each sublane's
   packed window id — ONE one-hot matmul on the MXU
-  (f32-HIGHEST, guarded per chunk tile: any non-finite vector window
-  falls back to the exact 16-step masked-SELECT sweep so inf/nan stay
-  localized) — then ONE ``dynamic_gather`` of the
-  whole ``(A, 128)`` block, then a 16-step masked sweep accumulates rows
-  into the ``(16, 128)`` margin block (``ohi = (row % 2048) // 128``,
-  packed per slot, selects the output sublane).  No scatter anywhere.
+  (f32-HIGHEST, guarded per grid step: a step whose vector windows carry
+  inf/nan falls back to an exact 16-step masked-SELECT sweep so inf/nan
+  stay localized) — then ONE ``dynamic_gather`` of the
+  whole ``(A, 128)`` block, then a 16-step masked sweep adds each output
+  window's slots, sublane group onto sublane group, into a VMEM
+  accumulator of 8 partial sums per output element
+  (``ohi = (row % 2048) // 128``, packed per slot, selects the window);
+  the 8 -> 1 reduce into the ``(16, 128)`` margin block runs once per
+  output block.  No scatter anywhere, and nothing serial per tile but the
+  tile's own chain, which the loop overlaps with its neighbours'
+  (``_tiles_per_block``).
 
 - rmatvec (the gradient side, Xᵀu) is the SAME kernel with roles mirrored
   (orientation "B": lane = col % 128, tables = 128-wide windows of ``u``,
   sweep over column-his).  Both directions therefore run at the same rate —
   the property Spark's treeAggregate had for free and TPUs do not.
 
-Measured on one TPU v5e chip (ledger, PR 28): in ``glm_lbfgs_fit``
-(804,414 x 47,237, 76 nnz/row) the kernel is 84.378% of the device's busy
-time at 4.2956% of its bytes-bound roofline, a 10-iteration solve
-0.36542 s; in ``game_cd_fit``'s fixed effect 45.833% at 0.16136%.  About
-half of a product is a fixed cost per tile (PERF.md §5).
+Measured on one TPU v5e chip (my chip runs, PR 30, traced; PERF.md §5,
+§6): in ``glm_lbfgs_fit`` (804,414 x 47,237, 76 nnz/row) a forward product
+over 9,432 tiles 128 sublanes deep takes 2.30 ms and a backward one (160
+deep) 2.91 ms (10.3 and 10.8 before PR 30), 17.4% of the bytes-bound
+roofline, a 10-iteration solve 0.136 s; in ``game_cd_fit``'s fixed effect
+(136.7 k unit tiles 32 / 48 deep) 7.2 and 9.9 ms in the solver's loop (63
+and 82), 1.37%.  A product is affine in the depth with almost no constant
+left (0.14 ms + 0.0176 ms a sublane forward on the text grid); what it
+costs now is the 16-step output sweep over mostly empty slots (PERF.md
+§5).
 
 Precision: everything is f32 — bit-comparable to the COO path (only
 summation ORDER differs).  Table construction is pure selection (no
@@ -116,13 +126,17 @@ EMPTY_MARK = np.iinfo(CODE_DTYPE).min
 # Sublane-count granularity: the int16 slot arrays tile as (16, 128) on TPU,
 # so A is padded to a multiple of 16 (8 would re-pad internally).
 SUBPAD = 16
-# Per-grid-step DMA budget for the tile kernel (bytes).  A grid step's
-# input blocks are double-buffered in VMEM beside the tables and the
-# output block, so the budget stays well under the compiler's scoped-VMEM
-# limit (16 MiB on a v5e), and it is in MBs so that the stream is not
-# bound by per-step overhead.  Both benchmark cells and
-# tests/test_kernel_names_v5e.py compile with this one value.
+# Per-grid-step DMA budget for the tile kernel (bytes): in MBs, so that the
+# stream is not bound by per-step overhead.  A grid step's input blocks are
+# double-buffered in VMEM beside the tables, the output block and the
+# accumulator; all of it stays within VMEM_BUDGET (``_pick_rect``), under
+# the compiler's scoped-VMEM limit (16 MiB on a v5e).  Both benchmark
+# cells and tests/test_kernel_names_v5e.py compile with these two values.
 DMA_BUDGET = 4 << 20
+VMEM_BUDGET = 12 << 20
+# Partial sums the kernel keeps per output element until an output block's
+# last grid step: one vreg's sublanes.
+ACC_SUB = 8
 
 
 #: Entries the stripe split hands one thread at a time.
@@ -433,40 +447,44 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
           matrices (every tiled value 1.0) stream codes only, 3x less
           DMA on a bandwidth-bound kernel; validity is ``code >= 0``
     tab:  (chunk, WINS, 128) gather-side vector windows for this chunk
-    out:  (batch, WINS, 128), accumulated across the chunked grid dim
+    out:  (batch, WINS, 128), written at the last step of the chunked
+          grid dim
+    acc:  (batch, WINS*8, 128) VMEM scratch that lives across the chunked
+          grid dim: 8 partial sums per output element
+
+    A tile's work is one dependency chain (codes -> tables -> gather ->
+    output sweep), and nothing in it is serial beyond that chain: the
+    branch on the vector's finiteness is taken once per grid step, around
+    the whole tile loop; the output sweep only ADDS vregs (each output
+    window's slots summed over their sublane groups, not across
+    sublanes) into ``acc``; and ``_tiles_per_block`` tile bodies share a
+    basic block, so that the scheduler overlaps their chains.  The 8 -> 1
+    cross-sublane reduce runs once per output block.
 
     Gather tables are built per tile from each sublane's packed window
-    id — a one-hot f32 matmul on the MXU, guarded per chunk
-    tile: a bare matmul would leak a non-finite vector entry into every
-    sublane's table via 0·inf = NaN, so tiles whose table windows carry
-    inf/nan take the exact masked-SELECT sweep instead (see the in-body
-    comment and test_nonfinite_vector_entries_stay_localized).
+    id — a one-hot f32 matmul on the MXU.  A bare matmul would leak a
+    non-finite vector entry into every sublane's table via 0·inf = NaN,
+    so a grid step whose vector windows carry inf/nan builds its tables
+    by masked selects instead (exact; see the in-body comment and
+    test_nonfinite_vector_entries_stay_localized).
     """
     from jax.experimental import pallas as pl
 
     if unit:
-        code_ref, tab_ref, out_ref = refs
+        code_ref, tab_ref, out_ref, acc_ref = refs
         val_ref = None
     else:
-        code_ref, val_ref, tab_ref, out_ref = refs
+        code_ref, val_ref, tab_ref, out_ref, acc_ref = refs
+    a = code_ref.shape[2]
 
     @pl.when(pl.program_id(1) == 0)
     def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def chunk_tile_body(j, _):
-        # Hoisted per CHUNK tile: the gather windows and their finiteness
-        # predicate are invariant across the batch dimension — slicing and
-        # reducing them once per (j) instead of per (b, j) saves
-        # batch-1 redundant (WINS, 128) passes.
+    def tile_body(t, mxu):
+        # j-major: per output block b the tiles add up in the order of j.
+        j, b = t // batch, t % batch
         tab_j = tab_ref[pl.ds(j, 1), :, :][0]                 # (WINS, 128)
-        tab_finite = jnp.all(jnp.isfinite(tab_j))
-        jax.lax.fori_loop(
-            0, batch, lambda b, _: tile_body(b, j, tab_j, tab_finite), 0
-        )
-        return 0
-
-    def tile_body(b, j, tab_j, tab_finite):
         code = code_ref[b, j].astype(jnp.int32)
         # Field bits through CODE_MASK: empty slots are sign-marked, and
         # int16→int32 sign extension would otherwise corrupt the window
@@ -475,27 +493,14 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
         lo = fields & (WIN - 1)
         ohi = (fields >> 7) & ((1 << OBITS) - 1)
         win = fields[:, 0:1] >> WIN_SHIFT                     # (A, 1)
-        a = code.shape[0]
 
         # Per-sublane tables.  The common all-finite case rides ONE
         # (A,WINS)x(WINS,128) one-hot matmul on the MXU; a vector
         # carrying inf/nan takes WINS masked selects instead (exact: a
         # non-finite entry stays localized to sublanes whose window
         # actually holds it, where a bare one-hot matmul would leak it
-        # everywhere via 0*inf=NaN).  A per-chunk-tile finiteness reduce
-        # chooses between the two.
-        def select_tables(_):
-            def w_body(wi, acc):
-                row = tab_ref[j, pl.ds(wi, 1), :]             # (1, 128)
-                return jnp.where(
-                    win == wi, jnp.broadcast_to(row, (a, WIN)), acc
-                )
-
-            return jax.lax.fori_loop(
-                0, WINS, w_body, jnp.zeros((a, WIN), jnp.float32)
-            )                                                 # (A, 128)
-
-        def mxu_tables(_):
+        # everywhere via 0*inf=NaN).
+        if mxu:
             onehot = (
                 win == jax.lax.broadcasted_iota(jnp.int32, (a, WINS), 1)
             ).astype(jnp.float32)
@@ -504,14 +509,16 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
             # product must return window entries exactly (the value
             # path is f32 end-to-end; sole exception: -0.0 gathers
             # as +0.0, numerically inert in the product-sum).
-            return jax.lax.dot_general(
+            tables = jax.lax.dot_general(
                 onehot, tab_j,
                 (((1,), (0,)), ((), ())),
                 precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32,
             )
-
-        tables = jax.lax.cond(tab_finite, mxu_tables, select_tables, 0)
+        else:
+            tables = jnp.zeros((a, WIN), jnp.float32)
+            for wi in range(WINS):
+                tables = jnp.where(win == wi, tab_j[wi:wi + 1, :], tables)
         g = jnp.take_along_axis(tables, lo, axis=1)           # (A, 128)
         if unit:
             # Unit values: v = v² = 1 for every real slot; empty slots
@@ -531,34 +538,85 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
             # of unrelated rows.
             contrib = jnp.where(v != 0.0, contrib, 0.0)
 
-        def h_body(h, _):
-            part = jnp.sum(jnp.where(ohi == h, contrib, 0.0), axis=0)
-            out_ref[b, pl.ds(h, 1), :] += part.reshape(1, WIN)
+        # Output sweep: window h's slots, summed over the tile's A/8
+        # sublane groups only (vreg adds; A is a multiple of SUBPAD).
+        acc_ref[b] += jnp.concatenate([
+            jnp.sum(jnp.where(ohi == h, contrib, 0.0)
+                    .reshape(a // ACC_SUB, ACC_SUB, WIN), axis=0)
+            for h in range(WINS)
+        ], axis=0)
+
+    def tile_loop(mxu, per_block):
+        """Every tile of the step, ``per_block`` bodies a basic block."""
+        n = batch * chunk
+
+        def block(first, count):
+            # unrolled by the lowering, so that the body is traced once
+            jax.lax.fori_loop(
+                0, count, lambda k, _: tile_body(first + k, mxu), None,
+                unroll=True)
+
+        if n >= per_block:
+            jax.lax.fori_loop(
+                0, n // per_block,
+                lambda i, _: block(i * per_block, per_block), None)
+        if n % per_block:
+            block(n - n % per_block, n % per_block)
+
+    # One finiteness reduce per grid step chooses the table build for all
+    # its tiles (a step with inf/nan anywhere in its windows is exact and
+    # slower, tile after tile).
+    finite = jnp.all(jnp.isfinite(tab_ref[...]))
+    pl.when(finite)(lambda: tile_loop(True, _tiles_per_block(a)))
+    pl.when(jnp.logical_not(finite))(lambda: tile_loop(False, 1))
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _():
+        # One output block at a time: the whole accumulator in one
+        # expression is a stack value of its size (20 MiB at batch 139).
+        def reduce_block(b, _):
+            for h in range(WINS):
+                out_ref[b, h:h + 1, :] = jnp.sum(
+                    acc_ref[b, h * ACC_SUB:(h + 1) * ACC_SUB, :],
+                    axis=0, keepdims=True)
             return 0
 
-        jax.lax.fori_loop(0, WINS, h_body, 0)
-        return 0
+        jax.lax.fori_loop(0, batch, reduce_block, 0)
 
-    # j-outer / b-inner: per-(b, h) accumulation order over j is unchanged
-    # vs the old flat (b-major) loop, so outputs stay bit-identical.
-    jax.lax.fori_loop(0, chunk, chunk_tile_body, 0)
+
+def _tiles_per_block(a: int) -> int:
+    """Tile bodies the kernel's loop puts into one basic block, from the
+    depth: about 1,024 sublanes of work in flight, at most 16 bodies.
+    Timed on a TPU v5e at both cells' grids, 1 to 16 a block (my chip
+    run, PR 30; PERF.md §6): a forward product over 136.7 k unit tiles
+    32 deep 43.7 / 24.5 / 15.0 / 10.7 / 8.9 ms at 1 / 2 / 4 / 8 / 16;
+    over 9,432 valued tiles 128 deep 4.35 / 3.18 / 2.65 / 2.37 / 2.32."""
+    return max(1, min(16, 1024 // a))
 
 
 def _pick_rect(nbo: int, nbg: int, a: int,
                unit: bool = False) -> tuple[int, int]:
-    """(batch, chunk) tiles per grid step fitting ~DMA_BUDGET input bytes."""
+    """(batch, chunk) tiles per grid step: ~DMA_BUDGET input bytes, and
+    everything the step holds in VMEM (input blocks, tables and output
+    block double-buffered, the accumulator) within VMEM_BUDGET."""
     # packed code (+ f32 val unless the unit-value layout dropped it)
     per_tile = a * WIN * (CODE_BYTES + (0 if unit else 4))
     cap = max(1, DMA_BUDGET // per_tile)
+    window_block = WINS * WIN * 4           # a tile's tables; its output
+    per_row = ACC_SUB * window_block + 2 * window_block
 
     def largest_divisor_leq(n, m):
-        d = min(n, m)
+        d = max(1, min(n, m))
         while n % d:
             d -= 1
         return d
 
-    chunk = largest_divisor_leq(nbg, cap)
-    batch = largest_divisor_leq(nbo, max(1, cap // chunk))
+    chunk = largest_divisor_leq(nbg, min(
+        cap, (VMEM_BUDGET - per_row) // (2 * per_tile + 2 * window_block)))
+    batch = largest_divisor_leq(nbo, min(
+        cap // chunk,
+        (VMEM_BUDGET - 2 * chunk * window_block)
+        // (2 * chunk * per_tile + per_row)))
     return batch, chunk
 
 
@@ -606,6 +664,8 @@ def _tiled_apply(code, val, vec_padded, *, nbo, nbg, square, side,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((batch, WINS, WIN), lambda i, j: (i, 0, 0),
                                memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((batch, WINS * ACC_SUB, WIN), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),

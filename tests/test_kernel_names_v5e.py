@@ -17,7 +17,11 @@ from jax.sharding import SingleDeviceSharding
 
 from benchmarks import trace as trace_mod
 from photon_ml_tpu.ops.sparse_pallas import (
+    CODE_DTYPE,
+    TILE_C,
+    WIN,
     PallasSparseMatrix,
+    _tiled_apply,
     build_pallas_host,
 )
 
@@ -76,6 +80,49 @@ def test_kernel_instruction_names(monkeypatch, one_chip, layout_shapes,
     assert len(calls) == 1, calls
     stem, _, suffix = calls[0].rpartition(".")
     assert stem == name and suffix.isdigit()
+
+
+# The grids of the two benchmark cells (PERF.md §4), shapes only: row blocks
+# x column blocks, the two depths, valued or unit.  Mosaic's verdict on the
+# kernel at these depths (the accumulator's VMEM beside the step's blocks,
+# the sublane-group reshape, 6 to 16 tile bodies a block) is then known
+# before a chip run.  The third grid is no cell's: the one where
+# ``_pick_rect`` batches the most output blocks into a step (139, with their
+# accumulators), the most VMEM a step ever holds beside its input blocks.
+CELL_GRIDS = {
+    "glm_lbfgs_fit": dict(nbr=393, nbc=24, a_f=128, a_b=160, unit=False),
+    "game_cd_fit": dict(nbr=9766, nbc=14, a_f=32, a_b=48, unit=True),
+    "shallow_one_gather_block": dict(
+        nbr=8 * 139, nbc=1, a_f=16, a_b=16, unit=True),
+}
+
+
+@pytest.mark.parametrize("cell, side, square", [
+    ("glm_lbfgs_fit", "fwd", False), ("glm_lbfgs_fit", "bwd", False),
+    ("glm_lbfgs_fit", "fwd", True), ("glm_lbfgs_fit", "bwd", True),
+    ("game_cd_fit", "fwd", False), ("game_cd_fit", "bwd", False),
+    ("shallow_one_gather_block", "fwd", False),
+])
+def test_kernel_compiles_at_cell_depths(monkeypatch, one_chip, cell, side,
+                                        square):
+    monkeypatch.delenv("PHOTON_PALLAS_INTERPRET", raising=False)
+    g = CELL_GRIDS[cell]
+    nbo, nbg, a = ((g["nbr"], g["nbc"], g["a_f"]) if side == "fwd"
+                   else (g["nbc"], g["nbr"], g["a_b"]))
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    code = struct((nbo, nbg, a, WIN), CODE_DTYPE)
+    val = (struct((1,), jnp.float32) if g["unit"]
+           else struct((nbo, nbg, a, WIN), jnp.float32))
+    vec = struct((nbg * TILE_C,), jnp.float32)
+    with jax.enable_x64(False):
+        text = _tiled_apply.lower(
+            code, val, vec, nbo=nbo, nbg=nbg, square=square, side=side,
+            unit=g["unit"]).compile().as_text()
+    (call,) = _kernel_calls(text)
+    assert call.rpartition(".")[0] == f"_tiled_apply_{side}"
 
 
 # The dense stripes' share of a product at the benchmark cell's width: 300

@@ -176,6 +176,127 @@ class TestPallasKernels:
         assert _rel(hp, hc) < 1e-5
 
 
+# ---------------------------------------------------------------------------
+# The kernel's accumulator across grid steps, and the rectangle's VMEM
+# ---------------------------------------------------------------------------
+
+# 10 x 10 tiles, so sparse that every tile is one sublane group deep: with
+# DMA_BUDGET at four tiles a step the grid is 5 x 5 steps of 2 x 2 tiles in
+# both orientations, and the VMEM accumulator is zeroed, carried across the
+# steps of an output block and reduced five times over.
+MS_N, MS_D, MS_NNZ = 9 * 2048 + 5, 9 * 2048 + 77, 4000
+
+
+@pytest.fixture(scope="module")
+def multi_step():
+    import scipy.sparse as sp
+
+    rng = np.random.default_rng(30)
+    flat = rng.choice(MS_N * MS_D, size=MS_NNZ, replace=False)
+    rows, cols = (flat // MS_D).astype(np.int64), (flat % MS_D).astype(np.int64)
+    built = {}
+
+    def get(unit):
+        if unit not in built:
+            vals = (np.ones(MS_NNZ, np.float32) if unit
+                    else rng.normal(size=MS_NNZ).astype(np.float32))
+            P = build_pallas_matrix(rows, cols, vals, MS_N, MS_D)
+            assert P.unit_vals == unit and not P.spill.has_spill
+            X = sp.csr_matrix((vals.astype(np.float64), (rows, cols)),
+                              shape=(MS_N, MS_D))
+            built[unit] = (P, X)
+        return built[unit]
+
+    return get
+
+
+def _four_steps_of_four_tiles(monkeypatch, P, transpose):
+    """Shrink the step to four tiles of this orientation's depth."""
+    from photon_ml_tpu.ops import sparse_pallas as spl
+
+    nbo, nbg, a = ((P.nbc, P.nbr, P.a_b) if transpose
+                   else (P.nbr, P.nbc, P.a_f))
+    per_tile = a * spl.WIN * (spl.CODE_BYTES + (0 if P.unit_vals else 4))
+    monkeypatch.setattr(spl, "DMA_BUDGET", 4 * per_tile)
+    batch, chunk = spl._pick_rect(nbo, nbg, a, unit=P.unit_vals)
+    assert batch > 1 and chunk > 1
+    assert nbo // batch >= 3 and nbg // chunk >= 3
+
+
+class TestAccumulatorAcrossGridSteps:
+    @pytest.mark.parametrize("unit", [False, True], ids=["valued", "unit"])
+    @pytest.mark.parametrize("product, transpose, square", [
+        ("matvec", False, False), ("rmatvec", True, False),
+        ("row_sq_matvec", False, True), ("sq_rmatvec", True, True),
+    ])
+    def test_products_match_float64(self, monkeypatch, multi_step, unit,
+                                    product, transpose, square):
+        P, X = multi_step(unit)
+        _four_steps_of_four_tiles(monkeypatch, P, transpose)
+        rng = np.random.default_rng(7)
+        vec = rng.normal(size=MS_N if transpose else MS_D)
+        vec = vec.astype(np.float32)
+        M = X.multiply(X) if square else X
+        want = (M.T if transpose else M) @ vec.astype(np.float64)
+        got = np.asarray(getattr(P, product)(jnp.asarray(vec)), np.float64)
+        assert np.abs(got - want).max() <= 1e-6 * np.abs(want).max()
+
+    def test_nonfinite_entries_stay_local_across_steps(self, monkeypatch,
+                                                       multi_step):
+        """An inf and a nan in the vector reach the rows that touch their
+        columns and no other, whichever grid step the window falls in (a
+        step with a non-finite window builds its tables by selects, its
+        neighbours on the MXU, into the same accumulator)."""
+        P, X = multi_step(False)
+        _four_steps_of_four_tiles(monkeypatch, P, False)
+        counts = np.diff(X.tocsc().indptr)
+        c_inf, c_nan = np.flatnonzero(counts > 0)[[3, -3]]
+        assert c_inf // (2 * 2048) != c_nan // (2 * 2048)   # two grid steps
+        w = np.random.default_rng(8).normal(size=MS_D).astype(np.float32)
+        w[c_inf], w[c_nan] = np.inf, np.nan
+        got = np.asarray(P.matvec(jnp.asarray(w)), np.float64)
+        touched = np.asarray(
+            (X[:, [c_inf, c_nan]] != 0).sum(axis=1)).ravel() > 0
+        assert touched.any() and not np.isfinite(got[touched]).any()
+        clean = w.astype(np.float64)
+        clean[[c_inf, c_nan]] = 0.0
+        want = X @ clean
+        assert np.isfinite(got[~touched]).all()
+        assert np.abs(got - want)[~touched].max() <= 1e-6 * np.abs(want).max()
+
+
+def test_pick_rect_keeps_a_grid_step_inside_vmem():
+    """Input blocks, tables and output block double-buffered, plus the
+    accumulator, stay under 12 MiB for any grid; the four grids of the
+    benchmark cells keep the rectangles they were measured with."""
+    from photon_ml_tpu.ops import sparse_pallas as spl
+
+    cells = {
+        (393, 24, 128, False): (1, 24), (24, 393, 160, False): (8, 3),
+        (9766, 14, 32, True): (19, 14), (14, 9766, 48, True): (1, 257),
+    }
+    grids = list(cells) + [
+        (nbo, nbg, a, unit)
+        for nbo in (1, 7, 1024, 9766, 100_003)
+        for nbg in (1, 2, 14, 1024, 9766)
+        for a in (16, 48, 512)
+        for unit in (False, True)
+    ]
+    assert (100_003, 1, 16, True) in grids       # shallow, few gather blocks
+    window_block = spl.WINS * spl.WIN * 4
+    for nbo, nbg, a, unit in grids:
+        batch, chunk = spl._pick_rect(nbo, nbg, a, unit=unit)
+        assert nbo % batch == 0 and nbg % chunk == 0
+        per_tile = a * spl.WIN * (spl.CODE_BYTES + (0 if unit else 4))
+        held = (2 * batch * chunk * per_tile          # code (+ val) blocks
+                + 2 * chunk * window_block            # tables
+                + 2 * batch * window_block            # output block
+                + batch * spl.ACC_SUB * window_block)  # accumulator
+        assert held <= 12 << 20, (nbo, nbg, a, unit, batch, chunk, held)
+        if (nbo, nbg, a, unit) in cells:
+            assert (batch, chunk) == cells[(nbo, nbg, a, unit)]
+
+
 class TestDegenerateInputs:
     def test_all_zero_values(self):
         """All stored values zero → empty live set; must build, not crash."""
