@@ -53,6 +53,9 @@ class Coordinate:
     #: "fixed" or "random": what the descent's ``coordinate.train`` layer
     #: span says this coordinate is.
     kind: str = "coordinate"
+    #: Rows this coordinate scores and never trains on (a random effect's
+    #: rows beyond its active-row cap): on the ``coordinate.score`` span.
+    rows_passive: int = 0
 
     def train_counts(self) -> dict:
         """What the last ``train`` counted, as attributes for the descent's
@@ -634,9 +637,19 @@ def _gather_block_offsets(offsets: Array, block: EntityBlock) -> Array:
     return jnp.take(padded, block.row_index, axis=0)
 
 
+def _program_name(stem: str, coordinate: str) -> str:
+    """``stem`` with the coordinate's name, as an identifier: a jitted
+    function's name is its program's in a device trace, so two random
+    effects' ladders are told apart there (``jit_random_effect_train_
+    per_user``, ``..._per_movie``)."""
+    suffix = "".join(c if c.isalnum() else "_" for c in coordinate)
+    return f"{stem}_{suffix}" if suffix else stem
+
+
 @functools.lru_cache(maxsize=64)
 def _re_train_all_jit(
-    task: str, config: GlmOptimizationConfig, layout_sig: tuple
+    task: str, config: GlmOptimizationConfig, layout_sig: tuple,
+    coordinate: str = "",
 ):
     """ONE jitted program for ALL buckets: per-bucket dispatches each pay
     a host→device round trip, and a long-tailed dataset has many buckets.
@@ -653,7 +666,7 @@ def _re_train_all_jit(
     lane's), the sum over its real lanes, and how many real lanes froze
     before the block's loop ended.  A real lane is one with a weighted
     row.  The function's name is the program's in a device trace
-    (``jit_random_effect_train``)."""
+    (``jit_random_effect_train_<coordinate>``)."""
     solver = _make_block_solver(task, config)
 
     def random_effect_train(blocks, offsets, w0s, l1, l2):
@@ -672,35 +685,37 @@ def _re_train_all_jit(
             })
         return states, counts
 
+    random_effect_train.__name__ = _program_name(
+        "random_effect_train", coordinate)
     return jax.jit(random_effect_train)
 
 
 @functools.lru_cache(maxsize=64)
-def _re_score_all_jit(n_rows: int, layout_sig: tuple):
+def _re_score_all_jit(n_rows: int, layout_sig: tuple, coordinate: str = ""):
     """One jitted scoring scatter over all buckets (active + passive),
     memoized on (global row count, dataset layout).  BOUNDED: layouts
     vary per dataset/fold, and an unbounded cache would pin one compiled
     program per distinct layout for process lifetime."""
 
     # The function's name is the program's in a device trace
-    # (``jit_random_effect_score``).
+    # (``jit_random_effect_score_<coordinate>``).
     def random_effect_score(blocks, passive_blocks, coefs_list):
         total = jnp.zeros((n_rows + 1,), jnp.float32)
         passive = passive_blocks or [None] * len(blocks)
-        for block, passive_block, coefs in zip(blocks, passive, coefs_list):
+        for block, passive_rows, coefs in zip(blocks, passive, coefs_list):
             s = jnp.einsum("erd,ed->er", block.x_erd, coefs)
             # Padding rows (sentinel index) scatter into the trailing slot.
             total = total.at[block.row_index.ravel()].add(s.ravel())
-            if passive_block is not None:
+            if passive_rows is not None:
                 # Active/passive split: capped-out rows are never trained
                 # on but MUST be scored, or other coordinates would see
                 # offsets missing this coordinate's contribution there.
-                sp_ = jnp.einsum("erd,ed->er", passive_block.x_erd, coefs)
-                total = total.at[passive_block.row_index.ravel()].add(
-                    sp_.ravel()
-                )
+                total = total.at[passive_rows.row_index].add(
+                    passive_rows.scores(coefs))
         return total[:n_rows]
 
+    random_effect_score.__name__ = _program_name(
+        "random_effect_score", coordinate)
     return jax.jit(random_effect_score)
 
 
@@ -715,6 +730,10 @@ class RandomEffectCoordinate(Coordinate):
     #: What the last ``train`` counted (rebound by ``train``; a subclass
     #: with a ``train`` of its own counts nothing).
     _counts: dict = {}
+
+    @property
+    def rows_passive(self) -> int:
+        return self.dataset.rows_passive
 
     def __init__(
         self,
@@ -735,10 +754,14 @@ class RandomEffectCoordinate(Coordinate):
         self.entity_key = entity_key or name
         self._solver = _make_block_solver(task, config)
         sig = _layout_sig((dataset.blocks, dataset.passive_blocks))
-        self._train_all_jit = _re_train_all_jit(self.task, config, sig)
-        self._score_all_jit = _re_score_all_jit(dataset.n_global_rows, sig)
-        telemetry_mod.current().gauge("game_re_bucket_count").set(
-            len(dataset.blocks))
+        self._train_all_jit = _re_train_all_jit(self.task, config, sig, name)
+        self._score_all_jit = _re_score_all_jit(
+            dataset.n_global_rows, sig, name)
+        tel = telemetry_mod.current()
+        tel.gauge("game_re_bucket_count").set(len(dataset.blocks))
+        tel.gauge(
+            _program_name("game_re", name).lower() + "_passive_rows"
+        ).set(dataset.rows_passive)
 
     def train_counts(self) -> dict:
         return self._counts

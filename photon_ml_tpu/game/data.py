@@ -109,6 +109,101 @@ def _x_minor(rows: int, dim: int, tile) -> str:
     return "r" if rows_minor < pad(rows, sub) * pad(dim, lanes) else "d"
 
 
+#: Passive rows looked up at a time (:meth:`PassiveRows.scores`).
+_PASSIVE_CHUNK = 8192
+
+
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["X", "row_index", "slot", "lanes"],
+    meta_fields=["n_rows", "block_dim", "chunk", "x_minor"],
+)
+@dataclasses.dataclass
+class PassiveRows:
+    """The score-only rows of one bucket's capped entities, FLAT: one entry
+    per passive row, whatever the entities' passive counts (a lane-aligned
+    ``(E, Rp, D)`` companion pads every lane to the heaviest entity's
+    passive rows: 57 k for a movie of 65 k ratings under a cap of 8,192,
+    beside lanes that have a handful).
+
+    ``lanes[c]`` is the lane, in the bucket's active block, of the c-th
+    entity that has passive rows; ``slot[p]`` is row p's entity's index
+    into ``lanes``, ascending; ``row_index[p]`` its global row.  The rows
+    are padded to ``P``, a whole number of chunks of ``chunk`` rows:
+    padding rows carry the sentinel row index, the last slot and no
+    features.  ``X`` is ``(P, D)`` (``x_minor == "d"``) or ``(D, P)``
+    (``"r"``) in the ACTIVE block's local columns (a passive row's features
+    outside its entity's active subspace drop, as the reference's projected
+    scoring does), stored by the same rule as a block's (:func:`_x_minor`).
+    """
+
+    X: Array  # (P, D) float, or (D, P) when x_minor == "r"
+    row_index: Array  # (P,) int32 — global row ids, sentinel pad
+    slot: Array  # (P,) int32 — index into ``lanes``, ascending
+    lanes: Array  # (Ec,) int32 — lanes of the entities with passive rows
+    n_rows: int  # real rows
+    block_dim: int
+    chunk: int
+    x_minor: str = "d"
+
+    def scores(self, coefs):
+        """``(P,)``: each row against its entity's row of ``coefs``, the
+        bucket's ``(E, D)`` coefficients; 0 for padding rows.
+
+        A row's coefficients are looked up a chunk of rows at a time with a
+        one-hot product: the slots ascend, so a chunk of C rows reads at
+        most C consecutive slots, a static window of the table.  On a TPU
+        that is the matrix unit's work, exact at ``HIGHEST`` precision (a
+        1.0 times the coefficient), and 30 times faster than XLA's gather
+        of ``(P, D)`` elements, which also pads its result's 9 columns to
+        128 lanes: 5.6 GB at 5.5 M rows.
+        """
+        D, C = self.block_dim, self.chunk
+        n_slots = self.lanes.shape[0]
+        W = min(n_slots, C)
+        table = jnp.take(coefs, self.lanes, axis=0).T  # (D, n_slots)
+        x = self.X if self.x_minor == "r" else self.X.T  # (D, P)
+        local = jnp.arange(W, dtype=jnp.int32)[:, None]
+        zero = jnp.int32(0)
+
+        def chunk(k):
+            at = k * jnp.int32(C)
+            s = jax.lax.dynamic_slice(self.slot, (at,), (C,))
+            first = jnp.minimum(s[0], jnp.int32(n_slots - W))
+            window = jax.lax.dynamic_slice(table, (zero, first), (D, W))
+            hot = (local == (s - first)[None, :]).astype(table.dtype)
+            rows_coefs = jnp.dot(
+                window, hot, precision=jax.lax.Precision.HIGHEST)
+            rows = jax.lax.dynamic_slice(x, (zero, at), (D, C))
+            return jnp.sum(rows * rows_coefs, axis=0)
+
+        n_chunks = self.row_index.shape[0] // C
+        return jax.lax.map(
+            chunk, jnp.arange(n_chunks, dtype=jnp.int32)).reshape(-1)
+
+    def lane_aligned(self, block: EntityBlock, sentinel: int) -> EntityBlock:
+        """The same rows as a HOST block lane-aligned with ``block`` and
+        padded to its heaviest lane, ``(E, Rp, D)``, for the coordinates
+        that cut their blocks by lanes (out-of-core slices)."""
+        n = self.n_rows
+        lane = np.asarray(self.lanes, np.int64)[np.asarray(self.slot)[:n]]
+        E, D = block.n_entities, self.block_dim
+        counts = np.bincount(lane, minlength=E)
+        Rp = int(counts.max()) if n else 0
+        local = np.arange(n) - np.repeat(np.cumsum(counts) - counts, counts)
+        x = np.asarray(self.X)
+        X = np.zeros((E, Rp, D), x.dtype)
+        X[lane, local] = (x.T if self.x_minor == "r" else x)[:n]
+        row_index = np.full((E, Rp), sentinel, np.int32)
+        row_index[lane, local] = np.asarray(self.row_index)[:n]
+        return EntityBlock(
+            X=X, labels=np.zeros((E, Rp), np.float32),
+            weights=np.zeros((E, Rp), np.float32),
+            col_map=np.asarray(block.col_map), row_index=row_index,
+            n_entities=E, rows_per_entity=Rp, block_dim=D,
+        )
+
+
 @dataclasses.dataclass
 class RandomEffectDataset:
     """All buckets for one random-effect coordinate + host-side id maps.
@@ -121,10 +216,11 @@ class RandomEffectDataset:
     reference's active/passive split: passive rows are never TRAINED on, but
     they must still be SCORED during coordinate descent or the other
     coordinates would train against offsets missing this coordinate's
-    contribution for those rows.  Lanes align with the active block (same
-    entity order, same col_map), so the trained (E, D) coefficients apply
-    directly; passive-row features outside the entity's active subspace drop,
-    as the reference's projector-based scoring does.
+    contribution for those rows.  They are stored flat (:class:`PassiveRows`:
+    one entry a row, each with its entity's lane in block b), so the
+    trained (E, D) coefficients apply through ``lanes``; passive-row
+    features outside the entity's active subspace drop, as the reference's
+    projector-based scoring does.
     """
 
     blocks: list[EntityBlock]
@@ -132,7 +228,7 @@ class RandomEffectDataset:
     entity_to_slot: dict
     n_global_rows: int
     n_features: int  # global feature-space width of this coordinate's shard
-    passive_blocks: list[Optional[EntityBlock]] = dataclasses.field(
+    passive_blocks: list[Optional[PassiveRows]] = dataclasses.field(
         default_factory=list
     )
     # Padding accounting from build time (docs/performance.md
@@ -150,6 +246,26 @@ class RandomEffectDataset:
     @property
     def n_entities(self) -> int:
         return len(self.entity_to_slot)
+
+    @property
+    def rows_active(self) -> int:
+        """Rows some entity trains on (0 on a dataset from before
+        ``block_rows_real``)."""
+        return int(sum(self.block_rows_real))
+
+    @property
+    def rows_passive(self) -> int:
+        """Rows that are scored and never trained on."""
+        return int(sum(p.n_rows for p in self.passive_blocks if p is not None))
+
+    def lane_aligned_passive(self) -> list[Optional[EntityBlock]]:
+        """Each bucket's passive rows as a host block lane-aligned with its
+        active block (:meth:`PassiveRows.lane_aligned`), or ``None``."""
+        passive = self.passive_blocks or [None] * len(self.blocks)
+        return [
+            None if p is None else p.lane_aligned(b, self.n_global_rows)
+            for p, b in zip(passive, self.blocks)
+        ]
 
     @property
     def padding_ratio(self) -> float:
@@ -372,6 +488,7 @@ def build_random_effect_dataset(
     repack: str = "geometric",
     program_budget: int = 16,
     repack_seed: int = 0,
+    name: str = "",
 ) -> RandomEffectDataset:
     """Group rows by entity, project to per-entity subspaces, bucket by size.
 
@@ -386,7 +503,8 @@ def build_random_effect_dataset(
     Entity keys are canonicalized to STRINGS — the on-disk model format
     (Avro entityId) is string-keyed, so training with int keys and scoring
     after reload must agree.  ``device=False`` keeps blocks as host numpy
-    arrays (pure-host scoring paths avoid the device round trip).
+    arrays (pure-host scoring paths avoid the device round trip).  ``name``
+    is the coordinate's, for the ``game.group`` / ``game.place`` spans.
     """
     import scipy.sparse as sp
 
@@ -428,7 +546,7 @@ def build_random_effect_dataset(
                 weights[keep], max_rows_per_entity=max_rows_per_entity,
                 dtype=dtype, device=device, bucket_growth=bucket_growth,
                 repack=repack, program_budget=program_budget,
-                repack_seed=repack_seed,
+                repack_seed=repack_seed, name=name,
             )
             # Re-point every block's row indices at the ORIGINAL row
             # space (scatter targets), keeping the sentinel padding slot.
@@ -456,39 +574,45 @@ def build_random_effect_dataset(
                 ),
                 n_global_rows=n_rows,
             )
-    with layer_span("game.group", rows=int(n_rows)) as group_span:
+    with layer_span(
+        "game.group", coordinate=name, rows=int(n_rows)
+    ) as group_span:
         host = _group_entities(
             entity_keys, rows_csr, labels, weights, max_rows_per_entity,
             bucket_growth, repack, program_budget, repack_seed,
             _device_tile() if device else None,
         )
         if host is not None:
-            group_span.set(
+            rows_active = int(sum(host["block_rows_real"]))
+            counted = dict(
                 entities=len(host["entity_to_slot"]),
-                buckets=len(host["blocks"]),
+                rows_active=rows_active,
+                rows_passive=int(n_rows) - rows_active,
             )
+            group_span.set(buckets=len(host["blocks"]), **counted)
     if host is None:
         return RandomEffectDataset(
             blocks=[], entity_ids=[], entity_to_slot={},
             n_global_rows=n_rows, n_features=d, passive_blocks=[],
         )
 
-    def place(fields, **shared):
-        if not device:
-            return EntityBlock(
-                **dict(fields, X=np.asarray(fields["X"], dtype)), **shared)
-        arrays = {k: jnp.asarray(fields[k]) for k in (
-            "labels", "weights", "col_map", "row_index") if k in fields}
-        return EntityBlock(
-            **dict(fields, X=jnp.asarray(fields["X"], dtype), **arrays),
-            **shared)
+    def place(cls, fields):
+        """One block's (or one bucket's passive rows') host fields as
+        ``cls``; the fields pop as they go, so a host array is freed once
+        its device copy is made."""
+        arrays = {}
+        for key in [k for k, v in fields.items() if isinstance(v, np.ndarray)]:
+            value = fields.pop(key)
+            if key == "X":
+                value = value.astype(dtype, copy=False)
+            arrays[key] = jnp.asarray(value) if device else value
+        return cls(**arrays, **fields)
 
-    with layer_span("game.place") as place_span:
-        blocks = [place(f) for f in host["blocks"]]
-        # The passive companion shares its active block's col_map.
+    with layer_span("game.place", coordinate=name, **counted) as place_span:
+        blocks = [place(EntityBlock, f) for f in host["blocks"]]
         passive_blocks = [
-            None if f is None else place(f, col_map=b.col_map)
-            for f, b in zip(host["passive_blocks"], blocks)
+            None if f is None else place(PassiveRows, f)
+            for f in host["passive_blocks"]
         ]
         if device:
             jax.block_until_ready((blocks, passive_blocks))
@@ -584,39 +708,57 @@ def _group_entities(
             ).astype(int)] = True
             keep[starts[g]:ends[g]] = m
 
-    ent_of_pos = np.repeat(np.arange(n_ent), span_sizes)
+    # Index arrays of the rows' and the entries' size are the grouping's
+    # memory: 4 bytes each wherever the counts allow.
+    index_t = np.int32 if n_sorted < (1 << 31) else np.int64
+    ent_of_pos = np.repeat(np.arange(n_ent, dtype=index_t), span_sizes)
     # Local row index within the entity's kept (resp. passive) rows.
     kept_counts = np.bincount(ent_of_pos, weights=keep, minlength=n_ent
                               ).astype(np.int64)
     kept_before = np.concatenate([[0], np.cumsum(kept_counts)[:-1]])
-    local_kept = (np.cumsum(keep) - 1) - kept_before[ent_of_pos]
-    psv = ~keep
-    psv_counts = np.bincount(ent_of_pos, weights=psv, minlength=n_ent
-                             ).astype(np.int64)
-    psv_before = np.concatenate([[0], np.cumsum(psv_counts)[:-1]])
-    local_psv = (np.cumsum(psv) - 1) - psv_before[ent_of_pos]
+    local_kept = (
+        (np.cumsum(keep) - 1) - kept_before[ent_of_pos]).astype(index_t)
+    psv_counts = span_sizes - kept_counts
+    n_passive = int(psv_counts.sum())
+    local_psv = None
+    if n_passive:
+        psv_before = np.concatenate([[0], np.cumsum(psv_counts)[:-1]])
+        local_psv = (
+            (np.cumsum(~keep) - 1) - psv_before[ent_of_pos]).astype(index_t)
 
     sorted_csr = rows_csr[order]  # one bulk row gather
-    nnz_per_row = np.diff(sorted_csr.indptr)
-    ent_of_nnz = np.repeat(ent_of_pos, nnz_per_row)
-    pos_of_nnz = np.repeat(np.arange(n_sorted), nnz_per_row)
-    nnz_keep = keep[pos_of_nnz]
+    indptr = sorted_csr.indptr.astype(np.int64)
+    nnz_per_row = np.diff(indptr)
 
     # Per-entity ACTIVE columns (from kept rows only, as the reference's
-    # projector sees them): one global unique over (entity, column) keys.
-    # upair is sorted entity-major, so each entity's active columns come
-    # out ascending — the same order np.unique(sub.indices) produced.
-    pair = ent_of_nnz.astype(np.int64) * d + sorted_csr.indices
+    # projector sees them): the distinct (entity, column) pairs of the
+    # kept entries, entity-major, so each entity's active columns come out
+    # ascending — the same order np.unique(sub.indices) produced.
+    # ``col_rank[k]`` is entry k's pair's index among them, and
+    # ``col_hit[k]`` whether it is one (every kept entry's is; a passive
+    # entry's only where its entity trained on that column).
+    pair = np.repeat(ent_of_pos, nnz_per_row).astype(np.int64)
+    pair *= d
+    pair += sorted_csr.indices
+    nnz_keep = np.repeat(keep, nnz_per_row)
     if n_ent * d <= _PAIR_TABLE_CELLS:
         # Few enough (entity, column) cells for a presence table: one
         # pass in place of the sort inside np.unique (8 s at 62 M pairs).
         present = np.zeros(n_ent * d, bool)
         present[pair[nnz_keep]] = True
         upair = np.flatnonzero(present)
-        inv_kept = (np.cumsum(present) - 1)[pair[nnz_keep]]
+        col_rank = (np.cumsum(present, dtype=np.int32) - 1)[pair]
+        col_hit = present[pair] if n_passive else None
         del present
     else:
-        upair, inv_kept = np.unique(pair[nnz_keep], return_inverse=True)
+        upair = np.unique(pair[nnz_keep])
+        col_rank = np.searchsorted(upair, pair)
+        col_hit = None
+        if n_passive:  # no active pair at all: every passive entry drops
+            col_hit = (
+                upair[np.minimum(col_rank, len(upair) - 1)] == pair
+                if len(upair) else np.zeros(len(pair), bool))
+    del pair
     act_ent = (upair // d).astype(np.int64)
     act_col = (upair % d).astype(np.int32)
     act_counts = np.bincount(act_ent, minlength=n_ent).astype(np.int64)
@@ -683,10 +825,10 @@ def _group_entities(
         lane_of_ent[m] = np.arange(len(m))
         block_of_ent[m] = bi
 
-    # Each bucket's sorted positions, stored entries and active pairs as
-    # index lists, ascending, from ONE stable sort by bucket each: a
-    # boolean mask over all rows and all entries per bucket cost a pass
-    # over the whole data for every bucket.
+    # Each bucket's sorted positions and active pairs as index lists,
+    # ascending, from ONE stable sort by bucket each: a boolean mask over
+    # all rows per bucket cost a pass over the whole data for every
+    # bucket.  A bucket's stored entries are its rows' ranges of the CSR.
     def by_block(block_ids):
         small = block_ids.astype(
             np.int16 if len(ordered_buckets) < (1 << 15) else np.int64)
@@ -695,13 +837,29 @@ def _group_entities(
             block_ids, minlength=len(ordered_buckets)))])
         return idx, bounds
 
-    block_of_pos = block_of_ent[ent_of_pos]
-    pos_idx, pos_bounds = by_block(block_of_pos)
-    nnz_idx, nnz_bounds = by_block(block_of_pos[pos_of_nnz])
+    pos_idx, pos_bounds = by_block(block_of_ent[ent_of_pos])
     act_idx, act_bounds = by_block(block_of_ent[act_ent])
-    del block_of_pos
-    # An entry's index among the KEPT entries (inv_kept's space).
-    kept_rank = np.cumsum(nnz_keep) - 1
+
+    def entries_of(positions):
+        """``(entry indices, each entry's sorted position)`` of the rows at
+        ``positions``, in their order."""
+        lens = nnz_per_row[positions]
+        first = np.cumsum(lens) - lens
+        pos_of = np.repeat(positions, lens)
+        return (np.repeat(indptr[positions] - first, lens)
+                + np.arange(int(lens.sum()))), pos_of
+
+    def features(minor, shape, lane_or_row, row, col, values):
+        """Features scattered in their storage order: ``shape`` is the
+        leading axes, then ``(rows, D)``, the last two swapped rows-minor."""
+        *lead, rows_, D = shape
+        if minor == "r":
+            X = np.zeros((*lead, D, rows_), np.float32)
+            X[(*lane_or_row, col, row)] = values
+        else:
+            X = np.zeros((*lead, rows_, D), np.float32)
+            X[(*lane_or_row, row, col)] = values
+        return X
 
     labels = np.asarray(labels)
     weights = np.asarray(weights)
@@ -716,16 +874,6 @@ def _group_entities(
         R = int(kept_counts[m].max())
         D = max(1, int(act_counts[m].max()))
         minor = _x_minor(R, D, tile)
-
-        def features(rows_, lane, row, col, values, minor=minor, E=E, D=D):
-            """The block's features, scattered in its storage order."""
-            if minor == "r":
-                X = np.zeros((E, D, rows_), np.float32)
-                X[lane, col, row] = values
-            else:
-                X = np.zeros((E, rows_, D), np.float32)
-                X[lane, row, col] = values
-            return X
 
         # Row-level fills: labels/weights/row_index at (lane, local_row).
         in_bucket = pos_idx[pos_bounds[bi]:pos_bounds[bi + 1]]
@@ -750,14 +898,13 @@ def _group_entities(
 
         # X: every kept nnz of the bucket scatters to
         # (lane, local_row, local_col); duplicates were pre-summed.
-        entries = nnz_idx[nnz_bounds[bi]:nnz_bounds[bi + 1]]
-        n_sel = entries[nnz_keep[entries]]
-        e_n = ent_of_nnz[n_sel]
+        n_sel, pos_n = entries_of(sel)
+        e_n = ent_of_pos[pos_n]
         X = features(
-            R, lane_of_ent[e_n], local_kept[pos_of_nnz[n_sel]],
-            inv_kept[kept_rank[n_sel]] - act_before[e_n],
-            sorted_csr.data[n_sel],
+            minor, (E, R, D), (lane_of_ent[e_n],), local_kept[pos_n],
+            col_rank[n_sel] - act_before[e_n], sorted_csr.data[n_sel],
         )
+        del n_sel, pos_n, e_n
 
         ids = list(ent_keys[m])
         for lane, key in enumerate(ids):
@@ -768,47 +915,39 @@ def _group_entities(
         ))
         ids_per_block.append(ids)
 
-        # Score-only passive companion block, lane-aligned with the
-        # active block (same entity order and col_map).
-        Rp = int(psv_counts[m].max()) if len(m) else 0
-        if Rp == 0:
+        # The bucket's score-only rows, flat and in lane order (sorted
+        # positions are entity-major and lanes ascend with the entity).
+        if not n_passive or not psv_counts[m].any():
             passive_blocks.append(None)
             continue
         selp = in_bucket[~keep[in_bucket]]
-        lane_p = lane_of_ent[ent_of_pos[selp]]
-        lrow_p = local_psv[selp]
-        rows_p = row_of_pos[selp]
-        labp = np.zeros((E, Rp), np.float32)
-        wtsp = np.zeros((E, Rp), np.float32)
-        rindexp = np.full((E, Rp), n_rows, np.int32)
-        labp[lane_p, lrow_p] = labels[rows_p]
-        wtsp[lane_p, lrow_p] = weights[rows_p]
-        rindexp[lane_p, lrow_p] = rows_p
-
+        Np = len(selp)
+        chunk = min(_PASSIVE_CHUNK, -(-Np // 128) * 128)
+        P = -(-Np // chunk) * chunk
+        has = psv_counts[m] > 0
+        slot_of_lane = np.cumsum(has) - 1
+        first_of_lane = np.cumsum(psv_counts[m]) - psv_counts[m]
+        rindexp = np.full(P, n_rows, np.int32)  # sentinel
+        rindexp[:Np] = row_of_pos[selp]
+        slot = np.full(P, slot_of_lane[-1], np.int32)
+        slot[:Np] = slot_of_lane[lane_of_ent[ent_of_pos[selp]]]
         # Passive features project onto the ACTIVE subspace (features the
         # entity never trained on drop, as in the reference's projected
-        # scoring): locate each passive nnz's (entity, col) in the sorted
-        # unique-pair table; misses drop.
-        np_sel = entries[~nnz_keep[entries]]
-        hit = np.zeros(len(np_sel), bool)
-        ss = np.zeros(len(np_sel), np.int64)
-        if len(upair):  # no active pairs at all → every passive nnz drops
-            p_pair = pair[np_sel]
-            ss = np.searchsorted(upair, p_pair)
-            hit = (ss < len(upair)) & (
-                upair[np.minimum(ss, len(upair) - 1)] == p_pair
-            )
-        e_p = ent_of_nnz[np_sel][hit]
-        passive_minor = _x_minor(Rp, D, tile)
+        # scoring): entries whose (entity, col) pair is not active drop.
+        np_sel, pos_p = entries_of(selp)
+        hit = col_hit[np_sel]
+        np_sel, pos_p = np_sel[hit], pos_p[hit]
+        e_p = ent_of_pos[pos_p]
+        passive_minor = _x_minor(P, D, tile)
         Xp = features(
-            Rp, lane_of_ent[e_p], local_psv[pos_of_nnz[np_sel][hit]],
-            ss[hit] - act_before[e_p], sorted_csr.data[np_sel][hit],
-            minor=passive_minor,
+            passive_minor, (P, D), (),
+            first_of_lane[lane_of_ent[e_p]] + local_psv[pos_p],
+            col_rank[np_sel] - act_before[e_p], sorted_csr.data[np_sel],
         )
         passive_blocks.append(dict(
-            X=Xp, labels=labp, weights=wtsp, row_index=rindexp,
-            n_entities=E, rows_per_entity=Rp, block_dim=D,
-            x_minor=passive_minor,
+            X=Xp, row_index=rindexp, slot=slot,
+            lanes=np.flatnonzero(has).astype(np.int32), n_rows=Np,
+            block_dim=D, chunk=chunk, x_minor=passive_minor,
         ))
 
     return {

@@ -359,6 +359,7 @@ class CoordinateDescent:
                             with layer_span(
                                 "coordinate.score", coordinate=coord.name,
                                 iteration=it,
+                                rows_passive=getattr(coord, "rows_passive", 0),
                             ):
                                 new_score = coord.score(state)
                         states[coord.name] = state

@@ -247,10 +247,17 @@ def shard_dataset_entities(
             lambda x: jax.device_put(x, sharding), padded
         )
 
+    # Passive rows index lanes of the whole block: replicated, each device
+    # scores them against the (sharded) coefficients it is handed.
+    replicated = NamedSharding(mesh, P())
     return dataclasses.replace(
         dataset,
         blocks=[place(b) for b in dataset.blocks],
-        passive_blocks=[place(b) for b in dataset.passive_blocks],
+        passive_blocks=[
+            None if p is None
+            else jax.tree.map(lambda x: jax.device_put(x, replicated), p)
+            for p in dataset.passive_blocks
+        ],
     )
 
 

@@ -376,6 +376,7 @@ class GameEstimator:
                             repack=cfg.repack,
                             program_budget=cfg.program_budget,
                             repack_seed=cfg.repack_seed,
+                            name=name,
                             device=False,
                         )
                         cache[ooc_key] = dataset
@@ -435,6 +436,7 @@ class GameEstimator:
                         repack=cfg.repack,
                         program_budget=cfg.program_budget,
                         repack_seed=cfg.repack_seed,
+                        name=name,
                     )
                     cache[key] = dataset
                 if factored:
@@ -559,6 +561,7 @@ class GameEstimator:
                 repack=cfg.repack,
                 program_budget=cfg.program_budget,
                 repack_seed=cfg.repack_seed,
+                name=name,
                 device=False,  # the coordinate places blocks on the mesh
             )
             cache[ds_key] = dataset

@@ -205,13 +205,9 @@ class FactoredRandomEffectCoordinate(Coordinate):
                 )
                 total = total.at[block.row_index.ravel()].add(s.ravel())
                 if pblock is not None:
-                    sp_ = jnp.einsum(
-                        "erd,edk,ek->er",
-                        pblock.x_erd, _gather_v(V, pblock.col_map), u,
-                    )
-                    total = total.at[pblock.row_index.ravel()].add(
-                        sp_.ravel()
-                    )
+                    w = jnp.einsum(
+                        "edk,ek->ed", _gather_v(V, block.col_map), u)
+                    total = total.at[pblock.row_index].add(pblock.scores(w))
             return total[:n_rows]
 
         def _materialize_impl(blocks, u_list, V):

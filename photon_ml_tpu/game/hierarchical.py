@@ -154,6 +154,11 @@ def _re_block_scores_jit(layout_sig: tuple):
     return jax.jit(_scores)
 
 
+@jax.jit
+def _passive_scores_jit(rows, coefs):
+    return rows.scores(coefs)
+
+
 @functools.lru_cache(maxsize=64)
 def _re_scatter_jit(n_rows: int, layout_sig: tuple):
     """The scatter half: per-block (row_index, scores) pairs accumulate
@@ -224,11 +229,12 @@ class ShardedBucketRandomEffectCoordinate(RandomEffectCoordinate):
                 place(b, p)
                 for b, p in zip(dataset.blocks, self.plan.placements)
             ],
+            # Passive rows are scored where the score scatter runs.
             passive_blocks=[
-                place(b, p)
-                for b, p in zip(
-                    dataset.passive_blocks, self.plan.placements
-                )
+                None if p is None
+                else jax.tree.map(
+                    lambda x: jax.device_put(jnp.asarray(x), devices[0]), p)
+                for p in dataset.passive_blocks
             ],
         )
         super().__init__(
@@ -265,10 +271,6 @@ class ShardedBucketRandomEffectCoordinate(RandomEffectCoordinate):
             return jax.device_put(jnp.asarray(x), devices[0])
 
         self._home_row_index = [home(b.row_index) for b in placed.blocks]
-        self._home_passive_row_index = [
-            home(b.row_index) if b is not None else None
-            for b in placed.passive_blocks
-        ]
 
     def train(self, offsets: Array, warm_state=None) -> list[Array]:
         l1 = jnp.asarray(
@@ -338,29 +340,19 @@ class ShardedBucketRandomEffectCoordinate(RandomEffectCoordinate):
             )
             for i, out in zip(idxs, outs):
                 per_block_scores[i] = out
-            passive = [
-                (i, self.dataset.passive_blocks[i])
-                for i in idxs
-                if self.dataset.passive_blocks
-                and self.dataset.passive_blocks[i] is not None
-            ]
-            if passive:
-                pblocks = [b for _, b in passive]
-                pouts = _re_block_scores_jit(_layout_sig(pblocks))(
-                    pblocks, [state[i] for i, _ in passive]
-                )
-                for (i, _), out in zip(passive, pouts):
-                    per_block_passive[i] = out
+        passive = self.dataset.passive_blocks or [None] * len(state)
+        for i, rows in enumerate(passive):
+            if rows is not None:
+                per_block_passive[i] = _passive_scores_jit(
+                    rows, jax.device_put(state[i], self._home))
         row_indexes: list = []
         scores: list = []
         for bi in range(len(self.dataset.blocks)):
             row_indexes.append(self._home_row_index[bi])
             scores.append(jax.device_put(per_block_scores[bi], self._home))
             if per_block_passive[bi] is not None:
-                row_indexes.append(self._home_passive_row_index[bi])
-                scores.append(
-                    jax.device_put(per_block_passive[bi], self._home)
-                )
+                row_indexes.append(passive[bi].row_index)
+                scores.append(per_block_passive[bi])
         out = _re_scatter_jit(
             self.dataset.n_global_rows,
             _layout_sig(row_indexes),

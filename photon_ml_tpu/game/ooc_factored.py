@@ -304,8 +304,8 @@ class OutOfCoreFactoredRandomEffectCoordinate(OutOfCoreRandomEffectCoordinate):
                          s.padded_e, sentinel),
                 )
                 passive = None
-                if self.dataset.passive_blocks:
-                    pb = self.dataset.passive_blocks[s.block_idx]
+                if self._passive_blocks:
+                    pb = self._passive_blocks[s.block_idx]
                     if pb is not None:
                         passive = (
                             _cut(pb.X, s.lane_lo, s.lane_hi, s.padded_e, 0),

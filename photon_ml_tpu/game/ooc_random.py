@@ -214,6 +214,9 @@ class OutOfCoreRandomEffectCoordinate(RandomEffectCoordinate):
         # a larger-than-HBM dataset cannot do.
         self.name = name
         self.dataset = dataset
+        # Slices cut blocks by lanes, so the passive rows (stored flat)
+        # are read through their lane-aligned view, on the host.
+        self._passive_blocks = dataset.lane_aligned_passive()
         self.task = losses_lib.get(task).name
         self.config = config
         self.reg_weight = reg_weight
@@ -354,8 +357,8 @@ class OutOfCoreRandomEffectCoordinate(RandomEffectCoordinate):
         group_bytes = 0
         for bi, block in enumerate(self.dataset.blocks):
             passive = (
-                self.dataset.passive_blocks[bi]
-                if self.dataset.passive_blocks else None
+                self._passive_blocks[bi]
+                if self._passive_blocks else None
             )
             # Placement sets the lane quantum: split slices need one
             # shardable lane per mesh device, packed (and unmeshed)
@@ -420,8 +423,8 @@ class OutOfCoreRandomEffectCoordinate(RandomEffectCoordinate):
                 per = 4 * (r * d + 3 * r + d)
             else:
                 per = 4 * (r * d + r)  # X + row_index
-                if self.dataset.passive_blocks:
-                    pb = self.dataset.passive_blocks[s.block_idx]
+                if self._passive_blocks:
+                    pb = self._passive_blocks[s.block_idx]
                     if pb is not None:
                         rp = pb.rows_per_entity
                         per += 4 * (rp * d + rp)
@@ -734,8 +737,8 @@ class OutOfCoreRandomEffectCoordinate(RandomEffectCoordinate):
                         s.padded_e, sentinel),
                 )
                 passive = None
-                if self.dataset.passive_blocks:
-                    pb = self.dataset.passive_blocks[s.block_idx]
+                if self._passive_blocks:
+                    pb = self._passive_blocks[s.block_idx]
                     if pb is not None:
                         passive = (
                             _cut(pb.X, s.lane_lo, s.lane_hi, s.padded_e, 0),

@@ -852,6 +852,8 @@ class TestBuilderDifferential:
             if rXp is None:
                 assert pb is None
             else:
+                # the flat passive rows, through their lane-aligned view
+                pb = pb.lane_aligned(b, len(keys))
                 np.testing.assert_array_equal(np.asarray(pb.X), rXp)
                 np.testing.assert_array_equal(
                     np.asarray(pb.row_index), rrindexp

@@ -277,7 +277,9 @@ def test_rows_minor_storage_fits_the_same_factored_model(host, monkeypatch):
 
 @pytest.mark.parametrize("rows,dim,want", [
     (32, 21, "r"), (20, 21, "d"), (9254, 21, "r"), (64, 200, "d"),
-    (1, 21, "d"), (4096, 1, "r")])
+    (1, 21, "d"), (4096, 1, "r"),
+    # flat passive rows (PR 31): 5.5 M rows of 9 columns in whole chunks
+    (5513216, 9, "r")])
 def test_minor_axis_is_the_one_that_pads_less(rows, dim, want):
     assert game_data._x_minor(rows, dim, (8, 128)) == want
     assert game_data._x_minor(rows, dim, None) == "d"
