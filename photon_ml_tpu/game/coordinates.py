@@ -280,7 +280,8 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
     Optimizer dispatch: any L1 component (static on the regularization
     TYPE) routes to OWL-QN.  SMOOTH problems prefer an exact fast path
     when one exists for the block shape — rank-1 Newton (R == 1), scalar
-    Newton (D == 1), or batched damped Newton (D <= 32) — regardless of
+    Newton (D == 1), or batched damped Newton with a direct entity-minor
+    solve (D <= 32) — regardless of
     whether the config names L-BFGS or TRON: these solve the identical
     regularized objective to the identical stationary point, the config's
     optimizer choice only governs HOW, and the fast paths are 2-13x
@@ -290,6 +291,7 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
     don't recompile.  Memoized on (task, config) —
     both hashable — so every coordinate/grid point with the same optimizer
     setup shares ONE jit cache (one compile per block shape process-wide).
+    ``solver.path(block)`` names the path a block's static shape takes.
     """
     from photon_ml_tpu.optim.tron import TRONConfig, tron_solve
 
@@ -426,63 +428,58 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
         )
         return w[:, None], n
 
-    _HI = jax.lax.Precision.HIGHEST
-
-    def spd_solve_cg(H, g, n_steps):
-        """Batched (E, D, D) SPD solve by ``n_steps`` unrolled CG
-        iterations (exact at n_steps = D in exact arithmetic) — NO
-        lax.linalg: batched ``jnp.linalg.solve`` lowers to scalar-heavy
-        LU loops on TPU (measured 4.5x slower than the vmapped L-BFGS it
-        was meant to replace), and a Gauss-Jordan inverse's (E, D, 2D)
-        row ops are bandwidth-heavy at large E; CG touches only
-        (E, D)-vectors plus one (E, D, D) matvec per step.  Zero lanes
-        (H = 0, g = 0 — bucket padding) stay exactly zero."""
-        x = jnp.zeros_like(g)
-        r = g
-        p = r
-        rs = jnp.sum(r * r, axis=1)
-        for _ in range(n_steps):
-            Hp = jnp.einsum("edk,ek->ed", H, p, precision=_HI)
-            alpha = rs / jnp.maximum(
-                jnp.sum(p * Hp, axis=1), 1e-30
-            )
-            x = x + alpha[:, None] * p
-            r = r - alpha[:, None] * Hp
-            rs_new = jnp.sum(r * r, axis=1)
-            beta = rs_new / jnp.maximum(rs, 1e-30)
-            rs = rs_new
-            p = r + beta[:, None] * p
-        return x
-
     def newton_block(block, offsets_block, w0, l2, max_iters, tol):
         """Batched damped Newton for smooth objectives on small-D blocks:
-        an exact (E, D, D) Hessian CG solve replaces the vmapped L-BFGS
-        machinery.  The win is SEQUENTIAL structure — the chip profile
-        showed the (E=27k, R=4) bucket costing 2x the (E=13k, R=16) one
-        despite HALF the lane-rows, i.e. these buckets are bound by the
-        while-loop body's launch/overhead count, not FLOPs.  One Newton
-        body is a single fusable chain (grad, one batched-matmul Hessian
-        build, D unrolled CG steps, damp) vs L-BFGS's nested scan + zoom
-        while_loop per iteration, and quadratic convergence needs fewer
-        outer trips — warm-started CD iterations exit in 1-2.  Per-lane
-        freezing + the Breeze-style relative gradient test match the
-        L-BFGS convergence semantics.  Small einsums run at HIGHEST
-        precision: default MXU bf16 puts a noise floor above the 1e-6
-        gradient tolerance, which silently disables the early exit."""
+        one exact solve of the regularised Newton system per trip replaces
+        the vmapped L-BFGS machinery.  The win is SEQUENTIAL structure:
+        one Newton body is a short chain (margin, gradient, one batched-
+        matmul Hessian build, one direct solve, damp) vs L-BFGS's nested
+        scan + zoom while_loop per iteration, and quadratic convergence
+        needs fewer outer trips.  Per-lane freezing + the Breeze-style
+        relative gradient test match the L-BFGS convergence semantics.
+
+        Everything D x D or D long lives ENTITY-MINOR inside the loop:
+        ``H`` as ``(D, D, E)``, ``w``, ``g`` and the step as ``(D, E)``.
+        A TPU pads an array's minor axis to 128 lanes, so ``(E, D, D)``
+        at D = 21 is seven times its bytes in every pass over it, and the
+        D-step CG that used to solve the system made D such passes a
+        trip (:func:`_spd_solve_direct` has the measurement).  The
+        Hessian is built on the matrix unit, or as elementwise pairs
+        for the short-row shapes where that was timed and won
+        (``_PAIRS_HESSIAN_UP_TO``): a choice read from the block's static
+        shape.  The dot products
+        with ``X`` run at HIGHEST precision: default MXU bf16 puts a
+        noise floor above the 1e-6 gradient tolerance, which silently
+        disables the early exit."""
         X, yb, wt = block.x_erd, block.labels, block.weights
         off = offsets_block.astype(X.dtype)
-        d = block.block_dim
-        eye = jnp.eye(d, dtype=X.dtype)
+        l2_eye = l2 * jnp.eye(block.block_dim, dtype=X.dtype)[:, :, None]
+        by_pairs = any(
+            block.block_dim <= dim and block.rows_per_entity <= rows
+            for dim, rows in _PAIRS_HESSIAN_UP_TO)
+
+        def hessian(d2):
+            Xw = X * d2[:, :, None]
+            if by_pairs:
+                # One multiply-and-reduce fusion over all D x D pairs, in
+                # float32 on the vector unit: no dot pins X to its
+                # lane-padded rows-minor order, so the compiler keeps a
+                # short-row block entity-minor through the whole body.
+                H = jnp.sum(Xw[:, :, :, None] * X[:, :, None, :], axis=1)
+                return jnp.transpose(H, (1, 2, 0)) + l2_eye
+            return jnp.einsum(
+                "erd,erk->dke", Xw, X, precision=_HI) + l2_eye
 
         def grad_at(w):
-            m = jnp.einsum("erd,ed->er", X, w, precision=_HI) + off
+            m = jnp.einsum("erd,de->er", X, w, precision=_HI) + off
             g = jnp.einsum(
-                "er,erd->ed", wt * loss.d1(m, yb), X, precision=_HI
+                "er,erd->de", wt * loss.d1(m, yb), X, precision=_HI
             ) + l2 * w
             return m, g
 
+        w0 = w0.T
         _, g0 = grad_at(w0)
-        gtol = tol * jnp.maximum(1.0, jnp.linalg.norm(g0, axis=1))
+        gtol = tol * jnp.maximum(1.0, jnp.linalg.norm(g0, axis=0))
 
         def cond(carry):
             i, _w, done, _n = carry
@@ -491,21 +488,17 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
         def body(carry):
             i, w, done, n = carry
             m, g = grad_at(w)
-            newly = jnp.linalg.norm(g, axis=1) <= gtol
-            d2 = wt * loss.d2(m, yb)
-            H = jnp.einsum(
-                "erd,erk->edk", X * d2[:, :, None], X, precision=_HI
-            ) + l2 * eye
-            step = spd_solve_cg(H, g, d)
+            newly = jnp.linalg.norm(g, axis=0) <= gtol
+            step = _spd_solve_direct(hessian(wt * loss.d2(m, yb)), g)
             # Margin-change damp (the rank1/dim1 clamp, per lane): one
             # step moves no row's margin by more than 20.
-            dm = jnp.einsum("erd,ed->er", X, step, precision=_HI)
+            dm = jnp.einsum("erd,de->er", X, step, precision=_HI)
             scale = jnp.minimum(
                 1.0,
                 20.0 / jnp.maximum(jnp.max(jnp.abs(dm), axis=1), 1e-12),
             )
             keep = done | newly
-            w = jnp.where(keep[:, None], w, w - scale[:, None] * step)
+            w = jnp.where(keep, w, w - scale * step)
             return i + 1, w, keep, n + ~keep
 
         done0 = jnp.zeros((X.shape[0],), bool)
@@ -513,7 +506,7 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
             cond, body,
             (jnp.zeros((), jnp.int32), w0, done0, _no_steps(done0)),
         )
-        return w, n
+        return w.T, n
 
     def make_solve_one(history: int):
         def solve_one(X, y, wts, off, w0, l1, l2):
@@ -560,30 +553,40 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
 
         return solve_one
 
+    def path(block: EntityBlock) -> str:
+        """The static (trace-time) path a block's shape takes."""
+        if use_owlqn:
+            return "owlqn"
+        # Single-row buckets, single-feature buckets and small-D buckets
+        # each have an exact Newton form.  (A gram-space dual Newton for
+        # 2 <= R <= 16 was tried and measured 4.5x SLOWER than the vmapped
+        # L-BFGS: batched small jnp.linalg.solve lowers to scalar-heavy LU
+        # loops on TPU.)
+        if block.rows_per_entity == 1:
+            return "rank1"
+        if block.block_dim == 1:
+            return "dim1"
+        if block.block_dim <= 32:
+            return "newton_direct"
+        return "tron" if use_tron else "lbfgs"
+
     def solve_block(
         block: EntityBlock, offsets_block: Array, w0: Array, l1: Array, l2: Array
     ) -> tuple[Array, Array]:
         """``(E, D)`` coefficients and each lane's iteration count."""
-        # Static shape dispatch (trace-time): single-row buckets take the
-        # rank-1 Newton path for smooth objectives.  (A gram-space dual
-        # Newton for 2 <= R <= 16 was tried and measured 4.5x SLOWER than
-        # the vmapped L-BFGS: batched small jnp.linalg.solve lowers to
-        # scalar-heavy LU loops on TPU.)
-        if block.rows_per_entity == 1 and not use_owlqn:
+        taken = path(block)
+        if taken == "rank1":
             return rank1_newton(block, offsets_block, w0, l2)
-        if block.block_dim == 1 and not use_owlqn:
+        if taken == "dim1":
             return dim1_newton(block, offsets_block, w0, l2)
-        if block.block_dim <= 32 and not use_owlqn:
-            # Small-D smooth blocks: exact batched Newton (D unrolled CG
-            # steps per Hessian solve stay cheap; the Hessian build is
-            # one MXU-friendly (E, D, R) x (E, R, D) batched matmul).
+        if taken == "newton_direct":
             return newton_block(
                 block, offsets_block, w0, l2,
                 opt.max_iters, opt.tolerance,
             )
         # History beyond the LOCAL problem dimension buys nothing (L-BFGS
         # with m >= d already behaves Newton-like) but every extra pair
-    # adds two scan steps per iteration — sequential step count is what
+        # adds two scan steps per iteration — sequential step count is what
         # dominates these small batched solves.
         solve_one = make_solve_one(min(opt.history, block.block_dim))
         res = jax.vmap(
@@ -591,7 +594,80 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
         )(block.x_erd, block.labels, block.weights, offsets_block, w0, l1, l2)
         return res.w, res.iterations
 
-    return _BlockSolver(solve_block)
+    return _BlockSolver(solve_block, path)
+
+
+_HI = jax.lax.Precision.HIGHEST
+
+#: ``(columns, rows)`` of an entity up to which ``newton_block`` builds the
+#: Hessian as elementwise pairs and not on the matrix unit: the shapes
+#: where both were timed and the pairs won, and nothing beyond them.  A
+#: batched matmul costs about the same for every 128-row chunk of an entity
+#: whatever the chunk holds, and its (D, D) result pads to 128 lanes; the
+#: pairs cost rows x D^2.  Measured a trip on one TPU v5 lite (PERF.md
+#: section 6, PR 32), matmul / pairs: 32 rows x 21 columns 3.52 / 1.45
+#: ms, 64 x 21 6.91 / 6.07, 128 x 9 0.76 / 0.55, 256 x 9 0.66 / 0.57;
+#: 128 x 21 5.52 / 7.99, 256 x 21 5.23 / 9.20.  The pairs' product is
+#: ``(E, R, D, D)`` before its reduction, 4.3 GB at 38,069 x 64 x 21: the
+#: compiler has to fuse the two, and tests/test_kernel_names_v5e.py holds
+#: it to that at these shapes.
+_PAIRS_HESSIAN_UP_TO = ((21, 64), (9, 256))
+
+#: A pivot at or under this share of its own diagonal entry is rounding
+#: noise (float32 resolves 1.2e-7): the column is a combination of the
+#: ones before it, and its component of the solution is set to zero.
+_PIVOT_FLOOR = 1e-6
+
+
+def _spd_solve_direct(H: Array, g: Array) -> Array:
+    """``x`` with ``H x = g`` per lane, ENTITY-MINOR: ``H`` is ``(D, D, E)``
+    symmetric positive semi-definite per lane, ``g`` and ``x`` are
+    ``(D, E)``.  One right-looking LDL^T elimination, a ``fori_loop`` over
+    the D columns, then one back substitution; every operation is
+    elementwise over E, so no reduction crosses lanes and nothing pads a
+    D-wide axis to 128 lanes.  Only ``H``'s upper triangle is read.
+
+    NO lax.linalg (batched ``jnp.linalg.solve`` lowers to scalar-heavy LU
+    loops on TPU: measured 4.5x slower than the vmapped L-BFGS it was meant
+    to replace) and no matvec pass: the D-step CG this replaces re-read a
+    lane-padded ``(E, D, D)`` D times a trip, 39 of a trip's 77 ms on the
+    MovieLens-20M per-user ladder (PERF.md section 6, PR 32).
+
+    A pivot at the floor (an all-zero or duplicated column with no L2, or
+    with an L2 under half of ``_PIVOT_FLOOR`` times the column's diagonal
+    entry: a duplicate's pivot is about twice the L2) zeroes that component of ``x``, so the step stays finite.  In
+    ``newton_block`` such a coefficient keeps its start value, trip after
+    trip, where CG would have moved it; its gradient is left at what the
+    rest of the lane leaves there (of the order of L2 times the
+    coefficient), and a lane that this keeps over its gradient test counts
+    to the cap.  A padding lane (``H = l2 I``, ``g = 0``) returns exactly
+    zero."""
+    dim = g.shape[0]
+    below = jnp.arange(dim)[:, None]                  # row index, (D, 1)
+
+    def eliminate(j, carry):
+        # After step j row j of ``A`` holds d_j L[k, j] for k > j and the
+        # pivot d_j at k = j; rows under it hold the Schur complement.
+        A, r, inv_d = carry
+        row = A[j]
+        pivot = row[j]
+        sound = pivot > jnp.maximum(_PIVOT_FLOOR * H[j, j], 1e-30)
+        inv = jnp.where(sound, 1.0 / pivot, 0.0)
+        under = below > j
+        col = jnp.where(under, row * inv, 0.0)        # L[:, j] under d_j
+        A = A - col[:, None, :] * jnp.where(under, row, 0.0)[None, :, :]
+        r = r - col * r[j]
+        return A, r, inv_d.at[j].set(inv)
+
+    A, y, inv_d = jax.lax.fori_loop(
+        0, dim, eliminate, (H, g, jnp.zeros_like(g)))
+
+    def substitute(t, x):
+        j = dim - 1 - t
+        tail = jnp.sum(jnp.where(below > j, A[j] * x, 0.0), axis=0)
+        return x.at[j].set(inv_d[j] * (y[j] - tail))
+
+    return jax.lax.fori_loop(0, dim, substitute, jnp.zeros_like(g))
 
 
 def _no_steps(done: Array) -> Array:
@@ -603,10 +679,13 @@ class _BlockSolver:
     """``solver(block, offsets_block, w0, l1, l2)`` gives the ``(E, D)``
     coefficients; ``solver.counted(...)`` also each lane's iteration count
     (a lane that froze early stopped counting).  Both jitted; under an
-    outer jit they inline."""
+    outer jit they inline.  ``solver.path(block)`` names the static path
+    the block's shape takes (``"rank1"``, ``"dim1"``, ``"newton_direct"``,
+    ``"lbfgs"``, ``"owlqn"``, ``"tron"``)."""
 
-    def __init__(self, solve):
+    def __init__(self, solve, path):
         self.counted = jax.jit(solve)
+        self.path = path
         self._coefficients = jax.jit(lambda *args: solve(*args)[0])
 
     def __call__(self, *args) -> Array:
@@ -796,7 +875,8 @@ class RandomEffectCoordinate(Coordinate):
         self._counts = {"buckets": [
             {"lanes": block.n_entities,
              "rows_padded": block.n_entities * block.rows_per_entity,
-             "rows_real": real, "dim": block.block_dim, **counted}
+             "rows_real": real, "dim": block.block_dim,
+             "solver": self._solver.path(block), **counted}
             for block, real, counted in zip(
                 self.dataset.blocks, rows_real, counts)
         ]}

@@ -4,6 +4,8 @@ Mirrors the reference's GAME integration-test strategy (SURVEY.md §4): mini
 GAME datasets with known per-entity structure; assertions that coordinate
 descent recovers it and that mixed-effects beat fixed-effects alone."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 import scipy.sparse as sp
@@ -632,6 +634,273 @@ class TestDim1Newton:
                 )
 
 
+def _dense_block(rng, n_entities, rows, dim, pad_lanes=0, minor="d"):
+    """One EntityBlock of dense features, ``pad_lanes`` trailing padding
+    lanes (no features, no weight), and its float64 arrays."""
+    from photon_ml_tpu.game.data import EntityBlock
+
+    lanes = n_entities + pad_lanes
+    X = rng.normal(size=(lanes, rows, dim)).astype(np.float32)
+    y = (rng.uniform(size=(lanes, rows)) < 0.5).astype(np.float32)
+    wt = (rng.uniform(size=(lanes, rows)) < 0.8).astype(np.float32)
+    wt[:, 0] = 1.0
+    off = (0.3 * rng.normal(size=(lanes, rows))).astype(np.float32)
+    X[n_entities:], wt[n_entities:], off[n_entities:] = 0.0, 0.0, 0.0
+    block = EntityBlock(
+        X=jnp.asarray(X if minor == "d" else X.swapaxes(1, 2)),
+        labels=jnp.asarray(y), weights=jnp.asarray(wt),
+        col_map=jnp.zeros((lanes, dim), jnp.int32),
+        row_index=jnp.zeros((lanes, rows), jnp.int32),
+        n_entities=lanes, rows_per_entity=rows, block_dim=dim,
+        x_minor=minor,
+    )
+    return block, X.astype(np.float64), y, wt, off
+
+
+def _logistic_grad64(X, y, wt, off, l2, w):
+    p = 1.0 / (1.0 + np.exp(-(np.einsum("erd,ed->er", X, w) + off)))
+    return p, np.einsum("er,erd->ed", wt * (p - y), X) + l2 * w
+
+
+def _newton64(X, y, wt, off, l2, iters=60):
+    """Per-entity damped Newton in float64, run far past convergence."""
+    w = np.zeros((X.shape[0], X.shape[2]))
+    for _ in range(iters):
+        p, g = _logistic_grad64(X, y, wt, off, l2, w)
+        H = np.einsum("erd,er,erk->edk", X, wt * p * (1 - p), X)
+        H = H + l2 * np.eye(X.shape[2])
+        step = np.linalg.solve(H, g[:, :, None])[:, :, 0]
+        dm = np.abs(np.einsum("erd,ed->er", X, step)).max(axis=1)
+        w = w - np.minimum(1.0, 20.0 / np.maximum(dm, 1e-12))[:, None] * step
+    return w
+
+
+def _smooth_solver(max_iters=30, tolerance=1e-7, **kw):
+    from photon_ml_tpu.game.coordinates import _make_block_solver
+
+    return _make_block_solver("logistic", GlmOptimizationConfig(
+        optimizer=OptimizerConfig(
+            max_iters=max_iters, tolerance=tolerance, **kw),
+        regularization=RegularizationContext.l2(),
+    ))
+
+
+class TestNewtonDirect:
+    """The small-D block path: damped Newton whose D x D system is solved
+    once, directly, with the entity on the minor axis."""
+
+    @pytest.mark.parametrize("rows", [2, 37, 130])
+    @pytest.mark.parametrize("dim", [2, 9, 21, 32])
+    def test_matches_float64_newton(self, dim, rows):
+        rng = np.random.default_rng(1000 * dim + rows)
+        block, X, y, wt, off = _dense_block(rng, 48, rows, dim)
+        solver = _smooth_solver()
+        assert solver.path(block) == "newton_direct"
+        w0 = jnp.zeros((48, dim), jnp.float32)
+        w = np.asarray(solver(
+            block, jnp.asarray(off), w0, jnp.asarray(0.0), jnp.asarray(1.0)))
+        ref = _newton64(X, y, wt, off, 1.0)
+        assert np.max(np.abs(w - ref)) <= 1e-4 * np.max(np.abs(ref))
+        _, g0 = _logistic_grad64(X, y, wt, off, 1.0, np.zeros_like(ref))
+        _, g = _logistic_grad64(X, y, wt, off, 1.0, w.astype(np.float64))
+        bound = 1e-4 * np.maximum(1.0, np.linalg.norm(g0, axis=1))
+        assert np.all(np.linalg.norm(g, axis=1) <= bound)
+
+    @pytest.mark.parametrize("minor", ["d", "r"])
+    def test_padding_lanes_zero_and_uncounted(self, minor):
+        rng = np.random.default_rng(7)
+        block, *_rest, off = _dense_block(
+            rng, 20, 12, 5, pad_lanes=12, minor=minor)
+        w, n = _smooth_solver().counted(
+            block, jnp.asarray(off), jnp.zeros((32, 5), jnp.float32),
+            jnp.asarray(0.0), jnp.asarray(1.0))
+        w, n = np.asarray(w), np.asarray(n)
+        assert w.shape == (32, 5) and n.shape == (32,)
+        assert n.dtype == np.int32
+        assert np.all(w[20:] == 0.0) and np.all(n[20:] == 0)
+        assert np.all(n[:20] >= 1) and np.all(np.abs(w[:20]).max(axis=1) > 0)
+
+    @pytest.mark.parametrize("fault", ["zero_column", "duplicated_column"])
+    def test_no_l2_degenerate_column_stays_finite(self, fault):
+        rng = np.random.default_rng(11)
+        block, _, y, wt, off = _dense_block(rng, 24, 40, 6)
+        Xf = np.asarray(block.X).copy()
+        if fault == "zero_column":
+            Xf[:, :, 2] = 0.0
+        else:
+            Xf[:, :, 4] = Xf[:, :, 1]
+        block = dataclasses.replace(block, X=jnp.asarray(Xf))
+        w = np.asarray(_smooth_solver()(
+            block, jnp.asarray(off), jnp.zeros((24, 6), jnp.float32),
+            jnp.asarray(0.0), jnp.asarray(0.0)))
+        assert np.all(np.isfinite(w))
+        # ... and still a stationary point of the unregularised objective
+        # (the degenerate direction carries no gradient).
+        _, g = _logistic_grad64(
+            Xf.astype(np.float64), y, wt, off, 0.0, w.astype(np.float64))
+        _, g0 = _logistic_grad64(
+            Xf.astype(np.float64), y, wt, off, 0.0, np.zeros_like(g))
+        assert np.all(np.linalg.norm(g, axis=1)
+                      <= 1e-3 * np.maximum(1.0, np.linalg.norm(g0, axis=1)))
+
+    @pytest.mark.parametrize("l2,floored", [(1e-6, True), (1e-4, False)])
+    def test_small_l2_duplicated_column(self, l2, floored):
+        """A duplicated column under an L2 so small that the second twin's
+        pivot (~2 l2 of a diagonal entry of ~8) is at the floor: that
+        component keeps its start value on every trip, the others reach
+        their stationary point, and the lane freezes or counts to the cap
+        by what is left of the twin's gradient.  A hundred times the L2
+        and the pivot is sound: the twins share the weight evenly, as the
+        penalty asks."""
+        rng = np.random.default_rng(13)
+        block, _, y, wt, off = _dense_block(rng, 24, 40, 6)
+        Xf = np.asarray(block.X).copy()
+        Xf[:, :, 4] = Xf[:, :, 1]
+        block = dataclasses.replace(block, X=jnp.asarray(Xf))
+        w0 = np.zeros((24, 6), np.float32)
+        w0[:, 4] = 0.5
+        w, n = _smooth_solver().counted(
+            block, jnp.asarray(off), jnp.asarray(w0), jnp.asarray(0.0),
+            jnp.asarray(l2, jnp.float32))
+        w, n = np.asarray(w), np.asarray(n)
+        assert np.all(np.isfinite(w)) and np.all((1 <= n) & (n <= 30))
+        X64 = Xf.astype(np.float64)
+        _, g = _logistic_grad64(X64, y, wt, off, l2, w.astype(np.float64))
+        _, g0 = _logistic_grad64(X64, y, wt, off, l2, w0.astype(np.float64))
+        scale = np.maximum(1.0, np.linalg.norm(g0, axis=1))
+        others = [0, 1, 2, 3, 5]
+        assert np.all(np.linalg.norm(g[:, others], axis=1) <= 1e-5 * scale)
+        if floored:
+            assert np.all(w[:, 4] == 0.5)
+            assert np.max(np.abs(w[:, 1] - w[:, 4])) > 0.1
+            assert n.max() == 30        # some lane's test stays open
+        else:
+            assert np.max(np.abs(w[:, 1] - w[:, 4])) <= 1e-5
+            assert np.all(np.abs(g[:, 4]) <= 1e-5 * scale)
+            assert n.max() < 30
+
+    @pytest.mark.parametrize("dim", [2, 9, 21, 32])
+    def test_direct_solve_matches_numpy(self, dim):
+        from photon_ml_tpu.game.coordinates import _spd_solve_direct
+
+        rng = np.random.default_rng(dim)
+        A = rng.normal(size=(300, dim, 2 * dim))
+        H = A @ A.transpose(0, 2, 1) / (2 * dim) + np.eye(dim)
+        g = rng.normal(size=(300, dim))
+        ref = np.linalg.solve(H, g[:, :, None])[:, :, 0]
+        got = np.asarray(_spd_solve_direct(
+            jnp.asarray(H.transpose(1, 2, 0), jnp.float32),
+            jnp.asarray(g.T, jnp.float32))).T
+        assert got.shape == (300, dim)
+        assert np.max(np.abs(got - ref)) <= 1e-5 * np.max(np.abs(ref))
+
+    def test_direct_solve_padding_lane_is_exact_zero(self):
+        from photon_ml_tpu.game.coordinates import _spd_solve_direct
+
+        for l2 in (0.0, 1.0, 3.7):
+            H = jnp.broadcast_to(
+                l2 * jnp.eye(5, dtype=jnp.float32)[:, :, None], (5, 5, 9))
+            x = np.asarray(_spd_solve_direct(H, jnp.zeros((5, 9))))
+            assert np.all(x == 0.0)
+
+    @pytest.mark.parametrize("rows,dim,l1,optimizer,expected", [
+        (1, 4, False, "LBFGS", "rank1"),
+        (6, 1, False, "LBFGS", "dim1"),
+        (6, 4, False, "LBFGS", "newton_direct"),
+        (6, 32, False, "TRON", "newton_direct"),
+        (6, 33, False, "LBFGS", "lbfgs"),
+        (6, 33, False, "TRON", "tron"),
+        (6, 4, True, "LBFGS", "owlqn"),
+        (1, 4, True, "LBFGS", "owlqn"),
+    ])
+    def test_solver_path_by_shape(self, rows, dim, l1, optimizer, expected):
+        from photon_ml_tpu.game.coordinates import _make_block_solver
+        from photon_ml_tpu.optim.problem import OptimizerType
+
+        solver = _make_block_solver("logistic", GlmOptimizationConfig(
+            optimizer=OptimizerConfig(
+                optimizer=OptimizerType[optimizer], max_iters=5),
+            regularization=(RegularizationContext.l1() if l1
+                            else RegularizationContext.l2()),
+        ))
+        block, *_ = _dense_block(np.random.default_rng(0), 4, rows, dim)
+        assert solver.path(block) == expected
+        w, n = solver.counted(
+            block, jnp.zeros((4, rows), jnp.float32),
+            jnp.zeros((4, dim), jnp.float32), jnp.asarray(0.1 * l1),
+            jnp.asarray(1.0))
+        assert w.shape == (4, dim) and n.shape == (4,)
+
+    def test_buckets_name_their_solver(self, rng):
+        """Every entry of the train span's ``buckets`` says which path its
+        block took, from the shape test ``solve_block`` makes."""
+        from photon_ml_tpu.game.coordinates import RandomEffectCoordinate
+
+        users, rows = [], []
+        for u, k in enumerate([1] * 6 + [3] * 5 + [9] * 4):
+            users += [f"u{u}"] * k
+            rows.append(k)
+        n = len(users)
+        X = sp.csr_matrix(rng.normal(size=(n, 3)).astype(np.float32))
+        y = (rng.uniform(size=n) < 0.5).astype(np.float32)
+        ds = build_random_effect_dataset(
+            np.array(users, dtype=object), X, y, np.ones(n, np.float32))
+        coord = RandomEffectCoordinate(
+            "per_user", ds, "logistic", GlmOptimizationConfig(
+                optimizer=OptimizerConfig(max_iters=10),
+                regularization=RegularizationContext.l2()),
+            reg_weight=1.0)
+        coord.train(jnp.zeros((n,), jnp.float32))
+        buckets = coord.train_counts()["buckets"]
+        assert len(buckets) == len(ds.blocks) >= 2
+        for entry, block in zip(buckets, ds.blocks):
+            want = "rank1" if block.rows_per_entity == 1 else "newton_direct"
+            assert entry["solver"] == want
+            assert entry["dim"] == block.block_dim
+
+    @pytest.mark.parametrize("R,n_products", [(32, 3), (256, 4)])
+    def test_body_makes_no_pass_over_the_hessian(self, R, n_products):
+        """Structural guard: in one Newton body at (E, D, R) = (256, 21, 32)
+        the only products are the ones with X (margin, gradient, damp; at
+        256 rows an entity also the Hessian build, which short-row blocks
+        make as elementwise pairs); none has the (., D, D) Hessian for an
+        operand, so the solve makes no matvec pass."""
+        import jax
+
+        E, D = 256, 21
+        block, *_rest, off = _dense_block(np.random.default_rng(0), E, R, D)
+        solver = _smooth_solver()
+        jaxpr = jax.make_jaxpr(solver.counted)(
+            block, jnp.asarray(off), jnp.zeros((E, D), jnp.float32),
+            jnp.asarray(0.0), jnp.asarray(1.0))
+
+        def subjaxprs(eqn):
+            for v in eqn.params.values():
+                for c in (v if isinstance(v, (list, tuple)) else [v]):
+                    inner = getattr(c, "jaxpr", c)
+                    if hasattr(inner, "eqns"):
+                        yield inner
+
+        def walk(jp, inside_while, found):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "dot_general" and inside_while:
+                    found.append([tuple(v.aval.shape) for v in eqn.invars])
+                if eqn.primitive.name == "while":
+                    walk(eqn.params["body_jaxpr"].jaxpr, True, found)
+                    continue
+                for inner in subjaxprs(eqn):
+                    walk(inner, inside_while, found)
+            return found
+
+        products = walk(jaxpr.jaxpr, False, [])
+        # the outer Newton loop's own; the solve's loops add none
+        assert len(products) == n_products, products
+        for shapes in products:
+            for shape in shapes:
+                assert sorted(shape) != sorted((E, D, D)), products
+            assert any(sorted(s) == sorted((E, R, D)) for s in shapes)
+
+
 class TestDeferredNormFlush:
     """The CD loop defers score_norm readbacks to ONE end-of-run sync when
     nothing needs per-iteration values (game/descent.py flush) — history
@@ -899,10 +1168,15 @@ class TestPartialRetraining:
         assert {h["coordinate"] for h in hist2} == {"fixed"}
         assert len(hist2) == 2
 
-    def test_locked_matches_manual_offsets(self, rng):
+    @pytest.mark.parametrize("seed", [20260729, 2, 4])
+    def test_locked_matches_manual_offsets(self, seed):
         """Training fixed against a locked per_user must equal training
-        fixed alone with per_user's scores as base offsets."""
-        prob = _mixed_effects_problem(rng, n_users=15)
+        fixed alone with per_user's scores as base offsets: both reach the
+        float64 optimum of that one problem as nearly as float32 can."""
+        import scipy.optimize
+
+        prob = _mixed_effects_problem(
+            np.random.default_rng(seed), n_users=15)
         est, base_model, _ = self._fit(prob)
         _, model_locked, _ = self._fit(
             prob, initial_model=base_model,
@@ -937,7 +1211,36 @@ class TestPartialRetraining:
         w_manual = np.asarray(
             model_manual.models["fixed"].model.coefficients.means
         )
-        np.testing.assert_allclose(w_locked, w_manual, rtol=2e-4, atol=2e-5)
+
+        X = prob["shards"]["global"].toarray().astype(np.float64)
+        y = prob["response"].astype(np.float64)
+        off = user_scores.astype(np.float64)
+
+        def objective(w):
+            m = X @ w + off
+            value = np.sum(np.logaddexp(0.0, m) - y * m) + 0.5 * w @ w
+            return value, X.T @ (1.0 / (1.0 + np.exp(-m)) - y) + w
+
+        best = scipy.optimize.minimize(
+            objective, np.zeros(X.shape[1]), jac=True, method="L-BFGS-B",
+            options=dict(gtol=1e-12, ftol=1e-16, maxiter=1000))
+        # Each side is a float32 L-BFGS solve that stops where its value
+        # repeats, so each is held to the float64 optimum's VALUE within 4
+        # float32 ulps (measured: at most 1.1, over these seeds and 1, 3,
+        # 5, on the parent and with the direct Newton solve).  Offsets
+        # wired wrongly move the value by 1e-3 of itself.
+        for w in (w_locked, w_manual):
+            gap = objective(w.astype(np.float64))[0] - best.fun
+            assert abs(gap) <= 4 * np.finfo(np.float32).eps * best.fun
+        # A value that flat leaves the coefficients 1e-4 to 7e-4 apart,
+        # from one seed to the next and from one start to another: the
+        # old bound here (rtol 2e-4, atol 2e-5) was inside that.  The
+        # parent commit, with this test as it was, passes at the fixture's
+        # seed (largest excess over the bound -5.1e-5) and at 1 and 5,
+        # and fails at 2 (+1.3e-6), 3 (+3.2e-5) and 4 (+5.4e-4, the sides
+        # 6.7e-4 apart); a stop at 1e-9 or 1e-12 and TRON land no nearer.
+        # Offsets wired wrongly move the coefficients by 1e-1.
+        np.testing.assert_allclose(w_locked, w_manual, rtol=1e-3, atol=1e-3)
 
     def test_locked_requires_initial_model(self, rng):
         prob = _mixed_effects_problem(rng, n_users=15)

@@ -1,7 +1,10 @@
 """The tile kernel's names in a program compiled for the chip: a described
 ``v5e:2x2`` (nothing attached, nothing runs).  ``pl.pallas_call(name=...)``
 renames the HLO instruction, and ``benchmarks/trace.py`` finds the kernel's
-device events by that name, so the name is part of the yardstick.
+device events by that name, so the name is part of the yardstick.  Beside
+them, what else only the chip's compiler can say: that the dense stripes stay
+bandwidth-bound fusions, and that the random effect's Newton body fuses its
+pairs Hessian.
 
 The topology is described in a module-scoped fixture and nowhere at import:
 only one process may load the TPU's library, and every xdist worker imports
@@ -155,3 +158,50 @@ def test_stripe_products_are_fusions(one_chip, form, squared):
     assert " convolution(" not in text and "bf16[" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < (
         STRIPES * LONG_AXIS * 4 // 8)
+
+
+# The Newton body of a random effect's block at the short-row shapes of the
+# two GAME cells' ladders (lanes, rows, columns; stored rows-minor as the
+# chip stores them).  There the Hessian is built as elementwise pairs, whose
+# product is (E, R, D, D) before its reduction: 4.3 GB at 38,069 x 64 x 21.
+# The compiler has to fuse the two, so the program holds no temporary of
+# anything like that size, and no matrix-unit product at all; one bucket up
+# (128 rows of 21 columns) the Hessian is the one batched matmul.
+@pytest.mark.parametrize("lanes, rows, dim, by_pairs", [
+    (10_548, 32, 21, True), (38_069, 64, 21, True), (6_266, 256, 9, True),
+    (34_126, 128, 21, False),
+])
+def test_newton_body_holds_no_pairs_product(one_chip, lanes, rows, dim,
+                                            by_pairs):
+    from photon_ml_tpu.game.coordinates import _make_block_solver
+    from photon_ml_tpu.game.data import EntityBlock
+    from photon_ml_tpu.optim.problem import (
+        GlmOptimizationConfig,
+        OptimizerConfig,
+    )
+    from photon_ml_tpu.optim.regularization import RegularizationContext
+
+    def struct(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    block = EntityBlock(
+        X=struct(lanes, dim, rows), labels=struct(lanes, rows),
+        weights=struct(lanes, rows),
+        col_map=struct(lanes, dim, dtype=jnp.int32),
+        row_index=struct(lanes, rows, dtype=jnp.int32),
+        n_entities=lanes, rows_per_entity=rows, block_dim=dim, x_minor="r")
+    solver = _make_block_solver("logistic", GlmOptimizationConfig(
+        optimizer=OptimizerConfig(max_iters=30, tolerance=1e-7),
+        regularization=RegularizationContext.l2()))
+    assert solver.path(block) == "newton_direct"
+    with jax.enable_x64(False):
+        compiled = solver.counted.lower(
+            block, struct(lanes, rows), struct(lanes, dim), struct(),
+            struct()).compile()
+    products = compiled.as_text().count(" convolution(")
+    if by_pairs:
+        assert products == 0
+        assert compiled.memory_analysis().temp_size_in_bytes < (
+            lanes * rows * dim * dim * 4 // 16)
+    else:
+        assert products == 1
