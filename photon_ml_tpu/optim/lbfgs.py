@@ -70,8 +70,17 @@ class SolveResult(NamedTuple):
     stalled: Array | None = None
     # Objective (value+gradient) evaluations the solve made, the starting
     # one included, counted inside the solve; None for solvers that do not
-    # count them.  ``lbfgs_solve`` reports it.
+    # count them.  ``lbfgs_solve`` and ``tron_solve`` report it.
     fn_evals: Array | None = None
+    # What a trust-region Newton solve counted in its loops' states
+    # (``tron_solve``); None for every other solver.  ``cg_iterations``:
+    # Steihaug CG steps over all outer iterations, which is also the count
+    # of Hessian-vector products (one per CG step); ``rejected_steps``:
+    # outer iterations whose step was refused (rho <= eta0);
+    # ``boundary_exits``: CG runs that ended on the trust region's boundary.
+    cg_iterations: Array | None = None
+    rejected_steps: Array | None = None
+    boundary_exits: Array | None = None
 
 
 class _LBFGSState(NamedTuple):

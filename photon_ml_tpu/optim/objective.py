@@ -113,8 +113,9 @@ class GlmObjective:
     def d2_weights(self, w: Array, data: GlmData) -> Array:
         """``weight ⊙ d2(m, y)`` — compute once per outer iterate and pass to
         :meth:`raw_hvp`/:meth:`hvp` so each CG step costs two matvecs, not three."""
-        m = self.margins(w, data)
-        return data.weights * self.loss.d2(m, data.labels)
+        with jax.named_scope("objective.d2_weights"):
+            m = self.margins(w, data)
+            return data.weights * self.loss.d2(m, data.labels)
 
     def raw_hvp(
         self, w: Array, v: Array, data: GlmData, d2w: Array | None = None
@@ -153,10 +154,11 @@ class GlmObjective:
         axis_name: str | None = None,
         d2w: Array | None = None,
     ) -> Array:
-        h = self.raw_hvp(w, v, data, d2w)
-        if axis_name is not None:
-            h = lax.psum(h, axis_name)
-        return h + l2_weight * v
+        with jax.named_scope("objective.hvp"):
+            h = self.raw_hvp(w, v, data, d2w)
+            if axis_name is not None:
+                h = lax.psum(h, axis_name)
+            return h + l2_weight * v
 
     # -- scoring -----------------------------------------------------------
     def mean(self, w: Array, data: GlmData) -> Array:

@@ -34,6 +34,9 @@ from photon_ml_tpu.optim.regularization import RegularizationContext
 
 Array = jax.Array
 
+# SolveResult's fields that only a trust-region Newton solve fills.
+_TRON_COUNTS = ("cg_iterations", "rejected_steps", "boundary_exits")
+
 
 class OptimizerType(enum.Enum):
     LBFGS = "lbfgs"
@@ -287,11 +290,17 @@ class GlmOptimizationProblem:
                         # and the read-back below waits for nothing (a
                         # copy asked for only then idles the device ~1 ms
                         # a solve on a TPU v5e).
-                        counts = jax.copy_to_host_async(
-                            (res.iterations, res.fn_evals, res.converged))
+                        counts = (res.iterations, res.fn_evals, res.converged)
+                        if res.cg_iterations is not None:
+                            # a trust-region Newton solve's own counts ride
+                            # along; every other solve reads what it read
+                            counts += tuple(
+                                getattr(res, k) for k in _TRON_COUNTS)
+                        counts = jax.copy_to_host_async(counts)
                         jax.block_until_ready(res.w)
                         wall = sp.stop()
-                        iters, fn_evals, converged = jax.device_get(counts)
+                        iters, fn_evals, converged, *extras = jax.device_get(
+                            counts)
                         iters = int(iters)
                         sp.set(
                             iterations=iters,
@@ -303,6 +312,11 @@ class GlmOptimizationProblem:
                         if fn_evals is not None:
                             sp.set(fn_evals=int(fn_evals))
                             tel.counter("solver_fn_evals").inc(int(fn_evals))
+                        if extras:
+                            extras = dict(zip(_TRON_COUNTS, map(int, extras)))
+                            sp.set(**extras)
+                            tel.counter("solver_cg_iterations").inc(
+                                extras["cg_iterations"])
                     self.grid_wall_seconds[lam] = wall
                     w = res.w
                     if on_solved is not None:

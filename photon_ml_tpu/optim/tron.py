@@ -72,13 +72,14 @@ def _steihaug_cg(
     max_iters: int,
     tol: Array,
     w_axis: Optional[str] = None,
-) -> tuple[Array, Array, Array]:
+) -> tuple[Array, Array, Array, Array]:
     """Approximately minimize g·s + ½ sᵀHs subject to ‖s‖ ≤ delta.
 
-    Returns (s, r, hit_boundary) with r = -g - H·s the final residual
-    (kept consistent with s even on boundary exits, so sᵀHs is recoverable
-    without another HVP).  Negative-curvature and radius-crossing cases move
-    to the trust-region boundary along the current direction.
+    Returns (s, r, hit_boundary, steps) with r = -g - H·s the final
+    residual (kept consistent with s even on boundary exits, so sᵀHs is
+    recoverable without another HVP) and ``steps`` the CG iterations made,
+    one Hessian-vector product each.  Negative-curvature and radius-crossing
+    cases move to the trust-region boundary along the current direction.
     """
     d = g.shape[0]
     dtype = g.dtype
@@ -140,8 +141,9 @@ def _steihaug_cg(
             hit_boundary=jnp.logical_or(c.hit_boundary, take_boundary),
         )
 
-    final = lax.while_loop(cond, body, init)
-    return final.s, final.r, final.hit_boundary
+    with jax.named_scope("tron.cg"):
+        final = lax.while_loop(cond, body, init)
+    return final.s, final.r, final.hit_boundary, final.i
 
 
 class _TRONState(NamedTuple):
@@ -155,6 +157,10 @@ class _TRONState(NamedTuple):
     converged: Array
     values: Array
     grad_norms: Array
+    # Counted per solve (SolveResult's TRON-only fields).
+    cg_iterations: Array
+    rejected_steps: Array
+    boundary_exits: Array
 
 
 def tron_solve(
@@ -197,6 +203,9 @@ def tron_solve(
         converged=g0_norm <= config.tolerance * tol_scale,
         values=values0,
         grad_norms=gnorms0,
+        cg_iterations=jnp.asarray(0, jnp.int32),
+        rejected_steps=jnp.asarray(0, jnp.int32),
+        boundary_exits=jnp.asarray(0, jnp.int32),
     )
 
     def cond(s: _TRONState):
@@ -204,7 +213,7 @@ def tron_solve(
 
     def body(s: _TRONState):
         cg_tol = config.cg_tol * pnorm(s.grad, w_axis)
-        step, residual, _ = _steihaug_cg(
+        step, residual, hit_boundary, cg_steps = _steihaug_cg(
             lambda v: hvp_fn(s.w, v, s.aux),
             s.grad,
             s.delta,
@@ -271,6 +280,9 @@ def tron_solve(
             converged=converged,
             values=s.values.at[k].set(f_new.astype(s.values.dtype)),
             grad_norms=s.grad_norms.at[k].set(g_norm),
+            cg_iterations=s.cg_iterations + cg_steps,
+            rejected_steps=s.rejected_steps + (~accept).astype(jnp.int32),
+            boundary_exits=s.boundary_exits + hit_boundary.astype(jnp.int32),
         )
 
     final = lax.while_loop(cond, body, init)
@@ -282,4 +294,11 @@ def tron_solve(
         converged=final.converged,
         values=final.values,
         grad_norms=final.grad_norms,
+        # One value+gradient per outer iteration, accepted or not, and the
+        # starting one; one Hessian-vector product per CG step, so
+        # cg_iterations is the count of both.
+        fn_evals=final.k + 1,
+        cg_iterations=final.cg_iterations,
+        rejected_steps=final.rejected_steps,
+        boundary_exits=final.boundary_exits,
     )

@@ -338,7 +338,10 @@ def _make_tp_tron_solver(task: str, mesh: Mesh, config: TRONConfig):
             spmd,
             mesh=mesh,
             in_specs=_TP_IN_SPECS,
-            out_specs=_TP_OUT_SPECS,
+            # tron_solve counts its evaluations and its CG's steps
+            out_specs=_TP_OUT_SPECS._replace(
+                fn_evals=P(), cg_iterations=P(), rejected_steps=P(),
+                boundary_exits=P()),
             check_vma=False,
         )
     )

@@ -205,3 +205,102 @@ def test_newton_body_holds_no_pairs_product(one_chip, lanes, rows, dim,
             lanes * rows * dim * dim * 4 // 16)
     else:
         assert products == 1
+
+
+# The whole trust-region Newton solve of ``glm_tron_fit`` (PERF.md §4): the
+# program ``solve_single_device`` jits, at the cell's layout -- the text
+# cell's grid and depths, 360 column stripes over 804,414 rows, a column
+# permutation -- shapes only.  Two nested ``while`` loops around the two
+# kernel orientations: the chip's compiler has to take it whole (VMEM beside
+# the loops' carries, the device's memory), and the Steihaug CG's body, the
+# inner loop, has to hold one forward and one backward product and nothing
+# of the value+gradient's.  It also shows what the source does not: the
+# forward product that ``tron_solve`` writes twice per trial point is in the
+# program once.
+TRON_ROWS, TRON_COLS, TRON_STRIPES = 804_414, 47_237, 360
+
+
+def _while_bodies(hlo_text):
+    """{computation name: its text} for every ``while`` body of a module."""
+    import re
+
+    bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", hlo_text))
+    blocks, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            name = head.group(1)
+        if name in bodies:
+            blocks[name] = blocks.get(name, "") + line + "\n"
+    return blocks
+
+
+def test_tron_solve_compiles_at_the_cell_layout(monkeypatch, one_chip):
+    import dataclasses
+
+    from photon_ml_tpu.data.dataset import GlmData
+    from photon_ml_tpu.optim.problem import (
+        GlmOptimizationConfig,
+        GlmOptimizationProblem,
+        OptimizerConfig,
+        OptimizerType,
+    )
+    from photon_ml_tpu.optim.regularization import RegularizationContext
+
+    monkeypatch.delenv("PHOTON_PALLAS_INTERPRET", raising=False)
+    g = CELL_GRIDS["glm_lbfgs_fit"]  # the same corpus, the same layout
+
+    def struct(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # a small build gives the placeholders (no spill, no row stripes) and
+    # the flags; the cell's shapes then take the leaves' places
+    rng = np.random.default_rng(0)
+    rows = np.concatenate([rng.integers(0, N_ROWS, NNZ), np.arange(N_ROWS)])
+    cols = np.concatenate([rng.integers(0, N_COLS - 1, NNZ),
+                           np.full(N_ROWS, N_COLS - 1)])  # an intercept
+    small = build_pallas_host(rows, cols, np.ones(len(rows), np.float32),
+                              N_ROWS, N_COLS, unit_values=False)
+    assert small.has_dense_cols and not small.has_dense_rows
+    assert not small.spill.has_spill
+    shapes = jax.tree.map(lambda x: struct(x.shape, x.dtype), small)
+    nbr, nbc = g["nbr"], g["nbc"]
+    layout = dataclasses.replace(
+        shapes,
+        f_code=struct((nbr, nbc, g["a_f"], WIN), CODE_DTYPE),
+        f_val=struct((nbr, nbc, g["a_f"], WIN)),
+        b_code=struct((nbc, nbr, g["a_b"], WIN), CODE_DTYPE),
+        b_val=struct((nbc, nbr, g["a_b"], WIN)),
+        dense_cols=struct((TRON_STRIPES, TRON_ROWS)),
+        dense_col_ids=struct((TRON_STRIPES,), jnp.int32),
+        col_perm_fwd=struct((TRON_COLS,), jnp.int32),
+        col_perm_inv=struct((nbc * TILE_C,), jnp.int32),
+        n_rows=TRON_ROWS, n_cols=TRON_COLS, nbr=nbr, nbc=nbc,
+        a_f=g["a_f"], a_b=g["a_b"], has_col_perm=True)
+    data = GlmData(layout, struct((TRON_ROWS,)), struct((TRON_ROWS,)),
+                   struct((TRON_ROWS,)))
+    problem = GlmOptimizationProblem("logistic", GlmOptimizationConfig(
+        optimizer=OptimizerConfig(optimizer=OptimizerType.TRON, max_iters=10,
+                                  tolerance=0.005),
+        regularization=RegularizationContext.l2()))
+    with jax.enable_x64(False):
+        compiled = jax.jit(
+            lambda d, lam, w0: problem.solve(d, lam, w0)).lower(
+                data, struct(()), struct((TRON_COLS,))).compile()
+    text = compiled.as_text()
+    bodies = _while_bodies(text)
+    assert len(bodies) == 2, sorted(bodies)  # the outer loop and the CG
+    kernels = {name: sorted(c.rpartition(".")[0] for c in _kernel_calls(b))
+               for name, b in bodies.items()}
+    # The CG: one Hessian-vector product, a forward and a backward kernel.
+    # The outer iteration: the trial point's value+gradient, and no third
+    # product: the margins ``d2_weights(w_try)`` asks for again are the
+    # value+gradient's own, two identical calls that the compiler merges
+    # into one (so is the pair at the solve's start, outside both loops).
+    pair = ["_tiled_apply_bwd", "_tiled_apply_fwd"]
+    assert list(kernels.values()) == [pair, pair]
+    assert sorted(c.rpartition(".")[0] for c in _kernel_calls(text)) == (
+        sorted(3 * pair))
+    # the matrix and the stripes are arguments; what the program adds to
+    # them is row and column vectors, far from a second copy of either
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20

@@ -317,15 +317,13 @@ class TestFnEvals:
         assert int(res.fn_evals) == int(res.iterations) + 1 == len(calls)
 
     def test_solvers_that_do_not_count_say_none(self, interpret):
-        from photon_ml_tpu.optim.problem import OptimizerType
-
+        # OWL-QN (any L1 component routes to it) counts nothing yet
         X, y = _corpus(512, 40)
         problem = GlmOptimizationProblem(
             "logistic",
             GlmOptimizationConfig(
-                optimizer=OptimizerConfig(optimizer=OptimizerType.TRON,
-                                          max_iters=3),
-                regularization=RegularizationContext.l2(),
+                optimizer=OptimizerConfig(max_iters=3),
+                regularization=RegularizationContext.l1(),
             ),
         )
         mark = _mark()
@@ -333,8 +331,137 @@ class TestFnEvals:
             make_glm_data(X, y, use_pallas=False), [1.0])
         assert res.fn_evals is None
         (solver,) = _named(_since(mark), "solver")
+        assert solver["attrs"]["optimizer"] == "lbfgs"  # as configured
         assert "fn_evals" not in solver["attrs"]
         assert solver["attrs"]["iterations"] == int(res.iterations)
+
+
+TRON_COUNTS = ("cg_iterations", "rejected_steps", "boundary_exits")
+
+
+def _tron_problem(max_iters=6, tolerance=1e-4):
+    from photon_ml_tpu.optim.problem import OptimizerType
+
+    return GlmOptimizationProblem(
+        "logistic",
+        GlmOptimizationConfig(
+            optimizer=OptimizerConfig(optimizer=OptimizerType.TRON,
+                                      max_iters=max_iters,
+                                      tolerance=tolerance),
+            regularization=RegularizationContext.l2(),
+        ),
+    )
+
+
+class TestTronCounts:
+    """What a trust-region Newton solve counts in its loops' states
+    (``SolveResult.cg_iterations`` and the fields beside it), and where
+    ``grid_loop`` puts it."""
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_equal_independent_counts(self, seed):
+        from photon_ml_tpu.optim.tron import TRONConfig, tron_solve
+
+        rng = np.random.default_rng(seed)
+        A = jnp.asarray(rng.normal(size=(60, 12)), jnp.float32)
+        y = jnp.asarray(rng.uniform(size=60) < 0.5, jnp.float32)
+        vg_calls, hv_calls = [], []
+
+        def f(w):
+            m = A @ w
+            return jnp.sum(jnp.logaddexp(0.0, m) - y * m) + 0.05 * w @ w
+
+        def vg(w):
+            jax.debug.callback(lambda: vg_calls.append(1))
+            return jax.value_and_grad(f)(w)
+
+        def hvp(w, v, aux):
+            jax.debug.callback(lambda: hv_calls.append(1))
+            return A.T @ (aux * (A @ v)) + 0.1 * v
+
+        def d2(w):
+            p = jax.nn.sigmoid(A @ w)
+            return p * (1.0 - p)
+
+        res = jax.jit(lambda w0: tron_solve(
+            vg, hvp, w0, TRONConfig(max_iters=25, tolerance=1e-5),
+            d2_fn=d2))(jnp.asarray(rng.normal(size=12) * 3, jnp.float32))
+        jax.block_until_ready(res)
+        jax.effects_barrier()
+        # (at 1e-5 a float32 solve reaches the plateau where rho is noise
+        # on some seeds and refuses steps: the count below sees them)
+        assert int(res.iterations) >= 2
+        assert int(res.cg_iterations) == len(hv_calls)
+        assert int(res.cg_iterations) >= int(res.iterations)
+        assert int(res.fn_evals) == int(res.iterations) + 1 == len(vg_calls)
+        values = np.asarray(res.values)[:int(res.iterations) + 1]
+        assert int(res.rejected_steps) == int(
+            np.sum(values[1:] == values[:-1]))
+        assert 0 <= int(res.boundary_exits) <= int(res.iterations)
+
+    def test_an_lbfgs_result_has_none_of_them(self):
+        res = lbfgs_solve(lambda w: (w @ w, 2 * w), jnp.ones(4),
+                          LBFGSConfig(max_iters=3))
+        assert res.fn_evals is not None
+        assert [getattr(res, c) for c in TRON_COUNTS] == [None] * 3
+
+    def test_on_the_solver_span_without_a_hub(self, interpret):
+        assert telemetry.current() is telemetry.NULL
+        X, y = _corpus()
+        data = make_glm_data(X, y, use_pallas=True)
+        mark = _mark()
+        results = _tron_problem().run_grid(data, GRID)
+        solvers = _named(_since(mark), "solver")
+        assert len(solvers) == len(GRID)
+        for s, (lam, _model, res) in zip(solvers, results):
+            assert s["attrs"] == {
+                "reg_weight": lam, "optimizer": "tron",
+                "iterations": int(res.iterations),
+                "fn_evals": int(res.iterations) + 1,
+                "converged": bool(res.converged),
+                "wall_seconds": s["dur"],
+                **{c: int(getattr(res, c)) for c in TRON_COUNTS},
+            }
+            assert s["attrs"]["cg_iterations"] >= s["attrs"]["iterations"] > 0
+
+    def test_under_a_hub_with_their_counters(self, interpret, tmp_path):
+        X, y = _corpus()
+        data = make_glm_data(X, y, use_pallas=True)
+        with telemetry.Telemetry(output_dir=str(tmp_path)) as tel:
+            with tel.span("train"):
+                results = _tron_problem().run_grid(data, GRID)
+        with open(os.path.join(tmp_path, "events.jsonl")) as f:
+            records = [json.loads(line) for line in f]
+        solvers = [r for r in records
+                   if r.get("type") == "span" and r["name"] == "solver"]
+        for rec, (_lam, _model, res) in zip(solvers, results):
+            for c in TRON_COUNTS:
+                assert rec["attrs"][c] == int(getattr(res, c))
+        counters = records[-1]["snapshot"]["counters"]
+        assert counters["solver_cg_iterations"] == sum(
+            int(res.cg_iterations) for _l, _m, res in results) > 0
+        assert counters["solver_fn_evals"] == sum(
+            int(res.iterations) + 1 for _l, _m, res in results)
+
+    @pytest.mark.parametrize("optimizer", ["lbfgs", "tron"])
+    def test_one_batched_read_a_solve(self, interpret, monkeypatch,
+                                      optimizer):
+        """The counts ride the one tuple that is queued behind the solve
+        and read once; an L-BFGS solve queues what it always did."""
+        problem = _problem() if optimizer == "lbfgs" else _tron_problem()
+        X, y = _corpus()
+        data = make_glm_data(X, y, use_pallas=True)
+        problem.run_grid(data, GRID)  # compile outside the count
+        queued, read = [], []
+        copy, get = jax.copy_to_host_async, jax.device_get
+        monkeypatch.setattr(jax, "copy_to_host_async",
+                            lambda x: (queued.append(x), copy(x))[1])
+        monkeypatch.setattr(jax, "device_get",
+                            lambda x: (read.append(x), get(x))[1])
+        problem.run_grid(data, GRID)
+        assert len(queued) == len(read) == len(GRID)
+        leaves = 3 if optimizer == "lbfgs" else 3 + len(TRON_COUNTS)
+        assert all(len(jax.tree.leaves(q)) == leaves for q in queued)
 
 
 class TestProfiler:
