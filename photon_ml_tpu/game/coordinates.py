@@ -35,7 +35,12 @@ from photon_ml_tpu.game.data import (
     FixedEffectDataset,
     RandomEffectDataset,
 )
-from photon_ml_tpu.game.model import FixedEffectModel, RandomEffectModel
+from photon_ml_tpu.game.model import (
+    EntityLanes,
+    EntityVariances,
+    FixedEffectModel,
+    RandomEffectModel,
+)
 from photon_ml_tpu.models.glm import Coefficients, GeneralizedLinearModel
 from photon_ml_tpu.ops import losses as losses_lib
 from photon_ml_tpu.optim.lbfgs import LBFGSConfig, lbfgs_solve
@@ -692,23 +697,6 @@ class _BlockSolver:
         return self._coefficients(*args)
 
 
-def pack_entity_tables(cmap: np.ndarray, w: np.ndarray, var=None):
-    """Per-lane (cols, vals[, variances]) lists for the host model table:
-    one bulk mask + ``np.split`` instead of several numpy calls per lane
-    (which cost ~4 s at 100k entities, once per coordinate per fit).
-    Keeps real columns whose coefficient is nonzero — the same
-    keep-then-nonzero filter the per-lane loop applied."""
-    valid = (cmap >= 0) & (w != 0)
-    bounds = np.cumsum(valid.sum(axis=1))[:-1]
-    col_parts = np.split(cmap[valid].astype(np.int32), bounds)
-    val_parts = np.split(w[valid].astype(np.float32), bounds)
-    var_parts = (
-        np.split(np.asarray(var)[valid].astype(np.float32), bounds)
-        if var is not None else None
-    )
-    return col_parts, val_parts, var_parts
-
-
 def _gather_block_offsets(offsets: Array, block: EntityBlock) -> Array:
     """Per-row offsets for one entity block; padding rows (sentinel index)
     read the appended zero slot."""
@@ -904,36 +892,34 @@ class RandomEffectCoordinate(Coordinate):
         diag = jnp.einsum("er,erd->ed", d2w, X * X) + l2
         return np.asarray(1.0 / jnp.maximum(diag, 1e-12))
 
-    def finalize(self, state: list[Array], offsets=None) -> RandomEffectModel:
-        compute_var = (
-            self.config.compute_variances and offsets is not None
+    @functools.cached_property
+    def _lanes(self) -> EntityLanes:
+        """The ladder's constants for the model table (entity keys and
+        ``col_map``s): read to the host and sorted once, not once a fit."""
+        return EntityLanes(
+            self.dataset.entity_ids,
+            [b.col_map for b in self.dataset.blocks],
         )
-        table: dict = {}
-        var_table: dict = {} if compute_var else None
-        for block, ids, coefs in zip(
-            self.dataset.blocks, self.dataset.entity_ids, state
-        ):
-            cmap = np.asarray(block.col_map)
-            w = np.asarray(coefs)
-            var = (
+
+    def finalize(self, state: list[Array], offsets=None) -> RandomEffectModel:
+        """The model table as flat arrays (:class:`EntityTable`): no Python
+        object per entity is made, here or when the old table is freed."""
+        variances = None
+        if self.config.compute_variances and offsets is not None:
+            variances = [
                 self._block_variances(block, coefs, offsets)
-                if compute_var
-                else None
-            )
-            col_parts, val_parts, var_parts = pack_entity_tables(
-                cmap, w, var
-            )
-            for lane, key in enumerate(ids):
-                table[key] = (col_parts[lane], val_parts[lane])
-                if var_parts is not None:
-                    var_table[key] = var_parts[lane]
+                for block, coefs in zip(self.dataset.blocks, state)
+            ]
+        table = self._lanes.table(jax.device_get(list(state)), variances)
         return RandomEffectModel(
             coefficients=table,
             feature_shard=self.feature_shard,
             entity_key=self.entity_key,
             task=self.task,
             n_features=self.dataset.n_features,
-            variances=var_table,
+            variances=(
+                None if variances is None else EntityVariances(table)
+            ),
         )
 
     def make_validation_scorer(self, shards: dict, ids: dict):

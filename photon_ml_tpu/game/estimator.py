@@ -32,7 +32,12 @@ from photon_ml_tpu.game.data import (
     build_random_effect_dataset,
 )
 from photon_ml_tpu.game.descent import CoordinateDescent
-from photon_ml_tpu.game.model import FixedEffectModel, GameModel, RandomEffectModel
+from photon_ml_tpu.game.model import (
+    EntityTable,
+    FixedEffectModel,
+    GameModel,
+    RandomEffectModel,
+)
 from photon_ml_tpu.ops import losses as losses_lib
 from photon_ml_tpu.optim.problem import GlmOptimizationConfig
 from photon_ml_tpu.telemetry import layer_span
@@ -955,7 +960,21 @@ class GameEstimator:
                 if total_np is not None
                 else None
             )
-            models[c.name] = c.finalize(result.states[c.name], offsets=off_c)
+            # A layer span (docs/telemetry.md): the host works alone here,
+            # after the flush's read and before the next fit's first
+            # program.
+            with layer_span(
+                "coordinate.finalize", coordinate=c.name, kind=c.kind
+            ) as span:
+                sub = c.finalize(result.states[c.name], offsets=off_c)
+                if isinstance(sub, RandomEffectModel):
+                    span.set(
+                        entities=sub.n_entities,
+                        table="arrays"
+                        if isinstance(sub.coefficients, EntityTable)
+                        else "dict",
+                    )
+            models[c.name] = sub
         return GameModel(models=models, task=self.task), result.history
 
     def fit_grid(

@@ -47,10 +47,9 @@ from photon_ml_tpu.game.coordinates import (
     Coordinate,
     _gather_block_offsets,
     _make_block_solver,
-    pack_entity_tables,
 )
 from photon_ml_tpu.game.data import EntityBlock, RandomEffectDataset
-from photon_ml_tpu.game.model import RandomEffectModel
+from photon_ml_tpu.game.model import EntityLanes, RandomEffectModel
 from photon_ml_tpu.ops import losses as losses_lib
 from photon_ml_tpu.optim.lbfgs import LBFGSConfig, lbfgs_solve
 from photon_ml_tpu.optim.problem import GlmOptimizationConfig
@@ -278,16 +277,10 @@ def finalize_factored_model(coord, state) -> RandomEffectModel:
     through the factorization (w_e is a deterministic function of the
     joint (U, V) fit), so none are produced — matching the reference,
     which computes variances only for unfactored coordinates."""
-    table: dict = {}
-    for block, ids, coefs in zip(
-        coord.dataset.blocks, coord.dataset.entity_ids,
-        coord.materialize(state),
-    ):
-        col_parts, val_parts, _ = pack_entity_tables(
-            np.asarray(block.col_map), np.asarray(coefs)
-        )
-        for lane, key in enumerate(ids):
-            table[key] = (col_parts[lane], val_parts[lane])
+    table = EntityLanes(
+        coord.dataset.entity_ids,
+        [b.col_map for b in coord.dataset.blocks],
+    ).table(jax.device_get(list(coord.materialize(state))))
     return RandomEffectModel(
         coefficients=table,
         feature_shard=coord.feature_shard,

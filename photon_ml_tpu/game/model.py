@@ -10,8 +10,9 @@ table here, materialized into dense device blocks when scoring).
 
 from __future__ import annotations
 
+import collections.abc
 import dataclasses
-from typing import Optional
+from typing import Mapping, Optional
 
 import numpy as np
 
@@ -26,27 +27,187 @@ class FixedEffectModel:
     feature_shard: str
 
 
+def _frozen(a) -> np.ndarray:
+    """A read-only view of ``a`` (the caller's own array stays as it was)."""
+    view = np.asarray(a).view()
+    view.flags.writeable = False
+    return view
+
+
+class EntityTable(collections.abc.Mapping):
+    """Read-only entity key → ``(cols, vals)`` over flat arrays: the table
+    a trained random effect holds, with no Python object per entity.
+
+    ``ids`` are the entity keys, sorted and distinct; entity ``i`` owns
+    ``cols[starts[i]:starts[i + 1]]`` (int32, ascending) and the ``vals``
+    (float32) and optional ``variances`` (float32) beside them.  A lookup
+    is one binary search and returns views; iteration is in ``ids``' order.
+    """
+
+    __slots__ = ("ids", "starts", "cols", "vals", "variances")
+
+    def __init__(self, ids, starts, cols, vals, variances=None):
+        if len(starts) != len(ids) + 1 or len(cols) != len(vals):
+            raise ValueError(
+                f"entity table of {len(ids)} keys, {len(starts)} starts, "
+                f"{len(cols)} columns and {len(vals)} values")
+        self.ids, self.starts = _frozen(ids), _frozen(starts)
+        self.cols, self.vals = _frozen(cols), _frozen(vals)
+        self.variances = None if variances is None else _frozen(variances)
+
+    def bounds(self, key) -> Optional[tuple[int, int]]:
+        """``(lo, hi)`` of ``key``'s entries in the flat arrays, or None."""
+        if np.ndim(key) != 0 or not len(self.ids):
+            return None
+        try:
+            i = int(np.searchsorted(self.ids, key))
+        except (TypeError, ValueError):
+            return None
+        if i == len(self.ids) or not self.ids[i] == key:
+            return None
+        return int(self.starts[i]), int(self.starts[i + 1])
+
+    def __getitem__(self, key):
+        found = self.bounds(key)
+        if found is None:
+            raise KeyError(key)
+        return self.cols[found[0]:found[1]], self.vals[found[0]:found[1]]
+
+    def __contains__(self, key) -> bool:
+        return self.bounds(key) is not None
+
+    def __len__(self) -> int:
+        return len(self.ids)
+
+    def __iter__(self):
+        return iter(self.ids)
+
+    def items(self):
+        return _EntityItems(self)
+
+
+class EntityLanes:
+    """What the table of a bucket ladder needs that no fit changes: lane
+    ``e`` of bucket ``b`` is entity ``entity_ids[b][e]`` and holds a
+    coefficient for column ``col_maps[b][e, k]`` where that is ``>= 0``;
+    lanes past a bucket's ids are padding.  Built once (one stable sort of
+    the keys across buckets; a key met twice keeps its last lane, as a
+    dict filled in lane order would), it holds the sorted keys, every real
+    column in the table's order and where its coefficient lies in the
+    buckets' concatenated ``(E, D)`` arrays.  :meth:`table` is then one
+    gather of the values, whole-array operations only."""
+
+    def __init__(self, entity_ids, col_maps):
+        self._lane_counts = [len(ids) for ids in entity_ids]
+        real = [b for b, n in enumerate(self._lane_counts) if n]
+        if not real:
+            self._table = (np.empty(0, object), np.zeros(1, np.int64),
+                           np.empty(0, np.int32))
+            self._source = np.empty(0, np.int64)
+            return
+        ids = np.concatenate([np.asarray(entity_ids[b]) for b in real])
+        cmaps = [np.asarray(col_maps[b])[:self._lane_counts[b]]
+                 for b in real]
+        flat_cols = np.concatenate([c.ravel() for c in cmaps])
+        counts = np.concatenate([(c >= 0).sum(axis=1) for c in cmaps])
+        order = np.argsort(ids, kind="stable")
+        sorted_ids = ids[order]
+        order = order[np.append(sorted_ids[1:] != sorted_ids[:-1], True)]
+        # Every real column, lane by lane; then the lanes in key order.
+        by_lane = np.flatnonzero(flat_cols >= 0)
+        lane_starts = np.cumsum(counts) - counts
+        counts = counts[order]
+        starts = np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
+        gather = np.repeat(lane_starts[order] - starts[:-1], counts)
+        gather += np.arange(starts[-1])
+        self._source = by_lane[gather]
+        self._table = (ids[order], starts,
+                       flat_cols[self._source].astype(np.int32, copy=False))
+
+    def _values(self, blocks) -> np.ndarray:
+        """``blocks[b][e, k]`` beside each of the table's columns."""
+        flat = [np.asarray(blocks[b], np.float32)[:n].ravel()
+                for b, n in enumerate(self._lane_counts) if n]
+        return np.concatenate(
+            [np.empty(0, np.float32)] + flat).take(self._source)
+
+    def table(self, coefs, variances=None) -> "EntityTable":
+        """The :class:`EntityTable` of ``coefs[b][e, k]`` (and
+        ``variances``, shaped alike): real columns with a nonzero
+        coefficient."""
+        ids, starts, cols = self._table
+        vals = self._values(coefs)
+        var = None if variances is None else self._values(variances)
+        nonzero = vals != 0
+        if not nonzero.all():
+            kept = np.concatenate([[0], np.cumsum(nonzero)])
+            starts, cols, vals = kept[starts], cols[nonzero], vals[nonzero]
+            var = None if var is None else var[nonzero]
+        return EntityTable(ids, starts, cols, vals, var)
+
+
+class _EntityItems(collections.abc.ItemsView):
+    """``EntityTable.items()`` in one pass over ``starts`` (the mixin's
+    would search for every key it has just been handed)."""
+
+    def __iter__(self):
+        t = self._mapping
+        bounds = t.starts.tolist()
+        for i, key in enumerate(t.ids):
+            lo, hi = bounds[i], bounds[i + 1]
+            yield key, (t.cols[lo:hi], t.vals[lo:hi])
+
+
+class EntityVariances(collections.abc.Mapping):
+    """Read-only entity key → float32 variances beside that entity's
+    ``cols``: a view of an :class:`EntityTable`'s ``variances``."""
+
+    __slots__ = ("table",)
+
+    def __init__(self, table: EntityTable):
+        self.table = table
+
+    def __getitem__(self, key):
+        found = self.table.bounds(key)
+        if found is None:
+            raise KeyError(key)
+        return self.table.variances[found[0]:found[1]]
+
+    def __len__(self) -> int:
+        return len(self.table)
+
+    def __iter__(self):
+        return iter(self.table)
+
+
 @dataclasses.dataclass
 class RandomEffectModel:
     """Per-entity GLMs over one feature shard.
 
-    ``coefficients`` maps entity key → (global_cols int32[], values float32[])
-    with columns sorted ascending — the sparse original-space coefficient
-    vector of that entity (the
+    ``coefficients`` is a mapping: entity key → (global_cols int32[],
+    values float32[]) with columns sorted ascending — the sparse
+    original-space coefficient vector of that entity (the
     reference stores per-entity ``Coefficients`` in projected space and
     carries the projector; storing sparse global-space pairs is equivalent
-    and projector-free).  Entities never seen at training time score 0, as
-    in the reference.
+    and projector-free).  It is one of two things: an :class:`EntityTable`
+    (flat arrays; what training's ``finalize`` returns) or a plain mapping
+    such as a ``dict`` (a loaded model, a hand-built one, serving's
+    ``SharedEntityTable``).  Either way it is READ-ONLY once training or
+    load has returned it: readers use ``get`` / ``[]`` / ``in`` / ``len``
+    / iteration / ``items()``, and one that needs to change entries copies
+    first (``dict(model.coefficients)``).  Entities never seen at training
+    time score 0, as in the reference.
     """
 
-    coefficients: dict
+    coefficients: Mapping
     feature_shard: str
     entity_key: str
     task: str
     n_features: int
     #: optional per-entity coefficient variances (reference: Bayesian model
-    #: output) — entity key → float32[] aligned with that entity's ``cols``.
-    variances: Optional[dict] = None
+    #: output) — a mapping, entity key → float32[] aligned with that
+    #: entity's ``cols``; read-only like ``coefficients``.
+    variances: Optional[Mapping] = None
     #: lazily-built packed view for vectorized lookup; the coefficient table
     #: is immutable after training/load, so this never needs invalidation.
     _packed: object = dataclasses.field(
@@ -66,6 +227,18 @@ class RandomEffectModel:
         a single ``searchsorted`` resolves every (lane, local column) pair."""
         if self._packed is not None:
             return self._packed
+        stride = self.n_features + 1
+        table = self.coefficients
+        if isinstance(table, EntityTable):
+            # Already this packing, but for the combined key.
+            ranks = np.repeat(
+                np.arange(len(table), dtype=np.int64), np.diff(table.starts)
+            )
+            self._packed = (
+                table.ids.astype(object), ranks * stride + table.cols,
+                table.vals, stride,
+            )
+            return self._packed
         keys = np.asarray(sorted(self.coefficients), dtype=object)
         sizes = np.array(
             [len(self.coefficients[k][0]) for k in keys], np.int64
@@ -78,7 +251,6 @@ class RandomEffectModel:
             c, v = self.coefficients[k]
             cols[starts[i] : starts[i + 1]] = c
             vals[starts[i] : starts[i + 1]] = v
-        stride = self.n_features + 1
         ranks = np.repeat(np.arange(len(keys), dtype=np.int64), sizes)
         combined = ranks * stride + cols
         self._packed = (keys, combined, vals, stride)

@@ -334,6 +334,17 @@ class TestSpans:
             assert by_id[it["parent"]]["name"] == "cd.fit"
         assert len([r for r in spans if r["name"] == "coordinate.score"]) == 4
 
+    def test_each_coordinate_is_finalized_under_a_span(self, spans):
+        ends = [r for r in spans if r["name"] == "coordinate.finalize"]
+        fit_end = max(r["ts"] + r["dur"] for r in spans
+                      if r["name"] == "cd.fit")
+        assert [(r["attrs"]["coordinate"], r["attrs"]["kind"])
+                for r in ends] == [("fixed", "fixed"), ("per_user", "random")]
+        assert all(r["ts"] >= fit_end for r in ends)
+        assert "table" not in ends[0]["attrs"]
+        assert ends[1]["attrs"]["table"] == "arrays"
+        assert 0 < ends[1]["attrs"]["entities"] <= 150
+
     def test_fixed_update_carries_what_its_solve_counted(self, spans):
         fixed = [r for r in spans if r["name"] == "coordinate.train"
                  and r["attrs"]["kind"] == "fixed"]
