@@ -47,7 +47,7 @@ SUBSYSTEMS = frozenset({
 UNITS = frozenset({
     "total", "seconds", "bytes", "ratio", "gbps", "rows", "ms",
     "count", "entries", "iterations", "retries", "depth", "version",
-    "tier", "rps", "residual", "evals",
+    "tier", "rps", "residual", "evals", "hits", "misses",
 })
 
 #: Pre-convention names (PRs 1-6), grandfathered verbatim.  Do NOT add

@@ -12,6 +12,7 @@ to any weighted sum, which is how all downstream math stays mask-free.
 from __future__ import annotations
 
 import dataclasses
+import time
 from functools import partial
 
 import jax
@@ -20,6 +21,7 @@ import numpy as np
 
 from photon_ml_tpu.ops.sparse import DenseMatrix, FeatureMatrix, from_scipy_csr
 from photon_ml_tpu.telemetry import layer_span
+from photon_ml_tpu.utils.placement import place_leaves
 
 Array = jax.Array
 
@@ -82,7 +84,9 @@ def make_glm_data(
     tiled layout its child ``layout.build``, and everything from the first
     host-to-device copy to the last leaf being ready its child
     ``layout.place`` (docs/telemetry.md "Layer spans"; the COO layout has
-    no build of its own, so its host canonicalisation counts as placing).
+    no build of its own, so its host canonicalisation counts as placing),
+    which says of every leaf what its copy cost the host (``leaves``) and
+    how long the one wait for all of them was (``wait_s``).
     """
     import scipy.sparse as sp
 
@@ -123,12 +127,11 @@ def make_glm_data(
             if use_pallas:
                 from photon_ml_tpu.ops.sparse_pallas import (
                     host_layout_from_scipy_csr,
-                    place_pallas_matrix,
                 )
 
                 features = host_layout_from_scipy_csr(
                     features, pad_nnz=pad_nnz, dtype=dtype)
-                to_device = place_pallas_matrix
+                to_device = None  # the host layout's leaves are placed below
             else:
                 to_device = partial(
                     from_scipy_csr, pad_nnz=pad_nnz, dtype=dtype)
@@ -145,16 +148,22 @@ def make_glm_data(
                 return DenseMatrix(jnp.asarray(dense, dtype=dtype))
 
         with layer_span("layout.place") as place:
-            data = GlmData(
-                features=to_device(features),
-                labels=jnp.asarray(labels),
-                weights=jnp.asarray(weights),
-                offsets=jnp.asarray(offsets),
-            )
-            # Every caller solves on these next; waiting here is what lets
-            # the span say when the data is resident.
+            if to_device is not None:
+                # COO and dense features canonicalise / cast and place in
+                # one call: their leaves arrive below resident already.
+                t0 = time.perf_counter()
+                features = to_device(features)
+                place.set(features_s=time.perf_counter() - t0)
+            data, leaves = place_leaves(GlmData(
+                features=features, labels=labels, weights=weights,
+                offsets=offsets))
+            # Every caller solves on these next; waiting here (the one
+            # sync) is what lets the span say when the data is resident.
+            t0 = time.perf_counter()
             jax.block_until_ready(data)
-            place.set(bytes=sum(x.nbytes for x in jax.tree.leaves(data)))
+            place.set(
+                wait_s=time.perf_counter() - t0,
+                bytes=sum(leaf["bytes"] for leaf in leaves), leaves=leaves)
         span.set(rows=int(target_rows), nnz=nnz,
                  layout=type(data.features).__name__)
     return data

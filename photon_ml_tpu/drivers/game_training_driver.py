@@ -56,7 +56,6 @@ from photon_ml_tpu.ops import losses as losses_lib
 from photon_ml_tpu.utils.compile_cache import (
     add_compile_cache_arg,
     enable_from_args,
-    publish_cache_metrics,
 )
 from photon_ml_tpu.utils.device_report import (
     CompileClock,
@@ -750,7 +749,6 @@ def _run_impl(args, logger, tel, clock) -> dict:
     result["wall_seconds"] = timer.stop()
     with open(os.path.join(args.output_dir, "training_result.json"), "w") as f:
         json.dump(result, f, indent=2)
-    publish_cache_metrics(cache_dir)
     tel.gauge("run_wall_seconds").set(result["wall_seconds"])
     logger.info("GAME training done in %.2fs", result["wall_seconds"])
     return result

@@ -57,7 +57,6 @@ from photon_ml_tpu.optim.regularization import RegularizationContext, Regulariza
 from photon_ml_tpu.utils.compile_cache import (
     add_compile_cache_arg,
     enable_from_args,
-    publish_cache_metrics,
 )
 from photon_ml_tpu.utils.device_report import (
     CompileClock,
@@ -983,7 +982,6 @@ def _run_impl(args, logger, tel, clock) -> dict:
         logger.info("training report: %s", hpath)
     with open(os.path.join(args.output_dir, "training_result.json"), "w") as f:
         json.dump(result, f, indent=2)
-    publish_cache_metrics(cache_dir)
     tel.gauge("run_wall_seconds").set(result["wall_seconds"])
     logger.info(
         "selected lambda=%g (%s=%.6f) in %.2fs",

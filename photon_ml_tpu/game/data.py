@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from functools import partial
 from typing import Optional, Sequence
 
@@ -40,6 +41,7 @@ import numpy as np
 
 from photon_ml_tpu.data.dataset import GlmData
 from photon_ml_tpu.telemetry import layer_span
+from photon_ml_tpu.utils.placement import place_leaves
 
 Array = jax.Array
 
@@ -596,28 +598,42 @@ def build_random_effect_dataset(
             n_global_rows=n_rows, n_features=d, passive_blocks=[],
         )
 
-    def place(cls, fields):
+    leaves: list[dict] = []
+
+    def place(cls, fields, path):
         """One block's (or one bucket's passive rows') host fields as
         ``cls``; the fields pop as they go, so a host array is freed once
-        its device copy is made."""
+        its device copy is made.  What each copy cost the host joins
+        ``leaves`` (the cast of ``X`` counts to its ``dispatch_s``)."""
         arrays = {}
         for key in [k for k, v in fields.items() if isinstance(v, np.ndarray)]:
             value = fields.pop(key)
+            src_dtype = str(value.dtype)
+            t0 = time.perf_counter()
             if key == "X":
                 value = value.astype(dtype, copy=False)
-            arrays[key] = jnp.asarray(value) if device else value
+            if device:
+                value, (leaf,) = place_leaves(value, f"{path}.{key}")
+                leaf.update(src_dtype=src_dtype,
+                            dispatch_s=time.perf_counter() - t0)
+                leaves.append(leaf)
+            arrays[key] = value
         return cls(**arrays, **fields)
 
     with layer_span("game.place", coordinate=name, **counted) as place_span:
-        blocks = [place(EntityBlock, f) for f in host["blocks"]]
+        blocks = [place(EntityBlock, f, f"blocks[{i}]")
+                  for i, f in enumerate(host["blocks"])]
         passive_blocks = [
-            None if f is None else place(PassiveRows, f)
-            for f in host["passive_blocks"]
+            None if f is None else place(PassiveRows, f, f"passive[{i}]")
+            for i, f in enumerate(host["passive_blocks"])
         ]
+        t0 = time.perf_counter()
         if device:
-            jax.block_until_ready((blocks, passive_blocks))
-        place_span.set(bytes=sum(
-            x.nbytes for x in jax.tree.leaves((blocks, passive_blocks))))
+            jax.block_until_ready((blocks, passive_blocks))  # the one sync
+        place_span.set(
+            wait_s=time.perf_counter() - t0, leaves=leaves,
+            bytes=sum(
+                x.nbytes for x in jax.tree.leaves((blocks, passive_blocks))))
 
     padded_flops = int(
         sum(b.n_entities * b.rows_per_entity * b.block_dim for b in blocks)
@@ -679,7 +695,12 @@ def _group_entities(
     """The host half of :func:`build_random_effect_dataset`: numpy fields
     of every block, or ``None`` without rows.  ``tile`` is the device's
     tiling (:func:`_device_tile`), or ``None`` for blocks that stay on the
-    host."""
+    host.
+
+    Six layer spans tile the call, children of the caller's ``game.group``
+    in the order of the code (docs/telemetry.md "Layer spans"):
+    ``game.group.sort``, ``.cap``, ``.gather``, ``.columns``, ``.plan`` and
+    ``.fill``; outside them there are only a few assignments."""
     n_rows, d = rows_csr.shape
 
     # Group rows by entity — FLAT-ARRAY pipeline throughout.  A previous
@@ -690,155 +711,160 @@ def _group_entities(
     # bulk row gather, with per-bucket flat scatters filling the blocks.
     if len(entity_keys) == 0:
         return None
-    order, starts, ent_keys = _sort_by_entity(entity_keys)
-    n_sorted = len(order)
-    ends = np.append(starts[1:], n_sorted)
-    span_sizes = ends - starts
-    n_ent = len(starts)
+    with layer_span("game.group.sort"):
+        order, starts, ent_keys = _sort_by_entity(entity_keys)
+        n_sorted = len(order)
+        ends = np.append(starts[1:], n_sorted)
+        span_sizes = ends - starts
+        n_ent = len(starts)
 
-    # Active-set cap (the reference's split): capped entities keep a
-    # uniformly-spaced row subset, the rest become score-only passive
-    # rows.  keep is over SORTED positions; only capped entities loop.
-    keep = np.ones(n_sorted, bool)
-    if max_rows_per_entity is not None:
-        for g in np.flatnonzero(span_sizes > max_rows_per_entity):
-            m = np.zeros(span_sizes[g], bool)
-            m[np.linspace(
-                0, span_sizes[g] - 1, max_rows_per_entity
-            ).astype(int)] = True
-            keep[starts[g]:ends[g]] = m
+    with layer_span("game.group.cap"):
+        # Active-set cap (the reference's split): capped entities keep a
+        # uniformly-spaced row subset, the rest become score-only passive
+        # rows.  keep is over SORTED positions; only capped entities loop.
+        keep = np.ones(n_sorted, bool)
+        if max_rows_per_entity is not None:
+            for g in np.flatnonzero(span_sizes > max_rows_per_entity):
+                m = np.zeros(span_sizes[g], bool)
+                m[np.linspace(
+                    0, span_sizes[g] - 1, max_rows_per_entity
+                ).astype(int)] = True
+                keep[starts[g]:ends[g]] = m
 
-    # Index arrays of the rows' and the entries' size are the grouping's
-    # memory: 4 bytes each wherever the counts allow.
-    index_t = np.int32 if n_sorted < (1 << 31) else np.int64
-    ent_of_pos = np.repeat(np.arange(n_ent, dtype=index_t), span_sizes)
-    # Local row index within the entity's kept (resp. passive) rows.
-    kept_counts = np.bincount(ent_of_pos, weights=keep, minlength=n_ent
-                              ).astype(np.int64)
-    kept_before = np.concatenate([[0], np.cumsum(kept_counts)[:-1]])
-    local_kept = (
-        (np.cumsum(keep) - 1) - kept_before[ent_of_pos]).astype(index_t)
-    psv_counts = span_sizes - kept_counts
-    n_passive = int(psv_counts.sum())
-    local_psv = None
-    if n_passive:
-        psv_before = np.concatenate([[0], np.cumsum(psv_counts)[:-1]])
-        local_psv = (
-            (np.cumsum(~keep) - 1) - psv_before[ent_of_pos]).astype(index_t)
+        # Index arrays of the rows' and the entries' size are the grouping's
+        # memory: 4 bytes each wherever the counts allow.
+        index_t = np.int32 if n_sorted < (1 << 31) else np.int64
+        ent_of_pos = np.repeat(np.arange(n_ent, dtype=index_t), span_sizes)
+        # Local row index within the entity's kept (resp. passive) rows.
+        kept_counts = np.bincount(ent_of_pos, weights=keep, minlength=n_ent
+                                  ).astype(np.int64)
+        kept_before = np.concatenate([[0], np.cumsum(kept_counts)[:-1]])
+        local_kept = (
+            (np.cumsum(keep) - 1) - kept_before[ent_of_pos]).astype(index_t)
+        psv_counts = span_sizes - kept_counts
+        n_passive = int(psv_counts.sum())
+        local_psv = None
+        if n_passive:
+            psv_before = np.concatenate([[0], np.cumsum(psv_counts)[:-1]])
+            local_psv = ((np.cumsum(~keep) - 1)
+                         - psv_before[ent_of_pos]).astype(index_t)
 
-    sorted_csr = rows_csr[order]  # one bulk row gather
-    indptr = sorted_csr.indptr.astype(np.int64)
-    nnz_per_row = np.diff(indptr)
+    with layer_span("game.group.gather"):
+        sorted_csr = rows_csr[order]  # one bulk row gather
+        indptr = sorted_csr.indptr.astype(np.int64)
+        nnz_per_row = np.diff(indptr)
 
-    # Per-entity ACTIVE columns (from kept rows only, as the reference's
-    # projector sees them): the distinct (entity, column) pairs of the
-    # kept entries, entity-major, so each entity's active columns come out
-    # ascending — the same order np.unique(sub.indices) produced.
-    # ``col_rank[k]`` is entry k's pair's index among them, and
-    # ``col_hit[k]`` whether it is one (every kept entry's is; a passive
-    # entry's only where its entity trained on that column).
-    pair = np.repeat(ent_of_pos, nnz_per_row).astype(np.int64)
-    pair *= d
-    pair += sorted_csr.indices
-    nnz_keep = np.repeat(keep, nnz_per_row)
-    if n_ent * d <= _PAIR_TABLE_CELLS:
-        # Few enough (entity, column) cells for a presence table: one
-        # pass in place of the sort inside np.unique (8 s at 62 M pairs).
-        present = np.zeros(n_ent * d, bool)
-        present[pair[nnz_keep]] = True
-        upair = np.flatnonzero(present)
-        col_rank = (np.cumsum(present, dtype=np.int32) - 1)[pair]
-        col_hit = present[pair] if n_passive else None
-        del present
-    else:
-        upair = np.unique(pair[nnz_keep])
-        col_rank = np.searchsorted(upair, pair)
-        col_hit = None
-        if n_passive:  # no active pair at all: every passive entry drops
-            col_hit = (
-                upair[np.minimum(col_rank, len(upair) - 1)] == pair
-                if len(upair) else np.zeros(len(pair), bool))
-    del pair
-    act_ent = (upair // d).astype(np.int64)
-    act_col = (upair % d).astype(np.int32)
-    act_counts = np.bincount(act_ent, minlength=n_ent).astype(np.int64)
-    act_before = np.concatenate([[0], np.cumsum(act_counts)[:-1]])
+    with layer_span("game.group.columns"):
+        # Per-entity ACTIVE columns (from kept rows only, as the reference's
+        # projector sees them): the distinct (entity, column) pairs of the
+        # kept entries, entity-major, so each entity's active columns come out
+        # ascending — the same order np.unique(sub.indices) produced.
+        # ``col_rank[k]`` is entry k's pair's index among them, and
+        # ``col_hit[k]`` whether it is one (every kept entry's is; a passive
+        # entry's only where its entity trained on that column).
+        pair = np.repeat(ent_of_pos, nnz_per_row).astype(np.int64)
+        pair *= d
+        pair += sorted_csr.indices
+        nnz_keep = np.repeat(keep, nnz_per_row)
+        if n_ent * d <= _PAIR_TABLE_CELLS:
+            # Few enough (entity, column) cells for a presence table: one
+            # pass in place of the sort inside np.unique (8 s at 62 M pairs).
+            present = np.zeros(n_ent * d, bool)
+            present[pair[nnz_keep]] = True
+            upair = np.flatnonzero(present)
+            col_rank = (np.cumsum(present, dtype=np.int32) - 1)[pair]
+            col_hit = present[pair] if n_passive else None
+            del present
+        else:
+            upair = np.unique(pair[nnz_keep])
+            col_rank = np.searchsorted(upair, pair)
+            col_hit = None
+            if n_passive:  # no active pair at all: every passive entry drops
+                col_hit = (
+                    upair[np.minimum(col_rank, len(upair) - 1)] == pair
+                    if len(upair) else np.zeros(len(pair), bool))
+        del pair
+        act_ent = (upair // d).astype(np.int64)
+        act_col = (upair % d).astype(np.int32)
+        act_counts = np.bincount(act_ent, minlength=n_ent).astype(np.int64)
+        act_before = np.concatenate([[0], np.cumsum(act_counts)[:-1]])
 
-    # GROUP entities into buckets, PADDING each block only to its
-    # members' actual maxima: the grouping key bounds the bucket COUNT
-    # (compile count per dataset), while the per-bucket entity count E
-    # already makes every block shape unique — so tight padding costs
-    # no extra compiles and cuts the padded bytes every objective
-    # evaluation touches.
-    #
-    # Two grouping policies (docs/performance.md "Hierarchical
-    # execution"):
-    #  - "geometric" (default): the static ladder — key by
-    #    (geo(rows), geo(dims)) on the floor·growth^k grid.
-    #  - "cost_model": plan_entity_buckets fits ≤ program_budget bucket
-    #    shapes to the OBSERVED size distribution, minimizing padded
-    #    FLOPs.  Same downstream machinery; only the membership map
-    #    changes.  NOTE: regrouping changes realized block shapes, and
-    #    XLA reduction tiling varies with padded length — repacked
-    #    coefficients are the same math but not bit-for-bit the
-    #    ladder's (unlike sharding/pipelining, which preserve the plan
-    #    and are bitwise; measured in docs/performance.md).
-    if repack == "cost_model":
-        from photon_ml_tpu.chaos import core as chaos_mod
+    with layer_span("game.group.plan"):
+        # GROUP entities into buckets, PADDING each block only to its
+        # members' actual maxima: the grouping key bounds the bucket COUNT
+        # (compile count per dataset), while the per-bucket entity count E
+        # already makes every block shape unique — so tight padding costs
+        # no extra compiles and cuts the padded bytes every objective
+        # evaluation touches.
+        #
+        # Two grouping policies (docs/performance.md "Hierarchical
+        # execution"):
+        #  - "geometric" (default): the static ladder — key by
+        #    (geo(rows), geo(dims)) on the floor·growth^k grid.
+        #  - "cost_model": plan_entity_buckets fits ≤ program_budget bucket
+        #    shapes to the OBSERVED size distribution, minimizing padded
+        #    FLOPs.  Same downstream machinery; only the membership map
+        #    changes.  NOTE: regrouping changes realized block shapes, and
+        #    XLA reduction tiling varies with padded length — repacked
+        #    coefficients are the same math but not bit-for-bit the
+        #    ladder's (unlike sharding/pipelining, which preserve the plan
+        #    and are bitwise; measured in docs/performance.md).
+        if repack == "cost_model":
+            from photon_ml_tpu.chaos import core as chaos_mod
 
-        chaos_mod.maybe_fail(
-            "game.repack", n_entities=n_ent, budget=program_budget
-        )
-        plan = plan_entity_buckets(
-            kept_counts, act_counts, program_budget=program_budget,
-            seed=repack_seed,
-        )
-        buckets: dict[tuple[int, int], list[int]] = {}
-        for g in range(n_ent):
-            bi = int(plan.assignment[g])
-            key = (int(plan.shapes[bi, 0]), int(plan.shapes[bi, 1]))
-            buckets.setdefault(key, []).append(g)
-    elif repack == "geometric":
-        geo = {}
+            chaos_mod.maybe_fail(
+                "game.repack", n_entities=n_ent, budget=program_budget
+            )
+            plan = plan_entity_buckets(
+                kept_counts, act_counts, program_budget=program_budget,
+                seed=repack_seed,
+            )
+            buckets: dict[tuple[int, int], list[int]] = {}
+            for g in range(n_ent):
+                bi = int(plan.assignment[g])
+                key = (int(plan.shapes[bi, 0]), int(plan.shapes[bi, 1]))
+                buckets.setdefault(key, []).append(g)
+        elif repack == "geometric":
+            geo = {}
 
-        def _geo(v: int) -> int:
-            if v not in geo:
-                geo[v] = _round_up_geometric(v, bucket_growth)
-            return geo[v]
+            def _geo(v: int) -> int:
+                if v not in geo:
+                    geo[v] = _round_up_geometric(v, bucket_growth)
+                return geo[v]
 
-        buckets = {}
-        for g in range(n_ent):
-            key = (_geo(int(kept_counts[g])), _geo(int(act_counts[g])))
-            buckets.setdefault(key, []).append(g)
-    else:
-        raise ValueError(
-            f"repack must be 'geometric' or 'cost_model', got {repack!r}"
-        )
+            buckets = {}
+            for g in range(n_ent):
+                key = (_geo(int(kept_counts[g])), _geo(int(act_counts[g])))
+                buckets.setdefault(key, []).append(g)
+        else:
+            raise ValueError(
+                f"repack must be 'geometric' or 'cost_model', got {repack!r}"
+            )
 
-    # lane_of_ent/block_of_ent drive every flat scatter below.
-    lane_of_ent = np.empty(n_ent, np.int64)
-    block_of_ent = np.full(n_ent, -1, np.int64)
-    ordered_buckets = []
-    for bi, (_key, members) in enumerate(sorted(buckets.items())):
-        m = np.asarray(members, np.int64)
-        ordered_buckets.append(m)
-        lane_of_ent[m] = np.arange(len(m))
-        block_of_ent[m] = bi
+        # lane_of_ent/block_of_ent drive every flat scatter below.
+        lane_of_ent = np.empty(n_ent, np.int64)
+        block_of_ent = np.full(n_ent, -1, np.int64)
+        ordered_buckets = []
+        for bi, (_key, members) in enumerate(sorted(buckets.items())):
+            m = np.asarray(members, np.int64)
+            ordered_buckets.append(m)
+            lane_of_ent[m] = np.arange(len(m))
+            block_of_ent[m] = bi
 
-    # Each bucket's sorted positions and active pairs as index lists,
-    # ascending, from ONE stable sort by bucket each: a boolean mask over
-    # all rows per bucket cost a pass over the whole data for every
-    # bucket.  A bucket's stored entries are its rows' ranges of the CSR.
-    def by_block(block_ids):
-        small = block_ids.astype(
-            np.int16 if len(ordered_buckets) < (1 << 15) else np.int64)
-        idx = np.argsort(small, kind="stable")
-        bounds = np.concatenate([[0], np.cumsum(np.bincount(
-            block_ids, minlength=len(ordered_buckets)))])
-        return idx, bounds
+        # Each bucket's sorted positions and active pairs as index lists,
+        # ascending, from ONE stable sort by bucket each: a boolean mask over
+        # all rows per bucket cost a pass over the whole data for every
+        # bucket.  A bucket's stored entries are its rows' ranges of the CSR.
+        def by_block(block_ids):
+            small = block_ids.astype(
+                np.int16 if len(ordered_buckets) < (1 << 15) else np.int64)
+            idx = np.argsort(small, kind="stable")
+            bounds = np.concatenate([[0], np.cumsum(np.bincount(
+                block_ids, minlength=len(ordered_buckets)))])
+            return idx, bounds
 
-    pos_idx, pos_bounds = by_block(block_of_ent[ent_of_pos])
-    act_idx, act_bounds = by_block(block_of_ent[act_ent])
+        pos_idx, pos_bounds = by_block(block_of_ent[ent_of_pos])
+        act_idx, act_bounds = by_block(block_of_ent[act_ent])
 
     def entries_of(positions):
         """``(entry indices, each entry's sorted position)`` of the rows at
@@ -869,86 +895,88 @@ def _group_entities(
     ids_per_block: list[list] = []
     entity_to_slot: dict = {}
     block_rows_real: list[int] = []
-    for bi, m in enumerate(ordered_buckets):
-        E = len(m)
-        R = int(kept_counts[m].max())
-        D = max(1, int(act_counts[m].max()))
-        minor = _x_minor(R, D, tile)
+    with layer_span(
+            "game.group.fill", buckets=len(ordered_buckets)):
+        for bi, m in enumerate(ordered_buckets):
+            E = len(m)
+            R = int(kept_counts[m].max())
+            D = max(1, int(act_counts[m].max()))
+            minor = _x_minor(R, D, tile)
 
-        # Row-level fills: labels/weights/row_index at (lane, local_row).
-        in_bucket = pos_idx[pos_bounds[bi]:pos_bounds[bi + 1]]
-        sel = in_bucket[keep[in_bucket]]
-        lane_r = lane_of_ent[ent_of_pos[sel]]
-        lrow = local_kept[sel]
-        lab = np.zeros((E, R), np.float32)
-        wts = np.zeros((E, R), np.float32)
-        rindex = np.full((E, R), n_rows, np.int32)  # sentinel
-        rows_sel = row_of_pos[sel]
-        lab[lane_r, lrow] = labels[rows_sel]
-        wts[lane_r, lrow] = weights[rows_sel]
-        rindex[lane_r, lrow] = rows_sel
-        block_rows_real.append(int(len(sel)))
+            # Row-level fills: labels/weights/row_index at (lane, local_row).
+            in_bucket = pos_idx[pos_bounds[bi]:pos_bounds[bi + 1]]
+            sel = in_bucket[keep[in_bucket]]
+            lane_r = lane_of_ent[ent_of_pos[sel]]
+            lrow = local_kept[sel]
+            lab = np.zeros((E, R), np.float32)
+            wts = np.zeros((E, R), np.float32)
+            rindex = np.full((E, R), n_rows, np.int32)  # sentinel
+            rows_sel = row_of_pos[sel]
+            lab[lane_r, lrow] = labels[rows_sel]
+            wts[lane_r, lrow] = weights[rows_sel]
+            rindex[lane_r, lrow] = rows_sel
+            block_rows_real.append(int(len(sel)))
 
-        # col_map: each unique active (entity, col) lands at its rank
-        # within the entity's active list.
-        cmap = np.full((E, D), -1, np.int32)
-        a_sel = act_idx[act_bounds[bi]:act_bounds[bi + 1]]
-        local_c = a_sel - act_before[act_ent[a_sel]]
-        cmap[lane_of_ent[act_ent[a_sel]], local_c] = act_col[a_sel]
+            # col_map: each unique active (entity, col) lands at its rank
+            # within the entity's active list.
+            cmap = np.full((E, D), -1, np.int32)
+            a_sel = act_idx[act_bounds[bi]:act_bounds[bi + 1]]
+            local_c = a_sel - act_before[act_ent[a_sel]]
+            cmap[lane_of_ent[act_ent[a_sel]], local_c] = act_col[a_sel]
 
-        # X: every kept nnz of the bucket scatters to
-        # (lane, local_row, local_col); duplicates were pre-summed.
-        n_sel, pos_n = entries_of(sel)
-        e_n = ent_of_pos[pos_n]
-        X = features(
-            minor, (E, R, D), (lane_of_ent[e_n],), local_kept[pos_n],
-            col_rank[n_sel] - act_before[e_n], sorted_csr.data[n_sel],
-        )
-        del n_sel, pos_n, e_n
+            # X: every kept nnz of the bucket scatters to
+            # (lane, local_row, local_col); duplicates were pre-summed.
+            n_sel, pos_n = entries_of(sel)
+            e_n = ent_of_pos[pos_n]
+            X = features(
+                minor, (E, R, D), (lane_of_ent[e_n],), local_kept[pos_n],
+                col_rank[n_sel] - act_before[e_n], sorted_csr.data[n_sel],
+            )
+            del n_sel, pos_n, e_n
 
-        ids = list(ent_keys[m])
-        for lane, key in enumerate(ids):
-            entity_to_slot[key] = (bi, lane)
-        blocks.append(dict(
-            X=X, labels=lab, weights=wts, col_map=cmap, row_index=rindex,
-            n_entities=E, rows_per_entity=R, block_dim=D, x_minor=minor,
-        ))
-        ids_per_block.append(ids)
+            ids = list(ent_keys[m])
+            for lane, key in enumerate(ids):
+                entity_to_slot[key] = (bi, lane)
+            blocks.append(dict(
+                X=X, labels=lab, weights=wts, col_map=cmap, row_index=rindex,
+                n_entities=E, rows_per_entity=R, block_dim=D, x_minor=minor,
+            ))
+            ids_per_block.append(ids)
 
-        # The bucket's score-only rows, flat and in lane order (sorted
-        # positions are entity-major and lanes ascend with the entity).
-        if not n_passive or not psv_counts[m].any():
-            passive_blocks.append(None)
-            continue
-        selp = in_bucket[~keep[in_bucket]]
-        Np = len(selp)
-        chunk = min(_PASSIVE_CHUNK, -(-Np // 128) * 128)
-        P = -(-Np // chunk) * chunk
-        has = psv_counts[m] > 0
-        slot_of_lane = np.cumsum(has) - 1
-        first_of_lane = np.cumsum(psv_counts[m]) - psv_counts[m]
-        rindexp = np.full(P, n_rows, np.int32)  # sentinel
-        rindexp[:Np] = row_of_pos[selp]
-        slot = np.full(P, slot_of_lane[-1], np.int32)
-        slot[:Np] = slot_of_lane[lane_of_ent[ent_of_pos[selp]]]
-        # Passive features project onto the ACTIVE subspace (features the
-        # entity never trained on drop, as in the reference's projected
-        # scoring): entries whose (entity, col) pair is not active drop.
-        np_sel, pos_p = entries_of(selp)
-        hit = col_hit[np_sel]
-        np_sel, pos_p = np_sel[hit], pos_p[hit]
-        e_p = ent_of_pos[pos_p]
-        passive_minor = _x_minor(P, D, tile)
-        Xp = features(
-            passive_minor, (P, D), (),
-            first_of_lane[lane_of_ent[e_p]] + local_psv[pos_p],
-            col_rank[np_sel] - act_before[e_p], sorted_csr.data[np_sel],
-        )
-        passive_blocks.append(dict(
-            X=Xp, row_index=rindexp, slot=slot,
-            lanes=np.flatnonzero(has).astype(np.int32), n_rows=Np,
-            block_dim=D, chunk=chunk, x_minor=passive_minor,
-        ))
+            # The bucket's score-only rows, flat and in lane order (sorted
+            # positions are entity-major and lanes ascend with the entity).
+            if not n_passive or not psv_counts[m].any():
+                passive_blocks.append(None)
+                continue
+            selp = in_bucket[~keep[in_bucket]]
+            Np = len(selp)
+            chunk = min(_PASSIVE_CHUNK, -(-Np // 128) * 128)
+            P = -(-Np // chunk) * chunk
+            has = psv_counts[m] > 0
+            slot_of_lane = np.cumsum(has) - 1
+            first_of_lane = np.cumsum(psv_counts[m]) - psv_counts[m]
+            rindexp = np.full(P, n_rows, np.int32)  # sentinel
+            rindexp[:Np] = row_of_pos[selp]
+            slot = np.full(P, slot_of_lane[-1], np.int32)
+            slot[:Np] = slot_of_lane[lane_of_ent[ent_of_pos[selp]]]
+            # Passive features project onto the ACTIVE subspace (features the
+            # entity never trained on drop, as in the reference's projected
+            # scoring): entries whose (entity, col) pair is not active drop.
+            np_sel, pos_p = entries_of(selp)
+            hit = col_hit[np_sel]
+            np_sel, pos_p = np_sel[hit], pos_p[hit]
+            e_p = ent_of_pos[pos_p]
+            passive_minor = _x_minor(P, D, tile)
+            Xp = features(
+                passive_minor, (P, D), (),
+                first_of_lane[lane_of_ent[e_p]] + local_psv[pos_p],
+                col_rank[np_sel] - act_before[e_p], sorted_csr.data[np_sel],
+            )
+            passive_blocks.append(dict(
+                X=Xp, row_index=rindexp, slot=slot,
+                lanes=np.flatnonzero(has).astype(np.int32), n_rows=Np,
+                block_dim=D, chunk=chunk, x_minor=passive_minor,
+            ))
 
     return {
         "blocks": blocks,

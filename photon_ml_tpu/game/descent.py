@@ -235,6 +235,15 @@ class CoordinateDescent:
             counted.clear()
 
         def flush():
+            """The blocking read, as one ``cd.flush`` layer span under
+            ``cd.fit`` (``cd.iteration`` with a logger or a checkpointer):
+            the host waits here for every program it has dispatched since
+            the last flush, so in a resident fit this is where the
+            device's seconds show on the host's clock."""
+            with layer_span("cd.flush", updates=len(pending)):
+                read_back()
+
+        def read_back():
             read_counts()
             if not pending:
                 return

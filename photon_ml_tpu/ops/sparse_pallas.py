@@ -84,6 +84,7 @@ from photon_ml_tpu.ops.sparse import (
     canonicalize_coo,
 )
 from photon_ml_tpu.telemetry import layer_span
+from photon_ml_tpu.utils.placement import place_leaves
 
 Array = jax.Array
 
@@ -1473,7 +1474,7 @@ def build_pallas_host(
 
 def place_pallas_matrix(P: PallasSparseMatrix) -> PallasSparseMatrix:
     """Put every leaf of a host-built layout on the default device."""
-    return jax.tree.map(jnp.asarray, P)
+    return place_leaves(P)[0]
 
 
 def build_pallas_matrix(*args, **kwargs) -> PallasSparseMatrix:
@@ -1484,10 +1485,14 @@ def build_pallas_matrix(*args, **kwargs) -> PallasSparseMatrix:
 def host_layout_from_scipy_csr(csr, depth_cap: int = 128,
                                pad_nnz: Optional[int] = None,
                                dtype=jnp.float32) -> PallasSparseMatrix:
-    """:func:`build_pallas_host` of a scipy CSR matrix."""
-    csr = csr.tocsr()
-    csr.sum_duplicates()
-    coo = csr.tocoo()
+    """:func:`build_pallas_host` of a scipy CSR matrix.  The way there
+    (duplicates summed, one row index an entry) is a ``layout.to_coo``
+    layer span of its own: seconds at 0.5 G entries, before
+    ``layout.build`` opens."""
+    with layer_span("layout.to_coo", nnz=int(csr.nnz)):
+        csr = csr.tocsr()
+        csr.sum_duplicates()
+        coo = csr.tocoo()
     return build_pallas_host(
         coo.row, coo.col, coo.data,
         csr.shape[0], csr.shape[1], depth_cap=depth_cap, pad_nnz=pad_nnz,

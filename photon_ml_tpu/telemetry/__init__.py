@@ -34,6 +34,7 @@ from photon_ml_tpu.telemetry.core import (  # noqa: F401
     Span,
     Telemetry,
     TraceContext,
+    compile_records,
     current,
     dump_flight_recorder,
     json_safe,

@@ -1104,3 +1104,30 @@ layer_span = LayerSpan
 def layer_spans() -> list[dict]:
     """A copy of the ring of layer-span records, oldest first."""
     return _LAYER_RING.snapshot()
+
+
+def open_layer_span() -> Optional[LayerSpan]:
+    """The innermost layer span open on the calling thread, if any."""
+    stack = getattr(_layer_local, "stack", None)
+    return stack[-1] if stack else None
+
+
+# ---------------------------------------------------------------------------
+# Compile records: one per backend compile, hub or no hub
+# ---------------------------------------------------------------------------
+
+#: Process-wide ring of the compile records (docs/telemetry.md "Compile
+#: records"), filed by ``utils/compile_cache``'s listeners on JAX's compile
+#: events.  A ring of its own: a cold start files hundreds of them, and
+#: none may push a layer span out of ``_LAYER_RING``.
+_COMPILE_RING = FlightRecorder(capacity=4096)
+
+
+def file_compile_record(record: dict) -> None:
+    """File one compile record (``utils/compile_cache`` is the caller)."""
+    _COMPILE_RING.emit(record)
+
+
+def compile_records() -> list[dict]:
+    """A copy of the ring of compile records, oldest first."""
+    return _COMPILE_RING.snapshot()

@@ -16,16 +16,27 @@ hits — so no other path is ever used.
 
 Opt-out rather than opt-in at the entry points (``--compile-cache off``);
 library users call :func:`enable_compile_cache` themselves.
+
+:func:`enable_compile_cache` also installs, once a process, listeners on
+``jax.monitoring`` that file one COMPILE RECORD per backend compile
+(``telemetry.compile_records()``, docs/telemetry.md "Compile records"): the
+program's name, its tracing, lowering and backend seconds, what the
+persistent cache did with it, and the layer span it lay under.
 """
 
 from __future__ import annotations
 
 import logging
 import os
+import threading
 import time
 from typing import Optional, Sequence
 
 from photon_ml_tpu import telemetry as telemetry_mod
+from photon_ml_tpu.telemetry.core import (
+    file_compile_record,
+    open_layer_span,
+)
 
 CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 _CHECKOUT = os.path.dirname(
@@ -34,8 +45,137 @@ _CHECKOUT = os.path.dirname(
 
 _log = logging.getLogger(__name__)
 
-#: cache dir -> entry count at enable time (for end-of-run miss deltas).
-_ENABLE_COUNTS: dict[str, int] = {}
+#: Compilations faster than this are not persisted by default (they would
+#: bloat the cache for no win), and only a compile record of at least this
+#: many seconds becomes a hub's ``compile`` event.
+MIN_COMPILE_SECS = 0.5
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: JAX's plain events inside one backend compile, in the order it records
+#: them: the request went to the persistent cache; it was served from it;
+#: it was compiled and WRITTEN (JAX records ``cache_misses`` only when it
+#: writes the entry: a compile under the thresholds records nothing).
+_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_STORED_EVENT = "/jax/compilation_cache/cache_misses"
+
+
+class _Pending(threading.local):
+    """What one thread's compile events have said since its last record."""
+
+    def __init__(self):
+        self.depth = 0        # tracing / lowering intervals now open
+        self.inside = 0.0     # backend seconds inside the outermost open one
+        self.trace_s = 0.0
+        self.lower_s = 0.0
+        self.retrieval_s = 0.0
+        self.events = set()   # of _REQUEST_EVENT, _HIT_EVENT, _STORED_EVENT
+
+
+_pending = _Pending()
+
+
+def _on_start(event: str, _start_time, **_kw) -> None:
+    """JAX announces each timed interval when it opens (a scalar event):
+    tracing nests (a jitted function traced into its caller) and so may
+    lowering, and only the outermost interval's seconds are wall seconds."""
+    if event == _TRACE_EVENT or event == _LOWER_EVENT:
+        _pending.depth += 1
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event in (_REQUEST_EVENT, _HIT_EVENT, _STORED_EVENT):
+        _pending.events.add(event)
+
+
+def _on_duration(event: str, secs: float, fun_name=None, **_kw) -> None:
+    p = _pending
+    if event == _TRACE_EVENT or event == _LOWER_EVENT:
+        p.depth = max(0, p.depth - 1)
+        if p.depth == 0:
+            # A program compiled while this interval was open (an eager
+            # operation inside a traced function) has its own record.
+            own = max(0.0, secs - p.inside)
+            p.inside = 0.0
+            if event == _TRACE_EVENT:
+                p.trace_s += own
+            else:
+                p.lower_s += own
+    elif event == _RETRIEVAL_EVENT:
+        p.retrieval_s += secs
+    elif event == _BACKEND_EVENT:
+        _file_record(p, str(fun_name), secs, time.perf_counter())
+
+
+def _file_record(p: _Pending, program: str, backend_s: float, end: float):
+    """One backend compile has ended on this thread: file its record, feed
+    the hub's counters, and start the thread's next record."""
+    import jax
+
+    # JAX 0.9.0 asks its cache even where no directory is set (it hashes
+    # the module, then finds no cache to read or write): that is off too.
+    if (_REQUEST_EVENT not in p.events
+            or not jax.config.jax_compilation_cache_dir):
+        cache = "off"
+    elif _HIT_EVENT in p.events:
+        cache = "hit"
+    elif _STORED_EVENT in p.events:
+        cache = "stored"
+    else:
+        cache = "unstored"
+    span = open_layer_span()
+    record = {
+        "type": "compile",
+        "program": program,
+        "ts": end - backend_s,
+        "dur": backend_s,
+        "trace_s": p.trace_s,
+        "lower_s": p.lower_s,
+        "backend_s": backend_s,
+        "cache": cache,
+        "retrieval_s": p.retrieval_s,
+        "span": None if span is None else {
+            "name": span.name, "id": span.span_id,
+            "coordinate": span.attrs.get("coordinate"),
+        },
+        "tid": threading.get_ident(),
+    }
+    python_s, retrieval_s = p.trace_s + p.lower_s, p.retrieval_s
+    if p.depth:
+        p.inside += backend_s
+    p.trace_s = p.lower_s = p.retrieval_s = 0.0
+    p.events = set()
+    file_compile_record(record)
+    tel = telemetry_mod.current()
+    if not tel.enabled:
+        return
+    tel.counter("compile_trace_lower_seconds").inc(python_s)
+    if cache == "hit":  # JAX times a hit's retrieval inside its backend_s
+        tel.counter("compile_cache_hits").inc()
+        tel.counter("compile_cache_load_seconds").inc(retrieval_s)
+    else:
+        tel.counter("compile_backend_seconds").inc(backend_s)
+        if cache != "off":
+            tel.counter("compile_cache_misses").inc()
+    if python_s + backend_s >= MIN_COMPILE_SECS:
+        tel.event("compile", **{k: v for k, v in record.items()
+                                if k not in ("type", "ts", "dur", "tid")})
+
+
+def _install_listeners() -> None:
+    """Register the three listeners unless this process already has them
+    (``jax.monitoring`` keeps plain lists: registering twice would file
+    every record twice)."""
+    from jax._src import monitoring
+
+    if _on_duration in monitoring.get_event_duration_listeners():
+        return
+    monitoring.register_scalar_listener(_on_start)
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def cache_entry_count(path: Optional[str]) -> Optional[int]:
@@ -49,28 +189,6 @@ def cache_entry_count(path: Optional[str]) -> Optional[int]:
         )
     except OSError:
         return None
-
-
-def publish_cache_metrics(path: Optional[str]) -> Optional[int]:
-    """End-of-run compile-cache attribution: entries now vs at enable
-    time.  New persisted entries are programs this run compiled (cache
-    MISSES at the >= min_compile_secs threshold); a run serving entirely
-    from cache adds zero.  Returns the delta (None when unknown)."""
-    tel = telemetry_mod.current()
-    n = cache_entry_count(path)
-    if n is None:
-        return None
-    start = _ENABLE_COUNTS.get(path)
-    delta = None if start is None else max(0, n - start)
-    if tel.enabled:
-        tel.gauge("compile_cache_entries").set(n)
-        if delta is not None:
-            tel.counter("compile_cache_new_entries").inc(delta)
-            tel.event(
-                "compile_cache.summary", dir=path, entries=n,
-                new_entries=delta,
-            )
-    return delta
 
 
 def warmup(fns: Sequence, shapes: Sequence, logger=None) -> int:
@@ -141,7 +259,7 @@ def add_compile_cache_arg(parser) -> None:
 
 
 def enable_from_args(
-    args, logger=None, min_compile_secs: float = 0.5
+    args, logger=None, min_compile_secs: float = MIN_COMPILE_SECS
 ) -> Optional[str]:
     """Entry-point preamble: enable per ``args.compile_cache`` and log the
     dir."""
@@ -151,7 +269,6 @@ def enable_from_args(
     if path:
         n = cache_entry_count(path)
         if n is not None:
-            _ENABLE_COUNTS[path] = n
             telemetry_mod.current().event(
                 "compile_cache.enabled", dir=path, entries=n
             )
@@ -167,7 +284,7 @@ def cache_dir() -> str:
 
 
 def enable_compile_cache(
-    mode: Optional[str] = "auto", min_compile_secs: float = 0.5
+    mode: Optional[str] = "auto", min_compile_secs: float = MIN_COMPILE_SECS
 ) -> Optional[str]:
     """Turn JAX's persistent compilation cache on at :func:`cache_dir`
     (``"auto"``/None) or off (``"off"``); returns the directory in use,
@@ -177,7 +294,8 @@ def enable_compile_cache(
     nothing is set in code.  Compilations faster than ``min_compile_secs``
     are not persisted (they'd bloat the cache for no win).  An uncreatable
     directory (read-only checkout) degrades to an uncached run with a
-    warning, never a crashed job.
+    warning, never a crashed job.  Either way the process's compiles are
+    recorded from here on (``telemetry.compile_records()``).
     """
     import jax
     from jax._src import compilation_cache as _cc
@@ -187,6 +305,7 @@ def enable_compile_cache(
             f"compile cache mode must be 'auto' or 'off', got {mode!r}; "
             f"the directory is ${CACHE_DIR_ENV} or <checkout>/.jax_cache"
         )
+    _install_listeners()
     path = None if mode == "off" else cache_dir()
     if path is not None:
         try:

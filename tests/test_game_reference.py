@@ -334,6 +334,18 @@ class TestSpans:
             assert by_id[it["parent"]]["name"] == "cd.fit"
         assert len([r for r in spans if r["name"] == "coordinate.score"]) == 4
 
+    def test_the_blocking_read_is_a_span_of_its_own(self, spans):
+        """``cd.flush`` (PR 35): where a resident fit's device seconds show
+        on the host's clock; without a logger, once, after the iterations."""
+        (fit_span,) = [r for r in spans if r["name"] == "cd.fit"]
+        (flush,) = [r for r in spans if r["name"] == "cd.flush"]
+        assert flush["parent"] == fit_span["id"]
+        assert flush["attrs"] == {"updates": 4}
+        last = max(r["ts"] + r["dur"] for r in spans
+                   if r["name"] == "cd.iteration")
+        assert last <= flush["ts"]
+        assert flush["ts"] + flush["dur"] <= fit_span["ts"] + fit_span["dur"]
+
     def test_each_coordinate_is_finalized_under_a_span(self, spans):
         ends = [r for r in spans if r["name"] == "coordinate.finalize"]
         fit_end = max(r["ts"] + r["dur"] for r in spans
