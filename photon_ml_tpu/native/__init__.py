@@ -185,6 +185,10 @@ def _bind_sorter(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_void_p, p_f32, p_i64,
     ]
     lib.pl_scatter.restype = i64
+    lib.pl_band_depths.argtypes = [
+        p_i64, p_i64, i64, i64, i64, i64, p_i64, i64, p_i64,
+    ]
+    lib.pl_band_depths.restype = i64
     lib.pl_observed_team.argtypes = []
     lib.pl_observed_team.restype = i64
     return lib

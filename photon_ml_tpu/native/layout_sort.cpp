@@ -15,6 +15,7 @@
 // native/__init__.py compiles this lazily with the system g++ and falls
 // back to the numpy path on any failure.
 
+#include <algorithm>
 #include <cstdint>
 #include <cstring>
 #include <vector>
@@ -260,6 +261,115 @@ int64_t pl_scatter(
     }
   }
   return spill_base[n_threads];
+}
+
+// The packed sublane counts of BOTH orientations of one column labeling,
+// uncapped: max over tiles of the sum over windows of the worst lane
+// load — what the numpy reference (_predict_a in ops/sparse_pallas.py)
+// gets from a sort of one key per entry, here from one counting pass.
+// rows must be nondecreasing: a band of tile_edge rows is then one
+// contiguous run, and every cell of both orientations that an entry of
+// the band can fall in belongs to the band.  Orientation F's cell is
+// (column tile, column window, row lane), orientation B's (column tile,
+// row window, column lane): nbc * tile_edge counters each.  relabel (old
+// col -> new col, n_relabel long; null for the identity) maps into
+// [0, nbc*tile_edge).  out2 = {F's depth, B's depth}.  Returns 0; 1 for a
+// row smaller than its predecessor or an index outside the grid; 2 where
+// nnz passes int32 or one thread's counters would outweigh the entries
+// themselves (the caller then takes the sort, which allocates nothing
+// per column).
+int64_t pl_band_depths(
+    const int64_t* rows, const int64_t* cols, int64_t nnz,
+    int64_t nbr, int64_t nbc, int64_t tile_edge,
+    const int64_t* relabel, int64_t n_relabel, int64_t* out2) {
+  out2[0] = out2[1] = 0;
+  if (nnz > INT32_MAX) return 2;
+  const Fields F{nbc, tile_edge, tile_edge >> 7};
+  const int64_t n_cells = nbc * tile_edge;   // per orientation, per band
+  const int64_t n_tw = nbc * F.wins;
+  // Two int32 counter arrays a thread, against two int64 an entry: the
+  // team is as large as keeps all counters within the entries' own bytes.
+  const int64_t afford = (2 * nnz) / (n_cells > 0 ? n_cells : 1);
+  if (afford < 1) return 2;
+  const int team = static_cast<int>(
+      std::min<int64_t>(afford, observed_team()));
+
+  int64_t bad = 0;
+#pragma omp parallel for schedule(static) reduction(| : bad)
+  for (int64_t i = 1; i < nnz; ++i) bad |= (rows[i] < rows[i - 1]);
+  if (bad || (nnz && (rows[0] < 0 || rows[nnz - 1] >= nbr * tile_edge)))
+    return 1;
+
+  // First entry of each band (rows are sorted: a binary search a band).
+  std::vector<int64_t> start(static_cast<size_t>(nbr) + 1);
+#pragma omp parallel for schedule(static)
+  for (int64_t b = 0; b <= nbr; ++b)
+    start[b] = std::lower_bound(rows, rows + nnz, b * tile_edge) - rows;
+
+  // Where entry i of the band that starts at row0 counts: its column
+  // tile, its (tile, window) of each orientation and the lane within.
+  // False for a column outside the grid.
+  struct Cell { int64_t tc, tw_f, tw_b, lane_f, lane_b; };
+  auto cell_of = [=](int64_t i, int64_t row0, Cell* at) -> bool {
+    int64_t c = cols[i];
+    if (relabel) {
+      if (c < 0 || c >= n_relabel) return false;
+      c = relabel[c];
+    }
+    if (c < 0 || c >= n_cells) return false;
+    const int64_t r = rows[i];
+    at->tc = c / tile_edge;
+    at->tw_f = at->tc * F.wins + F.gwin(c);
+    at->tw_b = at->tc * F.wins + ((r - row0) >> 7);
+    at->lane_f = F.lane(r);
+    at->lane_b = F.lane(c);
+    return true;
+  };
+
+  int64_t best_f = 0, best_b = 0;
+#pragma omp parallel num_threads(team) reduction(max : best_f, best_b) \
+    reduction(| : bad)
+  {
+    // cell counts, then per (column tile, window) the worst lane so far,
+    // then per column tile the sum of those: every increment of a worst
+    // lane is an increment of its tile's sum, so the running maximum of
+    // the sums is the depth and nothing is reduced at a band's end.
+    std::vector<int32_t> cnt_f(n_cells), cnt_b(n_cells);
+    std::vector<int32_t> top_f(n_tw), top_b(n_tw);
+    std::vector<int32_t> sum_f(nbc), sum_b(nbc);
+    Cell at;
+#pragma omp for schedule(dynamic)
+    for (int64_t b = 0; b < nbr; ++b) {
+      const int64_t lo = start[b], hi = start[b + 1];
+      const int64_t row0 = b * tile_edge;
+      for (int64_t i = lo; i < hi; ++i) {
+        if (!cell_of(i, row0, &at)) { bad = 1; continue; }
+        const int32_t nf = ++cnt_f[at.tw_f * 128 + at.lane_f];
+        if (nf > top_f[at.tw_f]) {
+          top_f[at.tw_f] = nf;
+          if (++sum_f[at.tc] > best_f) best_f = sum_f[at.tc];
+        }
+        const int32_t nb = ++cnt_b[at.tw_b * 128 + at.lane_b];
+        if (nb > top_b[at.tw_b]) {
+          top_b[at.tw_b] = nb;
+          if (++sum_b[at.tc] > best_b) best_b = sum_b[at.tc];
+        }
+      }
+      // Clear what the band touched by walking it again, not by memset:
+      // a wide matrix stays O(entries).
+      for (int64_t i = lo; i < hi; ++i) {
+        if (!cell_of(i, row0, &at)) continue;
+        cnt_f[at.tw_f * 128 + at.lane_f] = 0;
+        cnt_b[at.tw_b * 128 + at.lane_b] = 0;
+        top_f[at.tw_f] = top_b[at.tw_b] = 0;
+        sum_f[at.tc] = sum_b[at.tc] = 0;
+      }
+    }
+  }
+  if (bad) return 1;
+  out2[0] = best_f;
+  out2[1] = best_b;
+  return 0;
 }
 
 // Test introspection: the ACTUAL deliverable team size.  The multi-thread

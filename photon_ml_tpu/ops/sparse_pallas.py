@@ -990,11 +990,15 @@ class SpillData:
 
 
 def _predict_a(rows, cols, nbr, nbc):
-    """Predicted packed sublane count (max over tiles of Σ_w max-lane-load)
-    for orientation F of the given entry set.  Counts only PRESENT cells
-    (sort + reduceat) — a dense bincount over every possible cell is
-    O(tiles · TILE · 128) host memory and OOMs at millions of tiles.
-    Used to choose between identity and permuted column layouts."""
+    """Packed sublane count (max over tiles of Σ_w max-lane-load, uncapped)
+    of orientation F of the given entry set; swap the arguments for
+    orientation B.  Counts only PRESENT cells (sort + reduceat) — a dense
+    bincount over every possible cell is O(tiles · TILE · 128) host memory
+    and OOMs at millions of tiles.  The reference that
+    :func:`_band_depths`'s counting pass is held to (exact equality,
+    tests/test_sparse_pallas.py), and its path for entries whose rows are
+    not in order, a missing native library, or a grid too wide for
+    per-band counters."""
     t, w, l = _extract_fields(
         rows.astype(np.int32, copy=False),
         cols.astype(np.int32, copy=False), nbc,
@@ -1018,6 +1022,63 @@ def _predict_a(rows, cols, nbr, nbc):
         tw[tw_starts] // WINS, weights=m, minlength=nbr * nbc
     )
     return int(a_t.max())
+
+
+def _band_depths(r, c, relabelings, nbr, nbc):
+    """(orientation F's, orientation B's) packed depth of the tiled
+    entries under each column labeling of ``relabelings`` (a table old
+    col -> new col, or None for the identity): :func:`_predict_a`'s
+    integers from one counting pass a labeling
+    (native/layout_sort.cpp ``pl_band_depths``).  The entries arrive
+    sorted by row, so a band of TILE_R rows is one contiguous run that
+    owns every cell of both orientations its entries fall in: O(entries),
+    ``nbc · TILE_R`` counters an orientation.  None where the library is
+    absent or the entry set too small to be worth loading it, and where
+    the library refuses: rows out of order, or counters that would
+    outweigh the entries (a very wide, very sparse grid)."""
+    if len(r) < (1 << 18):
+        return None
+    from photon_ml_tpu.native import load_layout_sorter
+
+    lib = load_layout_sorter()
+    if lib is None:
+        return None
+    import ctypes
+
+    r64 = np.ascontiguousarray(r, np.int64)
+    c64 = np.ascontiguousarray(c, np.int64)
+    pairs = []
+    for m in relabelings:
+        table, n_table = None, 0
+        if m is not None:
+            m64 = np.ascontiguousarray(m, np.int64)
+            table, n_table = _cptr(m64, ctypes.c_int64), len(m64)
+        pair = np.zeros(2, np.int64)
+        if lib.pl_band_depths(
+            _cptr(r64, ctypes.c_int64), _cptr(c64, ctypes.c_int64),
+            len(r64), nbr, nbc, TILE_R, table, n_table,
+            _cptr(pair, ctypes.c_int64),
+        ) != 0:
+            return None
+        pairs.append((int(pair[0]), int(pair[1])))
+    return pairs
+
+
+def _labeling_depths(r, c, relabelings, nbr, nbc):
+    """Per labeling of ``relabelings`` the packed depth summed over both
+    orientations, and how it was counted: ``"band_count"``
+    (:func:`_band_depths`) where that path is open, else ``"sort"``
+    (:func:`_predict_a`, a sort of one key an entry per orientation and
+    labeling).  The integers are the same either way."""
+    pairs = _band_depths(r, c, relabelings, nbr, nbc)
+    if pairs is not None:
+        return [f + b for f, b in pairs], "band_count"
+    sums = []
+    for m in relabelings:
+        c_m = c if m is None else m[c]
+        sums.append(
+            _predict_a(r, c_m, nbr, nbc) + _predict_a(c_m, r, nbc, nbr))
+    return sums, "sort"
 
 
 def _round_robin_positions(n_cols, nbc):
@@ -1362,23 +1423,25 @@ def build_pallas_host(
         col_perm = None
         c_tiled = c
         if col_permutation and r.size and n_cols > WIN:
-            with layer_span("layout.col_perm"):
+            with layer_span("layout.col_perm") as perm_span:
                 m = _balance_col_perm(c, n_cols, nbc)
-                c_perm = m[c]
-                a_id = (_predict_a(r, c, nbr, nbc)
-                        + _predict_a(c, r, nbc, nbr))
-                a_pm = (_predict_a(r, c_perm, nbr, nbc)
-                        + _predict_a(c_perm, r, nbc, nbr))
-            # Engage only when the predicted slot-BYTE saving clearly exceeds
-            # the gather traffic the permutation adds (a d-sized take of w per
-            # matvec + an unpermute take per rmatvec).  The 8x margin covers
-            # jnp.take's per-byte inefficiency vs pure streaming for
-            # moderate-sized gathers; marginal predicted wins stay identity.
-            saving_bytes = (a_id - a_pm) * (nbr * nbc) * WIN * (CODE_BYTES + 4)
-            gather_bytes = 2 * (nbc * TILE_C) * 4
-            if a_pm < a_id and saving_bytes >= 8 * gather_bytes:
-                col_perm = m
-                c_tiled = c_perm
+                (a_id, a_pm), method = _labeling_depths(
+                    r, c, (None, m), nbr, nbc)
+                # Engage only when the predicted slot-BYTE saving clearly
+                # exceeds the gather traffic the permutation adds (a d-sized
+                # take of w per matvec + an unpermute take per rmatvec).  The
+                # 8x margin covers jnp.take's per-byte inefficiency vs pure
+                # streaming for moderate-sized gathers; marginal predicted
+                # wins stay identity.
+                saving_bytes = (
+                    (a_id - a_pm) * (nbr * nbc) * WIN * (CODE_BYTES + 4))
+                gather_bytes = 2 * (nbc * TILE_C) * 4
+                engaged = a_pm < a_id and saving_bytes >= 8 * gather_bytes
+                if engaged:
+                    col_perm = m
+                    c_tiled = m[c]
+                perm_span.set(method=method, a_identity=a_id,
+                              a_permuted=a_pm, engaged=engaged)
 
         def orient(side, rows_, cols_, vals_, **kw):
             with layer_span("layout.orient", side=side):

@@ -127,6 +127,16 @@ class TestNoHub:
         assert place["attrs"]["bytes"] == sum(
             x.nbytes for x in jax.tree.leaves(data))
 
+    def test_col_perm_says_how_it_decided(self, fit):
+        spans, data, _results = fit
+        (perm,) = _named(spans, "layout.col_perm")
+        attrs = perm["attrs"]
+        assert set(attrs) == {"method", "a_identity", "a_permuted", "engaged"}
+        # 20 k entries: too few to load the native library for
+        assert attrs["method"] == "sort"
+        assert attrs["a_identity"] > 0 and attrs["a_permuted"] > 0
+        assert attrs["engaged"] is data.features.has_col_perm
+
     def test_make_glm_data_returns_resident_data(self, fit):
         _spans, data, _results = fit
         assert all(x.is_fully_addressable and x.is_ready()

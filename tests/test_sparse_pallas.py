@@ -954,3 +954,200 @@ print(f"OK team={team}")
         C = from_coo(rows, cols, vals, n, d)
         w = jnp.asarray(rng.normal(size=d).astype(np.float32))
         assert _rel(P_nat.matvec(w), C.matvec(w)) < 1e-5
+
+
+def _band_case(name, rng):
+    """(rows sorted, cols, n_rows, n_cols) of one parity case, each with
+    >= 2^18 entries so the native path engages."""
+    from photon_ml_tpu.ops.sparse_pallas import TILE_R as T
+
+    nnz = 1 << 18
+    n, d = 3 * T - 100, 2 * T
+    rows = rng.integers(0, n, size=nnz)
+    cols = rng.integers(0, d, size=nnz)           # cells repeat
+    if name == "zipf_hot_adjacent":
+        # popularity-sorted ids: the hot columns share the first windows
+        cols = np.minimum(rng.zipf(1.2, size=nnz) - 1, d - 1)
+    elif name == "empty_bands":
+        n = 6 * T
+        rows = np.where(rng.uniform(size=nnz) < 0.7,
+                        rng.integers(T, T + 900, size=nnz),
+                        rng.integers(4 * T + 5, 5 * T, size=nnz))
+    elif name == "one_band":
+        n = T - 7
+        rows = rng.integers(0, n, size=nnz)
+    elif name == "ragged_cols_padded_relabel":
+        d = T + 53            # the round-robin lands in [n_cols, nbc*T)
+        cols = np.minimum(rng.zipf(1.5, size=nnz) - 1, d - 1)
+    elif name == "duplicate_free":
+        flat = rng.choice(n * d, size=nnz, replace=False)
+        rows, cols = flat // d, flat % d
+    else:
+        assert name == "uniform", name
+    order = np.argsort(rows, kind="stable")
+    return rows[order].astype(np.int32), cols[order].astype(np.int32), n, d
+
+
+class TestBandDepths:
+    """native/layout_sort.cpp ``pl_band_depths`` vs four ``_predict_a``
+    sorts: the four packed depths that choose the column permutation,
+    exactly, and the fall-backs to the sort."""
+
+    @staticmethod
+    def _four_by_sort(r, c, m, nbr, nbc):
+        from photon_ml_tpu.ops.sparse_pallas import _predict_a
+
+        c_perm = m[c]
+        return [(_predict_a(r, c, nbr, nbc), _predict_a(c, r, nbc, nbr)),
+                (_predict_a(r, c_perm, nbr, nbc),
+                 _predict_a(c_perm, r, nbc, nbr))]
+
+    @staticmethod
+    def _grid(r, c, n, d):
+        from photon_ml_tpu.ops.sparse_pallas import TILE_R, _balance_col_perm
+
+        nbr, nbc = -(-n // TILE_R), -(-d // TILE_R)
+        return nbr, nbc, _balance_col_perm(c, d, nbc)
+
+    @pytest.fixture
+    def native(self, monkeypatch):
+        import photon_ml_tpu.native as native_mod
+
+        monkeypatch.delenv("PHOTON_NO_NATIVE", raising=False)
+        if native_mod.load_layout_sorter() is None:
+            pytest.skip("no native toolchain here")
+
+    @pytest.mark.parametrize("name", [
+        "uniform", "zipf_hot_adjacent", "empty_bands", "one_band",
+        "ragged_cols_padded_relabel", "duplicate_free"])
+    def test_four_integers_equal_the_sorts(self, rng, native, name):
+        from photon_ml_tpu.ops.sparse_pallas import (
+            _band_depths,
+            _labeling_depths,
+        )
+
+        r, c, n, d = _band_case(name, rng)
+        nbr, nbc, m = self._grid(r, c, n, d)
+        if name == "ragged_cols_padded_relabel":
+            assert m.max() >= d          # a position of the padded range
+        want = self._four_by_sort(r, c, m, nbr, nbc)
+        assert _band_depths(r, c, (None, m), nbr, nbc) == want
+        assert _labeling_depths(r, c, (None, m), nbr, nbc) == (
+            [sum(p) for p in want], "band_count")
+
+    def test_tile_edge_not_a_power_of_two(self, native, tmp_path):
+        """``PHOTON_PALLAS_TILE`` is read at import, so a process of its
+        own: the pass divides where ``_extract_fields`` divides."""
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        script = tmp_path / "edge_check.py"
+        script.write_text(r"""
+import numpy as np
+
+from photon_ml_tpu.ops.sparse_pallas import (
+    TILE_R, _balance_col_perm, _band_depths, _predict_a)
+
+assert TILE_R == 1536
+rng = np.random.default_rng(5)
+nnz, n, d = 1 << 18, 4 * 1536 + 11, 3 * 1536 - 200
+r = np.sort(rng.integers(0, n, size=nnz)).astype(np.int32)
+c = np.minimum(rng.zipf(1.3, size=nnz) - 1, d - 1).astype(np.int32)
+nbr, nbc = -(-n // TILE_R), -(-d // TILE_R)
+m = _balance_col_perm(c, d, nbc)
+cp = m[c]
+want = [(_predict_a(r, c, nbr, nbc), _predict_a(c, r, nbc, nbr)),
+        (_predict_a(r, cp, nbr, nbc), _predict_a(cp, r, nbc, nbr))]
+got = _band_depths(r, c, (None, m), nbr, nbc)
+assert got == want, (got, want)
+print("OK", got)
+""")
+        env = dict(os.environ)
+        env.pop("PHOTON_NO_NATIVE", None)
+        env["PHOTON_PALLAS_TILE"] = "1536"
+        env["JAX_PLATFORMS"] = "cpu"
+        env["PYTHONPATH"] = repo + ":" + env.get("PYTHONPATH", "")
+        r = subprocess.run(
+            [sys.executable, str(script)], env=env,
+            capture_output=True, text=True, timeout=300,
+        )
+        assert r.returncode == 0, r.stderr[-2000:]
+        assert "OK" in r.stdout, r.stdout
+
+    @pytest.mark.parametrize("why", [
+        "rows_shuffled", "no_native", "few_entries", "grid_too_wide"])
+    def test_falls_back_to_the_sort(self, rng, native, monkeypatch, why):
+        from photon_ml_tpu.ops.sparse_pallas import (
+            _band_depths,
+            _labeling_depths,
+        )
+
+        r, c, n, d = _band_case("zipf_hot_adjacent", rng)
+        if why == "rows_shuffled":
+            shuffle = rng.permutation(len(r))
+            r, c = r[shuffle], c[shuffle]
+        elif why == "no_native":
+            monkeypatch.setenv("PHOTON_NO_NATIVE", "1")
+        elif why == "few_entries":
+            r, c = r[:1000], c[:1000]
+        else:
+            # 8 bytes of counters a column of the grid against 16 an entry
+            d = 600_000
+            c = (c.astype(np.int64) * 293 % d).astype(np.int32)
+        nbr, nbc, m = self._grid(r, c, n, d)
+        assert _band_depths(r, c, (None, m), nbr, nbc) is None
+        want = [sum(p) for p in self._four_by_sort(r, c, m, nbr, nbc)]
+        assert _labeling_depths(r, c, (None, m), nbr, nbc) == (want, "sort")
+
+    def test_wide_grid_counts_with_a_smaller_team(self, rng, native):
+        """Between "every thread has its counters" and "the sort": a grid
+        whose counters a few threads can afford is still counted."""
+        from photon_ml_tpu.ops.sparse_pallas import _band_depths
+
+        r, c, n, _ = _band_case("uniform", rng)
+        d = 200_000             # 2 * 2^18 / 200,704 cells: a team of 2
+        c = (c.astype(np.int64) * 48 % d).astype(np.int32)
+        nbr, nbc, m = self._grid(r, c, n, d)
+        assert _band_depths(r, c, (None, m), nbr, nbc) == (
+            self._four_by_sort(r, c, m, nbr, nbc))
+
+    @pytest.mark.parametrize("columns", ["clustered", "uniform"])
+    def test_permutation_decision_native_equals_numpy(
+            self, rng, native, monkeypatch, columns):
+        """The native build and the ``PHOTON_NO_NATIVE=1`` build decide
+        the permutation alike and give the same layout, leaf for leaf."""
+        import jax
+
+        from photon_ml_tpu import telemetry
+        from photon_ml_tpu.ops.sparse_pallas import build_pallas_host
+
+        # >= 2^18 entries are left once repeated cells are summed
+        n, d, nnz = 20000, 4096, 400_000
+        rows = rng.integers(0, n, size=nnz).astype(np.int64)
+        cols = rng.integers(0, d, size=nnz).astype(np.int64)
+        if columns == "clustered":
+            hot = rng.uniform(size=nnz) < 0.4
+            cols[hot] = rng.integers(0, 128, size=int(hot.sum()))
+        vals = rng.normal(size=nnz).astype(np.float32)
+
+        def build():
+            mark = max((s["id"] for s in telemetry.layer_spans()), default=0)
+            P = build_pallas_host(rows, cols, vals, n, d, max_dense=0)
+            (span,) = [s for s in telemetry.layer_spans()
+                       if s["id"] > mark and s["name"] == "layout.col_perm"]
+            return P, span["attrs"]
+
+        P_nat, at_nat = build()
+        monkeypatch.setenv("PHOTON_NO_NATIVE", "1")
+        P_py, at_py = build()
+        assert (at_nat.pop("method"), at_py.pop("method")) == (
+            "band_count", "sort")
+        assert at_nat == at_py
+        assert at_nat["engaged"] == (columns == "clustered")
+        assert P_nat.has_col_perm == P_py.has_col_perm == at_nat["engaged"]
+        leaves_nat, tree_nat = jax.tree_util.tree_flatten(P_nat)
+        leaves_py, tree_py = jax.tree_util.tree_flatten(P_py)
+        assert tree_nat == tree_py
+        for a, b in zip(leaves_nat, leaves_py):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
