@@ -66,11 +66,14 @@ class SolveResult(NamedTuple):
     # distinct from ``converged`` so callers can tell a constrained
     # stationary point from a stall.  None for solvers that fold the
     # plateau exit into ``converged`` (the historical contract); SPG
-    # reports it.
+    # reports it, and so does ``owlqn_solve``: a line search that found no
+    # decrease at a point that does not meet the pseudo-gradient test
+    # (its relative-decrease exit stays folded into ``converged``).
     stalled: Array | None = None
     # Objective (value+gradient) evaluations the solve made, the starting
     # one included, counted inside the solve; None for solvers that do not
-    # count them.  ``lbfgs_solve`` and ``tron_solve`` report it.
+    # count them.  ``lbfgs_solve``, ``tron_solve`` and ``owlqn_solve``
+    # (every line-search trial is one) report it.
     fn_evals: Array | None = None
     # What a trust-region Newton solve counted in its loops' states
     # (``tron_solve``); None for every other solver.  ``cg_iterations``:
@@ -81,6 +84,13 @@ class SolveResult(NamedTuple):
     cg_iterations: Array | None = None
     rejected_steps: Array | None = None
     boundary_exits: Array | None = None
+    # What an orthant-wise solve counted (``owlqn_solve``); None for every
+    # other solver.  ``orthant_clamps``: coordinates the projection onto
+    # the chosen orthant set to zero, summed over the accepted steps;
+    # ``nonzeros``: penalised coefficients (``l1_mask`` != 0) of the answer
+    # that are not exactly zero.
+    orthant_clamps: Array | None = None
+    nonzeros: Array | None = None
 
 
 class _LBFGSState(NamedTuple):

@@ -57,6 +57,8 @@ class _OWLQNState(NamedTuple):
     converged: Array
     values: Array
     grad_norms: Array  # pseudo-gradient norms
+    fn_evals: Array  # value+gradient evaluations so far (every trial is one)
+    clamps: Array  # coordinates the projection zeroed, over accepted steps
 
 
 def _pseudo_gradient(w: Array, grad: Array, l1: Array, mask: Array) -> Array:
@@ -102,6 +104,10 @@ def owlqn_solve(
     def full_value(w, smooth_value):
         return smooth_value + l1 * pvdot(mask, jnp.abs(w), w_axis)
 
+    def count(flags):
+        n = jnp.sum(flags, dtype=jnp.int32)
+        return lax.psum(n, w_axis) if w_axis is not None else n
+
     f0_smooth, g0 = value_and_grad(w0)
     f0 = full_value(w0, f0_smooth)
     pg0 = _pseudo_gradient(w0, g0, l1, mask)
@@ -124,26 +130,31 @@ def owlqn_solve(
         converged=pg0_norm <= config.tolerance * tol_scale,
         values=values0,
         grad_norms=gnorms0,
+        fn_evals=jnp.asarray(1, jnp.int32),
+        clamps=jnp.asarray(0, jnp.int32),
     )
 
     def cond(s: _OWLQNState):
         return jnp.logical_and(~s.done, s.k < config.max_iters)
 
     def body(s: _OWLQNState):
-        pg = _pseudo_gradient(s.w, s.grad, l1, mask)
+        with jax.named_scope("owlqn.pseudo_gradient"):
+            pg = _pseudo_gradient(s.w, s.grad, l1, mask)
 
-        direction = -_two_loop(
-            pg, s.S, s.Y, s.rho, s.gamma, s.n_pairs, w_axis
-        )
-        # Project the direction onto the descent orthant of -pg: zero any
-        # coordinate whose sign disagrees (Andrew & Gao §3.2 "alignment").
-        direction = jnp.where(direction * (-pg) > 0, direction, 0.0)
-        # Degenerate (all-zero) direction → steepest descent on pg.
-        deg = pvdot(direction, direction, w_axis) == 0.0
-        direction = jnp.where(deg, -pg, direction)
+        with jax.named_scope("owlqn.direction"):
+            direction = -_two_loop(
+                pg, s.S, s.Y, s.rho, s.gamma, s.n_pairs, w_axis
+            )
+            # Project the direction onto the descent orthant of -pg: zero
+            # any coordinate whose sign disagrees (Andrew & Gao §3.2
+            # "alignment").
+            direction = jnp.where(direction * (-pg) > 0, direction, 0.0)
+            # Degenerate (all-zero) direction → steepest descent on pg.
+            deg = pvdot(direction, direction, w_axis) == 0.0
+            direction = jnp.where(deg, -pg, direction)
 
-        # Orthant choice: sign(w) where nonzero, else sign of the step.
-        xi = jnp.where(s.w != 0, jnp.sign(s.w), jnp.sign(-pg))
+            # Orthant choice: sign(w) where nonzero, else sign of the step.
+            xi = jnp.where(s.w != 0, jnp.sign(s.w), jnp.sign(-pg))
 
         first = s.n_pairs == 0
         t = jnp.where(
@@ -181,10 +192,11 @@ def owlqn_solve(
             w, value, grad = trial(t_next)
             return (t_next, w, value, grad, n + 1)
 
-        w1, f1, g1 = trial(t)
-        t, w_new, f_new, g_new, _ = lax.while_loop(
-            ls_cond, ls_body, (t, w1, f1, g1, jnp.asarray(1, jnp.int32))
-        )
+        with jax.named_scope("owlqn.linesearch"):
+            w1, f1, g1 = trial(t)
+            t, w_new, f_new, g_new, n_trials = lax.while_loop(
+                ls_cond, ls_body, (t, w1, f1, g1, jnp.asarray(1, jnp.int32))
+            )
 
         # History pairs use the SMOOTH gradient (standard OWL-QN).
         S, Y, rho, gamma, n_pairs = update_history(
@@ -200,14 +212,24 @@ def owlqn_solve(
         # iterate (never adopt a trial point with a higher objective).
         # Convergence is measured at the iterate actually returned: the
         # pseudo-gradient test at the kept point on a stalled step, the usual
-        # tests otherwise.
+        # tests otherwise.  The relative-decrease test is taken only on a step
+        # whose direction came from a history of two pairs or more.  From an
+        # empty history the step is the normalised steepest-descent step
+        # (length min(1, |pg|)), from one pair the same direction rescaled
+        # by that pair's <s,y>/<y,y>: where the curvature along pg is large
+        # the search cuts both down, and their decrease says how short the
+        # step was, not how near the answer is.  On a warm start at a large
+        # objective it read as convergence after one iteration (and, with
+        # only the empty history exempt, after two).
         stalled = f_new >= s.value
         converged = jnp.where(
             stalled,
             pnorm(pg, w_axis) <= config.tolerance * tol_scale,
             jnp.logical_or(
                 pg_norm <= config.tolerance * tol_scale,
-                rel_impr <= config.tolerance * 1e-2,
+                jnp.logical_and(
+                    s.n_pairs >= 2, rel_impr <= config.tolerance * 1e-2
+                ),
             ),
         )
         w_keep = jnp.where(stalled, s.w, w_new)
@@ -216,6 +238,9 @@ def owlqn_solve(
         pg_norm = jnp.where(
             stalled, pnorm(pg, w_axis), pnorm(pg_new, w_axis)
         )
+        # What the projection zeroed on the accepted step: coordinates of
+        # the unprojected trial point that left the chosen orthant.
+        clamped = count((s.w + t * direction) * xi < 0)
 
         return _OWLQNState(
             w=w_keep, value=f_keep, grad=g_keep,
@@ -225,6 +250,8 @@ def owlqn_solve(
             converged=converged,
             values=s.values.at[k].set(f_keep.astype(s.values.dtype)),
             grad_norms=s.grad_norms.at[k].set(pg_norm),
+            fn_evals=s.fn_evals + n_trials,
+            clamps=s.clamps + jnp.where(stalled, 0, clamped),
         )
 
     final = lax.while_loop(cond, body, init)
@@ -237,4 +264,8 @@ def owlqn_solve(
         converged=final.converged,
         values=final.values,
         grad_norms=final.grad_norms,
+        stalled=jnp.logical_and(final.done, ~final.converged),
+        fn_evals=final.fn_evals,
+        orthant_clamps=final.clamps,
+        nonzeros=count(jnp.logical_and(final.w != 0, mask != 0)),
     )

@@ -36,6 +36,8 @@ Array = jax.Array
 
 # SolveResult's fields that only a trust-region Newton solve fills.
 _TRON_COUNTS = ("cg_iterations", "rejected_steps", "boundary_exits")
+# ... and those that only an orthant-wise solve fills.
+_OWLQN_COUNTS = ("stalled", "orthant_clamps", "nonzeros")
 
 
 class OptimizerType(enum.Enum):
@@ -291,11 +293,15 @@ class GlmOptimizationProblem:
                         # copy asked for only then idles the device ~1 ms
                         # a solve on a TPU v5e).
                         counts = (res.iterations, res.fn_evals, res.converged)
-                        if res.cg_iterations is not None:
-                            # a trust-region Newton solve's own counts ride
-                            # along; every other solve reads what it read
-                            counts += tuple(
-                                getattr(res, k) for k in _TRON_COUNTS)
+                        # a trust-region Newton or an orthant-wise solve's
+                        # own counts ride along; every other solve reads
+                        # what it read
+                        extra_names = (
+                            _TRON_COUNTS if res.cg_iterations is not None
+                            else _OWLQN_COUNTS
+                            if res.orthant_clamps is not None else ())
+                        counts += tuple(
+                            getattr(res, k) for k in extra_names)
                         counts = jax.copy_to_host_async(counts)
                         jax.block_until_ready(res.w)
                         wall = sp.stop()
@@ -313,10 +319,17 @@ class GlmOptimizationProblem:
                             sp.set(fn_evals=int(fn_evals))
                             tel.counter("solver_fn_evals").inc(int(fn_evals))
                         if extras:
-                            extras = dict(zip(_TRON_COUNTS, map(int, extras)))
+                            extras = dict(zip(extra_names, map(int, extras)))
+                            if "cg_iterations" in extras:
+                                tel.counter("solver_cg_iterations").inc(
+                                    extras["cg_iterations"])
+                            else:
+                                tel.counter("solver_orthant_clamps_total").inc(
+                                    extras["orthant_clamps"])
+                                tel.counter("solver_stalled_total").inc(
+                                    extras["stalled"])
+                                extras["stalled"] = bool(extras["stalled"])
                             sp.set(**extras)
-                            tel.counter("solver_cg_iterations").inc(
-                                extras["cg_iterations"])
                     self.grid_wall_seconds[lam] = wall
                     w = res.w
                     if on_solved is not None:

@@ -266,7 +266,11 @@ def _make_tp_owlqn_solver(task: str, mesh: Mesh, config: OWLQNConfig):
             spmd,
             mesh=mesh,
             in_specs=_TP_IN_SPECS[:5] + (P(), P(), P(FEATURE_AXIS)),
-            out_specs=_TP_OUT_SPECS,
+            # owlqn_solve counts its evaluations, its projection's clamps
+            # and its answer's non-zeros, and says when it stalled
+            out_specs=_TP_OUT_SPECS._replace(
+                stalled=P(), fn_evals=P(), orthant_clamps=P(),
+                nonzeros=P()),
             check_vma=False,
         )
     )

@@ -327,22 +327,18 @@ class TestFnEvals:
         assert int(res.fn_evals) == int(res.iterations) + 1 == len(calls)
 
     def test_solvers_that_do_not_count_say_none(self, interpret):
-        # OWL-QN (any L1 component routes to it) counts nothing yet
+        # the box-constrained path (bounds route to SPG) counts nothing
         X, y = _corpus(512, 40)
-        problem = GlmOptimizationProblem(
-            "logistic",
-            GlmOptimizationConfig(
-                optimizer=OptimizerConfig(max_iters=3),
-                regularization=RegularizationContext.l1(),
-            ),
-        )
+        problem = _problem(max_iters=3)
         mark = _mark()
+        box = (jnp.full((40,), -1.0), jnp.full((40,), 1.0))
         ((_lam, _model, res),) = problem.run_grid(
-            make_glm_data(X, y, use_pallas=False), [1.0])
-        assert res.fn_evals is None
+            make_glm_data(X, y, use_pallas=False), [1.0], bounds=box)
+        assert res.fn_evals is None and res.stalled is not None
         (solver,) = _named(_since(mark), "solver")
         assert solver["attrs"]["optimizer"] == "lbfgs"  # as configured
         assert "fn_evals" not in solver["attrs"]
+        assert "stalled" not in solver["attrs"]
         assert solver["attrs"]["iterations"] == int(res.iterations)
 
 
@@ -472,6 +468,133 @@ class TestTronCounts:
         assert len(queued) == len(read) == len(GRID)
         leaves = 3 if optimizer == "lbfgs" else 3 + len(TRON_COUNTS)
         assert all(len(jax.tree.leaves(q)) == leaves for q in queued)
+
+
+OWLQN_COUNTS = ("stalled", "orthant_clamps", "nonzeros")
+
+
+def _l1_problem(max_iters=12, tolerance=1e-3):
+    return GlmOptimizationProblem(
+        "logistic",
+        GlmOptimizationConfig(
+            optimizer=OptimizerConfig(max_iters=max_iters,
+                                      tolerance=tolerance),
+            regularization=RegularizationContext.l1(),
+        ),
+    )
+
+
+class TestOwlqnCounts:
+    """What an orthant-wise solve counts in its loop's state
+    (``SolveResult.orthant_clamps``, ``nonzeros``, and ``fn_evals`` and
+    ``stalled`` for OWL-QN too), and where ``grid_loop`` puts it."""
+
+    def test_an_lbfgs_or_tron_result_has_none_of_them(self):
+        res = lbfgs_solve(lambda w: (w @ w, 2 * w), jnp.ones(4),
+                          LBFGSConfig(max_iters=3))
+        assert [getattr(res, c) for c in OWLQN_COUNTS] == [None] * 3
+
+    def test_on_the_solver_span_without_a_hub(self, interpret):
+        assert telemetry.current() is telemetry.NULL
+        X, y = _corpus()
+        data = make_glm_data(X, y, use_pallas=True)
+        mask = jnp.ones((data.n_features,), jnp.float32).at[-1].set(0.0)
+        mark = _mark()
+        results = _l1_problem().run_grid(data, GRID, l1_mask=mask)
+        solvers = _named(_since(mark), "solver")
+        assert len(solvers) == len(GRID)
+        for s, (lam, _model, res) in zip(solvers, results):
+            assert s["attrs"] == {
+                # any L1 component routes to OWL-QN; the span says what was
+                # configured
+                "reg_weight": lam, "optimizer": "lbfgs",
+                "iterations": int(res.iterations),
+                "fn_evals": int(res.fn_evals),
+                "converged": bool(res.converged),
+                "wall_seconds": s["dur"],
+                "stalled": bool(res.stalled),
+                "orthant_clamps": int(res.orthant_clamps),
+                "nonzeros": int(res.nonzeros),
+            }
+            assert s["attrs"]["fn_evals"] > s["attrs"]["iterations"] > 0
+            w = np.asarray(res.w)
+            assert s["attrs"]["nonzeros"] == np.count_nonzero(w[:-1])
+        # the weaker penalty keeps more coefficients
+        assert solvers[0]["attrs"]["nonzeros"] < solvers[1]["attrs"][
+            "nonzeros"]
+
+    def test_under_a_hub_with_their_counters(self, interpret, tmp_path):
+        X, y = _corpus()
+        data = make_glm_data(X, y, use_pallas=True)
+        with telemetry.Telemetry(output_dir=str(tmp_path)) as tel:
+            with tel.span("train"):
+                results = _l1_problem().run_grid(data, GRID)
+        with open(os.path.join(tmp_path, "events.jsonl")) as f:
+            records = [json.loads(line) for line in f]
+        solvers = [r for r in records
+                   if r.get("type") == "span" and r["name"] == "solver"]
+        for rec, (_lam, _model, res) in zip(solvers, results):
+            for c in OWLQN_COUNTS:
+                assert rec["attrs"][c] == int(getattr(res, c))
+        counters = records[-1]["snapshot"]["counters"]
+        assert counters["solver_orthant_clamps_total"] == sum(
+            int(res.orthant_clamps) for _l, _m, res in results)
+        assert counters["solver_stalled_total"] == sum(
+            int(res.stalled) for _l, _m, res in results)
+        assert counters["solver_fn_evals"] == sum(
+            int(res.fn_evals) for _l, _m, res in results) > 0
+        assert "solver_cg_iterations" not in counters
+
+    def test_one_batched_read_a_solve(self, interpret, monkeypatch):
+        problem = _l1_problem()
+        X, y = _corpus()
+        data = make_glm_data(X, y, use_pallas=True)
+        problem.run_grid(data, GRID)  # compile outside the count
+        queued, read = [], []
+        copy, get = jax.copy_to_host_async, jax.device_get
+        monkeypatch.setattr(jax, "copy_to_host_async",
+                            lambda x: (queued.append(x), copy(x))[1])
+        monkeypatch.setattr(jax, "device_get",
+                            lambda x: (read.append(x), get(x))[1])
+        problem.run_grid(data, GRID)
+        assert len(queued) == len(read) == len(GRID)
+        assert all(len(jax.tree.leaves(q)) == 3 + len(OWLQN_COUNTS)
+                   for q in queued)
+
+    @pytest.mark.parametrize("reg_type", ["l1", "elastic_net"])
+    def test_through_glm_driver(self, tmp_path, reg_type):
+        """``glm_driver --reg-type l1`` (whatever ``--optimizer`` says) leaves
+        the counts on the ``solver`` spans of the telemetry ring."""
+        from photon_ml_tpu.data import libsvm
+        from photon_ml_tpu.drivers import glm_driver
+
+        rng = np.random.default_rng(3)
+        n, d = 600, 40
+        X = sp.random(n, d, density=0.15, random_state=3, format="csr")
+        X.data[:] = 1.0
+        w_true = rng.normal(size=d) * (rng.uniform(size=d) < 0.4)
+        y = np.where(
+            rng.uniform(size=n) < 1 / (1 + np.exp(-(X @ w_true))), 1.0, -1.0)
+        train = str(tmp_path / "t.libsvm")
+        libsvm.write_libsvm(train, X, y)
+        mark = _mark()
+        result = glm_driver.run([
+            "--train-data", train, "--output-dir", str(tmp_path / "out"),
+            "--task", "logistic", "--reg-type", reg_type,
+            "--optimizer", "lbfgs", "--reg-weights", "20,2",
+            "--n-features", str(d), "--tolerance", "1e-4",
+        ])
+        assert result
+        solvers = _named(_since(mark), "solver")
+        assert [s["attrs"]["reg_weight"] for s in solvers] == [20.0, 2.0]
+        for s in solvers:
+            attrs = s["attrs"]
+            assert attrs["stalled"] is False and attrs["converged"] is True
+            assert attrs["fn_evals"] > attrs["iterations"] >= 3
+            assert attrs["orthant_clamps"] >= 0
+            assert 0 < attrs["nonzeros"] <= d
+        assert solvers[0]["attrs"]["nonzeros"] < solvers[1]["attrs"][
+            "nonzeros"]
 
 
 class TestProfiler:
