@@ -29,6 +29,7 @@ descent can gather per-row offsets in and scatter per-row scores out
 
 from __future__ import annotations
 
+import ctypes
 import dataclasses
 import math
 import time
@@ -708,7 +709,8 @@ def _group_entities(
     # sub[:, active]); at 100k entities those 200k __getitem__ calls
     # spent ~26 s in scipy index validation for ~2 s of real work.
     # Everything below runs on the raw indptr/indices/data arrays of ONE
-    # bulk row gather, with per-bucket flat scatters filling the blocks.
+    # bulk row gather; the fill walks each entity's run of it once
+    # (native/group_fill.cpp), or scatters it a bucket at a time (numpy).
     if len(entity_keys) == 0:
         return None
     with layer_span("game.group.sort"):
@@ -851,7 +853,8 @@ def _group_entities(
             lane_of_ent[m] = np.arange(len(m))
             block_of_ent[m] = bi
 
-        # Each bucket's sorted positions and active pairs as index lists,
+        # Each bucket's sorted positions and active pairs as index lists
+        # (the numpy fill's: the native one walks the entities' runs),
         # ascending, from ONE stable sort by bucket each: a boolean mask over
         # all rows per bucket cost a pass over the whole data for every
         # bucket.  A bucket's stored entries are its rows' ranges of the CSR.
@@ -875,17 +878,16 @@ def _group_entities(
         return (np.repeat(indptr[positions] - first, lens)
                 + np.arange(int(lens.sum()))), pos_of
 
-    def features(minor, shape, lane_or_row, row, col, values):
-        """Features scattered in their storage order: ``shape`` is the
-        leading axes, then ``(rows, D)``, the last two swapped rows-minor."""
-        *lead, rows_, D = shape
-        if minor == "r":
-            X = np.zeros((*lead, D, rows_), np.float32)
-            X[(*lane_or_row, col, row)] = values
-        else:
-            X = np.zeros((*lead, rows_, D), np.float32)
-            X[(*lane_or_row, row, col)] = values
-        return X
+    def stored_zeros(minor, lead, rows_, D):
+        """Zeroed features in their storage order: the ``lead`` axes, then
+        ``(rows_, D)``, the last two swapped rows-minor."""
+        return np.zeros(
+            (*lead, D, rows_) if minor == "r" else (*lead, rows_, D),
+            np.float32)
+
+    def scatter(X, minor, lane_or_row, row, col, values):
+        X[(*lane_or_row, col, row) if minor == "r"
+          else (*lane_or_row, row, col)] = values
 
     labels = np.asarray(labels)
     weights = np.asarray(weights)
@@ -895,44 +897,59 @@ def _group_entities(
     ids_per_block: list[list] = []
     entity_to_slot: dict = {}
     block_rows_real: list[int] = []
+    # Each entity's first flat passive row and its slot among its bucket's
+    # lanes with passive rows (the native fill's; 0 without passive rows).
+    first_of_ent = np.zeros(n_ent, np.int64)
+    slot_of_ent = np.zeros(n_ent, np.int64)
     with layer_span(
-            "game.group.fill", buckets=len(ordered_buckets)):
+            "game.group.fill", buckets=len(ordered_buckets)) as fill_span:
+        # The library walks each entity's run of sorted positions once,
+        # after the buckets' arrays are allocated below (bit-identical
+        # arrays); the numpy chain of gathers and scatters fills each
+        # bucket in the loop otherwise, many times slower.
+        from photon_ml_tpu.native import load_group_fill
+
+        lib = load_group_fill()
+        fill_span.set(method="numpy" if lib is None else "native")
         for bi, m in enumerate(ordered_buckets):
             E = len(m)
             R = int(kept_counts[m].max())
             D = max(1, int(act_counts[m].max()))
             minor = _x_minor(R, D, tile)
-
-            # Row-level fills: labels/weights/row_index at (lane, local_row).
-            in_bucket = pos_idx[pos_bounds[bi]:pos_bounds[bi + 1]]
-            sel = in_bucket[keep[in_bucket]]
-            lane_r = lane_of_ent[ent_of_pos[sel]]
-            lrow = local_kept[sel]
             lab = np.zeros((E, R), np.float32)
             wts = np.zeros((E, R), np.float32)
             rindex = np.full((E, R), n_rows, np.int32)  # sentinel
-            rows_sel = row_of_pos[sel]
-            lab[lane_r, lrow] = labels[rows_sel]
-            wts[lane_r, lrow] = weights[rows_sel]
-            rindex[lane_r, lrow] = rows_sel
-            block_rows_real.append(int(len(sel)))
-
-            # col_map: each unique active (entity, col) lands at its rank
-            # within the entity's active list.
             cmap = np.full((E, D), -1, np.int32)
-            a_sel = act_idx[act_bounds[bi]:act_bounds[bi + 1]]
-            local_c = a_sel - act_before[act_ent[a_sel]]
-            cmap[lane_of_ent[act_ent[a_sel]], local_c] = act_col[a_sel]
+            X = stored_zeros(minor, (E,), R, D)
+            block_rows_real.append(int(kept_counts[m].sum()))
+            if lib is None:
+                # Row-level fills: labels/weights/row_index at
+                # (lane, local_row).
+                in_bucket = pos_idx[pos_bounds[bi]:pos_bounds[bi + 1]]
+                sel = in_bucket[keep[in_bucket]]
+                lane_r = lane_of_ent[ent_of_pos[sel]]
+                lrow = local_kept[sel]
+                rows_sel = row_of_pos[sel]
+                lab[lane_r, lrow] = labels[rows_sel]
+                wts[lane_r, lrow] = weights[rows_sel]
+                rindex[lane_r, lrow] = rows_sel
 
-            # X: every kept nnz of the bucket scatters to
-            # (lane, local_row, local_col); duplicates were pre-summed.
-            n_sel, pos_n = entries_of(sel)
-            e_n = ent_of_pos[pos_n]
-            X = features(
-                minor, (E, R, D), (lane_of_ent[e_n],), local_kept[pos_n],
-                col_rank[n_sel] - act_before[e_n], sorted_csr.data[n_sel],
-            )
-            del n_sel, pos_n, e_n
+                # col_map: each unique active (entity, col) lands at its
+                # rank within the entity's active list.
+                a_sel = act_idx[act_bounds[bi]:act_bounds[bi + 1]]
+                local_c = a_sel - act_before[act_ent[a_sel]]
+                cmap[lane_of_ent[act_ent[a_sel]], local_c] = act_col[a_sel]
+
+                # X: every kept nnz of the bucket scatters to
+                # (lane, local_row, local_col); duplicates were pre-summed.
+                n_sel, pos_n = entries_of(sel)
+                e_n = ent_of_pos[pos_n]
+                scatter(
+                    X, minor, (lane_of_ent[e_n],), local_kept[pos_n],
+                    col_rank[n_sel] - act_before[e_n],
+                    sorted_csr.data[n_sel],
+                )
+                del n_sel, pos_n, e_n
 
             ids = list(ent_keys[m])
             for lane, key in enumerate(ids):
@@ -948,35 +965,47 @@ def _group_entities(
             if not n_passive or not psv_counts[m].any():
                 passive_blocks.append(None)
                 continue
-            selp = in_bucket[~keep[in_bucket]]
-            Np = len(selp)
+            Np = int(psv_counts[m].sum())
             chunk = min(_PASSIVE_CHUNK, -(-Np // 128) * 128)
             P = -(-Np // chunk) * chunk
             has = psv_counts[m] > 0
             slot_of_lane = np.cumsum(has) - 1
             first_of_lane = np.cumsum(psv_counts[m]) - psv_counts[m]
+            first_of_ent[m] = first_of_lane
+            slot_of_ent[m] = slot_of_lane
             rindexp = np.full(P, n_rows, np.int32)  # sentinel
-            rindexp[:Np] = row_of_pos[selp]
             slot = np.full(P, slot_of_lane[-1], np.int32)
-            slot[:Np] = slot_of_lane[lane_of_ent[ent_of_pos[selp]]]
-            # Passive features project onto the ACTIVE subspace (features the
-            # entity never trained on drop, as in the reference's projected
-            # scoring): entries whose (entity, col) pair is not active drop.
-            np_sel, pos_p = entries_of(selp)
-            hit = col_hit[np_sel]
-            np_sel, pos_p = np_sel[hit], pos_p[hit]
-            e_p = ent_of_pos[pos_p]
             passive_minor = _x_minor(P, D, tile)
-            Xp = features(
-                passive_minor, (P, D), (),
-                first_of_lane[lane_of_ent[e_p]] + local_psv[pos_p],
-                col_rank[np_sel] - act_before[e_p], sorted_csr.data[np_sel],
-            )
+            Xp = stored_zeros(passive_minor, (), P, D)
+            if lib is None:
+                selp = in_bucket[~keep[in_bucket]]
+                rindexp[:Np] = row_of_pos[selp]
+                slot[:Np] = slot_of_lane[lane_of_ent[ent_of_pos[selp]]]
+                # Passive features project onto the ACTIVE subspace
+                # (features the entity never trained on drop, as in the
+                # reference's projected scoring): entries whose
+                # (entity, col) pair is not active drop.
+                np_sel, pos_p = entries_of(selp)
+                hit = col_hit[np_sel]
+                np_sel, pos_p = np_sel[hit], pos_p[hit]
+                e_p = ent_of_pos[pos_p]
+                scatter(
+                    Xp, passive_minor, (),
+                    first_of_lane[lane_of_ent[e_p]] + local_psv[pos_p],
+                    col_rank[np_sel] - act_before[e_p],
+                    sorted_csr.data[np_sel],
+                )
             passive_blocks.append(dict(
                 X=Xp, row_index=rindexp, slot=slot,
                 lanes=np.flatnonzero(has).astype(np.int32), n_rows=Np,
                 block_dim=D, chunk=chunk, x_minor=passive_minor,
             ))
+        if lib is not None:
+            _fill_native(
+                lib, blocks, passive_blocks, block_of_ent, lane_of_ent,
+                first_of_ent, slot_of_ent, starts, span_sizes, keep, order,
+                labels, weights, indptr, sorted_csr.data, col_rank, col_hit,
+                act_before, act_counts, act_col)
 
     return {
         "blocks": blocks,
@@ -986,3 +1015,63 @@ def _group_entities(
         "exact_flops": int(np.sum(kept_counts * np.maximum(act_counts, 1))),
         "block_rows_real": block_rows_real,
     }
+
+
+def _fill_native(lib, blocks, passive_blocks, block_of_ent, lane_of_ent,
+                 first_of_ent, slot_of_ent, starts, span_sizes, keep, order,
+                 labels, weights, indptr, data, col_rank, col_hit,
+                 act_before, act_counts, act_col):
+    """Every bucket's arrays of ``blocks`` and ``passive_blocks`` (the
+    fill's dicts, allocated and sentinel-filled) filled in place by
+    ``native/group_fill.cpp``'s one walk over the entities' runs.  Labels,
+    weights and values go to float32 here, as the numpy path's scatters
+    cast them."""
+    from photon_ml_tpu.native import GfBucket, GfRows
+
+    def i64(a):
+        return np.ascontiguousarray(a, np.int64)
+
+    def addr(a):
+        return None if a is None else a.ctypes.data
+
+    rank_t = np.int32 if col_rank.dtype == np.int32 else np.int64
+    held = dict(  # every buffer the call reads, alive until it returns
+        starts=i64(starts), span_sizes=i64(span_sizes),
+        keep=np.ascontiguousarray(keep).view(np.uint8), order=i64(order),
+        labels=np.ascontiguousarray(labels, np.float32),
+        weights=np.ascontiguousarray(weights, np.float32),
+        indptr=i64(indptr), data=np.ascontiguousarray(data, np.float32),
+        col_rank=np.ascontiguousarray(col_rank, rank_t),
+        col_hit=(None if col_hit is None
+                 else np.ascontiguousarray(col_hit).view(np.uint8)),
+        act_before=i64(act_before), act_counts=i64(act_counts),
+        act_col=np.ascontiguousarray(act_col, np.int32),
+        block_of=i64(block_of_ent), lane_of=i64(lane_of_ent),
+        first_of=i64(first_of_ent), slot_of=i64(slot_of_ent),
+    )
+    rows = GfRows(rank_i64=int(rank_t == np.int64), **{
+        k: addr(held[k]) for k in (
+            "starts", "span_sizes", "keep", "order", "labels", "weights",
+            "indptr", "data", "col_rank", "col_hit", "act_before",
+            "act_counts", "act_col")})
+    table = (GfBucket * len(blocks))()
+    for t, b, pb in zip(table, blocks, passive_blocks):
+        t.E, t.R, t.D = b["n_entities"], b["rows_per_entity"], b["block_dim"]
+        t.minor_r = int(b["x_minor"] == "r")
+        t.lab, t.wts = addr(b["labels"]), addr(b["weights"])
+        t.rindex, t.X, t.cmap = (
+            addr(b["row_index"]), addr(b["X"]), addr(b["col_map"]))
+        if pb is not None:
+            t.P = pb["row_index"].shape[0]
+            t.p_minor_r = int(pb["x_minor"] == "r")
+            t.rindexp, t.slot, t.Xp = (
+                addr(pb["row_index"]), addr(pb["slot"]), addr(pb["X"]))
+    p_i64 = ctypes.POINTER(ctypes.c_int64)
+    if lib.gf_fill(
+        ctypes.byref(rows), table, len(table), len(held["block_of"]),
+        len(held["data"]),
+        *(held[k].ctypes.data_as(p_i64)
+          for k in ("block_of", "lane_of", "first_of", "slot_of")),
+    ) != 0:
+        raise RuntimeError(
+            "native group fill: an index fell outside its block")

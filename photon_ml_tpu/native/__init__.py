@@ -237,3 +237,59 @@ def load_score_encoder() -> Optional[ctypes.CDLL]:
         "encoder", _ENC_SRC, "_score_encoder", "score encoder",
         _bind_encoder,
     )
+
+
+# ---------------------------------------------------------------------------
+# GAME grouping fill (the per-bucket copies of game/data._group_entities)
+# ---------------------------------------------------------------------------
+
+_FILL_SRC = os.path.join(_DIR, "group_fill.cpp")
+
+
+class GfRows(ctypes.Structure):
+    """``GfRows`` of group_fill.cpp: the grouping's arrays over sorted
+    positions, entries and entities."""
+
+    _fields_ = [
+        ("starts", ctypes.c_void_p), ("span_sizes", ctypes.c_void_p),
+        ("keep", ctypes.c_void_p), ("order", ctypes.c_void_p),
+        ("labels", ctypes.c_void_p), ("weights", ctypes.c_void_p),
+        ("indptr", ctypes.c_void_p), ("data", ctypes.c_void_p),
+        ("col_rank", ctypes.c_void_p), ("rank_i64", ctypes.c_int64),
+        ("col_hit", ctypes.c_void_p), ("act_before", ctypes.c_void_p),
+        ("act_counts", ctypes.c_void_p), ("act_col", ctypes.c_void_p),
+    ]
+
+
+class GfBucket(ctypes.Structure):
+    """``GfBucket`` of group_fill.cpp: one bucket's shapes and arrays."""
+
+    _fields_ = [
+        ("E", ctypes.c_int64), ("R", ctypes.c_int64), ("D", ctypes.c_int64),
+        ("minor_r", ctypes.c_int64), ("P", ctypes.c_int64),
+        ("p_minor_r", ctypes.c_int64),
+        ("lab", ctypes.c_void_p), ("wts", ctypes.c_void_p),
+        ("rindex", ctypes.c_void_p), ("X", ctypes.c_void_p),
+        ("cmap", ctypes.c_void_p), ("rindexp", ctypes.c_void_p),
+        ("slot", ctypes.c_void_p), ("Xp", ctypes.c_void_p),
+    ]
+
+
+def _bind_group_fill(lib: ctypes.CDLL) -> ctypes.CDLL:
+    p_i64 = ctypes.POINTER(ctypes.c_int64)
+    lib.gf_fill.argtypes = [
+        ctypes.POINTER(GfRows), ctypes.POINTER(GfBucket), ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, p_i64, p_i64, p_i64, p_i64,
+    ]
+    lib.gf_fill.restype = ctypes.c_int64
+    return lib
+
+
+def load_group_fill() -> Optional[ctypes.CDLL]:
+    """The grouping-fill library, building it if needed; None on failure
+    or when ``PHOTON_NO_NATIVE=1`` (numpy fallback — bit-identical
+    blocks, parity-tested)."""
+    return _load(
+        "group_fill", _FILL_SRC, "_group_fill", "group fill",
+        _bind_group_fill,
+    )

@@ -1129,6 +1129,131 @@ class TestBuilderDifferential:
                 )
 
 
+def _fill_problem(case):
+    """Inputs of one grouping for the fill's parity test: skewed entities
+    (some of one row, one alone in its bucket), duplicate entries summed
+    before the fill, and per case a cap, the width, dtypes and a passive
+    entry set that leaves some (entity, column) pairs inactive."""
+    rng = np.random.default_rng(3800 + sorted(FILL_CASES).index(case))
+    d, cap = FILL_CASES[case]
+    # "threads": over 2^21 entries, so the native fill runs a team
+    n_mid = 6000 if case == "threads" else 30
+    sizes = np.concatenate([
+        np.ones(5, np.int64), rng.integers(2, 400 if n_mid > 30 else 40,
+                                           size=n_mid), [300]])
+    n = int(sizes.sum())
+    keys = np.repeat(np.arange(len(sizes)), sizes)
+    rng.shuffle(keys)
+    nnz = 3 * n
+    r = rng.integers(0, n, size=nnz)
+    c = rng.integers(0, d, size=nnz)
+    if case == "inactive_passive":
+        # the heavy entity's rows all on column 0 but one passive row,
+        # which alone holds columns 1 and 2: its entries there must drop
+        heavy = np.flatnonzero(keys == len(sizes) - 1)
+        c[np.isin(r, heavy)] = 0
+        r = np.concatenate([r, [heavy[1], heavy[1]]])
+        c = np.concatenate([c, [1, 2]])
+    dtype = np.float64 if case == "float64" else np.float32
+    v = rng.normal(size=len(r)).astype(dtype)
+    X = sp.csr_matrix(sp.coo_matrix((v, (r, c)), shape=(n, d)))
+    if case == "string_keys":
+        keys = np.array([f"e{k}" for k in keys], dtype=object)
+    labels = rng.normal(size=n).astype(dtype)
+    weights = rng.uniform(0.5, 2.0, size=n).astype(dtype)
+    return keys, X, labels, weights, cap
+
+
+#: case -> (feature width, active-row cap)
+FILL_CASES = {
+    "no_cap": (9, None),
+    "capped": (9, 6),
+    "dim_1": (1, 3),
+    "inactive_passive": (3, 4),
+    "float64": (5, 5),
+    "string_keys": (6, 8),
+    "pair_sort": (7, 5),
+    "threads": (9, 64),
+}
+
+
+def _fill_blocks(monkeypatch, case, tile, native):
+    """``_group_entities``' output for ``case`` by the native fill or the
+    numpy chain, and the method its ``game.group.fill`` span read."""
+    from photon_ml_tpu import telemetry
+    from photon_ml_tpu.game import data as game_data
+
+    if native:
+        monkeypatch.delenv("PHOTON_NO_NATIVE", raising=False)
+    else:
+        monkeypatch.setenv("PHOTON_NO_NATIVE", "1")
+    if case == "pair_sort":  # the (entity, column) pairs by np.unique
+        monkeypatch.setattr(game_data, "_PAIR_TABLE_CELLS", 0)
+    keys, X, labels, weights, cap = _fill_problem(case)
+    X.sum_duplicates()
+    before = {r["id"] for r in telemetry.layer_spans()}
+    out = game_data._group_entities(
+        keys, X, labels, weights, cap, 2.0, "geometric", 16, 0, tile)
+    (fill,) = [r for r in telemetry.layer_spans()
+               if r["id"] not in before and r["name"] == "game.group.fill"]
+    return out, fill["attrs"]["method"]
+
+
+def _assert_bitwise(a, b):
+    assert a.keys() == b.keys()
+    for k in a:
+        if isinstance(a[k], np.ndarray):
+            assert (a[k].dtype, a[k].shape) == (b[k].dtype, b[k].shape), k
+            assert a[k].tobytes() == b[k].tobytes(), k
+        else:
+            assert a[k] == b[k], k
+
+
+class TestNativeGroupFill:
+    """The native fill (native/group_fill.cpp) against the numpy chain it
+    replaces, which stays as the fall-back: every array of every block and
+    passive block bit for bit, both storage orders."""
+
+    @pytest.mark.parametrize("tile", [None, (8, 128)], ids=["dense", "tpu"])
+    @pytest.mark.parametrize("case", sorted(FILL_CASES))
+    def test_matches_numpy_fill(self, monkeypatch, case, tile):
+        got, method = _fill_blocks(monkeypatch, case, tile, native=True)
+        want, ref_method = _fill_blocks(monkeypatch, case, tile, native=False)
+        assert (method, ref_method) == ("native", "numpy")
+        assert got["entity_to_slot"] == want["entity_to_slot"]
+        assert got["entity_ids"] == want["entity_ids"]
+        assert got["block_rows_real"] == want["block_rows_real"]
+        assert len(got["blocks"]) == len(want["blocks"])
+        for a, b in zip(got["blocks"], want["blocks"]):
+            _assert_bitwise(a, b)
+        assert [p is None for p in got["passive_blocks"]] == [
+            p is None for p in want["passive_blocks"]]
+        for a, b in zip(got["passive_blocks"], want["passive_blocks"]):
+            if a is not None:
+                _assert_bitwise(a, b)
+        # what the case is there to reach was reached
+        passive = [p for p in got["passive_blocks"] if p is not None]
+        cap = FILL_CASES[case][1]
+        assert bool(passive) == (cap is not None)
+        assert any(b["rows_per_entity"] == 1 for b in got["blocks"])
+        if cap is None:  # the 300-row entity is a bucket of its own
+            assert any(b["n_entities"] == 1 for b in got["blocks"])
+        if case == "dim_1":
+            assert {b["block_dim"] for b in got["blocks"]} == {1}
+        if tile is not None:  # rows-minor storage, by blocks or passive rows
+            assert "r" in {b["x_minor"] for b in got["blocks"] + passive}
+        if case == "inactive_passive":  # some passive entries dropped
+            _keys, X, *_ = _fill_problem(case)
+            rows = np.concatenate([p["row_index"][:p["n_rows"]]
+                                   for p in passive])
+            held = sum(np.count_nonzero(p["X"]) for p in passive)
+            assert 0 < held < X[rows].nnz
+
+    def test_no_native_reads_numpy(self, monkeypatch):
+        out, method = _fill_blocks(monkeypatch, "capped", None, native=False)
+        assert method == "numpy" and out["blocks"]
+
+
 class TestPartialRetraining:
     """Locked coordinates (the reference's partial retraining): held at
     the prior model, contributing scores but never retrained."""

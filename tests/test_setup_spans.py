@@ -134,7 +134,8 @@ class TestGameSetUp:
             assert kids[-1]["ts"] + kids[-1]["dur"] <= g["ts"] + g["dur"]
             covered = sum(k["dur"] for k in kids)
             assert covered >= 0.95 * g["dur"], (covered, g["dur"])
-            assert kids[-1]["attrs"] == {"buckets": g["attrs"]["buckets"]}
+            assert kids[-1]["attrs"] == {
+                "buckets": g["attrs"]["buckets"], "method": "native"}
 
     def test_game_place_reports_every_block(self, built):
         coordinates, spans = built
