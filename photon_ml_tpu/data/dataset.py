@@ -76,8 +76,10 @@ def make_glm_data(
     ``use_pallas`` selects the tiled Pallas layout for sparse features
     (ops/sparse_pallas.py): ``"auto"`` uses it on TPU when the matrix is
     large enough for the kernels to win (the tiled layout costs host build
-    time and ~3x slot memory, and pays off via ~70x faster value+grad);
-    ``True``/``False`` force it.
+    time and ~3x slot memory, and pays off via ~70x faster value+grad),
+    and its wide form (``WideSparseMatrix``) where the tile grid would be
+    mostly empty: ``grid_fill_bound`` under ``WIDE_FILL``;
+    ``True``/``False`` force the tiled layout or COO.
 
     The result is resident when this returns (every leaf is ready): the
     call is one ``data.make_glm_data`` layer span, the host build of the
@@ -115,14 +117,18 @@ def make_glm_data(
                 features = sp.vstack(
                     [features.tocsr(), sp.csr_matrix((pad, features.shape[1]))]
                 )
+            wide = False
             if use_pallas == "auto":
-                from photon_ml_tpu.ops.sparse_pallas import pallas_available
+                from photon_ml_tpu.ops.sparse_pallas import (
+                    WIDE_FILL, grid_fill_bound, pallas_available)
 
                 use_pallas = (
                     pallas_available()
                     and features.shape[0] >= 65536
                     and features.nnz >= 1 << 20
                 )
+                wide = use_pallas and grid_fill_bound(
+                    features.nnz, *features.shape) < WIDE_FILL
             nnz = int(features.nnz)
             if use_pallas:
                 from photon_ml_tpu.ops.sparse_pallas import (
@@ -130,7 +136,7 @@ def make_glm_data(
                 )
 
                 features = host_layout_from_scipy_csr(
-                    features, pad_nnz=pad_nnz, dtype=dtype)
+                    features, pad_nnz=pad_nnz, dtype=dtype, wide=wide)
                 to_device = None  # the host layout's leaves are placed below
             else:
                 to_device = partial(

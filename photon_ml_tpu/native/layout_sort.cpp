@@ -257,7 +257,7 @@ int64_t pl_scatter(
       } else {
         code32[flat] = static_cast<int32_t>(code);
       }
-      val_out[flat] = vals[e];
+      if (val_out) val_out[flat] = vals[e];  // null: a unit-value layout
     }
   }
   return spill_base[n_threads];

@@ -16,7 +16,10 @@ data-movement primitive is
 
 The kernel design exploits exactly that:
 
-- The matrix is cut into ``TILE_R x TILE_C = 2048 x 2048`` tiles; each tile's
+- :class:`PallasSparseMatrix` cuts the matrix into ``TILE_R x TILE_C =
+  2048 x 2048`` tiles and stores its grid whole, each tile at one depth:
+  right where every tile holds entries enough to fill a few sublanes (the
+  text and GAME shapes).  Each tile's
   entries are placed, ON HOST at build time, into a window-PACKED slot grid
   ``(A, 128)`` where
 
@@ -61,6 +64,29 @@ and 82), 1.37%.  A product is affine in the depth with almost no constant
 left (0.14 ms + 0.0176 ms a sublane forward on the text grid); what it
 costs now is the 16-step output sweep over mostly empty slots (PERF.md
 §5).
+
+- :class:`WideSparseMatrix` stores a matrix too wide and sparse for that
+  grid (a hashed click log: 10^6 columns, 39 entries a row, where the grid
+  would be 2 M tiles of ~10 entries a window).  Its columns are parted by
+  entry count (:func:`build_wide_host`): a WARM band, the popular prefix,
+  is a :class:`PallasSparseMatrix` of its own (stripes, permutation, the
+  tile kernel above) over only its own columns; the COLD band, every
+  other column's entries, lies in ``COLD_TILE``-square blocks whose slots
+  each carry their own gather window (:func:`_cold_kernel`: a lane gather
+  and a select a window, then the same output sweep), so that a block's
+  few entries from many windows share sublanes instead of each taking
+  one.  The band's width is chosen by the predicted device time of a
+  product pair (:func:`_warm_prefix`).  The cold band stores every block
+  of its grid at one depth an orientation: 4 x 128 x (``A_f`` + ``A_b``)
+  bytes a block, so its bytes follow rows x columns / ``COLD_TILE``^2 x
+  depth, which is 1/64 of the tile grid's count and proportional to the
+  entries only while a block's depth tracks its entries (PERF.md §5
+  gives the click cell's fill).
+
+- ``make_glm_data(use_pallas="auto")`` takes the wide form when the tile
+  grid's predicted fill at its least depth, :func:`grid_fill_bound` =
+  entries / (tiles x ``SUBPAD`` x 128), is under ``WIDE_FILL`` (1): the
+  text cells read 3.2, the GAME shard 1.44, the click log 0.08.
 
 Precision: everything is f32 — bit-comparable to the COO path (only
 summation ORDER differs).  Table construction is pure selection (no
@@ -215,6 +241,7 @@ def _build_orientation(
     nbc: int,
     depth_cap: int,
     spill_cost_ratio: float = 1024.0,
+    unit: bool = False,
 ):
     """Place entries into the window-PACKED (tile, sublane, lane) slot grid.
 
@@ -226,7 +253,10 @@ def _build_orientation(
          ``lo`` indexes the sublane's 128-wide gather table, ``ohi`` is the
          output window, ``win`` the SUBLANE's gather window (present in
          every slot, empty or not — the kernel reads lane 0's copy)
-    val  (NBR, NBC, A, 128) f32 — entry values (0 in empty slots)
+    val  (NBR, NBC, A, 128) f32 — entry values (0 in empty slots); with
+         ``unit`` a (1,) placeholder, never filled (the unit-value layout
+         streams codes alone, and at 10^8 entries the grid of values was
+         the build's largest temporary)
 
     Packing: each (tile, window) pair owns a CONTIGUOUS run of
     ``need = min(max-lane-load, depth)`` sublanes, bin-packed per tile, so
@@ -364,7 +394,10 @@ def _build_orientation(
         (winid << np.array(WIN_SHIFT, CODE_DTYPE))
         | np.array(EMPTY_MARK, CODE_DTYPE)
     )[:, :, None]
-    val = np.zeros((nt, a, WIN), np.float32)
+    val = np.zeros((1,) if unit else (nt, a, WIN), np.float32)
+
+    def shaped(v):
+        return v if unit else v.reshape(nbr, nbc, a, WIN)
 
     if lib is not None:
         import ctypes
@@ -381,15 +414,12 @@ def _build_orientation(
             _cptr(base32, ctypes.c_int32),
             len(rows64), nbc, TILE_R, depth, a, WIN_SHIFT, CODE_BYTES,
             code.ctypes.data_as(ctypes.c_void_p),
-            _cptr(val, ctypes.c_float),
+            None if unit else _cptr(val, ctypes.c_float),
             _cptr(spill_idx, ctypes.c_int64),
         )
         assert n_sp == n_spill_expected, (n_sp, n_spill_expected)
         spill_idx = spill_idx[:n_sp]
-        return (
-            code.reshape(nbr, nbc, a, WIN), val.reshape(nbr, nbc, a, WIN),
-            spill_idx, a, depth,
-        )
+        return code.reshape(nbr, nbc, a, WIN), shaped(val), spill_idx, a, depth
 
     # Decompose sorted keys with shifts (WIN is always 2^7; WINS is a
     # power of two for power-of-two tile edges), and gather per-entry
@@ -423,11 +453,11 @@ def _build_orientation(
         | (ohi[kidx].astype(np.int32) << 7)
         | glo[kidx]
     ).astype(CODE_DTYPE)
-    val.reshape(-1)[flat] = vals[kidx]
+    if not unit:
+        val.reshape(-1)[flat] = vals[kidx]
 
     spill_idx = order[~keep]            # indices into original entry arrays
-    return (code.reshape(nbr, nbc, a, WIN), val.reshape(nbr, nbc, a, WIN),
-            spill_idx, a, depth)
+    return code.reshape(nbr, nbc, a, WIN), shaped(val), spill_idx, a, depth
 
 
 # ---------------------------------------------------------------------------
@@ -1339,199 +1369,225 @@ def build_pallas_host(
     3. the residual overflow becomes a COMPACT spill COO (cost ∝ spill).
     """
     with layer_span("layout.build") as build:
-        # Canonicalize ON HOST (dedup + sort + nnz-budget pad/validation) —
-        # the old path built a full device COO first and read it straight
-        # back, paying two transfers of the entire entry set for nothing.
-        # Padding entries carry value 0, so the tiled build excludes them via
-        # the live filter below; P.nnz still reports the padded budget.
-        with layer_span("layout.canonicalize"):
-            r_all, c_all, v_all = canonicalize_coo(
-                rows, cols, vals, n_rows, n_cols, pad_nnz
-            )
-            host_coo = HostCoo(r_all, c_all, v_all, int(n_rows), int(n_cols))
-            # Zero-valued entries contribute nothing; excluding them keeps
-            # explicit zeros from faking a dense cell.
-            if np.all(v_all != 0):
-                r, c, v = r_all, c_all, v_all
-            else:
-                live = np.flatnonzero(v_all != 0)
-                r, c, v = r_all[live], c_all[live], v_all[live]
+        return _build_tiled(
+            [rows, cols, vals], n_rows, n_cols, depth_cap, pad_nnz, dtype,
+            max_dense, col_permutation, unit_values, build)
 
-        nbr = max(1, -(-n_rows // TILE_R))
-        nbc = max(1, -(-n_cols // TILE_C))
 
-        # --- dense stripe extraction (columns first, rows from the rest) --
-        with layer_span("layout.dense_split"):
-            n_valued = len(v)
-            # the slot bytes the memory guard counts: the unit-value layout
-            # streams codes alone
-            slot_bytes = CODE_BYTES + (
-                0 if unit_values is True or (
-                    unit_values == "auto" and bool(np.all(v == 1.0)))
-                else 4)
+def _build_tiled(triples, n_rows, n_cols, depth_cap, pad_nnz, dtype,
+                 max_dense, col_permutation, unit_values, build,
+                 keep_coo=True):
+    """:func:`build_pallas_host`'s body, its phases children of the open
+    ``layout.build`` span ``build`` (the wide layout's warm band is built
+    here too, inside the wide build's one span).  ``triples`` is a list
+    (rows, cols, vals), emptied here: without ``keep_coo`` (the warm band,
+    whose entries the wide matrix's own host triples hold) nothing holds
+    them once the stripes are split off -- at 10^8 entries, gigabytes of
+    the build's peak."""
+    # Canonicalize ON HOST (dedup + sort + nnz-budget pad/validation) —
+    # the old path built a full device COO first and read it straight
+    # back, paying two transfers of the entire entry set for nothing.
+    # Padding entries carry value 0, so the tiled build excludes them via
+    # the live filter below; P.nnz still reports the padded budget.
+    with layer_span("layout.canonicalize"):
+        r_all, c_all, v_all = canonicalize_coo(
+            *triples, n_rows, n_cols, pad_nnz
+        )
+        triples.clear()
+        host_coo = (HostCoo(r_all, c_all, v_all, int(n_rows), int(n_cols))
+                    if keep_coo else DroppedHostCoo(n_rows, n_cols))
+        # Zero-valued entries contribute nothing; excluding them keeps
+        # explicit zeros from faking a dense cell.
+        if np.all(v_all != 0):
+            r, c, v = r_all, c_all, v_all
+        else:
+            live = np.flatnonzero(v_all != 0)
+            r, c, v = r_all[live], c_all[live], v_all[live]
+        del r_all, c_all, v_all
 
-            def split(idx, other, vals_, n_idx, long_axis, permute):
-                """Stripe ids of one axis, their dense block (stripe,
-                long axis), which entries stay tiled, predicted depths."""
-                ids, predicted = _choose_stripes(
-                    np.bincount(idx, minlength=n_idx), long_axis, nbr * nbc,
-                    slot_bytes, depth_cap, max_dense, permute)
-                # Zero-SIZE block when absent (never read; has_dense_*
-                # gates).
-                block = np.zeros((len(ids), long_axis), np.float32)
-                if not ids.size:
-                    return ids, block, np.ones(len(idx), bool), predicted
-                # stripe of each index, -1 for the tiled ones: one table
-                # lookup per entry (np.isin + np.searchsorted were a sort
-                # and a search over every entry)
-                stripe_of = np.full(n_idx, -1, np.int32)
-                stripe_of[ids] = np.arange(len(ids), dtype=np.int32)
-                stay = np.empty(len(idx), bool)
+    nbr = max(1, -(-n_rows // TILE_R))
+    nbc = max(1, -(-n_cols // TILE_C))
 
-                def fill(lo):
-                    # Each entry has a (stripe, position) of its own, so
-                    # chunks of the entry list write disjoint cells.
-                    hi = min(len(idx), lo + _SPLIT_CHUNK)
-                    stripe = stripe_of[idx[lo:hi]]
-                    inside = stripe >= 0
-                    block[stripe[inside], other[lo:hi][inside]] = (
-                        vals_[lo:hi][inside])
-                    np.logical_not(inside, out=stay[lo:hi])
+    # --- dense stripe extraction (columns first, rows from the rest) --
+    with layer_span("layout.dense_split"):
+        n_valued = len(v)
+        # the slot bytes the memory guard counts: the unit-value layout
+        # streams codes alone
+        slot_bytes = CODE_BYTES + (
+            0 if unit_values is True or (
+                unit_values == "auto" and bool(np.all(v == 1.0)))
+            else 4)
 
-                _in_threads(fill, range(0, len(idx), _SPLIT_CHUNK))
-                return ids, block, stay, predicted
+        def split(idx, other, vals_, n_idx, long_axis, permute):
+            """Stripe ids of one axis, their dense block (stripe,
+            long axis), which entries stay tiled, predicted depths."""
+            ids, predicted = _choose_stripes(
+                np.bincount(idx, minlength=n_idx), long_axis, nbr * nbc,
+                slot_bytes, depth_cap, max_dense, permute)
+            # Zero-SIZE block when absent (never read; has_dense_*
+            # gates).
+            block = np.zeros((len(ids), long_axis), np.float32)
+            if not ids.size:
+                return ids, block, np.ones(len(idx), bool), predicted
+            # stripe of each index, -1 for the tiled ones: one table
+            # lookup per entry (np.isin + np.searchsorted were a sort
+            # and a search over every entry)
+            stripe_of = np.full(n_idx, -1, np.int32)
+            stripe_of[ids] = np.arange(len(ids), dtype=np.int32)
+            stay = np.empty(len(idx), bool)
 
-            def tiled(stay):
-                return _in_threads(lambda a: a[stay], (r, c, v))
+            def fill(lo):
+                # Each entry has a (stripe, position) of its own, so
+                # chunks of the entry list write disjoint cells.
+                hi = min(len(idx), lo + _SPLIT_CHUNK)
+                stripe = stripe_of[idx[lo:hi]]
+                inside = stripe >= 0
+                block[stripe[inside], other[lo:hi][inside]] = (
+                    vals_[lo:hi][inside])
+                np.logical_not(inside, out=stay[lo:hi])
 
-            dense_col_ids, dense_cols, stay, (a_f_pred, a_b_pred) = split(
-                c, r, v, n_cols, n_rows, col_permutation and n_cols > WIN)
-            if dense_col_ids.size:
-                r, c, v = tiled(stay)
-            dense_row_ids, dense_rows, stay, _ = split(
-                r, c, v, n_rows, n_cols, False)
-            if dense_row_ids.size:
-                r, c, v = tiled(stay)
-            stripe_nnz = n_valued - len(v)
+            _in_threads(fill, range(0, len(idx), _SPLIT_CHUNK))
+            return ids, block, stay, predicted
 
-        # --- optional column permutation (clustered-data balance) ---------
-        # Relabel columns frequency-round-robin across windows when that
-        # predicts fewer packed sublanes (summed over both orientations).
-        # Spill/dense/cold paths keep ORIGINAL column ids; only the tiled
-        # layouts see permuted ones, at the cost of one d-sized gather of the
-        # input vector (matvec side) / output vector (rmatvec side).
-        col_perm = None
-        c_tiled = c
-        if col_permutation and r.size and n_cols > WIN:
-            with layer_span("layout.col_perm") as perm_span:
-                m = _balance_col_perm(c, n_cols, nbc)
-                (a_id, a_pm), method = _labeling_depths(
-                    r, c, (None, m), nbr, nbc)
-                # Engage only when the predicted slot-BYTE saving clearly
-                # exceeds the gather traffic the permutation adds (a d-sized
-                # take of w per matvec + an unpermute take per rmatvec).  The
-                # 8x margin covers jnp.take's per-byte inefficiency vs pure
-                # streaming for moderate-sized gathers; marginal predicted
-                # wins stay identity.
-                saving_bytes = (
-                    (a_id - a_pm) * (nbr * nbc) * WIN * (CODE_BYTES + 4))
-                gather_bytes = 2 * (nbc * TILE_C) * 4
-                engaged = a_pm < a_id and saving_bytes >= 8 * gather_bytes
-                if engaged:
-                    col_perm = m
-                    c_tiled = m[c]
-                perm_span.set(method=method, a_identity=a_id,
-                              a_permuted=a_pm, engaged=engaged)
+        def tiled(stay):
+            return _in_threads(lambda a: a[stay], (r, c, v))
 
-        def orient(side, rows_, cols_, vals_, **kw):
-            with layer_span("layout.orient", side=side):
-                if side == "f":
-                    return _build_orientation(
-                        rows_, cols_, vals_, nbr, nbc, depth_cap, **kw)
+        dense_col_ids, dense_cols, stay, (a_f_pred, a_b_pred) = split(
+            c, r, v, n_cols, n_rows, col_permutation and n_cols > WIN)
+        if dense_col_ids.size:
+            r, c, v = tiled(stay)
+        dense_row_ids, dense_rows, stay, _ = split(
+            r, c, v, n_rows, n_cols, False)
+        if dense_row_ids.size:
+            r, c, v = tiled(stay)
+        stripe_nnz = n_valued - len(v)
+
+    # --- optional column permutation (clustered-data balance) ---------
+    # Relabel columns frequency-round-robin across windows when that
+    # predicts fewer packed sublanes (summed over both orientations).
+    # Spill/dense/cold paths keep ORIGINAL column ids; only the tiled
+    # layouts see permuted ones, at the cost of one d-sized gather of the
+    # input vector (matvec side) / output vector (rmatvec side).
+    col_perm = None
+    c_tiled = c
+    if col_permutation and r.size and n_cols > WIN:
+        with layer_span("layout.col_perm") as perm_span:
+            m = _balance_col_perm(c, n_cols, nbc)
+            (a_id, a_pm), method = _labeling_depths(
+                r, c, (None, m), nbr, nbc)
+            # Engage only when the predicted slot-BYTE saving clearly
+            # exceeds the gather traffic the permutation adds (a d-sized
+            # take of w per matvec + an unpermute take per rmatvec).  The
+            # 8x margin covers jnp.take's per-byte inefficiency vs pure
+            # streaming for moderate-sized gathers; marginal predicted
+            # wins stay identity.
+            saving_bytes = (
+                (a_id - a_pm) * (nbr * nbc) * WIN * (CODE_BYTES + 4))
+            gather_bytes = 2 * (nbc * TILE_C) * 4
+            engaged = a_pm < a_id and saving_bytes >= 8 * gather_bytes
+            if engaged:
+                col_perm = m
+                c_tiled = m[c]
+            perm_span.set(method=method, a_identity=a_id,
+                          a_permuted=a_pm, engaged=engaged)
+
+    # Known before the orientations are built when every tiled value is 1
+    # (a spill only takes entries away): their value grids are then never
+    # made.
+    unit_known = unit_values is True or (
+        unit_values == "auto" and bool(np.all(v == 1.0)))
+
+    def orient(side, rows_, cols_, vals_, **kw):
+        with layer_span("layout.orient", side=side):
+            if side == "f":
                 return _build_orientation(
-                    cols_, rows_, vals_, nbc, nbr, depth_cap, **kw)
+                    rows_, cols_, vals_, nbr, nbc, depth_cap,
+                    unit=unit_known, **kw)
+            return _build_orientation(
+                cols_, rows_, vals_, nbc, nbr, depth_cap, unit=unit_known,
+                **kw)
 
-        f_code, f_val, f_spill, a_f, depth_f = orient("f", r, c_tiled, v)
-        b_code, b_val, b_spill, a_b, depth_b = orient("b", r, c_tiled, v)
+    f_code, f_val, f_spill, a_f, depth_f = orient("f", r, c_tiled, v)
+    b_code, b_val, b_spill, a_b, depth_b = orient("b", r, c_tiled, v)
 
-        # Entries spilled from EITHER orientation go through the COO path for
-        # BOTH directions (keeps matvec and rmatvec consistent with one X).
-        spilled = np.union1d(f_spill, b_spill)
-        if spilled.size:
-            spill_triples = (r[spilled], c[spilled], v[spilled])
-            # Rebuild both orientations without the spilled entries so neither
-            # tiled layout double-counts them (host-side, one extra pass).
-            keep = np.ones(r.shape[0], bool)
-            keep[spilled] = False
-            f_code, f_val, fs2, a_f, depth_f = orient(
-                "f", r[keep], c_tiled[keep], v[keep], spill_cost_ratio=np.inf)
-            b_code, b_val, bs2, a_b, depth_b = orient(
-                "b", r[keep], c_tiled[keep], v[keep], spill_cost_ratio=np.inf)
-            assert fs2.size == 0 and bs2.size == 0, "re-spill after rebuild"
-        else:
-            spill_triples = (np.zeros(1, np.int64), np.zeros(1, np.int64),
-                             np.zeros(1, np.float32))
-        s_rows, s_cols, s_vals = canonicalize_coo(
-            *spill_triples, n_rows, n_cols)
-        spill_coo = SparseMatrix(
-            row_ids=s_rows, col_ids=s_cols, values=np.asarray(s_vals, dtype),
-            n_rows=int(n_rows), n_cols=int(n_cols),
-        )
+    # Entries spilled from EITHER orientation go through the COO path for
+    # BOTH directions (keeps matvec and rmatvec consistent with one X).
+    spilled = np.union1d(f_spill, b_spill)
+    if spilled.size:
+        spill_triples = (r[spilled], c[spilled], v[spilled])
+        # Rebuild both orientations without the spilled entries so neither
+        # tiled layout double-counts them (host-side, one extra pass).
+        keep = np.ones(r.shape[0], bool)
+        keep[spilled] = False
+        f_code, f_val, fs2, a_f, depth_f = orient(
+            "f", r[keep], c_tiled[keep], v[keep], spill_cost_ratio=np.inf)
+        b_code, b_val, bs2, a_b, depth_b = orient(
+            "b", r[keep], c_tiled[keep], v[keep], spill_cost_ratio=np.inf)
+        assert fs2.size == 0 and bs2.size == 0, "re-spill after rebuild"
+    else:
+        spill_triples = (np.zeros(1, np.int64), np.zeros(1, np.int64),
+                         np.zeros(1, np.float32))
+    s_rows, s_cols, s_vals = canonicalize_coo(
+        *spill_triples, n_rows, n_cols)
+    spill_coo = SparseMatrix(
+        row_ids=s_rows, col_ids=s_cols, values=np.asarray(s_vals, dtype),
+        n_rows=int(n_rows), n_cols=int(n_cols),
+    )
 
-        if col_perm is not None:
-            inv = np.full(nbc * TILE_C, n_cols, np.int64)  # default: zero slot
-            inv[col_perm] = np.arange(n_cols)
-            perm_fwd = col_perm.astype(np.int32)
-            perm_inv = inv.astype(np.int32)
-        else:
-            perm_fwd = np.zeros((1,), np.int32)
-            perm_inv = np.zeros((1,), np.int32)
+    if col_perm is not None:
+        inv = np.full(nbc * TILE_C, n_cols, np.int64)  # default: zero slot
+        inv[col_perm] = np.arange(n_cols)
+        perm_fwd = col_perm.astype(np.int32)
+        perm_inv = inv.astype(np.int32)
+    else:
+        perm_fwd = np.zeros((1,), np.int32)
+        perm_inv = np.zeros((1,), np.int32)
 
-        # Binary-matrix fast path: when every TILED value is 1.0 (dense
-        # stripes and spill keep their true values), drop the f32 val
-        # stream — the kernels then move 2 bytes/slot instead of 6 ("auto";
-        # False forces the valued layout, e.g. for A/B measurement).
-        tiled_vals = v[keep] if spilled.size else v
-        unit = (
-            unit_values == "auto"
-            and (tiled_vals.size == 0 or bool(np.all(tiled_vals == 1.0)))
-        ) or unit_values is True
-        if unit_values is True and tiled_vals.size and not np.all(
-            tiled_vals == 1.0
-        ):
-            raise ValueError(
-                "unit_values=True but tiled values are not all 1.0")
-        if unit:
-            f_val = np.zeros((1,), np.float32)
-            b_val = np.zeros((1,), np.float32)
+    # Binary-matrix fast path: when every TILED value is 1.0 (dense
+    # stripes and spill keep their true values), drop the f32 val
+    # stream — the kernels then move 2 bytes/slot instead of 6 ("auto";
+    # False forces the valued layout, e.g. for A/B measurement).
+    tiled_vals = v[keep] if spilled.size else v
+    unit = (
+        unit_values == "auto"
+        and (tiled_vals.size == 0 or bool(np.all(tiled_vals == 1.0)))
+    ) or unit_values is True
+    if unit_values is True and tiled_vals.size and not np.all(
+        tiled_vals == 1.0
+    ):
+        raise ValueError(
+            "unit_values=True but tiled values are not all 1.0")
+    if unit:
+        f_val = np.zeros((1,), np.float32)
+        b_val = np.zeros((1,), np.float32)
 
-        P = PallasSparseMatrix(
-            f_code=f_code, f_val=f_val, b_code=b_code, b_val=b_val,
-            spill=SpillData(
-                spill_coo=spill_coo, has_spill=bool(spilled.size),
-            ),
-            dense_cols=dense_cols,
-            dense_col_ids=dense_col_ids.astype(np.int32),
-            dense_rows=dense_rows,
-            dense_row_ids=dense_row_ids.astype(np.int32),
-            col_perm_fwd=perm_fwd, col_perm_inv=perm_inv,
-            host_coo=host_coo,
-            n_rows=int(n_rows), n_cols=int(n_cols),
-            nbr=nbr, nbc=nbc, a_f=a_f, a_b=a_b,
-            depth_f=depth_f, depth_b=depth_b,
-            has_dense_cols=bool(dense_col_ids.size),
-            has_dense_rows=bool(dense_row_ids.size),
-            has_col_perm=col_perm is not None,
-            unit_vals=unit,
-        )
-        build.set(
-            nnz=P.nnz, a_f=a_f, a_b=a_b,
-            a_f_predicted=a_f_pred, a_b_predicted=a_b_pred,
-            stripes=len(dense_col_ids) + len(dense_row_ids),
-            stripe_nnz_share=stripe_nnz / max(n_valued, 1),
-            stripe_bytes=int(dense_cols.nbytes + dense_rows.nbytes),
-            has_col_perm=P.has_col_perm, spilled=int(spilled.size),
-        )
+    P = PallasSparseMatrix(
+        f_code=f_code, f_val=f_val, b_code=b_code, b_val=b_val,
+        spill=SpillData(
+            spill_coo=spill_coo, has_spill=bool(spilled.size),
+        ),
+        dense_cols=dense_cols,
+        dense_col_ids=dense_col_ids.astype(np.int32),
+        dense_rows=dense_rows,
+        dense_row_ids=dense_row_ids.astype(np.int32),
+        col_perm_fwd=perm_fwd, col_perm_inv=perm_inv,
+        host_coo=host_coo,
+        n_rows=int(n_rows), n_cols=int(n_cols),
+        nbr=nbr, nbc=nbc, a_f=a_f, a_b=a_b,
+        depth_f=depth_f, depth_b=depth_b,
+        has_dense_cols=bool(dense_col_ids.size),
+        has_dense_rows=bool(dense_row_ids.size),
+        has_col_perm=col_perm is not None,
+        unit_vals=unit,
+    )
+    build.set(
+        nnz=P.nnz, a_f=a_f, a_b=a_b,
+        a_f_predicted=a_f_pred, a_b_predicted=a_b_pred,
+        stripes=len(dense_col_ids) + len(dense_row_ids),
+        stripe_nnz_share=stripe_nnz / max(n_valued, 1),
+        stripe_bytes=int(dense_cols.nbytes + dense_rows.nbytes),
+        has_col_perm=P.has_col_perm, spilled=int(spilled.size),
+    )
     return P
 
 
@@ -1547,16 +1603,17 @@ def build_pallas_matrix(*args, **kwargs) -> PallasSparseMatrix:
 
 def host_layout_from_scipy_csr(csr, depth_cap: int = 128,
                                pad_nnz: Optional[int] = None,
-                               dtype=jnp.float32) -> PallasSparseMatrix:
-    """:func:`build_pallas_host` of a scipy CSR matrix.  The way there
-    (duplicates summed, one row index an entry) is a ``layout.to_coo``
-    layer span of its own: seconds at 0.5 G entries, before
-    ``layout.build`` opens."""
+                               dtype=jnp.float32, wide: bool = False):
+    """:func:`build_pallas_host` (with ``wide``, :func:`build_wide_host`)
+    of a scipy CSR matrix.  The way there (duplicates summed, one row
+    index an entry) is a ``layout.to_coo`` layer span of its own: seconds
+    at 0.5 G entries, before ``layout.build`` opens."""
     with layer_span("layout.to_coo", nnz=int(csr.nnz)):
         csr = csr.tocsr()
         csr.sum_duplicates()
         coo = csr.tocoo()
-    return build_pallas_host(
+    build = build_wide_host if wide else build_pallas_host
+    return build(
         coo.row, coo.col, coo.data,
         csr.shape[0], csr.shape[1], depth_cap=depth_cap, pad_nnz=pad_nnz,
         dtype=dtype)
@@ -1566,6 +1623,530 @@ def from_scipy_csr_pallas(csr, depth_cap: int = 128, pad_nnz: Optional[int] = No
                           dtype=jnp.float32) -> PallasSparseMatrix:
     return place_pallas_matrix(
         host_layout_from_scipy_csr(csr, depth_cap, pad_nnz, dtype))
+
+
+# ---------------------------------------------------------------------------
+# The wide layout: a warm band of tiles and a cold band of mixed blocks
+# ---------------------------------------------------------------------------
+
+#: Below this predicted fill of the tile grid at its least depth
+#: (:func:`grid_fill_bound`) ``make_glm_data(use_pallas="auto")`` builds
+#: the wide layout, and the wide layout's warm band is the longest column
+#: prefix (by entry count) whose own grid stays at or above it.
+WIDE_FILL = 1.0
+#: Rows and columns of one cold block: 64 output windows by 64 gather
+#: windows, so that a block meets enough of a hashed tail's entries.
+COLD_TILE = 8192
+COLD_WINS = COLD_TILE // WIN
+COLD_OBITS = (COLD_WINS - 1).bit_length()
+COLD_WIN_SHIFT = 7 + COLD_OBITS
+#: int32 codes tile as (8, 128): the cold depth's granule.
+COLD_SUBPAD = 8
+#: Device seconds a cold block costs a product: a constant, the pick over
+#: 64 windows and the sweep over 64 outputs, and a slope a sublane of
+#: depth.  From one sweep on the chip (my chip run, PR 39; PERF.md
+#: section 6): 1,024 x 123 blocks of synthetic codes took 112 ms a product
+#: 8 deep and 153 ms 16 deep.
+COLD_BLOCK_SECONDS = 563e-9
+COLD_SUBLANE_SECONDS = 40.7e-9
+COLD_EMPTY = np.iinfo(np.int32).min
+#: Windows a loop trip of the cold kernel's pick and sweep handles.
+COLD_UNROLL = 8
+
+
+def grid_fill_bound(nnz: int, n_rows: int, n_cols: int) -> float:
+    """Entries over the slots the tile grid holds at its least depth
+    (every tile of the ``⌈n/2048⌉ × ⌈d/2048⌉`` grid, ``SUBPAD`` sublanes
+    of 128 slots, one orientation): an upper bound of its slot fill."""
+    nbr = max(1, -(-n_rows // TILE_R))
+    nbc = max(1, -(-n_cols // TILE_C))
+    return nnz / float(nbr * nbc * SUBPAD * WIN)
+
+
+def _cold_kernel(*refs, square, batch, chunk, unit):
+    """``_tile_kernel`` for blocks that hold a few entries of many windows
+    (a hashed vocabulary's tail): every SLOT names its own gather window,
+    where the tile kernel gives a sublane one.
+
+    code: (batch, chunk, A, 128) int32 ``win << COLD_WIN_SHIFT | ohi << 7
+          | lo`` -- the slot's gather window within the block, its output
+          window, its lane in the gather window; empty slots are negative
+    val:  (batch, chunk, A, 128) f32, absent in ``unit`` mode
+    tab:  (chunk, COLD_WINS, 128) the gather side's windows
+    out:  (batch, COLD_WINS, 128); acc: (batch, COLD_WINS*8, 128)
+
+    A slot's value is picked from the block's windows by one lane gather
+    and one select a window (exact: a non-finite vector entry reaches only
+    the slots that read it); the output sweep is the tile kernel's.
+    """
+    from jax.experimental import pallas as pl
+
+    if unit:
+        code_ref, tab_ref, out_ref, acc_ref = refs
+        val_ref = None
+    else:
+        code_ref, val_ref, tab_ref, out_ref, acc_ref = refs
+    a = code_ref.shape[2]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def block_body(t):
+        j, b = t // batch, t % batch
+        code = code_ref[b, j]
+        lo = code & (WIN - 1)
+        ohi = (code >> 7) & (COLD_WINS - 1)
+        win = (code >> COLD_WIN_SHIFT) & (COLD_WINS - 1)
+
+        def pick(i, g):
+            first = pl.multiple_of(i * COLD_UNROLL, COLD_UNROLL)
+            rows = tab_ref[j, pl.ds(first, COLD_UNROLL), :]
+            for k in range(COLD_UNROLL):
+                row = jnp.broadcast_to(rows[k:k + 1, :], (a, WIN))
+                g = jnp.where(win == first + k,
+                              jnp.take_along_axis(row, lo, axis=1), g)
+            return g
+
+        g = jax.lax.fori_loop(0, COLD_WINS // COLD_UNROLL, pick,
+                              jnp.zeros((a, WIN), jnp.float32))
+        if unit:
+            contrib = jnp.where(code >= 0, g, 0.0)
+        else:
+            v = val_ref[b, j]
+            contrib = v * v * g if square else v * g
+            contrib = jnp.where(v != 0.0, contrib, 0.0)
+
+        def sweep(i, _):
+            first = pl.multiple_of(i * COLD_UNROLL, COLD_UNROLL)
+            at = pl.ds(pl.multiple_of(first * ACC_SUB, ACC_SUB * COLD_UNROLL),
+                       ACC_SUB * COLD_UNROLL)
+            acc_ref[b, at, :] += jnp.concatenate([
+                jnp.sum(jnp.where(ohi == first + k, contrib, 0.0)
+                        .reshape(a // ACC_SUB, ACC_SUB, WIN), axis=0)
+                for k in range(COLD_UNROLL)], axis=0)
+            return 0
+
+        jax.lax.fori_loop(0, COLD_WINS // COLD_UNROLL, sweep, 0)
+
+    jax.lax.fori_loop(0, batch * chunk, lambda t, _: block_body(t), None)
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _():
+        def reduce_block(b, _):
+            for h in range(COLD_WINS):
+                out_ref[b, h:h + 1, :] = jnp.sum(
+                    acc_ref[b, h * ACC_SUB:(h + 1) * ACC_SUB, :],
+                    axis=0, keepdims=True)
+            return 0
+
+        jax.lax.fori_loop(0, batch, reduce_block, 0)
+
+
+def _divisors(n: int) -> list[int]:
+    return [d for d in range(1, n + 1) if n % d == 0]
+
+
+def _pick_cold_rect(nbo: int, nbg: int, a: int,
+                    unit: bool) -> tuple[int, int]:
+    """(batch, chunk) blocks a grid step: the most within DMA_BUDGET input
+    bytes and VMEM_BUDGET of everything the step holds (inputs, tables and
+    output double-buffered, the accumulator)."""
+    per_block = a * WIN * (4 + (0 if unit else 4))
+    window_block = COLD_WINS * WIN * 4
+    best = (1, 1)
+    for chunk in _divisors(nbg):
+        for batch in _divisors(nbo):
+            dma = batch * chunk * per_block
+            vmem = (2 * dma + 2 * chunk * window_block
+                    + batch * (2 + ACC_SUB) * window_block)
+            if (dma <= DMA_BUDGET and vmem <= VMEM_BUDGET
+                    and batch * chunk > best[0] * best[1]):
+                best = (batch, chunk)
+    return best
+
+
+@functools.partial(
+    jax.jit, static_argnames=("nbo", "nbg", "square", "side", "unit"))
+def _cold_apply(code, val, vec_padded, *, nbo, nbg, square, side,
+                unit=False):
+    """The cold band's product: ``code``/``val`` (nbo, nbg, A, 128),
+    ``vec_padded`` (nbg * COLD_TILE,) -> (nbo * COLD_TILE,).  ``side``
+    names the kernel: ``_cold_apply_fwd.N`` / ``_cold_apply_bwd.N`` in a
+    device trace."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    a = code.shape[2]
+    batch, chunk = _pick_cold_rect(nbo, nbg, a, unit)
+    tab = vec_padded.reshape(nbg, COLD_WINS, WIN)
+    kernel = functools.partial(_cold_kernel, square=square, batch=batch,
+                               chunk=chunk, unit=unit)
+    slot_spec = pl.BlockSpec((batch, chunk, a, WIN),
+                             lambda i, j: (i, j, 0, 0),
+                             memory_space=pltpu.VMEM)
+    in_specs, operands = [slot_spec], [code]
+    if not unit:
+        in_specs.append(slot_spec)
+        operands.append(val)
+    in_specs.append(pl.BlockSpec((chunk, COLD_WINS, WIN),
+                                 lambda i, j: (j, 0, 0),
+                                 memory_space=pltpu.VMEM))
+    operands.append(tab)
+    out = pl.pallas_call(
+        kernel,
+        grid=(nbo // batch, nbg // chunk),
+        out_shape=jax.ShapeDtypeStruct((nbo, COLD_WINS, WIN), jnp.float32),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((batch, COLD_WINS, WIN),
+                               lambda i, j: (i, 0, 0),
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((batch, COLD_WINS * ACC_SUB, WIN), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_interpret(),
+        name=f"_cold_apply_{side}",
+    )(*operands)
+    return out.reshape(nbo * COLD_TILE)
+
+
+def _build_cold_orientation(out_idx, gather_idx, vals, nbo, nbg, unit):
+    """Place the cold band's entries into (nbo, nbg, A, 128) blocks: lane
+    ``out_idx % 128``, each entry at the next free depth of its (block,
+    lane), A the deepest lane rounded up to ``COLD_SUBPAD`` (no spill).
+    Returns (code, val, a)."""
+    o = out_idx.astype(np.int64)
+    g = gather_idx.astype(np.int64)
+    shift = COLD_TILE.bit_length() - 1
+    block = (o >> shift) * nbg + (g >> shift)
+    key = block * WIN + (o & (WIN - 1))
+    order = np.argsort(key, kind="stable")
+    key = key[order]
+    if len(key):
+        change = np.empty(len(key), bool)
+        change[0] = True
+        np.not_equal(key[1:], key[:-1], out=change[1:])
+        starts = np.flatnonzero(change)
+        depth = np.arange(len(key)) - np.repeat(
+            starts, np.diff(np.append(starts, len(key))))
+        a = int(depth.max()) + 1
+    else:
+        depth = np.zeros(0, np.int64)
+        a = 1
+    a = -(-a // COLD_SUBPAD) * COLD_SUBPAD
+    o, g = o[order], g[order]
+    flat = ((key >> 7) * a + depth) * WIN + (key & (WIN - 1))
+    code = np.full(nbo * nbg * a * WIN, COLD_EMPTY, np.int32)
+    code[flat] = (
+        (((g & (COLD_TILE - 1)) >> 7) << COLD_WIN_SHIFT)
+        | (((o & (COLD_TILE - 1)) >> 7) << 7)
+        | (g & (WIN - 1))).astype(np.int32)
+    if unit:
+        val = np.zeros((1,), np.float32)
+    else:
+        val = np.zeros(nbo * nbg * a * WIN, np.float32)
+        val[flat] = vals[order]
+        val = val.reshape(nbo, nbg, a, WIN)
+    return code.reshape(nbo, nbg, a, WIN), val, a
+
+
+def _cold_depth(means: np.ndarray, copies: int) -> int:
+    """The cold band's predicted depth: the deepest of the lanes whose
+    loads are Poisson with ``means``, each lane met ``copies`` times (once
+    a block along the other side), at the median, rounded up to
+    ``COLD_SUBPAD``."""
+    from scipy.special import gammainc
+
+    means = means[means > 0]
+
+    def over(m):
+        # expected lanes at m or deeper: P(X >= m) = gammainc(m, mean)
+        return copies * gammainc(m, means).sum() >= 0.5
+
+    # the least m not over, by doubling then bisection: a band that holds
+    # popular columns has lanes thousands deep
+    hi = 1
+    while over(hi):
+        hi *= 2
+    lo = hi // 2 + 1
+    while lo < hi:
+        mid = (lo + hi) // 2
+        if over(mid):
+            lo = mid + 1
+        else:
+            hi = mid
+    return -(-lo // COLD_SUBPAD) * COLD_SUBPAD
+
+
+def _wide_max_stripes(n_rows: int) -> int:
+    """Stripes the wide layout's warm band may take: 512 MiB of them, the
+    threshold rule's bound (:func:`_threshold_stripes`), which the stripe
+    chooser's own guard does not keep where the grid is wide."""
+    return (512 << 20) // (4 * max(n_rows, 1))
+
+
+def _warm_prefix(counts: np.ndarray, n_rows: int, slot_bytes: int,
+                 depth_cap: int) -> np.ndarray:
+    """The warm band's columns, ascending: a prefix of the columns by
+    descending entry count, in whole column tiles, chosen by predicted
+    device time of a forward plus a backward product.  A warm band of k
+    tiles costs its predicted slots (the stripe chooser's depths and
+    stripes for those columns, :func:`_choose_stripes`) at
+    ``SLOT_SECONDS`` and its stripes at ``STRIPE_ELEMENT_SECONDS``; the
+    cold band the rest, every block at ``COLD_BLOCK_SECONDS`` an
+    orientation and ``COLD_SUBLANE_SECONDS`` a sublane of each
+    orientation's :func:`_cold_depth` (rows are alike; orientation B's
+    lanes carry the cold columns' own counts).  Candidates: k a power of two, up to the
+    longest prefix whose own grid keeps :func:`grid_fill_bound` at
+    ``WIDE_FILL`` or above (past it the warm grid is as empty as the one
+    the wide layout replaces)."""
+    order = np.argsort(-counts, kind="stable")
+    total = np.cumsum(counts[order])
+    live = int(np.count_nonzero(counts))
+    nbr = max(1, -(-n_rows // TILE_R))
+    longest = 0
+    for k in range(1, -(-live // TILE_C) + 1):
+        if total[min(k * TILE_C, live) - 1] < WIDE_FILL * nbr * k * SUBPAD * WIN:
+            break
+        longest = k
+    n_cols = len(counts)
+    cold_nbr = max(1, -(-n_rows // COLD_TILE))
+    cold_nbc = max(1, -(-n_cols // COLD_TILE))
+    blocks = cold_nbr * cold_nbc
+    # orientation B's lanes: the columns of a block that share col % 128
+    group = (np.arange(n_cols) // COLD_TILE) * WIN + np.arange(n_cols) % WIN
+
+    def seconds(k):
+        width = min(k * TILE_C, live)
+        cold = int(total[-1] - total[width - 1]) if width else int(total[-1])
+        s = 0.0
+        if cold:
+            rest = counts.astype(np.float64)
+            rest[order[:width]] = 0.0
+            a_f = _cold_depth(np.array([cold / (blocks * WIN)]), blocks * WIN)
+            a_b = _cold_depth(np.bincount(group, rest) * COLD_TILE / n_rows,
+                              cold_nbr)
+            s = blocks * (2 * COLD_BLOCK_SECONDS
+                          + COLD_SUBLANE_SECONDS * (a_f + a_b))
+        if width:
+            ids, (a_w, a_l) = _choose_stripes(
+                counts[np.sort(order[:width])], n_rows, nbr * k, slot_bytes,
+                depth_cap, _wide_max_stripes(n_rows), True)
+            s += (nbr * k * WIN * (a_w + a_l) * SLOT_SECONDS
+                  + 2 * len(ids) * n_rows * STRIPE_ELEMENT_SECONDS)
+        return s
+
+    cands = sorted({0, longest} | {
+        1 << i for i in range(longest.bit_length()) if 1 << i <= longest})
+    best = min(cands, key=seconds)
+    return np.sort(order[:min(best * TILE_C, live)]).astype(np.int64)
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=[
+        "warm", "warm_cols", "warm_slot",
+        "cold_f_code", "cold_f_val", "cold_b_code", "cold_b_val",
+    ],
+    meta_fields=[
+        "host_coo", "n_rows", "n_cols", "cold_nbr", "cold_nbc",
+        "cold_a_f", "cold_a_b", "has_warm", "has_cold", "cold_unit",
+    ],
+)
+@dataclasses.dataclass
+class WideSparseMatrix:
+    """Sparse feature matrix for wide, sparse inputs (a hashed click log:
+    10^6 columns, tens of entries a row), where the tile grid of
+    :class:`PallasSparseMatrix` would be mostly empty slots.  Two bands of
+    columns, split at build time by entry count (:func:`build_wide_host`):
+
+    - **warm** -- the popular columns, as a :class:`PallasSparseMatrix` of
+      their own (stripes, permutation, tiles; ``warm_cols`` are their
+      original ids, ascending);
+    - **cold** -- every other column's entries, in (``COLD_TILE``)^2
+      blocks whose slots each name their own gather window
+      (:func:`_cold_kernel`), in the original column order: no gather of
+      the vector on the way in.
+
+    ``warm_slot`` maps an original column to its warm position, or to the
+    appended zero for a cold one (the gradient's way back: a gather).
+    Products are float32 and scatter-free but for the warm band's stripes.
+    """
+
+    warm: Optional[PallasSparseMatrix]
+    warm_cols: Array       # (K,) int32
+    warm_slot: Array       # (n_cols,) int32, K for a cold column
+    cold_f_code: Array     # (cold_nbr, cold_nbc, A_f, 128) int32
+    cold_f_val: Array
+    cold_b_code: Array     # (cold_nbc, cold_nbr, A_b, 128) int32
+    cold_b_val: Array
+    host_coo: HostCoo
+    n_rows: int
+    n_cols: int
+    cold_nbr: int
+    cold_nbc: int
+    cold_a_f: int
+    cold_a_b: int
+    has_warm: bool
+    has_cold: bool
+    cold_unit: bool
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        return (self.n_rows, self.n_cols)
+
+    @property
+    def nnz(self) -> int:
+        return self.host_coo.nnz
+
+    def _apply(self, vec: Array, *, transpose: bool, square: bool) -> Array:
+        if transpose:
+            out = jnp.zeros((self.n_cols,), jnp.float32)
+            if self.has_cold:
+                out = _cold_apply(
+                    self.cold_b_code, self.cold_b_val,
+                    jnp.pad(vec, (0, self.cold_nbr * COLD_TILE - self.n_rows)),
+                    nbo=self.cold_nbc, nbg=self.cold_nbr, square=square,
+                    side="bwd", unit=self.cold_unit)[: self.n_cols]
+            if self.has_warm:
+                w = self.warm._apply(vec, transpose=True, square=square)
+                out = out + jnp.take(
+                    jnp.concatenate([w, jnp.zeros((1,), w.dtype)]),
+                    self.warm_slot, axis=0)
+            return out
+        out = jnp.zeros((self.n_rows,), jnp.float32)
+        if self.has_cold:
+            out = _cold_apply(
+                self.cold_f_code, self.cold_f_val,
+                jnp.pad(vec, (0, self.cold_nbc * COLD_TILE - self.n_cols)),
+                nbo=self.cold_nbr, nbg=self.cold_nbc, square=square,
+                side="fwd", unit=self.cold_unit)[: self.n_rows]
+        if self.has_warm:
+            out = out + self.warm._apply(
+                jnp.take(vec, self.warm_cols, axis=0), transpose=False,
+                square=square)
+        return out
+
+    def matvec(self, w: Array) -> Array:
+        return self._apply(w, transpose=False, square=False)
+
+    def rmatvec(self, u: Array) -> Array:
+        return self._apply(u, transpose=True, square=False)
+
+    def row_sq_matvec(self, v: Array) -> Array:
+        return self._apply(v, transpose=False, square=True)
+
+    def sq_rmatvec(self, u: Array) -> Array:
+        return self._apply(u, transpose=True, square=True)
+
+    def col_nnz(self, row_mask=None) -> Array:
+        return self.host_coo.col_nnz(row_mask)
+
+    def col_min_max(self, row_mask=None):
+        return self.host_coo.col_min_max(row_mask)
+
+    def to_dense(self):
+        return self.host_coo.to_dense()
+
+
+def build_wide_host(
+    rows: np.ndarray,
+    cols: np.ndarray,
+    vals: np.ndarray,
+    n_rows: int,
+    n_cols: int,
+    depth_cap: int = 128,
+    pad_nnz: Optional[int] = None,
+    dtype=jnp.float32,
+) -> WideSparseMatrix:
+    """The wide layout from host COO triples, on the host.  One
+    ``layout.build`` span; its children are the tiled build's phases for
+    the warm band (``layout.canonicalize``, ``.dense_split``,
+    ``.col_perm``, ``.orient``), and ``layout.wide_split`` (the warm
+    columns chosen, the entries parted) and ``layout.cold_orient`` (one
+    per side) for the cold band.  The span counts each storage class's
+    entries (``stripe_nnz``, ``warm_tiled_nnz``, ``cold_nnz``; spilled
+    ones in ``spilled``), the blocks stored against the grids'
+    (``tiles_stored``: the warm band's tiles and the cold band's blocks,
+    every one of which is stored; ``grid_tiles``: the ``TILE_R`` x
+    ``TILE_C`` grid the tiled layout would store, and the cold band's
+    grid), and the slots allocated against the entries placed in them
+    (``slots``, ``slot_entries``: both orientations of both bands)."""
+    with layer_span("layout.build") as build:
+        with layer_span("layout.canonicalize"):
+            r_all, c_all, v_all = canonicalize_coo(
+                rows, cols, vals, n_rows, n_cols, pad_nnz)
+            host_coo = HostCoo(r_all, c_all, v_all, int(n_rows), int(n_cols))
+            if np.all(v_all != 0):
+                r, c, v = r_all, c_all, v_all
+            else:
+                live = np.flatnonzero(v_all != 0)
+                r, c, v = r_all[live], c_all[live], v_all[live]
+
+        with layer_span("layout.wide_split") as split:
+            warm_cols = _warm_prefix(
+                np.bincount(c, minlength=n_cols), n_rows,
+                CODE_BYTES + (0 if np.all(v == 1.0) else 4), depth_cap)
+            k = len(warm_cols)
+            slot = np.full(n_cols, k, np.int32)
+            slot[warm_cols] = np.arange(k, dtype=np.int32)
+            local = slot[c]
+            is_warm = local < k
+            cold = ~is_warm
+            r_c, c_c, v_c = r[cold], c[cold], v[cold]
+            warm_triples = [r[is_warm], local[is_warm], v[is_warm]]
+            warm_nnz = len(warm_triples[0])
+            del r, c, v, local, is_warm, cold
+            split.set(warm_cols=k, cold_nnz=int(len(r_c)))
+
+        warm = None
+        if k:
+            warm = _build_tiled(
+                warm_triples, n_rows, k, depth_cap, None, dtype,
+                _wide_max_stripes(n_rows), True, "auto", build,
+                keep_coo=False)
+        del warm_triples
+
+        cold_nbr = max(1, -(-n_rows // COLD_TILE))
+        cold_nbc = max(1, -(-n_cols // COLD_TILE))
+        unit = bool(np.all(v_c == 1.0))
+        with layer_span("layout.cold_orient", side="f"):
+            f_code, f_val, a_f = _build_cold_orientation(
+                r_c, c_c, v_c, cold_nbr, cold_nbc, unit)
+        with layer_span("layout.cold_orient", side="b"):
+            b_code, b_val, a_b = _build_cold_orientation(
+                c_c, r_c, v_c, cold_nbc, cold_nbr, unit)
+
+        P = WideSparseMatrix(
+            warm=warm, warm_cols=warm_cols.astype(np.int32), warm_slot=slot,
+            cold_f_code=f_code, cold_f_val=f_val,
+            cold_b_code=b_code, cold_b_val=b_val, host_coo=host_coo,
+            n_rows=int(n_rows), n_cols=int(n_cols),
+            cold_nbr=cold_nbr, cold_nbc=cold_nbc, cold_a_f=a_f, cold_a_b=a_b,
+            has_warm=warm is not None, has_cold=bool(len(r_c)),
+            cold_unit=unit,
+        )
+        stripe_nnz = warm_tiled = spilled = 0
+        slots = f_code.size + b_code.size
+        tiles = cold_nbr * cold_nbc
+        if warm is not None:
+            stripe_nnz = int(np.count_nonzero(warm.dense_cols)
+                             + np.count_nonzero(warm.dense_rows))
+            spilled = build.attrs["spilled"]
+            warm_tiled = warm_nnz - stripe_nnz - spilled
+            slots += warm.f_code.size + warm.b_code.size
+            tiles += warm.nbr * warm.nbc
+        build.set(
+            nnz=P.nnz, layout="wide", warm_cols=k, stripe_nnz=stripe_nnz,
+            warm_tiled_nnz=warm_tiled, cold_nnz=int(len(r_c)),
+            tiles_stored=tiles,
+            grid_tiles=max(1, -(-n_rows // TILE_R))
+            * max(1, -(-n_cols // TILE_C)) + cold_nbr * cold_nbc,
+            cold_blocks=cold_nbr * cold_nbc, cold_a_f=a_f, cold_a_b=a_b,
+            slots=int(slots), slot_entries=2 * (warm_tiled + int(len(r_c))),
+        )
+    return P
 
 
 # ---------------------------------------------------------------------------

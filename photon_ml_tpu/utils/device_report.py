@@ -64,6 +64,11 @@ def describe_layout(features, shards: int = 1) -> str:
             f" dense={f.dense_col_ids.shape[0]}/{f.dense_row_ids.shape[0]}"
             f" spill={spill}{' perm' if f.has_col_perm else ''}]"
         )
+    elif name == "WideSparseMatrix":
+        f = features
+        name += (f"[warm={f.warm_cols.shape[0]} cols"
+                 + (f" A={f.warm.a_f}/{f.warm.a_b}" if f.has_warm else "")
+                 + f" cold A={f.cold_a_f}/{f.cold_a_b}]")
     if shards > 1:
         name += f" x{shards} row shards"
     return name
