@@ -73,11 +73,16 @@ costs now is the 16-step output sweep over mostly empty slots (PERF.md
   tile kernel above) over only its own columns; the COLD band, every
   other column's entries, lies in ``COLD_TILE``-square blocks whose slots
   each carry their own gather window (:func:`_cold_kernel`: a lane gather
-  and a select a window, then the same output sweep), so that a block's
-  few entries from many windows share sublanes instead of each taking
-  one.  The band's width is chosen by the predicted device time of a
-  product pair (:func:`_warm_prefix`).  The cold band stores every block
-  of its grid at one depth an orientation: 4 x 128 x (``A_f`` + ``A_b``)
+  and a select a window, then the same output sweep, all 64 windows
+  unrolled and several blocks a basic block), so that a block's few entries
+  from many windows share sublanes instead of each taking one.  Measured
+  on one TPU v5e (PERF.md §6): a product over 1,024 x 123 blocks 16 deep
+  takes 60.2 ms and over 123 x 1,024 blocks 24 deep 89.2 ms (153 and 173
+  ms one block a loop trip): the cold band's time now follows its
+  sweep over 64 output windows a block.  The band's width is chosen by
+  the predicted device time of a product pair (:func:`_warm_prefix`).
+  The cold band stores every block of its grid at one depth an
+  orientation: 4 x 128 x (``A_f`` + ``A_b``)
   bytes a block, so its bytes follow rows x columns / ``COLD_TILE``^2 x
   depth, which is 1/64 of the tile grid's count and proportional to the
   entries only while a block's depth tracks its entries (PERF.md §5
@@ -577,29 +582,14 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
             for h in range(WINS)
         ], axis=0)
 
-    def tile_loop(mxu, per_block):
-        """Every tile of the step, ``per_block`` bodies a basic block."""
-        n = batch * chunk
-
-        def block(first, count):
-            # unrolled by the lowering, so that the body is traced once
-            jax.lax.fori_loop(
-                0, count, lambda k, _: tile_body(first + k, mxu), None,
-                unroll=True)
-
-        if n >= per_block:
-            jax.lax.fori_loop(
-                0, n // per_block,
-                lambda i, _: block(i * per_block, per_block), None)
-        if n % per_block:
-            block(n - n % per_block, n % per_block)
-
     # One finiteness reduce per grid step chooses the table build for all
     # its tiles (a step with inf/nan anywhere in its windows is exact and
     # slower, tile after tile).
     finite = jnp.all(jnp.isfinite(tab_ref[...]))
-    pl.when(finite)(lambda: tile_loop(True, _tiles_per_block(a)))
-    pl.when(jnp.logical_not(finite))(lambda: tile_loop(False, 1))
+    pl.when(finite)(lambda: _body_loop(
+        batch * chunk, _tiles_per_block(a), lambda t: tile_body(t, True)))
+    pl.when(jnp.logical_not(finite))(lambda: _body_loop(
+        batch * chunk, 1, lambda t: tile_body(t, False)))
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _():
@@ -613,6 +603,27 @@ def _tile_kernel(*refs, square, batch, chunk, unit):
             return 0
 
         jax.lax.fori_loop(0, batch, reduce_block, 0)
+
+
+def _body_loop(n: int, per_block: int, body) -> None:
+    """``body(t)`` for t in ``range(n)``, in order, ``per_block`` bodies a
+    basic block (the remainder in one more), so that the scheduler overlaps
+    their dependency chains."""
+
+    def block(first, count):
+        # Unrolled by the lowering, so that the body is traced once.  The
+        # interpreter runs the same bodies in the same order as a loop: the
+        # unrolling is for the chip's scheduler alone, and would cost the
+        # interpreter's compiler a copy of the body each.
+        jax.lax.fori_loop(0, count, lambda k, _: body(first + k), None,
+                          unroll=not _interpret())
+
+    if n >= per_block:
+        jax.lax.fori_loop(
+            0, n // per_block,
+            lambda i, _: block(i * per_block, per_block), None)
+    if n % per_block:
+        block(n - n % per_block, n % per_block)
 
 
 def _tiles_per_block(a: int) -> int:
@@ -1642,16 +1653,20 @@ COLD_OBITS = (COLD_WINS - 1).bit_length()
 COLD_WIN_SHIFT = 7 + COLD_OBITS
 #: int32 codes tile as (8, 128): the cold depth's granule.
 COLD_SUBPAD = 8
-#: Device seconds a cold block costs a product: a constant, the pick over
-#: 64 windows and the sweep over 64 outputs, and a slope a sublane of
-#: depth.  From one sweep on the chip (my chip run, PR 39; PERF.md
-#: section 6): 1,024 x 123 blocks of synthetic codes took 112 ms a product
-#: 8 deep and 153 ms 16 deep.
-COLD_BLOCK_SECONDS = 563e-9
-COLD_SUBLANE_SECONDS = 40.7e-9
+#: Device seconds a cold block costs a product: a constant and a slope a
+#: sublane of depth, the line through two depths of the cold kernel as
+#: ``_cold_bodies`` runs it.  From ``scripts/cold_kernel_sweep.py`` on one
+#: TPU v5e (PERF.md section 6): 1,024 x 123 blocks of synthetic codes took
+#: 19.05 ms a product 8 deep (16 blocks a basic block) and 60.22 ms 16 deep
+#: (8), where one block a loop trip, the 64-window pick and sweep in loops
+#: of 8, took 112.6 and 153.0 ms.  The line's constant is below zero: a
+#: block 8 deep, one vreg of codes, costs less than the slope says (the
+#: band is never shallower: a pair 8 and 8 deep is 302 ns a block); deeper
+#: the line is within 12% (24 deep 722.6 ns a block, the line 805; 32 deep
+#: 1,214.0, the line 1,132).
+COLD_BLOCK_SECONDS = -175.6e-9
+COLD_SUBLANE_SECONDS = 40.86e-9
 COLD_EMPTY = np.iinfo(np.int32).min
-#: Windows a loop trip of the cold kernel's pick and sweep handles.
-COLD_UNROLL = 8
 
 
 def grid_fill_bound(nnz: int, n_rows: int, n_cols: int) -> float:
@@ -1663,7 +1678,7 @@ def grid_fill_bound(nnz: int, n_rows: int, n_cols: int) -> float:
     return nnz / float(nbr * nbc * SUBPAD * WIN)
 
 
-def _cold_kernel(*refs, square, batch, chunk, unit):
+def _cold_kernel(*refs, square, batch, chunk, unit, bodies):
     """``_tile_kernel`` for blocks that hold a few entries of many windows
     (a hashed vocabulary's tail): every SLOT names its own gather window,
     where the tile kernel gives a sublane one.
@@ -1677,7 +1692,15 @@ def _cold_kernel(*refs, square, batch, chunk, unit):
 
     A slot's value is picked from the block's windows by one lane gather
     and one select a window (exact: a non-finite vector entry reaches only
-    the slots that read it); the output sweep is the tile kernel's.
+    the slots that read it); the output sweep is the tile kernel's.  A
+    block's pick and sweep are unrolled over all 64 windows, and
+    ``_cold_bodies`` blocks share a basic block (:func:`_body_loop`), so
+    that the scheduler overlaps their chains: the kernel waited on latency,
+    not on the vector unit.  Each output block still adds its blocks in the
+    order of j, so the products are the same to the bit whatever the
+    number.  A pick as a tree over the window id's six bits (63 selects,
+    six deep) timed the same to 2% and slower at 24 deep and more
+    (PERF.md §6).
     """
     from jax.experimental import pallas as pl
 
@@ -1693,43 +1716,31 @@ def _cold_kernel(*refs, square, batch, chunk, unit):
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def block_body(t):
+        # j-major: per output block b the blocks add up in the order of j.
         j, b = t // batch, t % batch
         code = code_ref[b, j]
         lo = code & (WIN - 1)
         ohi = (code >> 7) & (COLD_WINS - 1)
         win = (code >> COLD_WIN_SHIFT) & (COLD_WINS - 1)
-
-        def pick(i, g):
-            first = pl.multiple_of(i * COLD_UNROLL, COLD_UNROLL)
-            rows = tab_ref[j, pl.ds(first, COLD_UNROLL), :]
-            for k in range(COLD_UNROLL):
+        g = jnp.zeros((a, WIN), jnp.float32)
+        for first in range(0, COLD_WINS, ACC_SUB):
+            rows = tab_ref[j, first:first + ACC_SUB, :]
+            for k in range(ACC_SUB):
                 row = jnp.broadcast_to(rows[k:k + 1, :], (a, WIN))
-                g = jnp.where(win == first + k,
-                              jnp.take_along_axis(row, lo, axis=1), g)
-            return g
-
-        g = jax.lax.fori_loop(0, COLD_WINS // COLD_UNROLL, pick,
-                              jnp.zeros((a, WIN), jnp.float32))
+                g = jnp.where(win == first + k, jnp.take_along_axis(
+                    row, lo, axis=1, mode="promise_in_bounds"), g)
         if unit:
             contrib = jnp.where(code >= 0, g, 0.0)
         else:
             v = val_ref[b, j]
             contrib = v * v * g if square else v * g
             contrib = jnp.where(v != 0.0, contrib, 0.0)
+        for h in range(COLD_WINS):
+            acc_ref[b, h * ACC_SUB:(h + 1) * ACC_SUB, :] += jnp.sum(
+                jnp.where(ohi == h, contrib, 0.0)
+                .reshape(a // ACC_SUB, ACC_SUB, WIN), axis=0)
 
-        def sweep(i, _):
-            first = pl.multiple_of(i * COLD_UNROLL, COLD_UNROLL)
-            at = pl.ds(pl.multiple_of(first * ACC_SUB, ACC_SUB * COLD_UNROLL),
-                       ACC_SUB * COLD_UNROLL)
-            acc_ref[b, at, :] += jnp.concatenate([
-                jnp.sum(jnp.where(ohi == first + k, contrib, 0.0)
-                        .reshape(a // ACC_SUB, ACC_SUB, WIN), axis=0)
-                for k in range(COLD_UNROLL)], axis=0)
-            return 0
-
-        jax.lax.fori_loop(0, COLD_WINS // COLD_UNROLL, sweep, 0)
-
-    jax.lax.fori_loop(0, batch * chunk, lambda t, _: block_body(t), None)
+    _body_loop(batch * chunk, bodies, block_body)
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _():
@@ -1766,6 +1777,36 @@ def _pick_cold_rect(nbo: int, nbg: int, a: int,
     return best
 
 
+def _cold_bodies(a: int) -> int:
+    """Cold blocks the kernel's loop puts into one basic block, from the
+    depth: the most, in powers of two up to 16, that keep 128 sublanes or
+    fewer in flight.  Timed on a TPU v5e (``scripts/cold_kernel_sweep.py``;
+    PERF.md §6), a product over 1,024 x 123 blocks of synthetic codes at 1
+    / 2 / 4 / 8 / 16 blocks a basic block: 8 deep 32.23 / 25.11 / 21.56 /
+    19.87 / 19.05 ms; 16 deep 70.70 / 64.89 / 61.72 / 60.22 / 59.44; 24
+    deep 101.39 / 92.74 / 91.02 / 89.44 / 89.30; 32 deep 162.15 / 158.17 /
+    152.90 / 151.55 / 151.58; over 123 x 1,024 blocks 24 deep 100.91 /
+    89.99 / 89.17 / 88.22 / 88.23; and, one sweep earlier, 40 deep 191.88 /
+    179.53 / 177.42 / 176.44 / 175.78 and 64 deep 320.30 / 305.67 / 301.16
+    / 298.61 / 297.69.  The rule is within 2.7% of the fastest at every
+    depth; every body more is one more copy of the 64-window body for each
+    compile of a program to lower (at 16 everywhere the click solve's
+    program took 19.4 s to trace and lower, at this rule 7.8 s, with the
+    pick and sweep in loops of 8 windows 3.7 s)."""
+    k = 1
+    while k < 16 and 2 * k * a <= 128:
+        k *= 2
+    return k
+
+
+def _cold_plan(nbo: int, nbg: int, a: int,
+               unit: bool) -> tuple[int, int, int]:
+    """(batch, chunk, bodies): the grid step's blocks and the blocks a basic
+    block that the cold kernel traces with (no more than the step holds)."""
+    batch, chunk = _pick_cold_rect(nbo, nbg, a, unit)
+    return batch, chunk, min(_cold_bodies(a), batch * chunk)
+
+
 @functools.partial(
     jax.jit, static_argnames=("nbo", "nbg", "square", "side", "unit"))
 def _cold_apply(code, val, vec_padded, *, nbo, nbg, square, side,
@@ -1778,10 +1819,10 @@ def _cold_apply(code, val, vec_padded, *, nbo, nbg, square, side,
     from jax.experimental.pallas import tpu as pltpu
 
     a = code.shape[2]
-    batch, chunk = _pick_cold_rect(nbo, nbg, a, unit)
+    batch, chunk, bodies = _cold_plan(nbo, nbg, a, unit)
     tab = vec_padded.reshape(nbg, COLD_WINS, WIN)
     kernel = functools.partial(_cold_kernel, square=square, batch=batch,
-                               chunk=chunk, unit=unit)
+                               chunk=chunk, unit=unit, bodies=bodies)
     slot_spec = pl.BlockSpec((batch, chunk, a, WIN),
                              lambda i, j: (i, j, 0, 0),
                              memory_space=pltpu.VMEM)
@@ -2071,8 +2112,11 @@ def build_wide_host(
     (``tiles_stored``: the warm band's tiles and the cold band's blocks,
     every one of which is stored; ``grid_tiles``: the ``TILE_R`` x
     ``TILE_C`` grid the tiled layout would store, and the cold band's
-    grid), and the slots allocated against the entries placed in them
-    (``slots``, ``slot_entries``: both orientations of both bands)."""
+    grid), the slots allocated against the entries placed in them
+    (``slots``, ``slot_entries``: both orientations of both bands), and the
+    cold blocks each orientation's kernel puts into one basic block
+    (``cold_bodies_f``, ``cold_bodies_b``, beside the depths ``cold_a_f``,
+    ``cold_a_b``)."""
     with layer_span("layout.build") as build:
         with layer_span("layout.canonicalize"):
             r_all, c_all, v_all = canonicalize_coo(
@@ -2144,6 +2188,8 @@ def build_wide_host(
             grid_tiles=max(1, -(-n_rows // TILE_R))
             * max(1, -(-n_cols // TILE_C)) + cold_nbr * cold_nbc,
             cold_blocks=cold_nbr * cold_nbc, cold_a_f=a_f, cold_a_b=a_b,
+            cold_bodies_f=_cold_plan(cold_nbr, cold_nbc, a_f, unit)[2],
+            cold_bodies_b=_cold_plan(cold_nbc, cold_nbr, a_b, unit)[2],
             slots=int(slots), slot_entries=2 * (warm_tiled + int(len(r_c))),
         )
     return P

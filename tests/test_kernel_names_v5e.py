@@ -1,7 +1,8 @@
-"""The tile kernel's names in a program compiled for the chip: a described
-``v5e:2x2`` (nothing attached, nothing runs).  ``pl.pallas_call(name=...)``
-renames the HLO instruction, and ``benchmarks/trace.py`` finds the kernel's
-device events by that name, so the name is part of the yardstick.  Beside
+"""The tile kernel's and the cold band's kernel's names in a program
+compiled for the chip: a described ``v5e:2x2`` (nothing attached, nothing
+runs).  ``pl.pallas_call(name=...)`` renames the HLO instruction, and the
+benchmark finds the kernels' device events by those names, so the names are
+part of the yardstick.  Beside
 them, what else only the chip's compiler can say: that the dense stripes stay
 bandwidth-bound fusions, and that the random effect's Newton body fuses its
 pairs Hessian.
@@ -83,6 +84,78 @@ def test_kernel_instruction_names(monkeypatch, one_chip, layout_shapes,
     assert len(calls) == 1, calls
     stem, _, suffix = calls[0].rpartition(".")
     assert stem == name and suffix.isdigit()
+
+
+def _pallas_calls(compiled_text):
+    """Names of every Mosaic kernel's custom call, the cold band's too."""
+    return [trace_mod.short_name(ln.strip())
+            for ln in compiled_text.splitlines()
+            if trace_mod.KERNEL_OPCODE in ln and "tpu_custom_call" in ln]
+
+
+@pytest.fixture(scope="module")
+def wide_shapes(one_chip):
+    """A valued wide layout with both bands (its 2,048 most popular columns
+    warm), shapes only."""
+    from unittest import mock
+
+    from photon_ml_tpu.ops import sparse_pallas as spl
+
+    rng = np.random.default_rng(1)
+    n, d = 2 * spl.COLD_TILE, 3 * spl.COLD_TILE
+    rows = rng.integers(0, n, NNZ)
+    cols = np.where(rng.random(NNZ) < 0.5, rng.integers(0, 2048, NNZ),
+                    rng.integers(0, d, NNZ))
+    warm = np.arange(2048)
+    with mock.patch.object(spl, "_warm_prefix", lambda *_: warm):
+        P = spl.build_wide_host(rows, cols, rng.normal(size=NNZ).astype(
+            np.float32), n, d)
+    assert P.has_warm and P.has_cold and not P.cold_unit
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        P)
+
+
+@pytest.mark.parametrize("product, length, side", [
+    ("matvec", 3 * 8192, "fwd"), ("rmatvec", 2 * 8192, "bwd"),
+    ("row_sq_matvec", 3 * 8192, "fwd"), ("sq_rmatvec", 2 * 8192, "bwd"),
+])
+def test_wide_kernel_instruction_names(monkeypatch, one_chip, wide_shapes,
+                                       product, length, side):
+    """The wide layout's product is the warm band's tile kernel and the cold
+    band's kernel, one each, under the names the benchmark's readers sum."""
+    monkeypatch.delenv("PHOTON_PALLAS_INTERPRET", raising=False)
+    vec = jax.ShapeDtypeStruct((length,), jnp.float32, sharding=one_chip)
+    with jax.enable_x64(False):
+        text = jax.jit(lambda P, v: getattr(P, product)(v)).lower(
+            wide_shapes, vec).compile().as_text()
+    stems = sorted(c.rpartition(".")[0] for c in _pallas_calls(text))
+    assert stems == [f"_cold_apply_{side}", f"_tiled_apply_{side}"]
+
+
+# ``glm_click_fit``'s cold band (PERF.md §4): 1,024 row blocks x 123 column
+# blocks of unit codes, 16 deep forward and 24 backward, and the sweep's
+# other depths: the bodies a basic block and the window pick lower under
+# Mosaic within the kernel's VMEM.
+@pytest.mark.parametrize("side, a", [
+    ("fwd", 16), ("bwd", 24), ("fwd", 8), ("fwd", 32), ("bwd", 40),
+])
+def test_cold_kernel_compiles_at_cell_depths(monkeypatch, one_chip, side, a):
+    from photon_ml_tpu.ops.sparse_pallas import COLD_TILE, _cold_apply
+
+    monkeypatch.delenv("PHOTON_PALLAS_INTERPRET", raising=False)
+    nbo, nbg = (1024, 123) if side == "fwd" else (123, 1024)
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with jax.enable_x64(False):
+        text = _cold_apply.lower(
+            struct((nbo, nbg, a, WIN), jnp.int32), struct((1,), jnp.float32),
+            struct((nbg * COLD_TILE,), jnp.float32), nbo=nbo, nbg=nbg,
+            square=False, side=side, unit=True).compile().as_text()
+    (call,) = _pallas_calls(text)
+    assert call.rpartition(".")[0] == f"_cold_apply_{side}"
 
 
 # The grids of the two benchmark cells (PERF.md §4), shapes only: row blocks

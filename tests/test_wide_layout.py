@@ -1,9 +1,12 @@
 """The wide layout (``ops/sparse_pallas.WideSparseMatrix``: a warm band of
 tiles and a cold band of mixed blocks) on the CPU, Pallas in interpret mode:
-its four products against a float64 SciPy CSR, a warm-started L-BFGS grid
-on it against a float64 objective, the split's counts, and the rule of
-``make_glm_data(use_pallas="auto")``, which has to build exactly today's
-layout at every accepted configuration of the benchmark."""
+its four products against a float64 SciPy CSR (both bands, and the cold band
+alone at several depths and block grids, where its kernel's products are
+also the same to the bit as with one block a basic block), non-finite
+vector entries kept to the rows and columns that read them, a warm-started
+L-BFGS grid on it against a float64 objective, the split's counts, and the
+rule of ``make_glm_data(use_pallas="auto")``, which has to build exactly
+today's layout at every accepted configuration of the benchmark."""
 
 import dataclasses
 import json
@@ -60,17 +63,67 @@ def _both_bands(X):
         return spl.build_wide_host(coo.row, coo.col, coo.data, *X.shape)
 
 
+def _cold_only(seed, unit, nbr, nbc, depth):
+    """Every column cold, over ``nbr`` x ``nbc`` cold blocks: scattered
+    entries, one row with ``depth`` entries in one column block and one
+    column with ``depth`` entries in one row block, so that both
+    orientations are ``depth`` deep (a multiple of ``COLD_SUBPAD``)."""
+    rng = np.random.default_rng(seed)
+    n, d = nbr * spl.COLD_TILE, nbc * spl.COLD_TILE
+    # nothing else in lane 0 of a block, where the deep row and column lie
+    rows = rng.integers(0, n // 128, 3000) * 128 + rng.integers(1, 128, 3000)
+    cols = rng.integers(0, d // 128, 3000) * 128 + rng.integers(1, 128, 3000)
+    rows = np.concatenate([rows, np.zeros(depth, np.int64),
+                           np.arange(1, depth + 1)])
+    cols = np.concatenate([cols, np.arange(1, depth + 1),
+                           np.zeros(depth, np.int64)])
+    vals = (np.ones(rows.size, np.float32) if unit
+            else (rng.uniform(0.5, 2.0, rows.size) * rng.choice(
+                [-1.0, 1.0], rows.size)).astype(np.float32))
+    X = sp.coo_matrix((vals, (rows, cols)), shape=(n, d)).tocsr()
+    X.sum_duplicates()
+    if unit:
+        X.data[:] = 1.0
+    coo = X.tocoo()
+    with mock.patch.object(spl, "_warm_prefix",
+                           lambda *_: np.zeros(0, np.int64)):
+        P = spl.build_wide_host(coo.row, coo.col, coo.data, n, d)
+    assert not P.has_warm and (P.cold_a_f, P.cold_a_b) == (depth, depth)
+    return X, P
+
+
 def _close(got, want, scale):
     np.testing.assert_array_less(
         np.abs(np.asarray(got, np.float64) - want), 2e-6 * scale + 1e-6)
 
 
-@pytest.mark.parametrize("unit", [True, False], ids=["binary", "valued"])
-def test_products_against_float64(unit):
-    X = _wide_matrix(3 + unit, unit)
-    P = _both_bands(X)
-    assert P.has_warm and P.has_cold
-    assert P.cold_unit is unit and P.warm.unit_vals is unit
+# Both bands, then the cold band alone: 41 row blocks (a prime past the grid
+# step's VMEM, so the forward product's steps hold one output block), 8 to
+# 40 deep, two blocks a basic block, so that every grid step (3 blocks
+# forward, 3 or 123 backward) leaves a remainder.
+PRODUCT_CASES = [
+    pytest.param(True, None, id="binary"),
+    pytest.param(False, None, id="valued"),
+    pytest.param(True, 8, id="cold-8-binary"),
+    pytest.param(False, 16, id="cold-16-valued"),
+    pytest.param(True, 24, id="cold-24-binary"),
+    pytest.param(False, 40, id="cold-40-valued"),
+]
+
+
+@pytest.mark.parametrize("unit, depth", PRODUCT_CASES)
+def test_products_against_float64(unit, depth):
+    bodies = spl._cold_bodies
+    if depth is None:
+        X = _wide_matrix(3 + unit, unit)
+        P = _both_bands(X)
+        assert P.has_warm and P.has_cold
+        assert P.cold_unit is unit and P.warm.unit_vals is unit
+    else:
+        X, P = _cold_only(depth + unit, unit, 41, 3, depth)
+        assert P.cold_unit is unit
+        assert spl._pick_cold_rect(41, 3, depth, unit)[0] == 1
+        bodies = lambda a: 2  # noqa: E731
     P = spl.place_pallas_matrix(P)
     rng = np.random.default_rng(7)
     n, d = X.shape
@@ -85,13 +138,29 @@ def test_products_against_float64(unit):
         S64 = X64.multiply(X64)
         products += [("row_sq_matvec", w, S64 @ w, S64 @ abs(w)),
                      ("sq_rmatvec", u, S64.T @ u, S64.T @ abs(u))]
-    for name, vec, want, scale in products:
-        got = jax.jit(lambda P, v, name=name: getattr(P, name)(v))(
-            P, jnp.asarray(vec))
-        _close(got, want, scale)
-    # the empty rows and the empty column tiles read exact zeros
-    assert not np.any(np.asarray(P.matvec(jnp.asarray(w)))[100:164])
-    assert not np.any(np.asarray(P.rmatvec(jnp.asarray(u)))[20480:30720])
+    if depth is not None:
+        products = products[:3]
+    with mock.patch.object(spl, "_cold_bodies", bodies):
+        spl._cold_apply.clear_cache()
+        got = {name: np.asarray(jax.jit(
+            lambda P, v, name=name: getattr(P, name)(v))(P, jnp.asarray(vec)))
+            for name, vec, _, _ in products}
+    spl._cold_apply.clear_cache()
+    for name, _vec, want, scale in products:
+        _close(got[name], want, scale)
+    if depth is None:
+        # the empty rows and the empty column tiles read exact zeros
+        assert not np.any(got["matvec"][100:164])
+        assert not np.any(got["rmatvec"][20480:30720])
+        return
+    # the kernel of several blocks a basic block adds up as the kernel of
+    # one does, to the bit
+    with mock.patch.object(spl, "_cold_bodies", lambda a: 1):
+        for name, vec, _, _ in products[:2]:
+            one = jax.jit(lambda P, v, name=name: getattr(P, name)(v))(
+                P, jnp.asarray(vec))
+            np.testing.assert_array_equal(np.asarray(one), got[name])
+    spl._cold_apply.clear_cache()
 
 
 def test_build_counts_every_entry_once():
@@ -117,6 +186,56 @@ def test_build_counts_every_entry_once():
             "layout.col_perm", "layout.orient", "layout.cold_orient"} <= kids
     cold_cols = np.setdiff1d(np.unique(X.indices), P.warm_cols)
     assert a["cold_nnz"] == int(np.isin(X.indices, cold_cols).sum())
+    # the blocks a basic block each orientation's kernel traces with
+    assert (a["cold_bodies_f"], a["cold_bodies_b"]) == (
+        spl._cold_plan(P.cold_nbr, P.cold_nbc, P.cold_a_f, True)[2],
+        spl._cold_plan(P.cold_nbc, P.cold_nbr, P.cold_a_b, True)[2])
+    assert a["cold_bodies_f"] > 1 and a["cold_bodies_b"] > 1
+
+
+@pytest.mark.parametrize("nbo, nbg, a, bodies", [
+    (1024, 123, 16, 8), (123, 1024, 24, 4),     # glm_click_fit's two sides
+    (1024, 123, 8, 16), (1024, 123, 64, 2),     # 128 sublanes in flight
+    (1024, 123, 128, 1), (41, 1, 8, 1),         # and a step of one block
+])
+def test_cold_bodies_follow_the_depth(nbo, nbg, a, bodies):
+    assert spl._cold_plan(nbo, nbg, a, True)[2] == bodies
+
+
+@pytest.mark.parametrize("unit", [True, False], ids=["binary", "valued"])
+def test_cold_nonfinite_entries_stay_localized(unit):
+    """A non-finite vector entry reaches only the rows (forward) or columns
+    (backward) whose cold entries read it: never an empty slot, whose
+    placeholder code gathers lane 0 of window 0 of its block (column 0 and
+    8,192 here, row 0), nor a slot that reads another window."""
+    v = 1.0 if unit else 2.0
+    n, d = spl.COLD_TILE, 2 * spl.COLD_TILE
+    # gather block 0's windows 0, 1 and 0, and gather block 1's window 0
+    cols = np.array([0, 133, 72, spl.COLD_TILE])
+    with mock.patch.object(spl, "_warm_prefix",
+                           lambda *_: np.zeros(0, np.int64)):
+        P = spl.build_wide_host(np.arange(4), cols, np.full(4, v, np.float32),
+                                n, d)
+    assert not P.has_warm and P.cold_unit is unit
+    P = spl.place_pallas_matrix(P)
+    matvec = jax.jit(lambda P, x: P.matvec(x))
+    rmatvec = jax.jit(lambda P, x: P.rmatvec(x))
+
+    def vector(size, at):
+        x = np.zeros(size, np.float32)
+        x[list(at)] = list(at.values())
+        return jnp.asarray(x)
+
+    out = np.asarray(matvec(P, vector(d, {0: np.inf, 133: 5, 72: 3,
+                                          spl.COLD_TILE: 1})))
+    assert np.isinf(out[0]) and list(out[1:4]) == [5 * v, 3 * v, v]
+    assert not np.any(out[4:])
+    out = np.asarray(matvec(P, vector(d, {72: np.inf, spl.COLD_TILE: np.nan})))
+    assert np.isinf(out[2]) and np.isnan(out[3])
+    assert out[0] == out[1] == 0 and not np.any(out[4:])
+    out = np.asarray(rmatvec(P, vector(n, {0: np.inf, 1: np.nan, 2: 2})))
+    assert np.isinf(out[0]) and np.isnan(out[133]) and out[72] == 2 * v
+    assert not np.any(np.delete(out, [0, 72, 133]))
 
 
 def test_lbfgs_grid_against_float64():
