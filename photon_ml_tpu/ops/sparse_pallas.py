@@ -86,7 +86,10 @@ costs now is the 16-step output sweep over mostly empty slots (PERF.md
   bytes a block, so its bytes follow rows x columns / ``COLD_TILE``^2 x
   depth, which is 1/64 of the tile grid's count and proportional to the
   entries only while a block's depth tracks its entries (PERF.md §5
-  gives the click cell's fill).
+  gives the click cell's fill).  Each depth is chosen by the predicted
+  device time of a product pair (:func:`_cold_depths`): a block's cost
+  grows faster than its depth, so the few entries of the deepest lanes
+  go to a compact spill COO rather than set every block's depth.
 
 - ``make_glm_data(use_pallas="auto")`` takes the wide form when the tile
   grid's predicted fill at its least depth, :func:`grid_fill_bound` =
@@ -1030,6 +1033,21 @@ class SpillData:
         return self.spill_coo.sq_rmatvec(u)
 
 
+def _spill_data(rows, cols, vals, n_rows, n_cols, dtype) -> SpillData:
+    """The compact spill of the entries given, sorted by row; with none,
+    the empty 1-entry placeholder and ``has_spill`` False."""
+    has_spill = bool(len(rows))
+    if not has_spill:
+        rows, cols = np.zeros(1, np.int64), np.zeros(1, np.int64)
+        vals = np.zeros(1, np.float32)
+    s_rows, s_cols, s_vals = canonicalize_coo(rows, cols, vals, n_rows, n_cols)
+    return SpillData(
+        spill_coo=SparseMatrix(
+            row_ids=s_rows, col_ids=s_cols, values=np.asarray(s_vals, dtype),
+            n_rows=int(n_rows), n_cols=int(n_cols)),
+        has_spill=has_spill)
+
+
 def _predict_a(rows, cols, nbr, nbc):
     """Packed sublane count (max over tiles of Σ_w max-lane-load, uncapped)
     of orientation F of the given entry set; swap the arguments for
@@ -1524,8 +1542,9 @@ def _build_tiled(triples, n_rows, n_cols, depth_cap, pad_nnz, dtype,
     # Entries spilled from EITHER orientation go through the COO path for
     # BOTH directions (keeps matvec and rmatvec consistent with one X).
     spilled = np.union1d(f_spill, b_spill)
+    spill = _spill_data(r[spilled], c[spilled], v[spilled], n_rows, n_cols,
+                        dtype)
     if spilled.size:
-        spill_triples = (r[spilled], c[spilled], v[spilled])
         # Rebuild both orientations without the spilled entries so neither
         # tiled layout double-counts them (host-side, one extra pass).
         keep = np.ones(r.shape[0], bool)
@@ -1535,15 +1554,6 @@ def _build_tiled(triples, n_rows, n_cols, depth_cap, pad_nnz, dtype,
         b_code, b_val, bs2, a_b, depth_b = orient(
             "b", r[keep], c_tiled[keep], v[keep], spill_cost_ratio=np.inf)
         assert fs2.size == 0 and bs2.size == 0, "re-spill after rebuild"
-    else:
-        spill_triples = (np.zeros(1, np.int64), np.zeros(1, np.int64),
-                         np.zeros(1, np.float32))
-    s_rows, s_cols, s_vals = canonicalize_coo(
-        *spill_triples, n_rows, n_cols)
-    spill_coo = SparseMatrix(
-        row_ids=s_rows, col_ids=s_cols, values=np.asarray(s_vals, dtype),
-        n_rows=int(n_rows), n_cols=int(n_cols),
-    )
 
     if col_perm is not None:
         inv = np.full(nbc * TILE_C, n_cols, np.int64)  # default: zero slot
@@ -1574,9 +1584,7 @@ def _build_tiled(triples, n_rows, n_cols, depth_cap, pad_nnz, dtype,
 
     P = PallasSparseMatrix(
         f_code=f_code, f_val=f_val, b_code=b_code, b_val=b_val,
-        spill=SpillData(
-            spill_coo=spill_coo, has_spill=bool(spilled.size),
-        ),
+        spill=spill,
         dense_cols=dense_cols,
         dense_col_ids=dense_col_ids.astype(np.int32),
         dense_rows=dense_rows,
@@ -1666,6 +1674,19 @@ COLD_SUBPAD = 8
 #: 1,214.0, the line 1,132).
 COLD_BLOCK_SECONDS = -175.6e-9
 COLD_SUBLANE_SECONDS = 40.86e-9
+#: Device seconds of the cold band's spill (:func:`_cold_cheapest`) a
+#: product: a constant for a spill that is not empty and a slope an entry.
+#: Fitted on a TPU v5e (``scripts/cold_kernel_sweep.py``; PERF.md §6) as
+#: the mean of two least-squares lines through the device's busy seconds
+#: of ``SparseMatrix.matvec`` and ``rmatvec``, each added into the band's
+#: output, on the spills of ``glm_click_fit``'s own log (2^23 rows by
+#: 1,000,001 columns) at depths (8, 8), (16, 8), (8, 16) and (16, 16):
+#: 246,185 / 222,192 / 24,633 / 133 entries, matvec 3.967 / 3.708 / 0.567
+#: / 0.203 ms, rmatvec 3.293 / 3.085 / 0.353 / 0.027 ms (the lines 196 us
+#: + 15.5 ns an entry and 26 us + 13.5 ns).  At the click log's counts the
+#: depths are (8, 8) while the slope stays under ~90 ns.
+COLD_SPILL_FIXED_SECONDS = 111e-6
+COLD_SPILL_SECONDS = 14.5e-9
 COLD_EMPTY = np.iinfo(np.int32).min
 
 
@@ -1779,7 +1800,7 @@ def _pick_cold_rect(nbo: int, nbg: int, a: int,
 
 def _cold_bodies(a: int) -> int:
     """Cold blocks the kernel's loop puts into one basic block, from the
-    depth: the most, in powers of two up to 16, that keep 128 sublanes or
+    depth: the most, in powers of two up to 8, that keep 128 sublanes or
     fewer in flight.  Timed on a TPU v5e (``scripts/cold_kernel_sweep.py``;
     PERF.md §6), a product over 1,024 x 123 blocks of synthetic codes at 1
     / 2 / 4 / 8 / 16 blocks a basic block: 8 deep 32.23 / 25.11 / 21.56 /
@@ -1788,13 +1809,16 @@ def _cold_bodies(a: int) -> int:
     152.90 / 151.55 / 151.58; over 123 x 1,024 blocks 24 deep 100.91 /
     89.99 / 89.17 / 88.22 / 88.23; and, one sweep earlier, 40 deep 191.88 /
     179.53 / 177.42 / 176.44 / 175.78 and 64 deep 320.30 / 305.67 / 301.16
-    / 298.61 / 297.69.  The rule is within 2.7% of the fastest at every
-    depth; every body more is one more copy of the 64-window body for each
-    compile of a program to lower (at 16 everywhere the click solve's
-    program took 19.4 s to trace and lower, at this rule 7.8 s, with the
-    pick and sweep in loops of 8 windows 3.7 s)."""
+    / 298.61 / 297.69; and 8 deep at 1 / 8 / 16 again, 32.18 / 19.92 /
+    19.09 over 1,024 x 123 and 32.93 / 19.18 / 18.17 over 123 x 1,024.  The
+    rule is within 5.6% of the fastest at every depth; every body more is
+    one more copy of the 64-window body for each compile of a program to
+    lower (at 16 everywhere the click solve's program took 19.4 s to trace
+    and lower, 16 deep forward and 24 backward at 8 and 4 bodies 7.8 s, 8
+    deep both ways at 8 bodies 8.8 s): 16 bodies 8 deep would be 4-6%
+    faster than 8 and as many copies as 16 everywhere."""
     k = 1
-    while k < 16 and 2 * k * a <= 128:
+    while k < 8 and 2 * k * a <= 128:
         k *= 2
     return k
 
@@ -1852,32 +1876,103 @@ def _cold_apply(code, val, vec_padded, *, nbo, nbg, square, side,
     return out.reshape(nbo * COLD_TILE)
 
 
-def _build_cold_orientation(out_idx, gather_idx, vals, nbo, nbg, unit):
-    """Place the cold band's entries into (nbo, nbg, A, 128) blocks: lane
-    ``out_idx % 128``, each entry at the next free depth of its (block,
-    lane), A the deepest lane rounded up to ``COLD_SUBPAD`` (no spill).
-    Returns (code, val, a)."""
-    o = out_idx.astype(np.int64)
-    g = gather_idx.astype(np.int64)
+def _cold_key(o, g, nbg):
+    """An entry's (block, lane) in a cold orientation: ``block * 128 +
+    lane``, from int64 output and gather indices."""
     shift = COLD_TILE.bit_length() - 1
-    block = (o >> shift) * nbg + (g >> shift)
-    key = block * WIN + (o & (WIN - 1))
+    return ((o >> shift) * nbg + (g >> shift)) * WIN + (o & (WIN - 1))
+
+
+def _run_positions(key):
+    """Each element's place in its run of equal neighbours of ``key``."""
+    if not len(key):
+        return np.zeros(0, np.int64)
+    change = np.empty(len(key), bool)
+    change[0] = True
+    np.not_equal(key[1:], key[:-1], out=change[1:])
+    starts = np.flatnonzero(change)
+    return np.arange(len(key)) - np.repeat(
+        starts, np.diff(np.append(starts, len(key))))
+
+
+def _cold_sort(out_idx, gather_idx, nbg):
+    """The cold entries of one orientation by (block, lane): the stable
+    order that sorts them, and in that order their keys
+    (:func:`_cold_key`) and each one's depth in its (block, lane)."""
+    key = _cold_key(out_idx.astype(np.int64), gather_idx.astype(np.int64),
+                    nbg)
     order = np.argsort(key, kind="stable")
+    if len(key) < 1 << 31:
+        order = order.astype(np.int32)  # held for the layout: half the bytes
     key = key[order]
-    if len(key):
-        change = np.empty(len(key), bool)
-        change[0] = True
-        np.not_equal(key[1:], key[:-1], out=change[1:])
-        starts = np.flatnonzero(change)
-        depth = np.arange(len(key)) - np.repeat(
-            starts, np.diff(np.append(starts, len(key))))
-        a = int(depth.max()) + 1
-    else:
-        depth = np.zeros(0, np.int64)
-        a = 1
-    a = -(-a // COLD_SUBPAD) * COLD_SUBPAD
-    o, g = o[order], g[order]
+    return order, key, _run_positions(key).astype(np.int32)
+
+
+def _cold_cheapest(blocks, a_f, a_b, spilled):
+    """The cold band's cheapest depths: ``(seconds, a_f, a_b)`` among the
+    candidate depths ``a_f`` (forward) and ``a_b`` (backward), by the
+    predicted device seconds of a forward and a backward product: every
+    block at ``COLD_BLOCK_SECONDS`` and ``COLD_SUBLANE_SECONDS`` a sublane
+    of each depth, and ``spilled[i, j]``, the entries that depths ``a_f[i]``
+    and ``a_b[j]`` leave to the spill (which both products read), at
+    ``COLD_SPILL_FIXED_SECONDS`` and ``COLD_SPILL_SECONDS`` an entry a
+    product.  The one pricing of the band: the build chooses its depths by
+    it (:func:`_cold_depths`), the split its warm band
+    (:func:`_warm_prefix`)."""
+    seconds = blocks * (2 * COLD_BLOCK_SECONDS + COLD_SUBLANE_SECONDS * (
+        a_f[:, None] + a_b[None, :])) + np.where(
+            spilled > 0,
+            2 * (COLD_SPILL_FIXED_SECONDS + COLD_SPILL_SECONDS * spilled), 0.0)
+    i, j = np.unravel_index(np.argmin(seconds), seconds.shape)
+    return float(seconds[i, j]), int(a_f[i]), int(a_b[j])
+
+
+def _cold_depths(f, b, blocks):
+    """``(a_f, a_b, spilled)``: the cold band's depth in each orientation, a
+    multiple of ``COLD_SUBPAD`` up to what its deepest lane needs, the
+    cheapest by :func:`_cold_cheapest`, and the entries (indices,
+    ascending) at depth ``a_f`` or deeper forward or ``a_b`` or deeper
+    backward, which go to the spill.  ``f``, ``b``: each orientation's
+    order and depths (:func:`_cold_sort`).  The depths that spill nothing
+    are among the candidates, so a band whose lanes are alike keeps its
+    full depth."""
+    # Only an entry COLD_SUBPAD or deeper on some side can spill: the rest
+    # is kept at every candidate.
+    (f_order, f_depth), (b_order, b_depth) = f, b
+    deep_f = np.flatnonzero(f_depth >= COLD_SUBPAD)
+    deep_b = np.flatnonzero(b_depth >= COLD_SUBPAD)
+    idx = np.union1d(f_order[deep_f], b_order[deep_b])
+    depths = []
+    for order, depth, deep in ((f_order, f_depth, deep_f),
+                               (b_order, b_depth, deep_b)):
+        d = np.zeros(len(idx), np.int64)
+        d[np.searchsorted(idx, order[deep])] = depth[deep]
+        top = int(depth.max()) + 1 if len(depth) else 1
+        # a candidate per COLD_SUBPAD sublanes up to a block's side, and
+        # the no-spill depth (a lane deeper than COLD_TILE is one level)
+        lv = np.minimum(d // COLD_SUBPAD, COLD_TILE // COLD_SUBPAD)
+        a = COLD_SUBPAD * np.arange(1, (int(lv.max()) if len(lv) else 0) + 2)
+        a[-1] = -(-top // COLD_SUBPAD) * COLD_SUBPAD
+        depths.append((d, lv, a))
+    (d_f, lf, a_f), (d_b, lb, a_b) = depths
+    # kept[i, j]: the deep entries under both a_f[i] and a_b[j]
+    kept = np.bincount(lf * len(a_b) + lb, minlength=len(a_f) * len(a_b))
+    kept = kept.reshape(len(a_f), len(a_b)).cumsum(0).cumsum(1)
+    _, a_f, a_b = _cold_cheapest(blocks, a_f, a_b, len(idx) - kept)
+    return a_f, a_b, idx[(d_f >= a_f) | (d_b >= a_b)]
+
+
+def _build_cold_orientation(out_idx, gather_idx, vals, nbo, nbg, unit,
+                            order, key, depth, a):
+    """Place the cold entries ``order`` names, with their keys and depths
+    (:func:`_cold_sort`'s, the spill's left out), into (nbo, nbg, a, 128)
+    blocks: lane ``out_idx % 128``, each entry at its depth in its (block,
+    lane), every one below ``a``; a spilled entry leaves its slot empty.
+    Returns (code, val)."""
+    assert not len(depth) or depth.max() < a, (int(depth.max()), a)
     flat = ((key >> 7) * a + depth) * WIN + (key & (WIN - 1))
+    o = out_idx[order].astype(np.int64)
+    g = gather_idx[order].astype(np.int64)
     code = np.full(nbo * nbg * a * WIN, COLD_EMPTY, np.int32)
     code[flat] = (
         (((g & (COLD_TILE - 1)) >> 7) << COLD_WIN_SHIFT)
@@ -1889,7 +1984,7 @@ def _build_cold_orientation(out_idx, gather_idx, vals, nbo, nbg, unit):
         val = np.zeros(nbo * nbg * a * WIN, np.float32)
         val[flat] = vals[order]
         val = val.reshape(nbo, nbg, a, WIN)
-    return code.reshape(nbo, nbg, a, WIN), val, a
+    return code.reshape(nbo, nbg, a, WIN), val
 
 
 def _cold_depth(means: np.ndarray, copies: int) -> int:
@@ -1920,6 +2015,32 @@ def _cold_depth(means: np.ndarray, copies: int) -> int:
     return -(-lo // COLD_SUBPAD) * COLD_SUBPAD
 
 
+def _cold_spill_expected(means: np.ndarray, copies: int):
+    """The candidate depths of a cold orientation whose lanes' loads are
+    Poisson with ``means``, each lane met ``copies`` times, as
+    :func:`_cold_depths` takes them from a built band: every multiple of
+    ``COLD_SUBPAD`` up to ``COLD_TILE`` below the no-spill depth
+    (:func:`_cold_depth`), and that depth.  Returns the depths and the
+    entries expected at each depth or deeper, E[(X - a)+] = mean P(X >= a)
+    - a P(X >= a + 1) a lane; none at the no-spill depth."""
+    from scipy.special import gammainc
+
+    top = _cold_depth(means, copies)
+    a = COLD_SUBPAD * np.arange(1, min(top, COLD_TILE + COLD_SUBPAD)
+                                // COLD_SUBPAD + 1)
+    a[-1] = top
+    m = np.sort(means[means > 0])
+    spilled = []
+    for x in a[:-1]:
+        # a lane whose mean lies 12 deviations and more below x holds
+        # nothing that deep: a deep band's many candidates skip its
+        # shallow lanes
+        mx = m[np.searchsorted(m, x - 12 * np.sqrt(x) - 40):]
+        spilled.append(copies * float(
+            (mx * gammainc(x, mx) - x * gammainc(x + 1, mx)).sum()))
+    return a, np.array(spilled + [0.0])
+
+
 def _wide_max_stripes(n_rows: int) -> int:
     """Stripes the wide layout's warm band may take: 512 MiB of them, the
     threshold rule's bound (:func:`_threshold_stripes`), which the stripe
@@ -1937,8 +2058,10 @@ def _warm_prefix(counts: np.ndarray, n_rows: int, slot_bytes: int,
     ``SLOT_SECONDS`` and its stripes at ``STRIPE_ELEMENT_SECONDS``; the
     cold band the rest, every block at ``COLD_BLOCK_SECONDS`` an
     orientation and ``COLD_SUBLANE_SECONDS`` a sublane of each
-    orientation's :func:`_cold_depth` (rows are alike; orientation B's
-    lanes carry the cold columns' own counts).  Candidates: k a power of two, up to the
+    orientation's depth by :func:`_cold_cheapest`, as the build prices
+    it, with the entries each depth leaves to the spill expected from
+    :func:`_cold_spill_expected` (rows are alike; orientation B's lanes
+    carry the cold columns' own counts).  Candidates: k a power of two, up to the
     longest prefix whose own grid keeps :func:`grid_fill_bound` at
     ``WIDE_FILL`` or above (past it the warm grid is as empty as the one
     the wide layout replaces)."""
@@ -1965,11 +2088,11 @@ def _warm_prefix(counts: np.ndarray, n_rows: int, slot_bytes: int,
         if cold:
             rest = counts.astype(np.float64)
             rest[order[:width]] = 0.0
-            a_f = _cold_depth(np.array([cold / (blocks * WIN)]), blocks * WIN)
-            a_b = _cold_depth(np.bincount(group, rest) * COLD_TILE / n_rows,
-                              cold_nbr)
-            s = blocks * (2 * COLD_BLOCK_SECONDS
-                          + COLD_SUBLANE_SECONDS * (a_f + a_b))
+            a_f, s_f = _cold_spill_expected(
+                np.array([cold / (blocks * WIN)]), blocks * WIN)
+            a_b, s_b = _cold_spill_expected(
+                np.bincount(group, rest) * COLD_TILE / n_rows, cold_nbr)
+            s = _cold_cheapest(blocks, a_f, a_b, s_f[:, None] + s_b)[0]
         if width:
             ids, (a_w, a_l) = _choose_stripes(
                 counts[np.sort(order[:width])], n_rows, nbr * k, slot_bytes,
@@ -1989,6 +2112,7 @@ def _warm_prefix(counts: np.ndarray, n_rows: int, slot_bytes: int,
     data_fields=[
         "warm", "warm_cols", "warm_slot",
         "cold_f_code", "cold_f_val", "cold_b_code", "cold_b_val",
+        "cold_spill",
     ],
     meta_fields=[
         "host_coo", "n_rows", "n_cols", "cold_nbr", "cold_nbc",
@@ -2008,11 +2132,15 @@ class WideSparseMatrix:
     - **cold** -- every other column's entries, in (``COLD_TILE``)^2
       blocks whose slots each name their own gather window
       (:func:`_cold_kernel`), in the original column order: no gather of
-      the vector on the way in.
+      the vector on the way in;
+    - **cold spill** -- the cold entries above each orientation's chosen
+      depth (:func:`_cold_depths`), a compact COO (:class:`SpillData`,
+      ``has_spill`` False where there are none).
 
     ``warm_slot`` maps an original column to its warm position, or to the
     appended zero for a cold one (the gradient's way back: a gather).
-    Products are float32 and scatter-free but for the warm band's stripes.
+    Products are float32 and scatter-free but for the warm band's stripes
+    and the two spills' segment sums.
     """
 
     warm: Optional[PallasSparseMatrix]
@@ -2022,6 +2150,7 @@ class WideSparseMatrix:
     cold_f_val: Array
     cold_b_code: Array     # (cold_nbc, cold_nbr, A_b, 128) int32
     cold_b_val: Array
+    cold_spill: SpillData
     host_coo: HostCoo
     n_rows: int
     n_cols: int
@@ -2050,6 +2179,10 @@ class WideSparseMatrix:
                     jnp.pad(vec, (0, self.cold_nbr * COLD_TILE - self.n_rows)),
                     nbo=self.cold_nbc, nbg=self.cold_nbr, square=square,
                     side="bwd", unit=self.cold_unit)[: self.n_cols]
+            if self.cold_spill.has_spill:
+                spill = self.cold_spill
+                out = out + (spill.sq_rmatvec if square else spill.rmatvec)(
+                    vec)
             if self.has_warm:
                 w = self.warm._apply(vec, transpose=True, square=square)
                 out = out + jnp.take(
@@ -2063,6 +2196,9 @@ class WideSparseMatrix:
                 jnp.pad(vec, (0, self.cold_nbc * COLD_TILE - self.n_cols)),
                 nbo=self.cold_nbr, nbg=self.cold_nbc, square=square,
                 side="fwd", unit=self.cold_unit)[: self.n_rows]
+        if self.cold_spill.has_spill:
+            spill = self.cold_spill
+            out = out + (spill.row_sq_matvec if square else spill.matvec)(vec)
         if self.has_warm:
             out = out + self.warm._apply(
                 jnp.take(vec, self.warm_cols, axis=0), transpose=False,
@@ -2104,12 +2240,15 @@ def build_wide_host(
     """The wide layout from host COO triples, on the host.  One
     ``layout.build`` span; its children are the tiled build's phases for
     the warm band (``layout.canonicalize``, ``.dense_split``,
-    ``.col_perm``, ``.orient``), and ``layout.wide_split`` (the warm
-    columns chosen, the entries parted) and ``layout.cold_orient`` (one
-    per side) for the cold band.  The span counts each storage class's
-    entries (``stripe_nnz``, ``warm_tiled_nnz``, ``cold_nnz``; spilled
-    ones in ``spilled``), the blocks stored against the grids'
-    (``tiles_stored``: the warm band's tiles and the cold band's blocks,
+    ``.col_perm``, ``.orient``), and for the cold band
+    ``layout.wide_split`` (the warm columns chosen, the entries parted),
+    ``layout.cold_orient`` (twice a side: the entries sorted by (block,
+    lane), then laid out) and between the two ``layout.cold_spill`` (the
+    depths chosen, the spill parted).  The span counts each storage
+    class's entries (``stripe_nnz``, ``warm_tiled_nnz``, ``cold_nnz``: what
+    the cold kernel holds; both bands' spilled ones in ``spilled``, the
+    cold band's alone in ``cold_spilled``), the blocks stored against the
+    grids' (``tiles_stored``: the warm band's tiles and the cold band's blocks,
     every one of which is stored; ``grid_tiles``: the ``TILE_R`` x
     ``TILE_C`` grid the tiled layout would store, and the cold band's
     grid), the slots allocated against the entries placed in them
@@ -2156,41 +2295,62 @@ def build_wide_host(
         cold_nbc = max(1, -(-n_cols // COLD_TILE))
         unit = bool(np.all(v_c == 1.0))
         with layer_span("layout.cold_orient", side="f"):
-            f_code, f_val, a_f = _build_cold_orientation(
-                r_c, c_c, v_c, cold_nbr, cold_nbc, unit)
+            f = _cold_sort(r_c, c_c, cold_nbc)
         with layer_span("layout.cold_orient", side="b"):
-            b_code, b_val, a_b = _build_cold_orientation(
-                c_c, r_c, v_c, cold_nbc, cold_nbr, unit)
+            b = _cold_sort(c_c, r_c, cold_nbr)
+        with layer_span("layout.cold_spill"):
+            a_f, a_b, spilled = _cold_depths(f[::2], b[::2],
+                                             cold_nbr * cold_nbc)
+            keep = np.ones(len(r_c), bool)
+            keep[spilled] = False
+            cold_nnz = len(r_c) - len(spilled)
+            cold_spill = _spill_data(r_c[spilled], c_c[spilled],
+                                     v_c[spilled], n_rows, n_cols, dtype)
+        # The kept entries keep their places: a lane only loses entries.
+        with layer_span("layout.cold_orient", side="f"):
+            sel = keep[f[0]]
+            f_code, f_val = _build_cold_orientation(
+                r_c, c_c, v_c, cold_nbr, cold_nbc, unit,
+                *(x[sel] for x in f), a_f)
+        del f
+        with layer_span("layout.cold_orient", side="b"):
+            sel = keep[b[0]]
+            b_code, b_val = _build_cold_orientation(
+                c_c, r_c, v_c, cold_nbc, cold_nbr, unit,
+                *(x[sel] for x in b), a_b)
+        del b, keep, sel
 
         P = WideSparseMatrix(
             warm=warm, warm_cols=warm_cols.astype(np.int32), warm_slot=slot,
             cold_f_code=f_code, cold_f_val=f_val,
-            cold_b_code=b_code, cold_b_val=b_val, host_coo=host_coo,
-            n_rows=int(n_rows), n_cols=int(n_cols),
+            cold_b_code=b_code, cold_b_val=b_val, cold_spill=cold_spill,
+            host_coo=host_coo, n_rows=int(n_rows), n_cols=int(n_cols),
             cold_nbr=cold_nbr, cold_nbc=cold_nbc, cold_a_f=a_f, cold_a_b=a_b,
-            has_warm=warm is not None, has_cold=bool(len(r_c)),
+            has_warm=warm is not None, has_cold=bool(cold_nnz),
             cold_unit=unit,
         )
-        stripe_nnz = warm_tiled = spilled = 0
+        cold_spilled = len(r_c) - cold_nnz
+        stripe_nnz = warm_tiled = warm_spilled = 0
         slots = f_code.size + b_code.size
         tiles = cold_nbr * cold_nbc
         if warm is not None:
             stripe_nnz = int(np.count_nonzero(warm.dense_cols)
                              + np.count_nonzero(warm.dense_rows))
-            spilled = build.attrs["spilled"]
-            warm_tiled = warm_nnz - stripe_nnz - spilled
+            warm_spilled = build.attrs["spilled"]
+            warm_tiled = warm_nnz - stripe_nnz - warm_spilled
             slots += warm.f_code.size + warm.b_code.size
             tiles += warm.nbr * warm.nbc
         build.set(
             nnz=P.nnz, layout="wide", warm_cols=k, stripe_nnz=stripe_nnz,
-            warm_tiled_nnz=warm_tiled, cold_nnz=int(len(r_c)),
+            warm_tiled_nnz=warm_tiled, cold_nnz=cold_nnz,
+            spilled=warm_spilled + cold_spilled, cold_spilled=cold_spilled,
             tiles_stored=tiles,
             grid_tiles=max(1, -(-n_rows // TILE_R))
             * max(1, -(-n_cols // TILE_C)) + cold_nbr * cold_nbc,
             cold_blocks=cold_nbr * cold_nbc, cold_a_f=a_f, cold_a_b=a_b,
             cold_bodies_f=_cold_plan(cold_nbr, cold_nbc, a_f, unit)[2],
             cold_bodies_b=_cold_plan(cold_nbc, cold_nbr, a_b, unit)[2],
-            slots=int(slots), slot_entries=2 * (warm_tiled + int(len(r_c))),
+            slots=int(slots), slot_entries=2 * (warm_tiled + cold_nnz),
         )
     return P
 

@@ -66,9 +66,10 @@ def describe_layout(features, shards: int = 1) -> str:
         )
     elif name == "WideSparseMatrix":
         f = features
+        spill = f.cold_spill.spill_coo.nnz if f.cold_spill.has_spill else 0
         name += (f"[warm={f.warm_cols.shape[0]} cols"
                  + (f" A={f.warm.a_f}/{f.warm.a_b}" if f.has_warm else "")
-                 + f" cold A={f.cold_a_f}/{f.cold_a_b}]")
+                 + f" cold A={f.cold_a_f}/{f.cold_a_b} spill={spill}]")
     if shards > 1:
         name += f" x{shards} row shards"
     return name

@@ -93,10 +93,13 @@ def _pallas_calls(compiled_text):
             if trace_mod.KERNEL_OPCODE in ln and "tpu_custom_call" in ln]
 
 
-@pytest.fixture(scope="module")
-def wide_shapes(one_chip):
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["full", "spill"])
+def wide_shapes(request, one_chip):
     """A valued wide layout with both bands (its 2,048 most popular columns
-    warm), shapes only."""
+    warm), shapes only: the cold band at its full depth (the spill priced
+    out), or cut to 8 deep with the rest of its entries in the cold spill
+    (the spill priced at nothing)."""
     from unittest import mock
 
     from photon_ml_tpu.ops import sparse_pallas as spl
@@ -107,10 +110,14 @@ def wide_shapes(one_chip):
     cols = np.where(rng.random(NNZ) < 0.5, rng.integers(0, 2048, NNZ),
                     rng.integers(0, d, NNZ))
     warm = np.arange(2048)
-    with mock.patch.object(spl, "_warm_prefix", lambda *_: warm):
+    price = 0.0 if request.param else 1.0
+    with mock.patch.object(spl, "_warm_prefix", lambda *_: warm), \
+            mock.patch.multiple(spl, COLD_SPILL_FIXED_SECONDS=price,
+                                COLD_SPILL_SECONDS=price):
         P = spl.build_wide_host(rows, cols, rng.normal(size=NNZ).astype(
             np.float32), n, d)
     assert P.has_warm and P.has_cold and not P.cold_unit
+    assert P.cold_spill.has_spill is request.param
     return jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         P)
@@ -123,7 +130,8 @@ def wide_shapes(one_chip):
 def test_wide_kernel_instruction_names(monkeypatch, one_chip, wide_shapes,
                                        product, length, side):
     """The wide layout's product is the warm band's tile kernel and the cold
-    band's kernel, one each, under the names the benchmark's readers sum."""
+    band's kernel, one each, under the names the benchmark's readers sum,
+    with the cold spill's product beside them or not."""
     monkeypatch.delenv("PHOTON_PALLAS_INTERPRET", raising=False)
     vec = jax.ShapeDtypeStruct((length,), jnp.float32, sharding=one_chip)
     with jax.enable_x64(False):
@@ -134,11 +142,13 @@ def test_wide_kernel_instruction_names(monkeypatch, one_chip, wide_shapes,
 
 
 # ``glm_click_fit``'s cold band (PERF.md §4): 1,024 row blocks x 123 column
-# blocks of unit codes, 16 deep forward and 24 backward, and the sweep's
-# other depths: the bodies a basic block and the window pick lower under
-# Mosaic within the kernel's VMEM.
+# blocks of unit codes, 8 deep both ways since the depths are chosen by
+# cost (16 forward and 24 backward without a spill), and the sweep's other
+# depths: the bodies a basic block and the window pick lower under Mosaic
+# within the kernel's VMEM.
 @pytest.mark.parametrize("side, a", [
     ("fwd", 16), ("bwd", 24), ("fwd", 8), ("fwd", 32), ("bwd", 40),
+    ("bwd", 8),
 ])
 def test_cold_kernel_compiles_at_cell_depths(monkeypatch, one_chip, side, a):
     from photon_ml_tpu.ops.sparse_pallas import COLD_TILE, _cold_apply

@@ -1,13 +1,15 @@
 """The wide layout (``ops/sparse_pallas.WideSparseMatrix``: a warm band of
 tiles and a cold band of mixed blocks) on the CPU, Pallas in interpret mode:
-its four products against a float64 SciPy CSR (both bands, and the cold band
-alone at several depths and block grids, where its kernel's products are
-also the same to the bit as with one block a basic block), non-finite
-vector entries kept to the rows and columns that read them, a warm-started
-L-BFGS grid on it against a float64 objective, the split's counts, and the
+its four products against a float64 SciPy CSR (both bands, with and without
+the cold band's spill, and the cold band alone at several depths and block
+grids, where its kernel's products are also the same to the bit as with one
+block a basic block), non-finite vector entries kept to the rows and
+columns that read them, a warm-started L-BFGS grid on it against a float64
+objective, the split's counts, the cold band's depths by cost, and the
 rule of ``make_glm_data(use_pallas="auto")``, which has to build exactly
 today's layout at every accepted configuration of the benchmark."""
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -53,14 +55,57 @@ def _wide_matrix(seed, unit, n=2048, d=65536):
     return X
 
 
-def _both_bands(X):
+def _both_bands(X, spill=False):
     """The wide layout of ``X`` with its 4,096 most popular columns warm
-    (the split's own rule is tested below)."""
+    (the split's own rule is tested below), the cold band at its full
+    depth; with ``spill``, the cold band's spill priced at nothing, so that
+    every lane is cut to ``COLD_SUBPAD``."""
     counts = np.bincount(X.indices, minlength=X.shape[1])
     warm = np.sort(np.argsort(-counts, kind="stable")[:4096])
     coo = X.tocoo()
-    with mock.patch.object(spl, "_warm_prefix", lambda *_: warm):
+    with mock.patch.object(spl, "_warm_prefix", lambda *_: warm), \
+            _spill_priced(0.0 if spill else 1.0):
         return spl.build_wide_host(coo.row, coo.col, coo.data, *X.shape)
+
+
+def _spill_priced(seconds):
+    """The cold spill's constant and slope both at ``seconds`` (at a
+    second, the cold band keeps its full depth); ``None`` leaves them as
+    they are."""
+    if seconds is None:
+        return contextlib.nullcontext()
+    return mock.patch.multiple(spl, COLD_SPILL_FIXED_SECONDS=seconds,
+                               COLD_SPILL_SECONDS=seconds)
+
+
+def _lane_depth(out, gather):
+    """The depth one cold orientation needs to hold the entries (out,
+    gather) without a spill: the deepest (block, lane), rounded up."""
+    key = spl._cold_key(out.astype(np.int64), gather.astype(np.int64),
+                        1 << 20)
+    deepest = np.unique(key, return_counts=True)[1].max() if key.size else 1
+    return -(-int(deepest) // spl.COLD_SUBPAD) * spl.COLD_SUBPAD
+
+
+def _cold_coo(X, P):
+    """(rows, columns) of the entries of ``X`` in ``P``'s cold columns."""
+    coo = X.tocoo()
+    cold = np.ones(X.shape[1], bool)
+    cold[np.asarray(P.warm_cols)] = False
+    return coo.row[cold[coo.col]], coo.col[cold[coo.col]]
+
+
+def _cold_entries(code, transpose=False):
+    """The (row, column) of every entry a cold orientation's codes hold
+    (``transpose``: orientation B, whose lanes are columns)."""
+    bo, bg, _, lane = np.nonzero(code >= 0)
+    c = code[code >= 0].astype(np.int64)
+    out = bo * spl.COLD_TILE + ((c >> 7) & (spl.COLD_WINS - 1)) * 128 + lane
+    gather = (bg * spl.COLD_TILE
+              + ((c >> spl.COLD_WIN_SHIFT) & (spl.COLD_WINS - 1)) * 128
+              + (c & 127))
+    pairs = (gather, out) if transpose else (out, gather)
+    return set(zip(*(p.tolist() for p in pairs)))
 
 
 def _cold_only(seed, unit, nbr, nbc, depth):
@@ -85,10 +130,13 @@ def _cold_only(seed, unit, nbr, nbc, depth):
     if unit:
         X.data[:] = 1.0
     coo = X.tocoo()
+    # the kernel at this depth: the spill priced out
     with mock.patch.object(spl, "_warm_prefix",
-                           lambda *_: np.zeros(0, np.int64)):
+                           lambda *_: np.zeros(0, np.int64)), \
+            _spill_priced(1.0):
         P = spl.build_wide_host(coo.row, coo.col, coo.data, n, d)
     assert not P.has_warm and (P.cold_a_f, P.cold_a_b) == (depth, depth)
+    assert not P.cold_spill.has_spill
     return X, P
 
 
@@ -97,13 +145,16 @@ def _close(got, want, scale):
         np.abs(np.asarray(got, np.float64) - want), 2e-6 * scale + 1e-6)
 
 
-# Both bands, then the cold band alone: 41 row blocks (a prime past the grid
-# step's VMEM, so the forward product's steps hold one output block), 8 to
-# 40 deep, two blocks a basic block, so that every grid step (3 blocks
-# forward, 3 or 123 backward) leaves a remainder.
+# Both bands, without and with the cold band's spill, then the cold band
+# alone: 41 row blocks (a prime past the grid step's VMEM, so the forward
+# product's steps hold one output block), 8 to 40 deep, two blocks a basic
+# block, so that every grid step (3 blocks forward, 3 or 123 backward)
+# leaves a remainder.
 PRODUCT_CASES = [
     pytest.param(True, None, id="binary"),
     pytest.param(False, None, id="valued"),
+    pytest.param(True, "spill", id="spill-binary"),
+    pytest.param(False, "spill", id="spill-valued"),
     pytest.param(True, 8, id="cold-8-binary"),
     pytest.param(False, 16, id="cold-16-valued"),
     pytest.param(True, 24, id="cold-24-binary"),
@@ -114,11 +165,19 @@ PRODUCT_CASES = [
 @pytest.mark.parametrize("unit, depth", PRODUCT_CASES)
 def test_products_against_float64(unit, depth):
     bodies = spl._cold_bodies
-    if depth is None:
+    both = depth in (None, "spill")
+    if both:
         X = _wide_matrix(3 + unit, unit)
-        P = _both_bands(X)
+        P = _both_bands(X, spill=depth == "spill")
         assert P.has_warm and P.has_cold
         assert P.cold_unit is unit and P.warm.unit_vals is unit
+        assert P.cold_spill.has_spill is (depth == "spill")
+        if depth == "spill":
+            # the lanes are uneven enough that the full depths would be
+            # deeper on both sides
+            r, c = _cold_coo(X, P)
+            assert (P.cold_a_f, P.cold_a_b) == (8, 8)
+            assert _lane_depth(r, c) > 8 and _lane_depth(c, r) > 8
     else:
         X, P = _cold_only(depth + unit, unit, 41, 3, depth)
         assert P.cold_unit is unit
@@ -138,7 +197,7 @@ def test_products_against_float64(unit, depth):
         S64 = X64.multiply(X64)
         products += [("row_sq_matvec", w, S64 @ w, S64 @ abs(w)),
                      ("sq_rmatvec", u, S64.T @ u, S64.T @ abs(u))]
-    if depth is not None:
+    if not both:
         products = products[:3]
     with mock.patch.object(spl, "_cold_bodies", bodies):
         spl._cold_apply.clear_cache()
@@ -148,7 +207,7 @@ def test_products_against_float64(unit, depth):
     spl._cold_apply.clear_cache()
     for name, _vec, want, scale in products:
         _close(got[name], want, scale)
-    if depth is None:
+    if both:
         # the empty rows and the empty column tiles read exact zeros
         assert not np.any(got["matvec"][100:164])
         assert not np.any(got["rmatvec"][20480:30720])
@@ -163,12 +222,13 @@ def test_products_against_float64(unit, depth):
     spl._cold_apply.clear_cache()
 
 
-def test_build_counts_every_entry_once():
+@pytest.mark.parametrize("spill", [False, True], ids=["full", "spill"])
+def test_build_counts_every_entry_once(spill):
     X = _wide_matrix(5, True)
     # the ring is bounded: after other tests it is full, so new spans are
     # told by their ids, not by their positions
     seen = {s["id"] for s in layer_spans()}
-    P = _both_bands(X)
+    P = _both_bands(X, spill=spill)
     spans = [s for s in layer_spans() if s["id"] not in seen]
     (build,) = [s for s in spans if s["name"] == "layout.build"]
     a = build["attrs"]
@@ -183,9 +243,29 @@ def test_build_counts_every_entry_once():
     # every phase is a child of the one build span
     kids = {s["name"] for s in spans if s["parent"] == build["id"]}
     assert {"layout.canonicalize", "layout.wide_split", "layout.dense_split",
-            "layout.col_perm", "layout.orient", "layout.cold_orient"} <= kids
-    cold_cols = np.setdiff1d(np.unique(X.indices), P.warm_cols)
-    assert a["cold_nnz"] == int(np.isin(X.indices, cold_cols).sum())
+            "layout.col_perm", "layout.orient", "layout.cold_orient",
+            "layout.cold_spill"} <= kids
+    # every cold entry exactly once: in both orientations' blocks, each
+    # (block, lane) no deeper than the chosen depth, or in the spill
+    cold = set(zip(*(x.tolist() for x in _cold_coo(X, P))))
+    sc = P.cold_spill.spill_coo
+    spilled = (set(zip(np.asarray(sc.row_ids).tolist(),
+                       np.asarray(sc.col_ids).tolist()))
+               if P.cold_spill.has_spill else set())
+    assert P.cold_f_code.shape[2] == P.cold_a_f == a["cold_a_f"]
+    assert P.cold_b_code.shape[2] == P.cold_a_b == a["cold_a_b"]
+    kept = _cold_entries(P.cold_f_code)
+    assert kept == _cold_entries(P.cold_b_code, transpose=True)
+    assert len(kept) == a["cold_nnz"] == int((P.cold_f_code >= 0).sum())
+    assert not kept & spilled and kept | spilled == cold
+    assert a["cold_spilled"] == len(spilled) == sc.nnz * P.cold_spill.has_spill
+    assert a["spilled"] == a["cold_spilled"] + int(
+        P.warm.spill.has_spill and P.warm.spill.spill_coo.nnz)
+    assert bool(spilled) is spill
+    if spill:
+        assert (a["cold_a_f"], a["cold_a_b"]) == (8, 8)
+        # the spill is sorted by row, as the segment sum is told
+        assert np.all(np.diff(np.asarray(sc.row_ids)) >= 0)
     # the blocks a basic block each orientation's kernel traces with
     assert (a["cold_bodies_f"], a["cold_bodies_b"]) == (
         spl._cold_plan(P.cold_nbr, P.cold_nbc, P.cold_a_f, True)[2],
@@ -195,28 +275,123 @@ def test_build_counts_every_entry_once():
 
 @pytest.mark.parametrize("nbo, nbg, a, bodies", [
     (1024, 123, 16, 8), (123, 1024, 24, 4),     # glm_click_fit's two sides
-    (1024, 123, 8, 16), (1024, 123, 64, 2),     # 128 sublanes in flight
+    (1024, 123, 8, 8), (1024, 123, 64, 2),      # at most 8, 128 sublanes
     (1024, 123, 128, 1), (41, 1, 8, 1),         # and a step of one block
 ])
 def test_cold_bodies_follow_the_depth(nbo, nbg, a, bodies):
     assert spl._cold_plan(nbo, nbg, a, True)[2] == bodies
 
 
-@pytest.mark.parametrize("unit", [True, False], ids=["binary", "valued"])
-def test_cold_nonfinite_entries_stay_localized(unit):
+def _click_like_depths(blocks=126, seed=0):
+    """Each cold entry's depth in its (block, lane), both orientations, for
+    a band cut like ``glm_click_fit``'s by a thousand (40,118 entries over
+    126 blocks): 24 entries 8 to 13 deep forward, 222 entries 8 to 15 deep
+    and one 16 deep backward, no entry deep on both sides; the rest under
+    8."""
+    rng = np.random.default_rng(seed)
+    n = 40118
+    f = rng.integers(0, 8, n)
+    b = rng.integers(0, 8, n)
+    f[:24] = rng.integers(8, 14, 24)
+    b[24:246] = rng.integers(8, 16, 222)
+    b[246] = 16
+    return f, b, blocks
+
+
+@pytest.mark.parametrize("entry_seconds, depths, spilled", [
+    (20e-9, (8, 8), 247),          # the spill is cheap: every lane 8 deep
+    (100e-9, (8, 16), 25),         # dear: only the backward band's 16th
+    (1.0, (16, 24), 0),            # priced out: the full depths
+])
+def test_cold_depths_by_cost(entry_seconds, depths, spilled):
+    """The depths trade the kernel's seconds, which every block pays,
+    against the spill's, which only the spilled entries pay: a pair at
+    (8, 16) is 79.3 us of kernel and 2 x 25 spilled entries, at (8, 8) 38.1
+    us and 2 x 247, so the two meet at 93 ns an entry a product."""
+    f, b, blocks = _click_like_depths()
+    # each side sees the entries in an order of its own, as its sort has it
+    rng = np.random.default_rng(1)
+    of, ob = rng.permutation(len(f)), rng.permutation(len(b))
+    with mock.patch.multiple(spl, COLD_SPILL_FIXED_SECONDS=0.0,
+                             COLD_SPILL_SECONDS=entry_seconds):
+        a_f, a_b, out = spl._cold_depths((of, f[of]), (ob, b[ob]), blocks)
+    assert (a_f, a_b) == depths
+    assert len(out) == spilled
+    assert np.array_equal(out, np.flatnonzero((f >= a_f) | (b >= a_b)))
+
+
+def test_an_even_cold_band_keeps_its_depth():
+    """Every (block, lane) 10 deep in both orientations: 8 deep, a fifth of
+    the band would spill, 256 entries a block against 8 sublanes of each
+    orientation (654 ns a block and a pair, so at any price over 1.3 ns an
+    entry a product), so the band keeps 16 sublanes a side and no spill;
+    and the empty band is one sublane group deep."""
+    r = np.arange(10 * 128)               # ten rows a lane ...
+    with mock.patch.object(spl, "_warm_prefix",
+                           lambda *_: np.zeros(0, np.int64)):
+        P = spl.build_wide_host(r, r, np.ones(len(r), np.float32),
+                                spl.COLD_TILE, spl.COLD_TILE)  # ... and cols
+    assert (P.cold_a_f, P.cold_a_b) == (16, 16)
+    assert P.has_cold and not P.cold_spill.has_spill
+    none = (np.zeros(0, np.int32), np.zeros(0, np.int32))
+    assert spl._cold_depths(none, none, 1)[:2] == (8, 8)
+
+
+@pytest.mark.parametrize("mean", [2.5, 6.0])
+def test_the_split_expects_the_spill_the_build_counts(mean):
+    """The split prices the cold band as the build does: the expected spill
+    of its Poisson lanes (``mean`` entries a (block, lane)) at each
+    candidate depth is the count of the entries that deep in a band drawn
+    with those loads, within three deviations of the count and 2%; the
+    no-spill depth, the last candidate, spills nothing; and at the price
+    of the spill that the sweep fits, the depths chosen from the
+    expectation are the build's."""
+    rng = np.random.default_rng(7)
+    side = 32 * spl.COLD_TILE
+    lanes = 32 * 32 * spl.WIN
+    nnz = int(mean * lanes)
+    r, c = rng.integers(0, side, nnz), rng.integers(0, side, nnz)
+    order, _, depth = spl._cold_sort(r, c, 32)
+    a, expected = spl._cold_spill_expected(np.array([mean]), lanes)
+    assert a[-1] == spl._cold_depth(np.array([mean]), lanes)
+    assert expected[-1] == 0 and len(a) > 1
+    for x, e in zip(a[:-1], expected[:-1]):
+        counted = int(np.count_nonzero(depth >= x))
+        assert abs(e - counted) <= 3 * np.sqrt(counted) + 0.02 * counted, (
+            x, e, counted)
+    exact = spl._cold_depths((order, depth), (order, depth), 32 * 32)
+    assert spl._cold_cheapest(32 * 32, a, a, expected[:, None]
+                              + expected)[1:] == exact[:2]
+
+
+@pytest.mark.parametrize("unit, spill", [
+    (True, False), (False, False), (True, True), (False, True)],
+    ids=["binary", "valued", "spill-binary", "spill-valued"])
+def test_cold_nonfinite_entries_stay_localized(unit, spill):
     """A non-finite vector entry reaches only the rows (forward) or columns
     (backward) whose cold entries read it: never an empty slot, whose
     placeholder code gathers lane 0 of window 0 of its block (column 0 and
-    8,192 here, row 0), nor a slot that reads another window."""
+    8,192 here, row 0), nor a slot that reads another window; with
+    ``spill``, row 5 holds nine entries in one (block, lane), so that its
+    last, in column 8,201, is in the cold band's spill."""
     v = 1.0 if unit else 2.0
     n, d = spl.COLD_TILE, 2 * spl.COLD_TILE
     # gather block 0's windows 0, 1 and 0, and gather block 1's window 0
-    cols = np.array([0, 133, 72, spl.COLD_TILE])
+    rows, cols = np.arange(4), np.array([0, 133, 72, spl.COLD_TILE])
+    if spill:
+        rows = np.append(rows, np.full(9, 5))
+        cols = np.append(cols, spl.COLD_TILE + np.arange(1, 10))
     with mock.patch.object(spl, "_warm_prefix",
-                           lambda *_: np.zeros(0, np.int64)):
-        P = spl.build_wide_host(np.arange(4), cols, np.full(4, v, np.float32),
-                                n, d)
+                           lambda *_: np.zeros(0, np.int64)), \
+            _spill_priced(0.0 if spill else None):
+        P = spl.build_wide_host(rows, cols,
+                                np.full(len(rows), v, np.float32), n, d)
     assert not P.has_warm and P.cold_unit is unit
+    assert P.cold_spill.has_spill is spill
+    if spill:
+        sc = P.cold_spill.spill_coo
+        assert (list(np.asarray(sc.row_ids)), list(np.asarray(sc.col_ids))
+                ) == ([5], [spl.COLD_TILE + 9])
     P = spl.place_pallas_matrix(P)
     matvec = jax.jit(lambda P, x: P.matvec(x))
     rmatvec = jax.jit(lambda P, x: P.rmatvec(x))
@@ -236,6 +411,18 @@ def test_cold_nonfinite_entries_stay_localized(unit):
     out = np.asarray(rmatvec(P, vector(n, {0: np.inf, 1: np.nan, 2: 2})))
     assert np.isinf(out[0]) and np.isnan(out[133]) and out[72] == 2 * v
     assert not np.any(np.delete(out, [0, 72, 133]))
+    if not spill:
+        return
+    # the spilled entry's column, then row 5 whose entries both paths hold
+    out = np.asarray(matvec(P, vector(d, {spl.COLD_TILE + 9: np.inf,
+                                          spl.COLD_TILE + 1: 1})))
+    assert np.isinf(out[5]) and not np.any(np.delete(out, 5))
+    out = np.asarray(matvec(P, vector(d, {spl.COLD_TILE + 1: np.nan})))
+    assert np.isnan(out[5]) and not np.any(np.delete(out, 5))
+    row5 = spl.COLD_TILE + np.arange(1, 10)
+    out = np.asarray(rmatvec(P, vector(n, {5: np.inf, 0: 1})))
+    assert np.all(np.isinf(out[row5])) and out[0] == v
+    assert not np.any(np.delete(out, [0, *row5]))
 
 
 def test_lbfgs_grid_against_float64():
