@@ -104,8 +104,8 @@ def parse_coordinate_config(spec: dict):
             optimizer=OptimizerType(spec.get("optimizer", "lbfgs")),
             max_iters=int(spec.get("max_iters", 100)),
             tolerance=float(spec.get("tolerance", 1e-7)),
-            # "solver" names a registered solver (docs/solvers.md);
-            # unset keeps the historical OWL-QN/TRON/L-BFGS routing
+            # "solver" names a solver (optim.problem.choose_solver,
+            # docs/solvers.md); unset keeps the historical routing
             # bitwise.  "solver_options" is a JSON object of knobs.
             solver=solver if solver is None else str(solver),
             solver_options=solver_options,

@@ -48,6 +48,8 @@ from photon_ml_tpu.evaluation.evaluators import (
 from photon_ml_tpu.io.model_store import save_glm_model
 from photon_ml_tpu.models.glm import GeneralizedLinearModel
 from photon_ml_tpu.optim.problem import (
+    DEVICE_SOLVERS,
+    HOST_LOOP_SOLVERS,
     GlmOptimizationConfig,
     GlmOptimizationProblem,
     OptimizerConfig,
@@ -89,18 +91,19 @@ def build_arg_parser() -> argparse.ArgumentParser:
     )
     p.add_argument(
         "--solver",
-        help="registered solver name (photon_ml_tpu/solvers): lbfgs | "
-        "owlqn | tron | spg | admm | block_cd.  Unset keeps the historical "
-        "routing (bounds → spg, any L1 → owlqn, else --optimizer) bitwise. "
-        "Host-kind solvers (admm, block_cd) run sharded: over the "
-        "--data-parallel mesh when available, else over --solver-shards "
-        "logical shards on one device",
+        choices=DEVICE_SOLVERS + HOST_LOOP_SOLVERS,
+        help="the solver, by name: lbfgs | owlqn | tron | spg run on the "
+        "device (optim/); admm | block_cd run a host-side loop "
+        "(solvers/).  Unset keeps the historical routing (bounds → spg, "
+        "any L1 → owlqn, else --optimizer) bitwise.  admm and block_cd "
+        "run sharded: over the --data-parallel mesh when available, else "
+        "over --solver-shards logical shards on one device",
     )
     p.add_argument(
         "--solver-shards",
         type=int,
         default=0,
-        help="logical shard count for host-kind solvers without a mesh "
+        help="logical shard count for host-loop solvers without a mesh "
         "(0 = auto: 2, or the solver_options 'shards' knob)",
     )
     p.add_argument(
@@ -333,14 +336,9 @@ def make_fit_once(
         from photon_ml_tpu.ops import losses as losses_lib
 
         suite = EvaluationSuite.for_task(losses_lib.get(task).name)
-    from photon_ml_tpu.solvers import registry as solver_registry
-
-    host_kind = (
-        solver is not None
-        and solver_registry.get(solver).kind == "host"
-    )
+    host_kind = solver in HOST_LOOP_SOLVERS
     if host_kind and hasattr(X_train, "todense"):
-        # Host-kind solvers shard dense row blocks; tuning-scale designs
+        # The host-loop solvers shard dense row blocks; tuning-scale designs
         # densify cheaply (the distributed grid path takes sparse).
         X_train = np.asarray(X_train.todense(), np.float32)
     data = make_glm_data(X_train, y_train)
@@ -373,9 +371,10 @@ def make_fit_once(
             return p
 
     def _sharded_solve(iters: int):
-        # Host-kind counterpart of the per-iters problem cache: one
+        # Host-loop counterpart of the per-iters problem cache: one
         # bound solver (logical shards, one compiled step program) per
         # iteration budget.
+        from photon_ml_tpu.solvers import HOST_SOLVERS
         from photon_ml_tpu.solvers import sharded as solvers_sharded
 
         problem = _problem(iters)
@@ -386,8 +385,7 @@ def make_fit_once(
                     problem.config.optimizer
                 )
                 dist = solvers_sharded.stack_resident(data, n_shards)
-                defn = solver_registry.get(solver)
-                s = sharded_solves[iters] = defn.sharded(
+                s = sharded_solves[iters] = HOST_SOLVERS[solver](
                     problem, dist, None, None
                 )
             return s
@@ -559,32 +557,25 @@ def _run_impl(args, logger, tel, clock) -> dict:
         solver_options.append((k.strip(), v.strip()))
     if args.solver_shards:
         solver_options.append(("shards", args.solver_shards))
-    host_solver = False
-    if args.solver is not None:
-        from photon_ml_tpu.solvers import registry as solver_registry
-
-        try:
-            host_solver = solver_registry.get(args.solver).kind == "host"
-        except KeyError as e:
-            raise SystemExit(str(e))
-        if host_solver:
-            if streaming:
-                raise SystemExit(
-                    f"--solver {args.solver} runs over sharded resident "
-                    "data; it does not compose with --streaming (the "
-                    "streamed pass loop IS the jit-kind solvers' "
-                    "distribution story)"
-                )
-            if args.compute_variances:
-                raise SystemExit(
-                    f"--solver {args.solver} does not support "
-                    "--compute-variances"
-                )
-            if args.coefficient_bounds:
-                raise SystemExit(
-                    f"--solver {args.solver} does not support "
-                    "--coefficient-bounds (only spg does)"
-                )
+    host_solver = args.solver in HOST_LOOP_SOLVERS
+    if host_solver:
+        if streaming:
+            raise SystemExit(
+                f"--solver {args.solver} runs over sharded resident "
+                "data; it does not compose with --streaming (the "
+                "streamed pass loop IS the on-device solvers' "
+                "distribution story)"
+            )
+        if args.compute_variances:
+            raise SystemExit(
+                f"--solver {args.solver} does not support "
+                "--compute-variances"
+            )
+        if args.coefficient_bounds:
+            raise SystemExit(
+                f"--solver {args.solver} does not support "
+                "--coefficient-bounds (only spg does)"
+            )
     problem = GlmOptimizationProblem(
         args.task,
         GlmOptimizationConfig(
@@ -777,19 +768,26 @@ def _run_impl(args, logger, tel, clock) -> dict:
                 hot_budget_bytes=int(args.stream_hot_budget_mb * 1e6),
             )
         if data_parallel:
-            from photon_ml_tpu.parallel.distributed import (
-                run_grid_distributed,
-                shard_glm_data,
-            )
+            from photon_ml_tpu.parallel.distributed import shard_glm_data
 
+            if host_solver:
+                # its own loop around the mesh's step program
+                from photon_ml_tpu.solvers.sharded import (
+                    run_grid_sharded as run_grid,
+                )
+            else:
+                # one shard_map program a solve
+                from photon_ml_tpu.parallel.distributed import (
+                    run_grid_distributed as run_grid,
+                )
             dist = shard_glm_data(X_train, y_train, mesh)
             note_placement(dist.data.features, dist.n_shards)
-            return run_grid_distributed(
+            return run_grid(
                 problem, dist, mesh, reg_weights, w0=w0, l1_mask=l1_mask,
                 solved=solved_now, on_solved=on_solved,
             )
         if host_solver:
-            # No mesh: a host-kind solver still runs sharded, over
+            # No mesh: a host-loop solver still runs sharded, over
             # logical row blocks on one device (same step program as the
             # mesh path, vmap + axis-0 sum standing in for the psum).
             from photon_ml_tpu.parallel.distributed import shard_glm_data

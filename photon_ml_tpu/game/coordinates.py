@@ -45,7 +45,12 @@ from photon_ml_tpu.models.glm import Coefficients, GeneralizedLinearModel
 from photon_ml_tpu.ops import losses as losses_lib
 from photon_ml_tpu.optim.lbfgs import LBFGSConfig, lbfgs_solve
 from photon_ml_tpu.optim.owlqn import OWLQNConfig, owlqn_solve
-from photon_ml_tpu.optim.problem import GlmOptimizationConfig, OptimizerType
+from photon_ml_tpu.optim.problem import (
+    DEVICE_SOLVERS,
+    HOST_LOOP_SOLVERS,
+    GlmOptimizationConfig,
+    choose_solver,
+)
 from photon_ml_tpu import telemetry as telemetry_mod
 
 Array = jax.Array
@@ -191,29 +196,26 @@ class FixedEffectCoordinate(Coordinate):
         self.feature_shard = feature_shard
         self.axis_name = axis_name
         self._sharded_trainer = None
-        solver_name = getattr(config.optimizer, "solver", None)
-        if solver_name is not None:
-            from photon_ml_tpu.solvers import registry as solver_registry
+        solver_name = config.optimizer.solver
+        if solver_name in HOST_LOOP_SOLVERS:
+            # The host-loop solvers (ADMM, block CD) distribute this
+            # coordinate's solve over logical row shards; per-GAME-
+            # iteration offsets re-slot into one shard template so the
+            # compiled step program is reused across iterations.
+            from photon_ml_tpu.solvers import sharded as solvers_sharded
 
-            if solver_registry.get(solver_name).kind == "host":
-                # Host-kind solvers (ADMM, block CD) distribute this
-                # coordinate's solve over logical row shards; per-GAME-
-                # iteration offsets re-slot into one shard template so
-                # the compiled step program is reused across iterations.
-                from photon_ml_tpu.solvers import sharded as solvers_sharded
-
-                if axis_name is not None:
-                    raise ValueError(
-                        f"solver {solver_name!r} manages its own mesh "
-                        "collectives; it cannot nest inside an existing "
-                        f"axis {axis_name!r} (drop data-parallel GAME or "
-                        "the solver override)"
-                    )
-                self._sharded_trainer = solvers_sharded.make_fixed_effect_trainer(
-                    self.problem,
-                    dataset.data,
-                    solvers_sharded.resolve_shard_count(config.optimizer),
+            if axis_name is not None:
+                raise ValueError(
+                    f"solver {solver_name!r} manages its own mesh "
+                    "collectives; it cannot nest inside an existing "
+                    f"axis {axis_name!r} (drop data-parallel GAME or "
+                    "the solver override)"
                 )
+            self._sharded_trainer = solvers_sharded.make_fixed_effect_trainer(
+                self.problem,
+                dataset.data,
+                solvers_sharded.resolve_shard_count(config.optimizer),
+            )
         self._train_jit, self._score_jit = _fixed_effect_jits(
             self.task, config, axis_name, _layout_sig(dataset.data)
         )
@@ -302,28 +304,18 @@ def _make_block_solver_cached(task: str, config: GlmOptimizationConfig):
 
     loss = losses_lib.get(task)
     opt = config.optimizer
-    has_l1 = config.regularization.l1_weight(1.0) > 0.0
-    if getattr(opt, "solver", None) is not None:
-        # Registry dispatch for an explicit solver name.  Random-effect
-        # blocks are batched per-entity traced solves, so only jit-kind
-        # solvers apply here (host-kind ADMM/block-CD distribute the
-        # FIXED-effect coordinate — see FixedEffectCoordinate).
-        from photon_ml_tpu.solvers import registry as solver_registry
-
-        defn = solver_registry.resolve(
-            opt, l1_frac=config.regularization.l1_weight(1.0)
+    # Random-effect blocks are batched per-entity traced solves, so only
+    # the on-device solvers apply here (the host-loop ADMM / block CD
+    # distribute the FIXED-effect coordinate — see FixedEffectCoordinate).
+    name = choose_solver(opt, l1_frac=config.regularization.l1_weight(1.0))
+    if name not in DEVICE_SOLVERS:
+        raise ValueError(
+            f"solver {name!r} runs a host-side loop and cannot run the "
+            "per-entity random-effect blocks; set it on the "
+            "fixed-effect coordinate's spec instead"
         )
-        if defn.kind != "jit":
-            raise ValueError(
-                f"solver {defn.name!r} is host-kind and cannot run the "
-                "per-entity random-effect blocks; set it on the "
-                "fixed-effect coordinate's spec instead"
-            )
-        use_owlqn = defn.name == "owlqn" or has_l1
-        use_tron = defn.name == "tron"
-    else:
-        use_owlqn = opt.optimizer is OptimizerType.OWLQN or has_l1
-        use_tron = opt.optimizer is OptimizerType.TRON
+    use_owlqn = name == "owlqn"
+    use_tron = name == "tron"
 
     def rank1_newton(block, offsets_block, w0, l2):
         """Single-row entities (R == 1 — the LARGEST bucket class in

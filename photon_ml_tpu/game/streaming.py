@@ -31,8 +31,9 @@ from photon_ml_tpu.models.glm import Coefficients, GeneralizedLinearModel
 from photon_ml_tpu.ops import losses as losses_lib
 from photon_ml_tpu.optim.lbfgs import LBFGSConfig
 from photon_ml_tpu.optim.owlqn import OWLQNConfig
-from photon_ml_tpu.optim.problem import GlmOptimizationConfig, OptimizerType
+from photon_ml_tpu.optim.problem import GlmOptimizationConfig, choose_solver
 from photon_ml_tpu.optim.streaming import (
+    STREAMED_SOLVERS,
     StreamingObjective,
     ensure_streamable,
     streaming_lbfgs_solve,
@@ -100,6 +101,14 @@ class StreamingFixedEffectCoordinate(Coordinate):
         process holding their rows, the reference's hash-partitioner
         invariant), and reduce metrics with a psum or allgather."""
         ensure_streamable(config)
+        self._solver = choose_solver(
+            config.optimizer, l1_frac=config.regularization.l1_weight(1.0)
+        )
+        if self._solver not in STREAMED_SOLVERS:
+            raise ValueError(
+                f"solver {self._solver!r} has no streamed implementation; "
+                f"a streamed fixed effect runs one of {STREAMED_SOLVERS}"
+            )
         if mesh is None and stream.n_shards != 1:
             raise ValueError(
                 f"stream has n_shards={stream.n_shards}; pass the mesh it "
@@ -172,17 +181,12 @@ class StreamingFixedEffectCoordinate(Coordinate):
             ))
             if self.batch_linesearch else None
         )
-        # Static routing as in problem.solve: any L1 component needs the
-        # orthant machinery.
-        if (
-            self.config.optimizer.optimizer is OptimizerType.OWLQN
-            or self._l1_frac > 0.0
-        ):
+        if self._solver == "owlqn":
             res = streaming_owlqn_solve(
                 vg, w0, self._l1_frac * self.reg_weight, self._owlqn,
                 value_and_grad_batch=vgb,
             )
-        elif self.config.optimizer.optimizer is OptimizerType.TRON:
+        elif self._solver == "tron":
             from photon_ml_tpu.optim.tron import TRONConfig
 
             opt = self.config.optimizer

@@ -93,6 +93,27 @@ class SolveResult(NamedTuple):
     nonzeros: Array | None = None
 
 
+#: The counts a solver family adds to :class:`SolveResult`, keyed by the
+#: field that marks the family (None in every other solve's result): each
+#: field with the counter it feeds (None: a ``solver`` span attribute
+#: only) and the type the span records it as.  ``grid_loop`` reads exactly
+#: these; SPG's ``stalled`` marks no family and is not read.
+SOLVE_COUNTS = {
+    # trust-region Newton (``tron_solve``)
+    "cg_iterations": (
+        ("cg_iterations", "solver_cg_iterations", int),
+        ("rejected_steps", None, int),
+        ("boundary_exits", None, int),
+    ),
+    # orthant-wise (``owlqn_solve``)
+    "orthant_clamps": (
+        ("stalled", "solver_stalled_total", bool),
+        ("orthant_clamps", "solver_orthant_clamps_total", int),
+        ("nonzeros", None, int),
+    ),
+}
+
+
 class _LBFGSState(NamedTuple):
     w: Array
     value: Array

@@ -28,16 +28,19 @@ from photon_ml_tpu.data.dataset import GlmData
 from photon_ml_tpu.data.normalization import NormalizationContext
 from photon_ml_tpu.models.glm import Coefficients, GeneralizedLinearModel
 from photon_ml_tpu.ops import losses as losses_lib
-from photon_ml_tpu.optim.lbfgs import SolveResult
+from photon_ml_tpu.optim.lbfgs import (
+    SOLVE_COUNTS,
+    LBFGSConfig,
+    SolveResult,
+    lbfgs_solve,
+)
 from photon_ml_tpu.optim.objective import GlmObjective
+from photon_ml_tpu.optim.owlqn import OWLQNConfig, owlqn_solve
+from photon_ml_tpu.optim.projected import SPGConfig, spg_solve
 from photon_ml_tpu.optim.regularization import RegularizationContext
+from photon_ml_tpu.optim.tron import TRONConfig, tron_solve
 
 Array = jax.Array
-
-# SolveResult's fields that only a trust-region Newton solve fills.
-_TRON_COUNTS = ("cg_iterations", "rejected_steps", "boundary_exits")
-# ... and those that only an orthant-wise solve fills.
-_OWLQN_COUNTS = ("stalled", "orthant_clamps", "nonzeros")
 
 
 class OptimizerType(enum.Enum):
@@ -51,12 +54,13 @@ class OptimizerConfig:
     """Mirrors the reference's ``OptimizerConfig`` (optimizerType,
     maximumIterations, tolerance).
 
-    ``solver`` names a registered solver (photon_ml_tpu/solvers/registry.py)
-    explicitly; None keeps the historical routing (bounds → SPG, any L1
-    component → OWL-QN, else ``optimizer``) bitwise.  ``solver_options`` is
-    a tuple of (key, value) pairs — a TUPLE, not a dict, because this
-    config lives in lru_cache keys (GAME block solvers, fixed-effect jit
-    caches) and must stay hashable."""
+    ``solver`` names a solver explicitly (:data:`DEVICE_SOLVERS` or
+    :data:`HOST_LOOP_SOLVERS`); None keeps the historical routing (bounds →
+    SPG, any L1 component → OWL-QN, else ``optimizer``) bitwise — see
+    :func:`choose_solver`.  ``solver_options`` is a tuple of (key, value)
+    pairs — a TUPLE, not a dict, because this config lives in lru_cache
+    keys (GAME block solvers, fixed-effect jit caches) and must stay
+    hashable."""
 
     optimizer: OptimizerType = OptimizerType.LBFGS
     max_iters: int = 100
@@ -64,6 +68,62 @@ class OptimizerConfig:
     history: int = 10  # L-BFGS/OWL-QN corrections
     solver: Optional[str] = None
     solver_options: tuple = ()
+
+    def solver_options_dict(self) -> dict:
+        """``solver_options`` as a plain dict."""
+        return dict(self.solver_options)
+
+
+#: The solvers a traced solve runs (``GlmOptimizationProblem.solve``, the
+#: streamed grid, the GAME block solvers).
+DEVICE_SOLVERS = ("lbfgs", "owlqn", "tron", "spg")
+#: The solvers that run a host-side outer loop around a compiled step
+#: program (consensus ADMM, distributed block CD); ``solvers.HOST_SOLVERS``
+#: holds their factories.  Both handle L1.
+HOST_LOOP_SOLVERS = ("admm", "block_cd")
+
+
+def choose_solver(opt: OptimizerConfig, *, l1_frac: float,
+                  has_bounds: bool = False) -> str:
+    """The solver an ``OptimizerConfig`` runs, by name.
+
+    ``opt.solver`` unset is the historical routing: bounds → SPG, any L1
+    component → OWL-QN (the only orthant-capable machinery, as in the
+    reference), else the configured optimizer.  An explicit name is honored
+    as-is, but incompatible combinations (an L1 component with a solver
+    that has no subgradient handling; bounds with anything but SPG; SPG
+    without bounds) are refused here — statically, before any compute is
+    spent: ``l1_frac`` is a float, the name a config string."""
+    name = opt.solver
+    if name is None:
+        if has_bounds:
+            return "spg"
+        if l1_frac > 0.0:
+            return "owlqn"
+        return opt.optimizer.value
+    if name not in DEVICE_SOLVERS + HOST_LOOP_SOLVERS:
+        raise KeyError(
+            f"unknown solver {name!r}; known: "
+            f"{sorted(DEVICE_SOLVERS + HOST_LOOP_SOLVERS)}"
+        )
+    if has_bounds and name != "spg":
+        raise ValueError(
+            f"solver {name!r} does not support box constraints; "
+            "only 'spg' does — drop the bounds or the solver override"
+        )
+    if l1_frac > 0.0 and name not in ("owlqn",) + HOST_LOOP_SOLVERS:
+        raise ValueError(
+            f"solver {name!r} has no L1 subgradient handling; use "
+            "'owlqn', 'admm', or 'block_cd' for L1/elastic-net configs"
+        )
+    if name == "spg" and not has_bounds:
+        # SPG is a projection method: without box constraints there is no
+        # feasible set to project onto.
+        raise ValueError(
+            "solver 'spg' needs box constraints (lower/upper bounds); "
+            "use 'lbfgs' or 'tron' for unconstrained smooth configs"
+        )
+    return name
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,28 +237,55 @@ class GlmOptimizationProblem:
                 "machineries conflict (drop the L1 component or the "
                 "bounds)"
             )
-        # Dispatch through the solver registry (photon_ml_tpu/solvers/):
-        # cfg.optimizer.solver unset reproduces the pre-registry static
-        # routing bitwise — bounds → SPG for any smooth config, any L1
-        # component → OWL-QN (the only orthant-capable machinery, as in
-        # the reference), else the configured optimizer.  All checks are
-        # static: l1_frac is a float, the solver name a config string.
-        from photon_ml_tpu.solvers import registry as solver_registry
-
-        defn = solver_registry.resolve(
+        name = choose_solver(
             opt, l1_frac=l1_frac, has_bounds=bounds is not None
         )
-        if defn.kind != "jit":
+        if name not in DEVICE_SOLVERS:
             raise ValueError(
-                f"solver {defn.name!r} runs a host-side outer loop and "
+                f"solver {name!r} runs a host-side outer loop and "
                 "cannot execute inside a traced solve; route through "
                 "solvers.sharded.run_grid_sharded (glm_driver --solver "
-                "and run_grid_distributed do this automatically)"
+                "does this automatically)"
             )
-        return defn.resident(solver_registry.ResidentSolve(
-            objective=obj, data=data, w0=w0, l1=l1, l2=l2, opt=opt,
-            axis_name=axis_name, l1_mask=l1_mask, bounds=bounds,
-        ))
+        vg = lambda w: obj.value_and_grad(
+            w, data, l2_weight=l2, axis_name=axis_name
+        )
+        if name == "lbfgs":
+            return lbfgs_solve(vg, w0, LBFGSConfig(
+                max_iters=opt.max_iters,
+                tolerance=opt.tolerance,
+                history=opt.history,
+            ))
+        if name == "owlqn":
+            return owlqn_solve(
+                vg,
+                w0,
+                l1,
+                OWLQNConfig(
+                    max_iters=opt.max_iters,
+                    tolerance=opt.tolerance,
+                    history=opt.history,
+                ),
+                l1_mask=l1_mask,
+            )
+        if name == "tron":
+            return tron_solve(
+                vg,
+                lambda w, v, aux: obj.hvp(
+                    w, v, data, l2_weight=l2, axis_name=axis_name, d2w=aux
+                ),
+                w0,
+                TRONConfig(max_iters=opt.max_iters, tolerance=opt.tolerance),
+                d2_fn=lambda w: obj.d2_weights(w, data),
+            )
+        return spg_solve(
+            vg,
+            w0,
+            bounds[0],
+            bounds[1],
+            SPGConfig(max_iters=opt.max_iters, tolerance=opt.tolerance),
+            w_axis=None,
+        )
 
     # -- variances (reference: optional coefficient variance computation) ---
     def coefficient_variances(
@@ -293,15 +380,14 @@ class GlmOptimizationProblem:
                         # copy asked for only then idles the device ~1 ms
                         # a solve on a TPU v5e).
                         counts = (res.iterations, res.fn_evals, res.converged)
-                        # a trust-region Newton or an orthant-wise solve's
-                        # own counts ride along; every other solve reads
-                        # what it read
-                        extra_names = (
-                            _TRON_COUNTS if res.cg_iterations is not None
-                            else _OWLQN_COUNTS
-                            if res.orthant_clamps is not None else ())
+                        # a solver family's own counts ride along
+                        # (SOLVE_COUNTS); every other solve reads what it
+                        # read
+                        family = next(
+                            (fields for mark, fields in SOLVE_COUNTS.items()
+                             if getattr(res, mark) is not None), ())
                         counts += tuple(
-                            getattr(res, k) for k in extra_names)
+                            getattr(res, field) for field, _, _ in family)
                         counts = jax.copy_to_host_async(counts)
                         jax.block_until_ready(res.w)
                         wall = sp.stop()
@@ -318,18 +404,10 @@ class GlmOptimizationProblem:
                         if fn_evals is not None:
                             sp.set(fn_evals=int(fn_evals))
                             tel.counter("solver_fn_evals").inc(int(fn_evals))
-                        if extras:
-                            extras = dict(zip(extra_names, map(int, extras)))
-                            if "cg_iterations" in extras:
-                                tel.counter("solver_cg_iterations").inc(
-                                    extras["cg_iterations"])
-                            else:
-                                tel.counter("solver_orthant_clamps_total").inc(
-                                    extras["orthant_clamps"])
-                                tel.counter("solver_stalled_total").inc(
-                                    extras["stalled"])
-                                extras["stalled"] = bool(extras["stalled"])
-                            sp.set(**extras)
+                        for (field, counter, kind), v in zip(family, extras):
+                            if counter is not None:
+                                tel.counter(counter).inc(int(v))
+                            sp.set(**{field: kind(int(v))})
                     self.grid_wall_seconds[lam] = wall
                     w = res.w
                     if on_solved is not None:

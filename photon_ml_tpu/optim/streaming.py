@@ -1064,7 +1064,7 @@ class StreamingObjective:
             # Every streamed pass is one logical all-reduce round: the
             # chunk-sequential accumulation folds a (batch × (d+1)) carry
             # exactly like a psum across shards.  Publishing it here puts
-            # the jit-kind solvers on the same instrument the distributed
+            # the on-device solvers on the same instrument the distributed
             # solvers (solvers/admm.py, solvers/block_cd.py) report on, so
             # reduces per solve compare across solver kinds.
             tel.counter("solver_allreduce_count").inc(1)
@@ -1846,6 +1846,10 @@ def streaming_tron_solve(
 # ---------------------------------------------------------------------------
 
 
+#: The solvers with a streamed pass loop (``OptimizerConfig.solver`` names).
+STREAMED_SOLVERS = ("lbfgs", "owlqn", "tron")
+
+
 def ensure_streamable(config) -> None:
     """Reject configs the streamed path cannot train — callable BEFORE the
     (possibly hours-long) chunk-store ingest, and always re-checked by
@@ -1888,7 +1892,8 @@ def streaming_run_grid(
     :class:`StreamingObjective`); lossless compression and the cache
     leave every solve bitwise unchanged.
     """
-    from photon_ml_tpu.solvers import registry as solver_registry
+    from photon_ml_tpu.optim.problem import choose_solver
+    from photon_ml_tpu.optim.tron import TRONConfig
 
     cfg = problem.config
     ensure_streamable(cfg)
@@ -1899,12 +1904,12 @@ def streaming_run_grid(
     )
     opt = cfg.optimizer
     l1_frac = cfg.regularization.l1_weight(1.0)
-    defn = solver_registry.resolve(opt, l1_frac=l1_frac)
-    if defn.streamed is None:
+    name = choose_solver(opt, l1_frac=l1_frac)
+    if name not in STREAMED_SOLVERS:
         raise ValueError(
-            f"solver {defn.name!r} has no streamed implementation; the "
-            "streamed grid serves jit-kind solvers with a streamed pass "
-            "loop (lbfgs, owlqn, tron) — distributed solvers run over "
+            f"solver {name!r} has no streamed implementation; the "
+            "streamed grid serves the solvers with a streamed pass "
+            f"loop {STREAMED_SOLVERS} — distributed solvers run over "
             "sharded resident data (solvers.sharded.run_grid_sharded)"
         )
 
@@ -1913,14 +1918,41 @@ def streaming_run_grid(
         l2 = cfg.regularization.l2_weight(1.0) * float(lam)
         if w_prev is None:
             w_prev = jnp.zeros((stream.n_features,), jnp.float32)
+        vg = lambda w: sobj.value_and_grad(w, l2)
         vgb = (
             (lambda ws: sobj.value_and_grad_batch(ws, l2))
             if batch_linesearch else None
         )
-        return defn.streamed(solver_registry.StreamedSolve(
-            sobj=sobj, w0=w_prev, l1=l1, l2=l2, opt=opt,
-            l1_mask=l1_mask, value_and_grad_batch=vgb,
-        ))
+        if name == "lbfgs":
+            return streaming_lbfgs_solve(
+                vg,
+                w_prev,
+                LBFGSConfig(
+                    max_iters=opt.max_iters,
+                    tolerance=opt.tolerance,
+                    history=opt.history,
+                ),
+                value_and_grad_batch=vgb,
+            )
+        if name == "owlqn":
+            return streaming_owlqn_solve(
+                vg,
+                w_prev,
+                l1,
+                OWLQNConfig(
+                    max_iters=opt.max_iters,
+                    tolerance=opt.tolerance,
+                    history=opt.history,
+                ),
+                l1_mask=l1_mask,
+                value_and_grad_batch=vgb,
+            )
+        return streaming_tron_solve(
+            vg,
+            lambda w, v: sobj.hvp(w, v, l2),
+            w_prev,
+            TRONConfig(max_iters=opt.max_iters, tolerance=opt.tolerance),
+        )
 
     variance_fn = None
     if cfg.compute_variances:

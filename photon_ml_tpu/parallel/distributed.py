@@ -92,7 +92,7 @@ def shard_glm_data(
 
     ``mesh=None`` builds LOGICAL shards: the same leading-shard-axis layout
     with ``n_shards`` row blocks, left on the default device — the
-    single-device stand-in the host-kind solvers (solvers/admm.py,
+    single-device stand-in the host-loop solvers (solvers/admm.py,
     solvers/block_cd.py) vmap over when no mesh participates.
     """
     import scipy.sparse as sp
@@ -186,24 +186,10 @@ def run_grid_distributed(
     collapsed onto ICI.  Coefficient variances, when configured, run as a
     second shard_map program (one psum'd squared-column reduction per λ).
 
-    Host-kind solvers (``OptimizerConfig.solver`` naming admm/block_cd)
-    cannot run inside the traced shard_map solve; they route to
-    ``solvers.sharded.run_grid_sharded``, which drives the same grid_loop
-    warm-start chain around the solver's own host outer loop."""
+    The host-loop solvers (``OptimizerConfig.solver`` naming admm or
+    block_cd) cannot run inside the traced shard_map solve: their grid is
+    ``solvers.sharded.run_grid_sharded``, which takes the same arguments."""
     import jax.numpy as jnp
-
-    from photon_ml_tpu.solvers import registry as solver_registry
-    from photon_ml_tpu.solvers import sharded as solvers_sharded
-
-    cfg = problem.config
-    defn = solver_registry.resolve(
-        cfg.optimizer, l1_frac=cfg.regularization.l1_weight(1.0)
-    )
-    if defn.kind == "host":
-        return solvers_sharded.run_grid_sharded(
-            problem, dist_data, mesh, reg_weights, w0=w0, l1_mask=l1_mask,
-            warm_start=warm_start, solved=solved, on_solved=on_solved,
-        )
 
     d = dist_data.data.features.shape[-1]
     if w0 is None:

@@ -1,29 +1,18 @@
-"""Solver subsystem: config-keyed registry + communication-light solvers.
+"""The host-loop solvers: consensus-ADMM (solvers/admm.py) and distributed
+block coordinate descent (solvers/block_cd.py).
 
-The registry (solvers/registry.py) turns the hard-wired optimizer
-dispatch that lived in ``optim/problem.solve``, ``optim/streaming
-.streaming_run_grid`` and the GAME block solvers into a config-keyed
-factory: every solver — the existing L-BFGS / OWL-QN / TRON / SPG and
-the new consensus-ADMM (solvers/admm.py) and distributed block
-coordinate descent (solvers/block_cd.py) — registers a
-:class:`~photon_ml_tpu.solvers.registry.SolverDef` and is selected by
-``OptimizerConfig.solver`` (name) + ``solver_options`` (knobs).  Unset
-``solver`` reproduces the historical static routing bitwise (bounds →
-SPG, any L1 component → OWL-QN, else the configured optimizer).
-
-Importing the package registers every built-in solver.
+Each runs a host-side outer loop around one compiled step program, so it
+cannot execute inside a traced solve; ``optim.problem.choose_solver`` names
+it (``OptimizerConfig.solver``) and :data:`HOST_SOLVERS` maps the name to
+its factory, ``factory(problem, dist, mesh, l1_mask) → solve_fn(lam,
+w_prev, dist_override=None)``.  ``solvers/sharded.py`` runs a factory's
+solves as a warm-started λ grid.  The four on-device solvers (L-BFGS,
+OWL-QN, TRON, SPG) are ``optim``'s own.
 """
 
-from photon_ml_tpu.solvers import admm as _admm  # noqa: F401  (registers)
-from photon_ml_tpu.solvers import block_cd as _block_cd  # noqa: F401
-from photon_ml_tpu.solvers import registry
-from photon_ml_tpu.solvers.registry import (  # noqa: F401
-    ResidentSolve,
-    SolverDef,
-    StreamedSolve,
-    get,
-    names,
-    register,
-    resolve,
-    solver_options_dict,
-)
+from photon_ml_tpu.solvers import admm, block_cd
+
+HOST_SOLVERS = {
+    "admm": admm.make_sharded_solver,
+    "block_cd": block_cd.make_sharded_solver,
+}

@@ -116,7 +116,7 @@ def _soft_threshold(t: Array, thresh: Array) -> Array:
 
 
 def make_sharded_solver(problem, dist, mesh, l1_mask=None):
-    """Registry ``sharded`` factory: bind (problem, sharded data, mesh) once,
+    """``HOST_SOLVERS`` factory: bind (problem, sharded data, mesh) once,
     return ``solve_fn(lam, w_prev, dist_override=None) → SolveResult``.
 
     ``dist`` is a ``parallel.distributed.DistributedGlmData`` (every array
@@ -127,12 +127,11 @@ def make_sharded_solver(problem, dist, mesh, l1_mask=None):
     coordinate re-slots its per-iteration offsets this way."""
     from jax import shard_map
     from photon_ml_tpu.parallel.distributed import DATA_AXIS
-    from photon_ml_tpu.solvers import registry as registry_mod
 
     obj = problem.objective
     cfg = problem.config
     opt = cfg.optimizer
-    opts = ADMMOptions.from_options(registry_mod.solver_options_dict(opt))
+    opts = ADMMOptions.from_options(opt.solver_options_dict())
     max_outer = opts.max_outer or opt.max_iters
     abstol = opts.abstol or opt.tolerance
     l1_frac = cfg.regularization.l1_weight(1.0)
@@ -441,20 +440,3 @@ def make_sharded_solver(problem, dist, mesh, l1_mask=None):
 
     return solve_fn
 
-
-def _register():
-    from photon_ml_tpu.solvers import registry
-
-    registry.register(registry.SolverDef(
-        name="admm",
-        kind="host",
-        description=(
-            "consensus ADMM: per-shard subproblems + soft-threshold "
-            "consensus, one all-reduce per outer iteration"
-        ),
-        supports_l1=True,
-        sharded=make_sharded_solver,
-    ))
-
-
-_register()

@@ -88,12 +88,11 @@ class BlockCDOptions:
 
 
 def make_sharded_solver(problem, dist, mesh, l1_mask=None):
-    """Registry ``sharded`` factory (same contract as
+    """``HOST_SOLVERS`` factory (same contract as
     solvers.admm.make_sharded_solver)."""
     from photon_ml_tpu.ops.sparse import DenseMatrix
     from jax import shard_map
     from photon_ml_tpu.parallel.distributed import DATA_AXIS
-    from photon_ml_tpu.solvers import registry as registry_mod
 
     if not isinstance(dist.data.features, DenseMatrix):
         raise ValueError(
@@ -112,9 +111,7 @@ def make_sharded_solver(problem, dist, mesh, l1_mask=None):
     loss = obj.loss
     cfg = problem.config
     opt = cfg.optimizer
-    opts = BlockCDOptions.from_options(
-        registry_mod.solver_options_dict(opt)
-    )
+    opts = BlockCDOptions.from_options(opt.solver_options_dict())
     l1_frac = cfg.regularization.l1_weight(1.0)
     l2_frac = cfg.regularization.l2_weight(1.0)
 
@@ -322,20 +319,3 @@ def make_sharded_solver(problem, dist, mesh, l1_mask=None):
 
     return solve_fn
 
-
-def _register():
-    from photon_ml_tpu.solvers import registry
-
-    registry.register(registry.SolverDef(
-        name="block_cd",
-        kind="host",
-        description=(
-            "distributed block coordinate descent: drift-corrected local "
-            "prox-Newton CD sweeps + two all-reduces per block round"
-        ),
-        supports_l1=True,
-        sharded=make_sharded_solver,
-    ))
-
-
-_register()

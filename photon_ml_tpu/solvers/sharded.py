@@ -1,9 +1,9 @@
-"""Grid runner + shard builders for host-kind solvers (ADMM, block CD).
+"""Grid runner + shard builders for the host-loop solvers (ADMM, block CD).
 
-Host-kind solvers (``SolverDef.kind == "host"``) run a host-side outer loop
+The host-loop solvers (``solvers.HOST_SOLVERS``) run a host-side outer loop
 around one compiled step program, so they cannot execute inside the traced
 ``problem.solve``.  This module is their entry: :func:`run_grid_sharded`
-plugs a host-kind solver's ``sharded`` factory into the SAME
+plugs a host-loop solver's factory into the SAME
 ``problem.grid_loop`` warm-start chain the traced paths use — identical
 checkpoint/resume semantics (GridCheckpointer via ``on_solved``, the
 ``grid.point`` chaos boundary), identical solver telemetry spans.
@@ -29,18 +29,34 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from photon_ml_tpu.optim.problem import choose_solver
+from photon_ml_tpu.solvers import HOST_SOLVERS
+
 Array = jax.Array
 
 
+def _host_solver(problem) -> str:
+    """The host-loop solver ``problem``'s config names."""
+    cfg = problem.config
+    name = choose_solver(
+        cfg.optimizer, l1_frac=cfg.regularization.l1_weight(1.0)
+    )
+    if name not in HOST_SOLVERS:
+        raise ValueError(
+            f"run_grid_sharded serves the host-loop solvers; {name!r} runs "
+            "on the device (jit-kind) — use problem.run_grid / "
+            "run_grid_distributed"
+        )
+    return name
+
+
 def resolve_shard_count(opt, mesh=None, default: int = 2) -> int:
-    """The shard count for a host-kind solve: the mesh size when a mesh
+    """The shard count for a host-loop solve: the mesh size when a mesh
     participates, else the solver_options ``shards`` knob, else
     ``default`` logical shards."""
-    from photon_ml_tpu.solvers import registry
-
     if mesh is not None:
         return mesh.devices.size
-    shards = int(registry.solver_options_dict(opt).get("shards", 0) or 0)
+    shards = int(opt.solver_options_dict().get("shards", 0) or 0)
     return shards if shards > 0 else default
 
 
@@ -96,27 +112,18 @@ def run_grid_sharded(
     solved: Optional[dict] = None,
     on_solved=None,
 ):
-    """The λ-grid warm-start chain for a host-kind solver over sharded
+    """The λ-grid warm-start chain for a host-loop solver over sharded
     data — the host-loop counterpart of
-    ``parallel.distributed.run_grid_distributed``."""
-    from photon_ml_tpu.solvers import registry
-
-    cfg = problem.config
-    defn = registry.resolve(
-        cfg.optimizer, l1_frac=cfg.regularization.l1_weight(1.0)
-    )
-    if defn.kind != "host":
-        raise ValueError(
-            f"run_grid_sharded serves host-kind solvers; {defn.name!r} is "
-            "jit-kind — use problem.run_grid / run_grid_distributed"
-        )
-    if cfg.compute_variances:
+    ``parallel.distributed.run_grid_distributed``, with the same
+    arguments."""
+    name = _host_solver(problem)
+    if problem.config.compute_variances:
         raise ValueError(
             f"compute_variances is not supported with solver "
-            f"{defn.name!r}; drop the variance request or use a jit-kind "
+            f"{name!r}; drop the variance request or use an on-device "
             "solver"
         )
-    solve = defn.sharded(problem, dist, mesh, l1_mask)
+    solve = HOST_SOLVERS[name](problem, dist, mesh, l1_mask)
     d = int(dist.data.features.shape[-1])
     if w0 is None:
         w0 = jnp.zeros((d,), jnp.float32)
@@ -127,7 +134,7 @@ def run_grid_sharded(
 
 
 def make_fixed_effect_trainer(problem, data, n_shards: int, l1_mask=None):
-    """A GAME fixed-effect trainer backed by a host-kind solver:
+    """A GAME fixed-effect trainer backed by a host-loop solver:
     ``trainer(offsets, w0, reg_weight) → coefficients``.
 
     The dataset shards once (logical, dense); each GAME outer iteration's
@@ -138,13 +145,8 @@ def make_fixed_effect_trainer(problem, data, n_shards: int, l1_mask=None):
     rows_per = int(template.data.labels.shape[-1])
     total = rows_per * n_shards
 
-    from photon_ml_tpu.solvers import registry
-
-    cfg = problem.config
-    defn = registry.resolve(
-        cfg.optimizer, l1_frac=cfg.regularization.l1_weight(1.0)
-    )
-    solve = defn.sharded(problem, template, None, l1_mask)
+    solve = HOST_SOLVERS[_host_solver(problem)](
+        problem, template, None, l1_mask)
 
     def trainer(offsets: Array, w0: Array, reg_weight: float) -> Array:
         off = jnp.asarray(offsets, jnp.float32)

@@ -59,7 +59,7 @@ def build_arg_parser() -> argparse.ArgumentParser:
     p.add_argument("--task", default="logistic", help="glm: task type")
     p.add_argument("--reg-type", default="l2", help="glm: regularization")
     p.add_argument("--optimizer", default="lbfgs", help="glm")
-    p.add_argument("--solver", help="glm: registered solver name "
+    p.add_argument("--solver", help="glm: solver name "
                    "(lbfgs|owlqn|tron|admm|block_cd); unset keeps the "
                    "historical routing bitwise — docs/solvers.md")
     p.add_argument("--max-iters", type=int, default=100, help="glm: full-"

@@ -103,7 +103,7 @@ if [[ "${1:-}" == "--fast" ]]; then
   # is the binary-parity smoke: a 3-bucket synthetic model scored over
   # live HTTP in both wire formats must produce BITWISE-identical
   # scores (plus fused-kernel parity and frame refusal tests).  The
-  # solver smoke pins registry dispatch (explicit --solver lbfgs is
+  # solver smoke pins the solver choice (explicit --solver lbfgs is
   # bitwise the implicit routing) and consensus-ADMM landing within
   # 1e-5 of the resident OWL-QN optimum over logical shards.
   # test_cluster covers the control plane: membership expiry/heal,

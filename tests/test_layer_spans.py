@@ -17,7 +17,7 @@ import scipy.sparse as sp
 
 from photon_ml_tpu import telemetry
 from photon_ml_tpu.data.dataset import make_glm_data
-from photon_ml_tpu.optim.lbfgs import LBFGSConfig, lbfgs_solve
+from photon_ml_tpu.optim.lbfgs import LBFGSConfig, SolveResult, lbfgs_solve
 from photon_ml_tpu.optim.problem import (
     GlmOptimizationConfig,
     GlmOptimizationProblem,
@@ -595,6 +595,70 @@ class TestOwlqnCounts:
             assert 0 < attrs["nonzeros"] <= d
         assert solvers[0]["attrs"]["nonzeros"] < solvers[1]["attrs"][
             "nonzeros"]
+
+
+# What each solver family fills in SolveResult beyond the base fields, and
+# what grid_loop must make of it: the ``solver`` span's own attributes past
+# the five every solve has, and every counter it moves (per solve).
+FAMILY_FIELDS = {
+    "lbfgs": (dict(fn_evals=9),
+              {"fn_evals": 9},
+              {"solver_iterations": 7, "solver_fn_evals": 9}),
+    "tron": (dict(fn_evals=8, cg_iterations=31, rejected_steps=2,
+                  boundary_exits=1),
+             {"fn_evals": 8, "cg_iterations": 31, "rejected_steps": 2,
+              "boundary_exits": 1},
+             {"solver_iterations": 7, "solver_fn_evals": 8,
+              "solver_cg_iterations": 31}),
+    "owlqn": (dict(fn_evals=11, stalled=True, orthant_clamps=5, nonzeros=2),
+              {"fn_evals": 11, "stalled": True, "orthant_clamps": 5,
+               "nonzeros": 2},
+              {"solver_iterations": 7, "solver_fn_evals": 11,
+               "solver_orthant_clamps_total": 5, "solver_stalled_total": 1}),
+    # SPG fills ``stalled`` and nothing else; the grid loop does not read it
+    "spg": (dict(stalled=True),
+            {},
+            {"solver_iterations": 7}),
+}
+
+
+class TestSolverCountsRule:
+    """``grid_loop``'s contract with the benchmark's span readers, pinned on
+    a fake result per solver family: which fields land on the ``solver``
+    span, under which names and types, and which counters they feed."""
+
+    @pytest.mark.parametrize("family", sorted(FAMILY_FIELDS))
+    def test_span_attributes_and_counters(self, family):
+        filled, attrs, counters = FAMILY_FIELDS[family]
+        res = SolveResult(
+            w=jnp.arange(3, dtype=jnp.float32), value=jnp.float32(1.5),
+            grad=jnp.zeros(3), iterations=jnp.int32(7),
+            converged=jnp.bool_(True), values=jnp.zeros(8),
+            grad_norms=jnp.zeros(8),
+            **{k: (jnp.bool_(v) if isinstance(v, bool) else jnp.int32(v))
+               for k, v in filled.items()},
+        )
+        tel = telemetry.Telemetry(enabled=True, sinks=[])
+        prev = telemetry.set_current(tel)
+        mark = _mark()
+        try:
+            results = _problem().grid_loop(lambda lam, w: res, GRID)
+        finally:
+            telemetry.set_current(prev)
+        solvers = _named(_since(mark), "solver")
+        assert len(solvers) == len(results) == len(GRID)
+        for s, lam in zip(solvers, sorted(GRID, reverse=True)):
+            assert s["attrs"] == {
+                "reg_weight": lam, "optimizer": "lbfgs", "iterations": 7,
+                "converged": True, "wall_seconds": s["dur"], **attrs,
+            }
+            assert all(type(s["attrs"][k]) is type(v)
+                       for k, v in attrs.items())
+        snap = tel.snapshot()
+        assert {k: v for k, v in snap["counters"].items()
+                if k.startswith("solver_")} == {
+            k: v * len(GRID) for k, v in counters.items()}
+        assert snap["histograms"]["solver_wall_seconds"]["count"] == len(GRID)
 
 
 class TestProfiler:

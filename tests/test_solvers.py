@@ -2,10 +2,10 @@
 
 Three pillars:
 
-1. **Registry** — dispatch mechanics, legacy-routing reproduction (an
-   unset ``OptimizerConfig.solver`` must be BITWISE identical to the
-   pre-registry static if-chains on the resident, streamed, and
-   distributed paths), and the static compatibility guards.
+1. **Solver choice** — ``optim.problem.choose_solver``'s legacy routing
+   (an unset ``OptimizerConfig.solver`` must be BITWISE identical to the
+   explicit name on the resident, streamed, and distributed paths), its
+   static compatibility guards, and ``solvers.HOST_SOLVERS``.
 2. **Host-kind solvers** — consensus-ADMM (L-BFGS and cached-eigh ridge
    x-updates, logical shards AND the 8-virtual-device mesh) and
    drift-corrected distributed block CD converge to the same optimum as
@@ -23,15 +23,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from photon_ml_tpu import chaos
+from photon_ml_tpu import chaos, solvers
 from photon_ml_tpu import telemetry as telemetry_mod
 from photon_ml_tpu.data.dataset import make_glm_data
 from photon_ml_tpu.io.checkpoint import GridCheckpointer
 from photon_ml_tpu.optim.problem import (
+    DEVICE_SOLVERS,
+    HOST_LOOP_SOLVERS,
     GlmOptimizationConfig,
     GlmOptimizationProblem,
     OptimizerConfig,
     OptimizerType,
+    choose_solver,
 )
 from photon_ml_tpu.optim.regularization import RegularizationContext
 from photon_ml_tpu.parallel.distributed import (
@@ -39,7 +42,6 @@ from photon_ml_tpu.parallel.distributed import (
     run_grid_distributed,
     shard_glm_data,
 )
-from photon_ml_tpu.solvers import registry
 from photon_ml_tpu.solvers import sharded as solvers_sharded
 from photon_ml_tpu.utils.watchdog import RetryPolicy, run_with_retries
 
@@ -96,69 +98,51 @@ def _objective_value(problem, data, w, lam):
 
 
 # ---------------------------------------------------------------------------
-# Registry mechanics
+# The solver choice
 # ---------------------------------------------------------------------------
 
 class TestRegistry:
     def test_builtins_registered(self):
-        assert {"lbfgs", "owlqn", "tron", "spg", "admm", "block_cd"} <= set(
-            registry.names()
-        )
-
-    def test_duplicate_refused_replace_allowed(self):
-        defn = registry.SolverDef(
-            name="scratch_test_solver", kind="jit",
-            description="test double", resident=lambda ctx: None,
-        )
-        registry.register(defn)
-        with pytest.raises(ValueError, match="already registered"):
-            registry.register(defn)
-        registry.register(defn, replace=True)  # tests may swap doubles
-        assert registry.get("scratch_test_solver") is defn
-
-    def test_def_validation(self):
-        with pytest.raises(ValueError, match="kind"):
-            registry.SolverDef(name="x", kind="weird", description="")
-        with pytest.raises(ValueError, match="resident"):
-            registry.SolverDef(name="x", kind="jit", description="")
-        with pytest.raises(ValueError, match="sharded"):
-            registry.SolverDef(name="x", kind="host", description="")
+        assert set(DEVICE_SOLVERS + HOST_LOOP_SOLVERS) == {
+            "lbfgs", "owlqn", "tron", "spg", "admm", "block_cd"}
+        assert set(solvers.HOST_SOLVERS) == set(HOST_LOOP_SOLVERS)
+        assert all(map(callable, solvers.HOST_SOLVERS.values()))
 
     def test_unknown_name(self):
         with pytest.raises(KeyError, match="unknown solver"):
-            registry.get("levenberg")
+            choose_solver(OptimizerConfig(solver="levenberg"), l1_frac=0.0)
 
     def test_legacy_routing(self):
         opt = OptimizerConfig(optimizer=OptimizerType.TRON)
-        assert registry.resolve(opt, l1_frac=0.0).name == "tron"
-        assert registry.resolve(opt, l1_frac=0.5).name == "owlqn"
-        assert registry.resolve(
-            opt, l1_frac=0.0, has_bounds=True
-        ).name == "spg"
+        assert choose_solver(opt, l1_frac=0.0) == "tron"
+        assert choose_solver(opt, l1_frac=0.5) == "owlqn"
+        assert choose_solver(opt, l1_frac=0.0, has_bounds=True) == "spg"
+        assert choose_solver(OptimizerConfig(), l1_frac=0.0) == "lbfgs"
+        assert choose_solver(
+            OptimizerConfig(optimizer=OptimizerType.OWLQN), l1_frac=0.0
+        ) == "owlqn"
 
     def test_explicit_name_guards(self):
         lbfgs = OptimizerConfig(solver="lbfgs")
         with pytest.raises(ValueError, match="no L1 subgradient"):
-            registry.resolve(lbfgs, l1_frac=0.5)
+            choose_solver(lbfgs, l1_frac=0.5)
         with pytest.raises(ValueError, match="box constraints"):
-            registry.resolve(lbfgs, l1_frac=0.0, has_bounds=True)
+            choose_solver(lbfgs, l1_frac=0.0, has_bounds=True)
         with pytest.raises(ValueError, match="needs box constraints"):
-            registry.resolve(
-                OptimizerConfig(solver="spg"), l1_frac=0.0
-            )
+            choose_solver(OptimizerConfig(solver="spg"), l1_frac=0.0)
         admm = OptimizerConfig(solver="admm")
-        assert registry.resolve(admm, l1_frac=0.5).name == "admm"
+        assert choose_solver(admm, l1_frac=0.5) == "admm"
         with pytest.raises(ValueError, match="box constraints"):
-            registry.resolve(admm, l1_frac=0.0, has_bounds=True)
+            choose_solver(admm, l1_frac=0.0, has_bounds=True)
+        assert choose_solver(
+            OptimizerConfig(solver="tron"), l1_frac=0.0) == "tron"
 
     def test_solver_options_dict(self):
         opt = OptimizerConfig(
             solver="admm", solver_options=(("rho", "0.5"), ("shards", "4"))
         )
-        assert registry.solver_options_dict(opt) == {
-            "rho": "0.5", "shards": "4"
-        }
-        assert registry.solver_options_dict(OptimizerConfig()) == {}
+        assert opt.solver_options_dict() == {"rho": "0.5", "shards": "4"}
+        assert OptimizerConfig().solver_options_dict() == {}
 
     def test_host_kind_rejected_in_traced_solve(self, rng):
         X, y = _make_xy(rng)
@@ -171,13 +155,43 @@ class TestRegistry:
 
 
 # ---------------------------------------------------------------------------
-# Registry dispatch = pre-registry routing, bitwise
+# An explicit name = the legacy routing, bitwise
 # ---------------------------------------------------------------------------
+
+def _run_driver(tmp_path, X, y, *flags):
+    """glm_driver over a LIBSVM copy of (X, y); returns (result, the saved
+    coefficients by λ, the run's metrics.json counters)."""
+    import json
+    import os
+
+    import scipy.sparse as sp
+
+    from photon_ml_tpu.data import libsvm
+    from photon_ml_tpu.drivers import glm_driver
+    from photon_ml_tpu.io.model_store import load_glm_model
+
+    train = str(tmp_path / "train.libsvm")
+    if not os.path.exists(train):
+        libsvm.write_libsvm(train, sp.csr_matrix(X), np.where(y > 0, 1.0, -1.0))
+    out = str(tmp_path / "_".join(f.strip("-") for f in flags) or "plain")
+    result = glm_driver.run([
+        "--train-data", train, "--output-dir", out, "--task", "logistic",
+        "--n-features", str(X.shape[1]), "--output-mode", "all", *flags,
+    ])
+    coefs = {
+        lam: np.asarray(load_glm_model(os.path.join(
+            out, f"model_lambda_{float(lam):g}.avro"))[0].coefficients.means)
+        for lam in result["objective_values"]
+    }
+    with open(os.path.join(out, "metrics.json")) as f:
+        counters = json.load(f)["counters"]
+    return result, coefs, counters
+
 
 class TestDispatchParity:
     """An EXPLICIT solver name must be bitwise identical to the implicit
-    legacy routing on every execution path (the registry builds exactly
-    the closures the static if-chains built)."""
+    legacy routing on every execution path (``choose_solver`` names what
+    the routing would have picked, and the same solve runs)."""
 
     @pytest.mark.parametrize("name,optimizer,reg", [
         ("lbfgs", OptimizerType.LBFGS, RegularizationContext.l2()),
@@ -216,22 +230,47 @@ class TestDispatchParity:
                 m_i.coefficients.means, m_e.coefficients.means
             )
 
-    def test_distributed_bitwise(self, rng, eight_devices):
+    def test_distributed_bitwise(self, rng, tmp_path, eight_devices):
+        """Through glm_driver, which chooses the grid: on the mesh the
+        implicit routing and ``--solver owlqn`` run the same shard_map
+        solve."""
         X, y = _make_xy(rng)
+        flags = ("--data-parallel", "auto", "--reg-type", "elastic_net",
+                 "--reg-weights", "0.1")
+        imp, coefs_i, _ = _run_driver(tmp_path, X, y, *flags)
+        exp, coefs_e, counters = _run_driver(
+            tmp_path, X, y, *flags, "--solver", "owlqn")
+        assert imp["objective_values"] == exp["objective_values"]
+        assert coefs_i.keys() == coefs_e.keys()
+        for lam in coefs_i:
+            assert _bitwise_equal(coefs_i[lam], coefs_e[lam])
+        assert "solvers_sharded_solves_total" not in counters
+
+    def test_driver_runs_host_loop_on_the_mesh(self, rng, tmp_path,
+                                               eight_devices):
+        """``--solver admm --data-parallel auto``: the driver hands the
+        mesh to the host-loop grid, which lands on OWL-QN's optimum."""
+        X, y = _make_xy(rng, n=256, d=6)
+        flags = ("--data-parallel", "auto", "--reg-type", "elastic_net",
+                 "--reg-weights", "0.2", "--max-iters", "150",
+                 "--tolerance", "1e-8")
+        ref, _, _ = _run_driver(tmp_path, X, y, *flags)
+        res, _, counters = _run_driver(
+            tmp_path, X, y, *flags, "--solver", "admm",
+            "--solver-option", "reltol=1e-6")
+        assert counters["solvers_sharded_solves_total"] == 1
+        (f_ref,) = ref["objective_values"].values()
+        (f_admm,) = res["objective_values"].values()
+        assert abs(f_admm - f_ref) / max(1.0, abs(f_ref)) <= 1e-4
+
+    def test_distributed_grid_refuses_host_loop(self, rng, eight_devices):
+        X, y = _make_xy(rng, n=80, d=4)
         mesh = data_mesh(eight_devices)
         dist = shard_glm_data(X, y, mesh)
-        reg = RegularizationContext.elastic_net(0.5)
-        grid = [0.1]
-        imp = run_grid_distributed(
-            _make_problem(reg=reg), dist, mesh, grid
-        )
-        exp = run_grid_distributed(
-            _make_problem(reg=reg, solver="owlqn"), dist, mesh, grid
-        )
-        for (_, m_i, _), (_, m_e, _) in zip(imp, exp):
-            assert _bitwise_equal(
-                m_i.coefficients.means, m_e.coefficients.means
-            )
+        problem = _make_problem(
+            reg=RegularizationContext.elastic_net(0.5), solver="admm")
+        with pytest.raises(ValueError, match="host-side outer loop"):
+            run_grid_distributed(problem, dist, mesh, [0.1])
 
 
 # ---------------------------------------------------------------------------
@@ -301,7 +340,7 @@ class TestADMM:
         mesh = data_mesh(eight_devices)
         problem = _make_problem(reg=reg, solver="admm", solver_options=opts)
         dist_mesh = shard_glm_data(X, y, mesh)
-        [(_, m_mesh, _)] = run_grid_distributed(
+        [(_, m_mesh, _)] = solvers_sharded.run_grid_sharded(
             problem, dist_mesh, mesh, [0.2]
         )
         dist_log = shard_glm_data(X, y, None, n_shards=8)
@@ -408,7 +447,7 @@ class TestBlockCD:
             reg=reg, solver="block_cd", solver_options=opts
         )
         dist_mesh = shard_glm_data(X, y, mesh)
-        [(_, m_mesh, _)] = run_grid_distributed(
+        [(_, m_mesh, _)] = solvers_sharded.run_grid_sharded(
             problem, dist_mesh, mesh, [0.2]
         )
         dist_log = shard_glm_data(X, y, None, n_shards=8)
